@@ -10,7 +10,7 @@
 # dir is left alone (wipe with `rm -rf $(JAX_CACHE_DIR)` if a run dies with
 # a faulthandler dump, then re-seed). CI: store the artifact, `make
 # cache-seed test`. See docs/usage_guides/testing.md for measured times.
-JAX_CACHE_DIR ?= /tmp/accelerate_tpu_jax_cache
+JAX_CACHE_DIR ?= $(or $(JAX_COMPILATION_CACHE_DIR),.cache/jax)
 JAX_CACHE_ARTIFACT ?= .cache/jax_compile_cache.tar.gz
 
 cache-pack:

@@ -120,17 +120,29 @@ def device_generation(device=None) -> Optional[str]:
 
 
 def peak_flops(generation: str, dtype: str = "bf16") -> float:
-    """Peak FLOP/s per device for ``generation``; unknown generations fall
-    back to v5e (the cost-optimised part — a conservative denominator).
-    ``cpu`` has its own explicit (nominal) row."""
-    row = PEAK_FLOPS_TABLE.get(generation, PEAK_FLOPS_TABLE["v5e"])
+    """Peak FLOP/s per device for ``generation`` (a key of
+    ``PEAK_FLOPS_TABLE``; ``cpu`` has its own explicit nominal row). A
+    generation the table does not know is an error: a utilisation priced
+    against some other chip's peak is a wrong number, not a conservative
+    one. Offline analyzers that want a default name one (``"v5e"``)."""
+    try:
+        row = PEAK_FLOPS_TABLE[generation]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s known for generation {generation!r} (known: {sorted(PEAK_FLOPS_TABLE)})"
+        ) from None
     return row.get(dtype, row["bf16"])
 
 
 def hbm_bandwidth(generation: str) -> float:
-    """HBM bytes/second per device for ``generation`` (v5e fallback for
-    unknown generations, explicit ``cpu`` row for the host backend)."""
-    return HBM_BW_TABLE.get(generation, HBM_BW_TABLE["v5e"])
+    """HBM bytes/second per device for ``generation``; raises for one the
+    table does not know (explicit ``cpu`` row for the host backend)."""
+    try:
+        return HBM_BW_TABLE[generation]
+    except KeyError:
+        raise ValueError(
+            f"no HBM bandwidth known for generation {generation!r} (known: {sorted(HBM_BW_TABLE)})"
+        ) from None
 
 
 def vmem_bytes(generation: str) -> int:

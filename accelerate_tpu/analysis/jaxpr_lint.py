@@ -69,7 +69,7 @@ def _jax():
 def _iter_subjaxprs(params: dict):
     """Yield every (Closed)Jaxpr nested in an equation's params —
     pjit/shard_map bodies, scan/while/cond branches."""
-    from jax import core
+    from jax.extend import core
 
     def coerce(v):
         if isinstance(v, core.ClosedJaxpr):

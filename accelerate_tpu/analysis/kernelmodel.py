@@ -155,7 +155,12 @@ def _index_map_fn(index_map_jaxpr, n_args: int) -> Optional[Callable]:
 
 def _block_info(bm, n_grid: int) -> BlockInfo:
     aval = getattr(bm, "array_shape_dtype", None)
-    block_shape = tuple(getattr(bm, "block_shape", ()) or ())
+    # block dims arrive wrapped: Blocked(n) / Element(n) carry the size,
+    # Squeezed() is a dim the kernel does not see (None here)
+    block_shape = tuple(
+        int(b.block_size) if hasattr(b, "block_size") else (b if isinstance(b, int) else None)
+        for b in getattr(bm, "block_shape", ()) or ()
+    )
     array_shape = tuple(getattr(aval, "shape", ()) or ())
     dtype = str(getattr(aval, "dtype", ""))
     import numpy as np

@@ -26,6 +26,7 @@ from .cache import (
     backend_descriptor,
     configure_persistent_cache,
     content_key,
+    default_compile_cache_dir,
     deserialize_compiled,
     resolve_cache_dir,
     serialize_compiled,
@@ -41,6 +42,7 @@ __all__ = [
     "backend_descriptor",
     "configure_persistent_cache",
     "content_key",
+    "default_compile_cache_dir",
     "default_program_cache",
     "deserialize_compiled",
     "next_pow2",

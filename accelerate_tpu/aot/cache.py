@@ -28,7 +28,7 @@ Entry layout (one file per program, ``<key>.aotx``)::
 
     ATPX1\\n
     {"key": ..., "name": ..., "crc32": ..., "size": ..., "jax": ...}\\n
-    <pickled (xla payload, in_tree, out_tree)>
+    <pickled (xla payload, in_tree, out_tree, device ids)>
 
 Writes are atomic (tmp + rename) so a killed process never publishes a
 half-written entry.
@@ -99,21 +99,30 @@ def content_key(lowered, extra=()) -> str:
 
 
 def serialize_compiled(compiled) -> bytes:
-    """A compiled executable -> storable bytes (XLA payload + the arg
-    pytree defs ``deserialize_and_load`` needs on the other side)."""
+    """A compiled executable -> storable bytes (XLA payload, the arg
+    pytree defs ``deserialize_and_load`` needs on the other side, and the
+    ids of the devices the program was compiled for)."""
     from jax.experimental import serialize_executable
 
     payload, in_tree, out_tree = serialize_executable.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree), protocol=4)
+    device_ids = [d.id for d in compiled._executable._unloaded_executable.device_list]
+    return pickle.dumps((payload, in_tree, out_tree, device_ids), protocol=4)
 
 
 def deserialize_compiled(blob: bytes):
     """Inverse of :func:`serialize_compiled`: bytes -> a loaded, callable
-    executable (no XLA compile happens here)."""
+    executable (no XLA compile happens here). The program is loaded onto
+    the devices it was compiled for: left to itself, jax loads it onto
+    every device of the backend, and a one-device program then refuses its
+    arguments on a host with several."""
+    import jax
     from jax.experimental import serialize_executable
 
-    payload, in_tree, out_tree = pickle.loads(blob)
-    return serialize_executable.deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return serialize_executable.deserialize_and_load(
+        payload, in_tree, out_tree, execution_devices=[by_id[i] for i in device_ids]
+    )
 
 
 class ExecutableStore:
@@ -301,29 +310,37 @@ def resolve_cache_dir(
     return None
 
 
-_persistent_configured: list = []  # one-shot latch (per process)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def configure_persistent_cache(cache_dir: str, min_compile_time_secs: float = 0.0) -> bool:
-    """Point jax's persistent XLA compilation cache at ``cache_dir``.
+def default_compile_cache_dir() -> str:
+    """Where jax's persistent compilation cache lives when nobody placed it:
+    ``JAX_COMPILATION_CACHE_DIR`` if the environment sets it, else
+    ``<checkout>/.cache/jax`` resolved from this package's location. The
+    directory is part of the cache's key, so it is a fixed path — never a
+    temporary name, a pid or the time."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(_CHECKOUT, ".cache", "jax")
 
-    Respects an existing configuration: if the process (or the
-    environment via ``JAX_COMPILATION_CACHE_DIR``) already chose a cache
-    dir, that choice wins — silently re-pointing a shared cache
-    mid-process would split the warm set. Returns True when THIS call
-    did the configuring."""
+
+def configure_persistent_cache(cache_dir: Optional[str] = None, min_compile_time_secs: float = 0.0) -> str:
+    """Turn on jax's persistent XLA compilation cache and return the
+    directory in effect. The ONE place in the tree that sets it.
+
+    Yields to an existing choice: where ``JAX_COMPILATION_CACHE_DIR`` is
+    set, jax already reads it and nothing is set in code; where the
+    process configured a directory earlier, that one stays (re-pointing
+    a shared cache mid-process would split the warm set). Otherwise the
+    cache goes to ``cache_dir`` or :func:`default_compile_cache_dir`."""
     import jax
 
-    already = getattr(jax.config, "jax_compilation_cache_dir", None)
-    if already or os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return False
-    if _persistent_configured:
-        return False
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    already = jax.config.jax_compilation_cache_dir
+    if already:
+        return already
+    cache_dir = cache_dir or default_compile_cache_dir()
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", float(min_compile_time_secs))
-    except Exception:  # older jax: flag spelled differently; dir alone still works
-        pass
-    _persistent_configured.append(cache_dir)
-    return True
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", float(min_compile_time_secs))
+    return cache_dir

@@ -173,22 +173,21 @@ class ProgramCache:
         return compiled
 
     def _compile_fresh(self, lowered):
-        """``lowered.compile()``, bypassing jax's persistent XLA cache when
-        an executable store is attached: XLA:CPU executables *restored
-        from that disk cache* serialize into blobs that fail to load
-        ("Symbols not found" at deserialize) — only a fresh compile
-        yields a serializable executable. The one-time cost (no XLA-cache
-        shortcut on the very first build of a program) buys every later
-        process a zero-compile deserialize, which is strictly cheaper
-        than the XLA cache hit it forgoes."""
-        if self.store is None or self._serialize_broken:
-            return lowered.compile()
+        """``lowered.compile()``, asking jax to leave its persistent XLA
+        cache out of it when an executable store is attached: XLA:CPU
+        executables *restored from that disk cache* serialize into blobs
+        that fail to load ("Symbols not found" / "Function ... not found")
+        — only a fresh compile yields a serializable executable there. On
+        the TPU restored executables serialize into blobs that load (PR 21
+        chip run: 14 + 5 store entries written from cache hits, all
+        deserialized, 0 rejected). Under the installed jax the flag is
+        latched at the process's first compile
+        (``compilation_cache.is_cache_used``), so this request only takes
+        effect in a process whose first compile is this one. A CPU process
+        that must ship loadable blobs turns the cache off for itself before
+        its first compile (``serving_proc.worker_main``)."""
         jax = _jax()
-        try:
-            prev = bool(jax.config.jax_enable_compilation_cache)
-        except AttributeError:  # ancient jax: no flag, nothing to bypass
-            return lowered.compile()
-        if not prev:
+        if self.store is None or self._serialize_broken or not jax.config.jax_enable_compilation_cache:
             return lowered.compile()
         jax.config.update("jax_enable_compilation_cache", False)
         try:

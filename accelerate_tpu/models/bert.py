@@ -184,7 +184,8 @@ def create_bert_model(
         "input_ids": jnp.zeros((batch_size, seq_len), jnp.int32),
         "attention_mask": jnp.ones((batch_size, seq_len), jnp.bool_),
     }
-    params = module.init(jax.random.key(seed), dummy["input_ids"], dummy["attention_mask"])["params"]
+    # one jitted program: an eager init runs the whole forward op-by-op
+    params = jax.jit(module.init)(jax.random.key(seed), dummy["input_ids"], dummy["attention_mask"])["params"]
 
     def apply_fn(p, input_ids, attention_mask, token_type_ids=None, deterministic=True, rngs=None):
         if not deterministic and rngs is None:

@@ -655,11 +655,27 @@ def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> 
     return model
 
 
-def create_llama_model(config: Optional[LlamaConfig] = None, seed: int = 0, seq_len: int = 128) -> Model:
+def create_llama_model(
+    config: Optional[LlamaConfig] = None, seed: int = 0, seq_len: int = 128, dtype=None
+) -> Model:
+    """Seeded random-weight model. The init is ONE jitted program on the
+    default device (an eager ``module.init`` runs the whole forward
+    op-by-op, which a full-width config cannot afford on a chip);
+    ``dtype`` casts the float params inside that program, so a bf16 model
+    never holds its float32 copy."""
     config = config or LlamaConfig.tiny()
     module = LlamaModel(config)
-    dummy = jnp.zeros((2, seq_len), jnp.int32)
-    variables = module.init(jax.random.key(seed), dummy)
+
+    def init(key):
+        variables = module.init(key, jnp.zeros((2, seq_len), jnp.int32))
+        if dtype is not None:
+            variables["params"] = jax.tree.map(
+                lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x,
+                variables["params"],
+            )
+        return variables
+
+    variables = jax.jit(init)(jax.random.key(seed))
     params = variables["params"]
     state = {k: v for k, v in variables.items() if k != "params"} or None
     return _wrap_llama(module, params, config, state=state)

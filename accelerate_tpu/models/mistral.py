@@ -76,7 +76,9 @@ class MistralConfig(LlamaConfig):
         return cls(**kw)
 
 
-def create_mistral_model(config: Optional[MistralConfig] = None, seed: int = 0, seq_len: int = 128):
+def create_mistral_model(
+    config: Optional[MistralConfig] = None, seed: int = 0, seq_len: int = 128, dtype=None
+):
     """A :class:`~accelerate_tpu.modeling.Model` running the llama module
     with the Mistral band mask (config.sliding_window)."""
-    return create_llama_model(config or MistralConfig.tiny(), seed=seed, seq_len=seq_len)
+    return create_llama_model(config or MistralConfig.tiny(), seed=seed, seq_len=seq_len, dtype=dtype)

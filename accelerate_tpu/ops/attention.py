@@ -162,7 +162,7 @@ def sharded_pallas_attention(
     # Already inside a shard_map region (e.g. the GPipe trunk): inputs are
     # per-shard blocks and axes are Manual — nesting another shard_map over
     # the same mesh is an error; the bare kernel is exactly right here.
-    from ..utils.compat import in_manual_region, shard_map
+    from ..utils.compat import in_manual_region
 
     if in_manual_region():
         return kernel(q, k, v)
@@ -189,7 +189,7 @@ def sharded_pallas_attention(
     from jax.sharding import PartitionSpec as P
 
     spec = P(bspec, None, hspec, None)
-    fn = shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
+    fn = jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 

@@ -153,9 +153,23 @@ def paged_cached_attention(
         if run is not None:  # None: TP mesh the heads can't split -> XLA path
             return run(q[:, 0], kp.value, vp.value, bt.value, cur)[:, None]
 
+    return paged_gather_attention(
+        q, kp.value, vp.value, bt.value, cur, scale=scale, sliding_window=sliding_window
+    )
+
+
+def paged_gather_attention(q, key_pool, value_pool, block_table, cur, *, scale, sliding_window=None):
+    """The plain XLA paged decode step: gather each row's pages into a
+    contiguous copy and attend to it. ``q`` is ``[B, 1, H, D]``, the pools
+    ``[NB, bs, H_kv, D]``, ``block_table`` ``[B, MB]`` and ``cur`` the
+    per-row frontier ``[B]``; returns ``[B, 1, H, D]``. What the Pallas
+    kernel is checked against, and what runs where it cannot."""
+    b = q.shape[0]
+    _, bs_, h_kv, d = key_pool.shape
+    mb = block_table.shape[1]
     # gather each row's pages: [B, MB, bs, H_kv, D] -> [B, L, H_kv, D]
-    k_all = kp.value[bt.value].reshape(b, mb * bs_, h_kv, d)
-    v_all = vp.value[bt.value].reshape(b, mb * bs_, h_kv, d)
+    k_all = key_pool[block_table].reshape(b, mb * bs_, h_kv, d)
+    v_all = value_pool[block_table].reshape(b, mb * bs_, h_kv, d)
     key_pos = jnp.arange(mb * bs_)
     live = key_pos[None, :] <= cur[:, None]  # [B, L] causal frontier per row
     if sliding_window is not None:
@@ -186,7 +200,7 @@ def _kernel_runner(fn, heads: int, kv_heads: int):
     non-trivial tensor axis is active (or we're already inside a
     shard_map region), and None when heads don't divide the axis — the
     caller then uses the XLA gather path, which partitions naturally."""
-    from ..utils.compat import in_manual_region, shard_map
+    from ..utils.compat import in_manual_region
 
     if in_manual_region():
         return fn
@@ -204,7 +218,7 @@ def _kernel_runner(fn, heads: int, kv_heads: int):
         return None
     qspec = P(None, "tensor", None)
     pspec = P(None, None, "tensor", None)
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(qspec, pspec, pspec, P(None, None), P(None)),

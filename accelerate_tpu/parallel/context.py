@@ -158,9 +158,7 @@ def context_parallel_attention(
     spec = P(bspec, axis_name, None, None)
     local = _ring_attention_local if method == "ring" else _ulysses_attention_local
 
-    from ..utils.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(local, axis_name=axis_name, causal=causal, scale=scale, window=window),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -101,16 +101,10 @@ class MeshConfig:
             devices = list(devices)[: self.num_devices]
         sizes = self.sizes(len(devices))
         shape = tuple(sizes[a] for a in AXIS_NAMES)
-        # Auto axis types = classic GSPMD propagation (jax>=0.9 defaults new
+        # Auto axis types = classic GSPMD propagation (jax defaults new
         # meshes to Explicit sharding-in-types, which changes jit semantics)
-        try:
-            axis_types = (jax.sharding.AxisType.Auto,) * len(AXIS_NAMES)
-            return jax.make_mesh(shape, AXIS_NAMES, devices=devices, axis_types=axis_types)
-        except (AttributeError, TypeError):
-            # jax < 0.6 has no AxisType (meshes are implicitly Auto) and older
-            # make_mesh signatures lack axis_types — same GSPMD semantics
-            mesh_devices = np.asarray(devices).reshape(shape)
-            return jax.sharding.Mesh(mesh_devices, AXIS_NAMES)
+        axis_types = (jax.sharding.AxisType.Auto,) * len(AXIS_NAMES)
+        return jax.make_mesh(shape, AXIS_NAMES, devices=devices, axis_types=axis_types)
 
     @classmethod
     def from_env(cls) -> "MeshConfig":

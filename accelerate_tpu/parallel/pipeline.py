@@ -220,9 +220,7 @@ def pipeline_apply(
             getattr(a, "ndim", 0) >= 1 and a.shape[0] == x.shape[0] for a in broadcast_args
         )
     arg_specs = tuple(x_spec if b else P() for b in batched_arg_mask)
-    from ..utils.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _gpipe_local,
             layer_fn=layer_fn,

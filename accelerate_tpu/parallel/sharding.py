@@ -130,9 +130,18 @@ def infer_shardings(tree: Any, rules: Rules, mesh: Mesh, *, default: PartitionSp
     return jax.tree_util.tree_map_with_path(to_sharding, tree)
 
 
-def fsdp_rules_for(tree: Any, mesh: Mesh, axis: str = "fsdp", *, min_size: int = 2**12) -> Rules:
+def fsdp_rules_for(
+    tree: Any, mesh: Mesh, axis: str = "fsdp", *, min_size: int = 2**12, base_rules: Rules = ()
+) -> Rules:
     """Auto-generate ZeRO-3-style rules: for every leaf above ``min_size``
     elements, shard its largest ``axis``-divisible dimension.
+
+    ``base_rules`` (a model's tensor-parallel rules) compose instead of
+    competing: a leaf keeps the spec they give it and ``axis`` takes the
+    largest dimension that spec leaves unsharded, so on an
+    ``fsdp x tensor`` mesh a kernel is split over both axes. The rules
+    returned name exact paths; put them BEFORE ``base_rules`` (first match
+    wins).
 
     Replaces the reference's FSDP auto-wrap policy + flat-param machinery
     (reference: accelerator.py:1694-1750) — under GSPMD no wrapping is
@@ -146,17 +155,22 @@ def fsdp_rules_for(tree: Any, mesh: Mesh, axis: str = "fsdp", *, min_size: int =
         shape = getattr(leaf, "shape", ())
         if int(np.prod(shape or (0,))) < min_size:
             continue
-        # largest divisible dim, ties broken toward the last (contraction-
-        # friendly) dimension
+        path = path_str(key_path)
+        base = spec_for_path(path, base_rules) or PartitionSpec()
+        spec = list(_prune_spec(base, len(shape), shape, mesh))
+        spec += [None] * (len(shape) - len(spec))
+        if any(axis in (e if isinstance(e, tuple) else (e,)) for e in spec if e is not None):
+            continue
+        # largest free divisible dim, ties broken toward the last
+        # (contraction-friendly) dimension
         best = None
         for i, d in enumerate(shape):
-            if d % n == 0 and (best is None or d >= shape[best]):
+            if spec[i] is None and d % n == 0 and (best is None or d >= shape[best]):
                 best = i
         if best is None:
             continue
-        spec = [None] * len(shape)
         spec[best] = axis
-        rules.append((f"^{re.escape(path_str(key_path))}$", PartitionSpec(*spec)))
+        rules.append((f"^{re.escape(path)}$", PartitionSpec(*spec)))
     return rules
 
 

@@ -249,9 +249,12 @@ class EngineWorker:
     # ------------------------------------------------------------------ #
 
     def hello(self) -> dict:
+        import jax
+
         per_tok = fixed = 0
         if not self.engine.paged and self.engine.draft_model is None:
             per_tok, fixed = self.engine.kv_handoff_dims()
+        devices = jax.devices()
         return {
             "op": "hello",
             "worker": self.name,
@@ -264,6 +267,13 @@ class EngineWorker:
             "kv_fixed_bytes": int(fixed),
             "max_len": int(self.engine.max_len),
             "vocab_size": int(self.engine.model.config.vocab_size),
+            # the device this worker serves from, as jax reports it: the
+            # front door shows it in /healthz so nobody has to guess
+            "device": {
+                "platform": devices[0].platform,
+                "kind": devices[0].device_kind,
+                "count": len(devices),
+            },
         }
 
     def _busy(self) -> bool:
@@ -324,6 +334,7 @@ class EngineWorker:
             "snaps": meta,
             "compiles": int(self.engine.program_cache.misses),
             "deserialized": int(self.engine.program_cache.deserialized),
+            "rejected": int(self.engine.program_cache.rejected),
             "fault": fault,
             "metrics": self._metrics_snapshot(),
         }
@@ -468,21 +479,32 @@ def worker_main(spec_path: str) -> int:
     ``hits`` countdown must index served traffic, not boot-time warmup."""
     with open(spec_path) as f:
         spec = json.load(f)
-    from .utils.environment import force_host_platform
-
-    force_host_platform(int(spec.get("host_devices", 1)))
-    # The shared ExecutableStore is this process's zero-compile path; jax's
-    # own persistent compilation cache must stay OFF here. The poison is
-    # process-global: once ANY executable has been restored from that
-    # cache, every LATER fresh compile in the process serializes into a
-    # blob that fails to load elsewhere ("Symbols not found"), so the
-    # per-compile bypass in ProgramCache cannot contain it — and a worker
-    # that ships unloadable blobs silently costs every future incarnation
-    # its warm start.
-    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
     import jax
 
-    jax.config.update("jax_enable_compilation_cache", False)
+    if spec.get("host_devices"):
+        # the caller asked for the host platform (JAX_PLATFORMS=cpu in the
+        # supervisor's environment: tests, CPU rehearsals)
+        from .utils.environment import force_host_platform
+
+        force_host_platform(int(spec["host_devices"]))
+        # The shared ExecutableStore is this process's zero-compile path;
+        # on XLA:CPU jax's own persistent compilation cache must stay OFF
+        # beside it. The poison is process-global there: once ANY
+        # executable has been restored from that cache, every LATER fresh
+        # compile in the process serializes into a blob that fails to load
+        # elsewhere ("Symbols not found"), so the per-compile bypass in
+        # ProgramCache cannot contain it — and a worker that ships
+        # unloadable blobs silently costs every future incarnation its
+        # warm start.
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+        jax.config.update("jax_enable_compilation_cache", False)
+    else:
+        # the platform this process was given (a chip): nothing is forced,
+        # and whatever the store does not hold compiles through jax's
+        # persistent cache
+        from .aot import configure_persistent_cache
+
+        configure_persistent_cache()
     from .test_utils.fault_injection import ReplicaChaos
 
     worker = EngineWorker(spec)
@@ -611,13 +633,8 @@ class ProcessSupervisor:
             "warm_prompt_lens": list(cfg.warm_prompt_lens),
             "warm_max_new_tokens": cfg.warm_max_new_tokens,
             "seed": cfg.seed,
-            "host_devices": 1,
         }
-        spec_path = os.path.join(self.run_dir, f"worker_{name}.json")
-        with open(spec_path, "w") as f:
-            json.dump(spec, f)
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         pkg_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
         env.pop(PROC_CHAOS_ENV, None)
@@ -625,6 +642,14 @@ class ProcessSupervisor:
             env[PROC_CHAOS_ENV] = json.dumps(cfg.chaos)
         if cfg.worker_env:
             env.update(cfg.worker_env)
+        # The worker runs on the platform this environment gives it. It is
+        # put on the host (one virtual device) only where the environment
+        # asks for the CPU; nothing here replaces an attached accelerator.
+        if env.get("JAX_PLATFORMS", "").strip() == "cpu":
+            spec["host_devices"] = 1
+        spec_path = os.path.join(self.run_dir, f"worker_{name}.json")
+        with open(spec_path, "w") as f:
+            json.dump(spec, f)
         log_path = os.path.join(self.run_dir, f"worker_{name}.log")
         with open(log_path, "ab") as out:
             slot["proc"] = subprocess.Popen(
@@ -976,6 +1001,7 @@ class ProcessSupervisor:
             "queue": reply.get("queue", 0), "active": reply.get("active", 0),
             "busy": reply.get("busy", False), "compiles": reply.get("compiles", 0),
             "deserialized": reply.get("deserialized", 0),
+            "rejected": reply.get("rejected", 0),
             "metrics": reply.get("metrics", {}),
         }
         # progress → published streams
@@ -1237,9 +1263,11 @@ class ProcessSupervisor:
                 "health": slot["health"], "reason": slot["reason"],
                 "slot": slot["slot"], "respawns": slot["respawns"],
                 "pid": slot["proc"].pid if slot["proc"] else None,
+                "device": (slot["hello"] or {}).get("device"),
                 "outstanding": len(slot["uids"]),
                 "compiles": (slot.get("status") or {}).get("compiles"),
                 "deserialized": (slot.get("status") or {}).get("deserialized"),
+                "rejected": (slot.get("status") or {}).get("rejected"),
                 "draining": self._drain_flag.is_set(),
             }
             for slot in self._slots

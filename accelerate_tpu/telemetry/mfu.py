@@ -55,7 +55,13 @@ def mfu(
     if n_devices < 1:
         raise ValueError(f"n_devices must be >= 1, got {n_devices}")
     if peak is None:
-        peak = peak_flops(generation or device_generation() or "v5e", dtype)
+        generation = generation or device_generation()
+        if generation is None:
+            raise ValueError(
+                "mfu: the attached device's generation is not in PEAK_FLOPS_TABLE; "
+                "pass generation= or peak= (a utilisation against another chip's peak is wrong)"
+            )
+        peak = peak_flops(generation, dtype)
     return flops_per_step / step_time_s / (peak * n_devices)
 
 

@@ -1,11 +1,4 @@
-"""Cross-version jax compatibility shims.
-
-The codebase targets the jax >= 0.6 API surface (``jax.shard_map`` with
-``check_vma``, ``jax.sharding.AxisType`` / ``get_abstract_mesh``); these
-adapters keep identical call sites running on jax 0.4.x, where shard_map
-lives in ``jax.experimental`` with ``check_rep`` and meshes have no axis
-types. Only behavior-preserving renames are adapted here — anything with
-different semantics across versions does not belong in this module.
+"""Small queries about the installed jax that several modules share.
 
 jax is imported lazily: this module sits under the package's eager import
 path and must not initialise a backend.
@@ -14,22 +7,9 @@ path and must not initialise a backend.
 from __future__ import annotations
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` (0.6+ signature) with fallback to
-    ``jax.experimental.shard_map.shard_map`` (``check_vma`` was named
-    ``check_rep`` there — same meaning, per-value replication checking)."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma)
-
-
 def supports_memory_kind(kind: str = "pinned_host") -> bool:
     """Whether the default device can address ``kind`` memory. TPU backends
-    expose ``pinned_host`` for optimizer-state offload; older CPU backends
+    expose ``pinned_host`` for optimizer-state offload; the CPU backend may
     address only ``unpinned_host``, where offload must degrade gracefully
     instead of dying in ``NamedSharding.with_memory_kind``."""
     import jax
@@ -41,22 +21,10 @@ def supports_memory_kind(kind: str = "pinned_host") -> bool:
 
 
 def in_manual_region() -> bool:
-    """True when tracing inside a shard_map/pmap body — mesh axes are
-    Manual there, and nesting another shard_map over the same mesh is an
-    error, so sharded-dispatch wrappers must use the bare kernel. On new
-    jax this reads the abstract mesh's axis types; on 0.4.x the bound
-    axis env carries the same information."""
+    """True when tracing inside a shard_map body — mesh axes are Manual
+    there, and nesting another shard_map over the same mesh is an error, so
+    sharded-dispatch wrappers must use the bare kernel."""
     import jax
 
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        manual = jax.sharding.AxisType.Manual
-        return any(t == manual for t in getattr(am, "axis_types", ()))
-    except AttributeError:
-        pass
-    try:
-        from jax._src.core import get_axis_env
-
-        return bool(get_axis_env().axis_sizes)
-    except Exception:
-        return False
+    manual = jax.sharding.AxisType.Manual
+    return any(t == manual for t in jax.sharding.get_abstract_mesh().axis_types)

@@ -199,7 +199,7 @@ def tpu606_roundtrip_check(n_data: int):
     from accelerate_tpu.analysis.numerics_rules import COMPRESSION_NUMERICS
     from accelerate_tpu.parallel.mesh import MeshConfig
     from accelerate_tpu.parallel.zero import all_gather_updates, reduce_scatter_grads
-    from accelerate_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     mesh = MeshConfig(data=n_data).build()
     g = jax.random.normal(jax.random.key(11), (n_data, 4096), jnp.float32) * 1.7

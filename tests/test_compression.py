@@ -8,7 +8,7 @@ import optax
 import pytest
 
 from accelerate_tpu import Accelerator, MeshConfig, ParallelismPlugin
-from accelerate_tpu.utils.compat import shard_map
+from jax import shard_map
 from accelerate_tpu.parallel.compression import compressed_psum_mean, wire_bytes
 from accelerate_tpu.test_utils import RegressionDataset, RegressionModel, linear_loss_fn
 

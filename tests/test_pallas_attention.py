@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from accelerate_tpu.ops.attention import dot_product_attention
-from accelerate_tpu.utils.compat import shard_map
+from jax import shard_map
 from accelerate_tpu.ops.pallas_attention import pallas_flash_attention
 
 

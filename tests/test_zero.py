@@ -245,7 +245,7 @@ def test_zero1_collectives_within_tpu606_bound(mesh8):
     residual, the bound must hold for a single shot."""
     from accelerate_tpu.analysis.numerics_rules import COMPRESSION_NUMERICS
     from accelerate_tpu.parallel.zero import all_gather_updates, reduce_scatter_grads
-    from accelerate_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     n = 8
     g = jax.random.normal(jax.random.key(3), (8, 1024), jnp.float32) * 2.5
@@ -430,7 +430,7 @@ def test_zero1_clip_grad_norm_matches_baseline():
 
 def test_sharded_global_norm_is_psum_of_partials(mesh8):
     from accelerate_tpu.parallel.zero import sharded_global_norm
-    from accelerate_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     x = jax.random.normal(jax.random.key(0), (8, 64), jnp.float32)
 
