@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import time
 from typing import Any, Callable, Optional
 
 import numpy as np
@@ -37,6 +38,7 @@ from .parallel.mesh import data_parallel_size
 from .parallel.sharding import fsdp_rules_for, infer_shardings
 from .scheduler import AcceleratedScheduler
 from .state import AcceleratorState, GradientState
+from .telemetry.trace import phase
 from .utils.dataclasses import (
     AutocastKwargs,
     DataLoaderConfiguration,
@@ -1536,7 +1538,7 @@ class Accelerator:
 
             zero_fns = {True: zero_body(True), False: zero_body(False)}
 
-        def step_fn(params, opt_state, grad_buf, mstate, batch, scale_state, do_sync, rng, clip_norm, comp_state):
+        def train_step(params, opt_state, grad_buf, mstate, batch, scale_state, do_sync, rng, clip_norm, comp_state):
             # With offload, do_sync is a STATIC python bool (two compiled
             # variants): a non-sync microbatch's program never touches the
             # host-resident state, so grad accumulation amortizes the
@@ -1708,17 +1710,17 @@ class Accelerator:
             # the host-resident state can't be donated to device outputs
             # (memory-kind mismatch); its buffers are replaced by the push.
             # do_sync turns static (two program variants) so non-sync
-            # microbatches never stream the state — see step_fn.
+            # microbatches never stream the state — see train_step.
             donate_args = tuple(i for i in donate_args if i != 1)
-            jitted = jax.jit(step_fn, donate_argnums=donate_args, static_argnums=(6,))
+            jitted = jax.jit(train_step, donate_argnums=donate_args, static_argnums=(6,))
             step_statics = (6,)
         elif zero_layout is not None:
             # static do_sync: two program variants, no collective under a
             # value-dependent cond (see zero_fns)
-            jitted = jax.jit(step_fn, donate_argnums=donate_args, static_argnums=(6,))
+            jitted = jax.jit(train_step, donate_argnums=donate_args, static_argnums=(6,))
             step_statics = (6,)
         else:
-            jitted = jax.jit(step_fn, donate_argnums=donate_args)
+            jitted = jax.jit(train_step, donate_argnums=donate_args)
             step_statics = ()
         if self._program_cache is not None and self.compile_handler.aot_train_step:
             # AOT warm-start: dispatch goes signature -> executable through
@@ -1820,59 +1822,65 @@ class Accelerator:
             self.gradient_state._set_sync_gradients(do_sync)
             from .utils.random import key_for_step
 
-            with self._matmul_precision_ctx():
-                new_params, new_opt, new_buf, new_state, loss, gnorm, finite, aux, new_scale_state, new_comp = jitted(
-                    model.params,
-                    optimizer.opt_state,
-                    state_box["grad_buf"],
-                    getattr(model, "state", None) if has_state else None,
-                    batch,
-                    state_box["scale_state"],
-                    bool(do_sync) if (offload_push is not None or zero_layout is not None) else jnp.bool_(do_sync),
-                    key_for_step(self.step),
-                    jnp.float32(-1.0 if self._clip_max_norm is None else self._clip_max_norm),
-                    state_box["comp_state"],
-                )
-            model.params = new_params
-            if has_state:
-                model.state = new_state
-            if offload_push is None:
-                optimizer.opt_state = new_opt
-            elif do_sync:
-                optimizer.opt_state = offload_push(new_opt)
-            # offload + non-sync: the state passed through the program
-            # untouched (and unstreamed) — nothing to write back
-            state_box["grad_buf"] = new_buf
-            state_box["scale_state"] = new_scale_state
-            state_box["comp_state"] = new_comp
-            state_box["micro"] = 0 if do_sync else state_box["micro"] + 1
-            self.step += 1
-            self._last_grad_norm = gnorm
-            # opt-in runtime finiteness probe (TelemetryKwargs
-            # nonfinite_every=N) — the runtime counterpart of the static
-            # TPU602 overflow proof. Gated inside observe(): off-cadence
-            # steps coerce nothing, so no host sync is added
-            if self._telemetry is not None and self._telemetry.nonfinite.enabled:
-                self._telemetry.nonfinite.observe(
-                    self.step,
-                    loss=loss,
-                    grad_norm=gnorm,
-                    loss_scale=new_scale_state["scale"] if use_fp16 else None,
-                    # the fp16 scaler skips the update and backs off on a
-                    # grad overflow — that's calibration, not divergence
-                    scaler_handled=use_fp16,
-                )
-            if do_sync:
-                if use_fp16:
-                    # device value, coerced lazily by the property — reading
-                    # step_was_skipped is what forces the fetch, not the step
-                    optimizer._step_was_skipped = jnp.logical_not(finite)
-                    state_box["boundaries"] += 1
-                    if state_box["boundaries"] % _SCALE_REFRESH == 0:
-                        self._loss_scale = float(new_scale_state["scale"])
-                        self._scale_growth_tracker = int(new_scale_state["growth"])
-                if scheduler is not None:
-                    scheduler.step()
+            with phase("train.step", step=self.step, do_sync=int(do_sync), mono_ns=time.monotonic_ns()):
+                with phase("train.step.args"):
+                    sync_arg = bool(do_sync) if (offload_push is not None or zero_layout is not None) else jnp.bool_(do_sync)
+                    rng = key_for_step(self.step)
+                    clip_norm = jnp.float32(-1.0 if self._clip_max_norm is None else self._clip_max_norm)
+                with phase("train.step.call"), self._matmul_precision_ctx():
+                    new_params, new_opt, new_buf, new_state, loss, gnorm, finite, aux, new_scale_state, new_comp = jitted(
+                        model.params,
+                        optimizer.opt_state,
+                        state_box["grad_buf"],
+                        getattr(model, "state", None) if has_state else None,
+                        batch,
+                        state_box["scale_state"],
+                        sync_arg,
+                        rng,
+                        clip_norm,
+                        state_box["comp_state"],
+                    )
+                with phase("train.step.swap"):
+                    model.params = new_params
+                    if has_state:
+                        model.state = new_state
+                    if offload_push is None:
+                        optimizer.opt_state = new_opt
+                    elif do_sync:
+                        optimizer.opt_state = offload_push(new_opt)
+                    # offload + non-sync: the state passed through the program
+                    # untouched (and unstreamed) — nothing to write back
+                    state_box["grad_buf"] = new_buf
+                    state_box["scale_state"] = new_scale_state
+                    state_box["comp_state"] = new_comp
+                    state_box["micro"] = 0 if do_sync else state_box["micro"] + 1
+                    self.step += 1
+                    self._last_grad_norm = gnorm
+                    # opt-in runtime finiteness probe (TelemetryKwargs
+                    # nonfinite_every=N) — the runtime counterpart of the static
+                    # TPU602 overflow proof. Gated inside observe(): off-cadence
+                    # steps coerce nothing, so no host sync is added
+                    if self._telemetry is not None and self._telemetry.nonfinite.enabled:
+                        self._telemetry.nonfinite.observe(
+                            self.step,
+                            loss=loss,
+                            grad_norm=gnorm,
+                            loss_scale=new_scale_state["scale"] if use_fp16 else None,
+                            # the fp16 scaler skips the update and backs off on a
+                            # grad overflow — that's calibration, not divergence
+                            scaler_handled=use_fp16,
+                        )
+                    if do_sync:
+                        if use_fp16:
+                            # device value, coerced lazily by the property — reading
+                            # step_was_skipped is what forces the fetch, not the step
+                            optimizer._step_was_skipped = jnp.logical_not(finite)
+                            state_box["boundaries"] += 1
+                            if state_box["boundaries"] % _SCALE_REFRESH == 0:
+                                self._loss_scale = float(new_scale_state["scale"])
+                                self._scale_growth_tracker = int(new_scale_state["growth"])
+                        if scheduler is not None:
+                            scheduler.step()
             return (loss, aux) if has_aux else loss
 
         step._jitted = jitted

@@ -68,6 +68,7 @@ import numpy as np
 
 from .ft.crashpoints import crash_point
 from .scheduling import Scheduler, SchedulerConfig, ShedError
+from .telemetry.trace import phase
 
 
 def _jax():
@@ -398,6 +399,8 @@ class ServingEngine:
         self._done_new: dict[int, np.ndarray] = {}  # uid -> generated suffix only
         self._done_lps: dict[int, np.ndarray] = {}  # uid -> per-generated-token logprobs
         self._uid = 0
+        self._tick = 0  # ordinal of the running tick (the ``engine.tick`` span's count)
+        self._tick_prefill_tokens = 0  # prompt tokens dispatched by this tick's prefills
         self._pool_blocked = False  # last admit pass hit pool exhaustion
         self.bucket_compile_ms: dict = {}  # (kind, bucket) -> build wall ms
         # raw (pre-jit) program + sample-args builder + trace contexts per
@@ -411,6 +414,17 @@ class ServingEngine:
             (f32 log-softmax) — the standard serving logprob surface, even
             when sampling is temperature/top-k shaped."""
             return jax.nn.log_softmax(row.astype(jnp.float32))[tok]
+
+        def named(fn, name):
+            """``fn`` under ``name``: jit names the module after the
+            function, and the device line of a profile shows the module,
+            so a program is jitted under the name ProgramCache logs."""
+
+            def call(*args):
+                return fn(*args)
+
+            call.__name__ = call.__qualname__ = name
+            return call
 
         def prefill(params, ids, true_len, key):
             """[1, B] padded prompt -> (first next-token, its logprob,
@@ -434,7 +448,7 @@ class ServingEngine:
                 t0 = time.perf_counter()
                 with self._trace_ctx():
                     prog = self._pc.compile(
-                        prefill, params, jax.ShapeDtypeStruct((1, b), jnp.int32),
+                        named(prefill, f"prefill_b{b}"), params, jax.ShapeDtypeStruct((1, b), jnp.int32),
                         jax.ShapeDtypeStruct((), jnp.int32), key_aval,
                         name=f"prefill_b{b}",
                     )
@@ -587,7 +601,7 @@ class ServingEngine:
             # between pastes — an eagerly .lower()ed program would pin the
             # shardings it saw at construction and reject the real ones.
             raw_tick = make_tick(paged_step)
-            tick = self._pc.wrap_jit(jax.jit(raw_tick), name="paged_decode_tick")
+            tick = self._pc.wrap_jit(jax.jit(named(raw_tick, "paged_decode_tick")), name="paged_decode_tick")
             pcfg = self._pcfg
 
             def decode_tick(*args):
@@ -696,7 +710,7 @@ class ServingEngine:
                 t0 = time.perf_counter()
                 with self._trace_ctx():
                     prog = self._pc.compile(
-                        spec_prefill, params, draft_model.params,
+                        named(spec_prefill, f"spec_prefill_b{b}"), params, draft_model.params,
                         jax.ShapeDtypeStruct((1, b), jnp.int32), jax.ShapeDtypeStruct((), jnp.int32),
                         name=f"spec_prefill_b{b}",
                     )
@@ -775,7 +789,9 @@ class ServingEngine:
         """Execute ONE prefill window starting at new-token offset ``s``;
         returns ``(logits, cache, s_adj, e)``. With a trace id, each
         window records one ``prefill`` span — its frontier-contiguous
-        wall time plus the compute-only dispatch in ``compute_ms``."""
+        wall time plus ``dispatch_ms``, the time to enqueue the window:
+        nothing here waits for the device, so there is no compute time
+        to report."""
         jnp = _jax().numpy
         t = len(full_tokens)
         w, s_adj, e = self._next_window(t, s)
@@ -793,7 +809,7 @@ class ServingEngine:
         if self.tracer is not None and trace is not None:
             self.tracer.seg(
                 trace, "prefill", tokens=int(w),
-                compute_ms=round((time.perf_counter() - t0) * 1000.0, 3),
+                dispatch_ms=round((time.perf_counter() - t0) * 1000.0, 3),
             )
         return logits, row_cache, s_adj, e
 
@@ -939,23 +955,24 @@ class ServingEngine:
                     f"request needs {need} pool blocks but the pool has "
                     f"{self._pcfg.num_blocks - 1}; raise pool_blocks or paged_block_size"
                 )
-        priority = self._admission_shed_check(int(priority), trace=trace)
-        uid = self._uid
-        self._uid += 1
-        if self.tracer is not None:
-            # a router-minted trace arrives via ``trace=``; standalone
-            # engines mint their own here, after the shed gate passed
-            if trace is None:
-                trace = self.tracer.start()
-            self.tracer.attach(trace, uid=uid, prompt_tokens=len(prompt))
-        req = _Request(
-            uid, prompt, max_new_tokens, [], prefix_id, stops,
-            priority=priority, submit_ts=time.monotonic(), trace=trace,
-        )
-        self._queue_push(req)
-        self._index[uid] = ("queued", req)
-        self.metrics.on_submit(uid)
-        return uid
+        with phase("engine.submit", uid=self._uid, prompt_tokens=len(prompt), queue_len=len(self.queue)):
+            priority = self._admission_shed_check(int(priority), trace=trace)
+            uid = self._uid
+            self._uid += 1
+            if self.tracer is not None:
+                # a router-minted trace arrives via ``trace=``; standalone
+                # engines mint their own here, after the shed gate passed
+                if trace is None:
+                    trace = self.tracer.start()
+                self.tracer.attach(trace, uid=uid, prompt_tokens=len(prompt))
+            req = _Request(
+                uid, prompt, max_new_tokens, [], prefix_id, stops,
+                priority=priority, submit_ts=time.monotonic(), trace=trace,
+            )
+            self._queue_push(req)
+            self._index[uid] = ("queued", req)
+            self.metrics.on_submit(uid)
+            return uid
 
     # ---- disaggregated prefill / KV handoff (serving_fleet) -------------
 
@@ -1121,20 +1138,21 @@ class ServingEngine:
                     f"{self._pcfg.num_blocks - 1}; raise pool_blocks or paged_block_size"
                 )
         trace = handoff.get("trace")
-        priority = self._admission_shed_check(int(priority), trace=trace)
-        uid = self._uid
-        self._uid += 1
-        if self.tracer is not None and trace is not None:
-            self.tracer.attach(trace, decode_uid=uid)
-        req = _Request(
-            uid, prompt, max_new, [], None, stops,
-            priority=priority, submit_ts=time.monotonic(), handoff=dict(handoff),
-            trace=trace,
-        )
-        self._queue_push(req)
-        self._index[uid] = ("queued", req)
-        self.metrics.on_submit(uid)
-        return uid
+        with phase("engine.submit", uid=self._uid, prompt_tokens=len(prompt), queue_len=len(self.queue)):
+            priority = self._admission_shed_check(int(priority), trace=trace)
+            uid = self._uid
+            self._uid += 1
+            if self.tracer is not None and trace is not None:
+                self.tracer.attach(trace, decode_uid=uid)
+            req = _Request(
+                uid, prompt, max_new, [], None, stops,
+                priority=priority, submit_ts=time.monotonic(), handoff=dict(handoff),
+                trace=trace,
+            )
+            self._queue_push(req)
+            self._index[uid] = ("queued", req)
+            self.metrics.on_submit(uid)
+            return uid
 
     # ---- fleet failover: in-flight export / import (serving_fleet) ------
 
@@ -1415,11 +1433,27 @@ class ServingEngine:
         at least one unit of progress per tick, so no budget setting can
         livelock ``run()``."""
         crash_point("pre_tick", replica=self.metrics.replica)
-        now = time.monotonic()
-        self._pool_blocked = False
-        self._shed_pass(now)
         n_dec = sum(1 for ph in self.slot_phase if ph == "decode")
-        budget = self._sched.tick_budget(n_dec, self.tick_block)
+        self._tick += 1
+        with phase(
+            "engine.tick", tick=self._tick, queue_len=len(self.queue), decoding=n_dec,
+            prefilling=len(self._prefill_order), mono_ns=time.monotonic_ns(),
+        ):
+            self._tick_phases(n_dec)
+        return self.active_count
+
+    def _tick_phases(self, n_dec: int) -> None:
+        """The body of one tick, cut into the non-overlapping phases
+        :data:`~accelerate_tpu.telemetry.trace.PHASES` lists: what a
+        profile shows inside ``engine.tick``."""
+        m = self.metrics
+        admitted, tokens_was, completed_was = 0, m.tokens_generated, m.requests_completed
+        self._tick_prefill_tokens = 0
+        with phase("engine.schedule"):
+            now = time.monotonic()
+            self._pool_blocked = False
+            self._shed_pass(now)
+            budget = self._sched.tick_budget(n_dec, self.tick_block)
         # Admissions run FIRST and one admission per tick may overrun the
         # budget: a queued request's TTFT progress must not wait for an
         # in-flight long prefill to finish streaming (head-of-line
@@ -1438,12 +1472,20 @@ class ServingEngine:
                 # priority inversion: a strictly more important request
                 # waits while a lower class decodes — evict the youngest
                 # such decode (policy-gated; None without preemption)
-                slot = self._sched.pick_victim(self.queue[0].priority, self._decoding_info())
+                with phase("engine.schedule"):
+                    slot = self._sched.pick_victim(self.queue[0].priority, self._decoding_info())
+                    if slot is not None:
+                        self._preempt(slot)
                 if slot is None:
                     break
-                self._preempt(slot)
-            if not self._admit(slot):
-                break  # pool blocked: the whole queue waits on its head
+            head = self.queue[0]
+            with phase(
+                "engine.admit", uid=head.uid, slot=slot, prompt_tokens=len(head.prompt),
+                queue_wait_ms=(time.monotonic() - head.submit_ts) * 1000.0,
+            ):
+                if not self._admit(slot):
+                    break  # pool blocked: the whole queue waits on its head
+            admitted += 1
             budget = self._advance_prefill(slot, budget, force=force)
             force = False
         force = True
@@ -1457,8 +1499,15 @@ class ServingEngine:
                 self._spec_decode_pass()
             else:
                 self._plain_decode_pass()
-        self._expire_window_blocks()
-        return self.active_count
+        with phase("engine.expire"):
+            self._expire_window_blocks()
+        with phase(
+            "engine.tick.done", admitted=admitted, prefill_tokens=self._tick_prefill_tokens,
+            emitted=m.tokens_generated - tokens_was, retired=m.requests_completed - completed_was,
+            pool_blocked=int(self._pool_blocked), free_blocks=self._alloc.free_count if self.paged else -1,
+            queue_len=len(self.queue),
+        ):
+            pass
 
     # ---- scheduler passes (one step() = one tick) -----------------------
 
@@ -1651,7 +1700,8 @@ class ServingEngine:
             # pad the trimmed rows back onto the template and paste —
             # zero tokens of this tick's budget are spent
             h = st.pop("handoff")
-            cache = self._untrim_row_cache(h["cache"], h["total"])
+            with phase("engine.prefill.dispatch", uid=req.uid, tokens=0, prompt_tokens=len(req.prompt)):
+                cache = self._untrim_row_cache(h["cache"], h["total"])
             if self.tracer is not None:
                 # the paste half of the handoff (the router recorded the
                 # priced wire move); no moved_bytes here, so critpath
@@ -1663,25 +1713,24 @@ class ServingEngine:
             b = st["bucket"]
             if budget < b and not force:
                 return budget
-            padded = np.zeros((1, b), np.int32)
-            padded[0, : len(req.prompt)] = req.prompt
-            t0 = time.perf_counter()
-            if st.get("spec"):
-                # speculative admit: both models prefill the prompt (greedy)
-                next_tok, lp, row_cache = self._spec_prefill[b](
-                    self.model.params, self.draft_model.params,
-                    jnp.asarray(padded), jnp.int32(len(req.prompt)),
-                )
-                key = st["key"]
-            else:
-                next_tok, lp, row_cache, key = self._prefill[b](
-                    self.model.params, jnp.asarray(padded), jnp.int32(len(req.prompt)), st["key"]
-                )
-            if self.tracer is not None:
-                self.tracer.seg(
-                    req.trace, "prefill", tokens=int(b),
-                    compute_ms=round((time.perf_counter() - t0) * 1000.0, 3),
-                )
+            with phase("engine.prefill.dispatch", uid=req.uid, tokens=b, prompt_tokens=len(req.prompt)):
+                padded = np.zeros((1, b), np.int32)
+                padded[0, : len(req.prompt)] = req.prompt
+                # the request's ``prefill`` span closes at the first-token
+                # sync in _finalize_prefill: dispatch to there is compute
+                st["dispatched"] = (time.perf_counter(), int(b))
+                if st.get("spec"):
+                    # speculative admit: both models prefill the prompt (greedy)
+                    next_tok, lp, row_cache = self._spec_prefill[b](
+                        self.model.params, self.draft_model.params,
+                        jnp.asarray(padded), jnp.int32(len(req.prompt)),
+                    )
+                    key = st["key"]
+                else:
+                    next_tok, lp, row_cache, key = self._prefill[b](
+                        self.model.params, jnp.asarray(padded), jnp.int32(len(req.prompt)), st["key"]
+                    )
+            self._tick_prefill_tokens += b
             self._finalize_prefill(slot, row_cache, len(req.prompt), next_tok, lp, key)
             return budget - b
         full = st["full"]
@@ -1690,19 +1739,20 @@ class ServingEngine:
             w, _, _ = self._next_window(t, st["done"])
             if budget < w and not force:
                 return budget
-            st["logits"], st["cache"], st["s_last"], st["done"] = self._run_window(
-                full, st["done"], st["cache"], trace=req.trace
-            )
+            with phase("engine.prefill.dispatch", uid=req.uid, tokens=w, prompt_tokens=len(req.prompt)):
+                st["logits"], st["cache"], st["s_last"], st["done"] = self._run_window(
+                    full, st["done"], st["cache"], trace=req.trace
+                )
+            self._tick_prefill_tokens += w
             budget -= w
             force = False
-        cache = self._reset_idx(st["cache"], jnp.int32(t))
-        if st["resume"]:
-            self._finalize_prefill(slot, cache, t, None, None, st["key"])
-        else:
-            next_tok, lp, key = self._sample_at(
-                st["logits"], jnp.int32(t - 1 - st["s_last"]), st["key"]
-            )
-            self._finalize_prefill(slot, cache, t, next_tok, lp, key)
+        next_tok = lp = None
+        key = st["key"]
+        with phase("engine.prefill.dispatch", uid=req.uid, tokens=0, prompt_tokens=len(req.prompt)):
+            cache = self._reset_idx(st["cache"], jnp.int32(t))
+            if not st["resume"]:
+                next_tok, lp, key = self._sample_at(st["logits"], jnp.int32(t - 1 - st["s_last"]), key)
+        self._finalize_prefill(slot, cache, t, next_tok, lp, key)
         return budget
 
     def _finalize_prefill(self, slot: int, row_cache, total: int, next_tok, lp, key) -> None:
@@ -1713,14 +1763,15 @@ class ServingEngine:
         jnp = _jax().numpy
         st = self._prefill_state[slot]
         req = st["req"]
-        self._slot_keys = self._slot_keys.at[slot].set(key)
-        if self.paged:
-            self.slot_caches = self._paste(
-                self.slot_caches, row_cache, jnp.asarray(st["write_row"]),
-                jnp.asarray(st["table"]), jnp.int32(slot), jnp.int32(total),
-            )
-        else:
-            self.slot_caches = self._insert(self.slot_caches, row_cache, jnp.int32(slot))
+        with phase("engine.prefill.paste", uid=req.uid):
+            self._slot_keys = self._slot_keys.at[slot].set(key)
+            if self.paged:
+                self.slot_caches = self._paste(
+                    self.slot_caches, row_cache, jnp.asarray(st["write_row"]),
+                    jnp.asarray(st["table"]), jnp.int32(slot), jnp.int32(total),
+                )
+            else:
+                self.slot_caches = self._insert(self.slot_caches, row_cache, jnp.int32(slot))
         self._prefill_state[slot] = None
         self._prefill_order.remove(slot)
         self.slot_phase[slot] = "decode"
@@ -1737,9 +1788,15 @@ class ServingEngine:
             if self.tracer is not None:
                 self.tracer.seg(req.trace, "resume", recomputed_tokens=int(total))
             return
-        tok = int(next_tok)
+        with phase("engine.prefill.sync", uid=req.uid):
+            tok, lp = int(next_tok), float(lp)  # the first token exists on the host from here
+        if self.tracer is not None and "dispatched" in st:
+            t0, b = st["dispatched"]
+            self.tracer.seg(
+                req.trace, "prefill", tokens=b, compute_ms=round((time.perf_counter() - t0) * 1000.0, 3)
+            )
         req.out_tokens.append(tok)
-        req.out_lps.append(float(lp))
+        req.out_lps.append(lp)
         if not req.ttft_done:
             req.ttft_done = True
             self.metrics.on_first_token(req.uid)  # TTFT: prefill's tail token
@@ -1776,33 +1833,44 @@ class ServingEngine:
         caches are fully replaced at prefill paste/insert."""
         crash_point("mid_decode", replica=self.metrics.replica)
         jnp = _jax().numpy
-        self.slot_caches, toks_k, lps_k, self._slot_keys = self._decode_tick(
-            self.model.params, self.slot_caches,
-            jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos), self._slot_keys
+        with self._decode_dispatch_phase():
+            self.slot_caches, toks_k, lps_k, self._slot_keys = self._decode_tick(
+                self.model.params, self.slot_caches,
+                jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos), self._slot_keys
+            )
+        with phase("engine.decode.sync"):
+            toks_k = np.asarray(toks_k)  # [K, slots] — ONE host sync per block
+            lps_k = np.asarray(lps_k)
+        with phase("engine.decode.walk"):
+            for slot, req in enumerate(self.slot_req):
+                if req is None or self.slot_phase[slot] != "decode":
+                    continue
+                n_new, retired = 0, False
+                for k in range(self.tick_block):
+                    tok = int(toks_k[k, slot])
+                    req.out_tokens.append(tok)
+                    req.out_lps.append(float(lps_k[k, slot]))
+                    self.metrics.on_tokens(1)
+                    n_new += 1
+                    self.slot_pos[slot] += 1
+                    self.slot_tok[slot] = tok
+                    if self._finished(req, tok):
+                        retired = True
+                        break  # remaining block tokens are overshoot — discarded
+                if n_new:
+                    self.metrics.on_tick_tokens(req.uid, n_new)
+                    if self.tracer is not None:
+                        self.tracer.window(req.trace, "decode", tokens=n_new)
+                if retired:
+                    self._retire(slot)
+
+    def _decode_dispatch_phase(self):
+        """``engine.decode.dispatch`` with the tick's counts at its entry."""
+        decoding = [ph == "decode" for ph in self.slot_phase]
+        return phase(
+            "engine.decode.dispatch", decoding=sum(decoding), tick_block=self.tick_block,
+            live_tokens=int(self.slot_pos[decoding].sum()),
         )
-        toks_k = np.asarray(toks_k)  # [K, slots] — ONE host sync per block
-        lps_k = np.asarray(lps_k)
-        for slot, req in enumerate(self.slot_req):
-            if req is None or self.slot_phase[slot] != "decode":
-                continue
-            n_new, retired = 0, False
-            for k in range(self.tick_block):
-                tok = int(toks_k[k, slot])
-                req.out_tokens.append(tok)
-                req.out_lps.append(float(lps_k[k, slot]))
-                self.metrics.on_tokens(1)
-                n_new += 1
-                self.slot_pos[slot] += 1
-                self.slot_tok[slot] = tok
-                if self._finished(req, tok):
-                    retired = True
-                    break  # remaining block tokens are overshoot — discarded
-            if n_new:
-                self.metrics.on_tick_tokens(req.uid, n_new)
-                if self.tracer is not None:
-                    self.tracer.window(req.trace, "decode", tokens=n_new)
-            if retired:
-                self._retire(slot)
 
     def _expire_window_blocks(self) -> None:
         """Sliding-window models: expire blocks the band can no longer
@@ -1868,46 +1936,49 @@ class ServingEngine:
         one-token tick walks its block — overshoot past retirement is
         discarded identically."""
         jnp = _jax().numpy
-        self.slot_caches, emits_k, lps_k, n_k = self._spec_tick(
-            self.model.params, self.draft_model.params, self.slot_caches,
-            jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos),
-        )
-        emits_k = np.asarray(emits_k)  # [K, slots, gamma+1]
-        lps_k = np.asarray(lps_k)
-        n_k = np.asarray(n_k)  # [K, slots]
-        for slot, req in enumerate(self.slot_req):
-            if req is None or self.slot_phase[slot] != "decode":
-                continue
-            retired, n_new = False, 0
-            for k in range(self.tick_block):
-                n = int(n_k[k, slot])
-                self.spec_stats["steps"] += 1  # one target forward spent
-                walked = 0
-                for j in range(n):
-                    tok = int(emits_k[k, slot, j])
-                    req.out_tokens.append(tok)
-                    req.out_lps.append(float(lps_k[k, slot, j]))
-                    self.metrics.on_tokens(1)
-                    walked += 1
-                    n_new += 1
-                    self.slot_pos[slot] += 1
-                    self.slot_tok[slot] = tok
-                    if self._finished(req, tok):
-                        retired = True
+        with self._decode_dispatch_phase():
+            self.slot_caches, emits_k, lps_k, n_k = self._spec_tick(
+                self.model.params, self.draft_model.params, self.slot_caches,
+                jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos),
+            )
+        with phase("engine.decode.sync"):
+            emits_k = np.asarray(emits_k)  # [K, slots, gamma+1]
+            lps_k = np.asarray(lps_k)
+            n_k = np.asarray(n_k)  # [K, slots]
+        with phase("engine.decode.walk"):
+            for slot, req in enumerate(self.slot_req):
+                if req is None or self.slot_phase[slot] != "decode":
+                    continue
+                retired, n_new = False, 0
+                for k in range(self.tick_block):
+                    n = int(n_k[k, slot])
+                    self.spec_stats["steps"] += 1  # one target forward spent
+                    walked = 0
+                    for j in range(n):
+                        tok = int(emits_k[k, slot, j])
+                        req.out_tokens.append(tok)
+                        req.out_lps.append(float(lps_k[k, slot, j]))
+                        self.metrics.on_tokens(1)
+                        walked += 1
+                        n_new += 1
+                        self.slot_pos[slot] += 1
+                        self.slot_tok[slot] = tok
+                        if self._finished(req, tok):
+                            retired = True
+                            break
+                    # only USED tokens count (a mid-run EOS discards the rest;
+                    # the correction/bonus token is target-sourced, not a
+                    # draft acceptance) — matches speculative_generate's stats
+                    self.spec_stats["emitted"] += walked
+                    self.spec_stats["accepted"] += min(walked, n - 1)
+                    if retired:
                         break
-                # only USED tokens count (a mid-run EOS discards the rest;
-                # the correction/bonus token is target-sourced, not a
-                # draft acceptance) — matches speculative_generate's stats
-                self.spec_stats["emitted"] += walked
-                self.spec_stats["accepted"] += min(walked, n - 1)
+                if n_new:
+                    self.metrics.on_tick_tokens(req.uid, n_new)
+                    if self.tracer is not None:
+                        self.tracer.window(req.trace, "decode", tokens=n_new)
                 if retired:
-                    break
-            if n_new:
-                self.metrics.on_tick_tokens(req.uid, n_new)
-                if self.tracer is not None:
-                    self.tracer.window(req.trace, "decode", tokens=n_new)
-            if retired:
-                self._retire(slot)
+                    self._retire(slot)
         return self.active_count
 
     def _finished(self, req: _Request, tok: int) -> bool:

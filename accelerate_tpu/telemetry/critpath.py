@@ -11,8 +11,10 @@ request:
 * ``queue_wait``  vs the scheduler's own accounting (``on_admit``'s
   ``queue_wait_ms``, carried in span meta as ``accounted_ms``);
 * ``prefill``     vs ``perfmodel``/``costmodel.prefill_compute_us``
-  (span meta ``compute_ms`` — the compute-only timing, not the
-  frontier span which absorbs queueing);
+  (span meta ``compute_ms``: a fused bucket's dispatch to its
+  first-token sync, not the frontier span which absorbs queueing; a
+  chunk window carries only ``dispatch_ms``, its enqueue time, and is
+  not checked);
 * ``kv_handoff``  vs ``costmodel.price_kv_handoff`` (``moved_bytes``
   must equal ``predicted_bytes`` byte-for-byte);
 * ``failover``    vs ``costmodel.price_failover`` (same byte equality

@@ -15,9 +15,11 @@ crossed router -> prefill replica -> KV handoff -> decode replica ->
 * segments are **frontier-contiguous**: each new segment covers the gap
   since the trace's last covered timestamp, so the segment sum
   reconciles with the request's end-to-end latency by construction (the
-  property ``bench_serving.py --trace`` gates on). Compute-only timings
-  ride in span meta (``compute_ms``) where a predictor cross-check needs
-  them (:mod:`~accelerate_tpu.telemetry.critpath`);
+  property ``bench_serving.py --trace`` gates on). A ``prefill`` span of
+  a fused bucket program carries ``compute_ms``, dispatch to the
+  first-token sync, which a predictor cross-check reads
+  (:mod:`~accelerate_tpu.telemetry.critpath`); a chunk window has no
+  sync and carries ``dispatch_ms``, the enqueue time, under that name;
 * the trace id rides the request record through
   ``FleetRouter``/``ServingEngine``/``scheduling.py``, is serialized
   inside the ``HandoffCodec`` blob (schema v2; v1 blobs still decode),
@@ -29,8 +31,20 @@ crossed router -> prefill replica -> KV handoff -> decode replica ->
   Chrome trace-event JSON loadable in Perfetto (one ``tid`` per
   request).
 
-jax is never imported here — ``accelerate-tpu trace ...`` runs on a
-box with nothing but the stdlib.
+Beside the per-request traces, :func:`phase` puts the program's own
+phases (:data:`PHASES`: one engine tick and one train step, cut into
+non-overlapping children, each with its counts) into the profiler's
+trace, on the device lines' clock. They record only while a profiler
+session runs (``Accelerator.profile()``, ``jax.profiler.start_trace``)
+and cost about a microsecond each otherwise, so there is no switch.
+Every ``engine.tick`` / ``train.step`` carries ``mono_ns``, this
+module's default clock at the span's entry: ``mono_ns`` less the span's
+start on the profiler's clock (the trace's ``profile_start_time`` plus
+the event's ``start_ns``) is the offset that places a :class:`Tracer`
+span, a ``ServingMetrics`` timestamp or a ``submit_ts`` on that timeline.
+
+jax is imported only inside :func:`phase` — ``accelerate-tpu trace ...``
+runs on a box with nothing but the stdlib.
 """
 
 from __future__ import annotations
@@ -54,6 +68,39 @@ SEGMENTS = (
     "failover",
     "drain",
 )
+
+#: program phase -> the PERF.md layer it belongs to. One ``engine.tick``
+#: is tiled by its children (``engine.tick.done`` is a zero-length
+#: marker that carries the tick's counts); the spans of one request
+#: share ``uid``. No name equals a span of the benchmark's own.
+PHASES = {
+    "engine.submit": "scheduler",
+    "engine.tick": "engine host loop",
+    "engine.schedule": "scheduler",
+    "engine.admit": "scheduler",
+    "engine.prefill.dispatch": "jitted programs",
+    "engine.prefill.paste": "jitted programs",
+    "engine.prefill.sync": "jitted programs",
+    "engine.decode.dispatch": "jitted programs",
+    "engine.decode.sync": "jitted programs",
+    "engine.decode.walk": "engine host loop",
+    "engine.expire": "engine host loop",
+    "engine.tick.done": "engine host loop",
+    "train.step": "jitted programs",
+    "train.step.args": "jitted programs",
+    "train.step.call": "jitted programs",
+    "train.step.swap": "jitted programs",
+}
+
+
+def phase(name: str, **counts):
+    """A span named ``name`` in the profiler's trace, ``counts`` as its
+    stats: a context manager that records only while a profiler session
+    runs."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **counts)
+
 
 #: eventlog record-name prefix for exported span segments.
 TRACE_EVENT_PREFIX = "trace."

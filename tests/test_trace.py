@@ -229,6 +229,11 @@ def test_critpath_prefill_vs_injected_price():
     ))  # predicted 8 ms vs computed 100 ms: > 2x threshold
     assert list(mon.drift_events) == ["prefill"]
     assert mon.drift_events["prefill"]["check"] == "prefill_compute_us"
+    # a chunk window has no sync to end a compute time at: it carries its
+    # enqueue time as dispatch_ms, which is not held against a compute price
+    mon2 = CritPathMonitor(price_prefill_us=lambda tokens: tokens * 1000.0)
+    mon2.observe(_mk_trace([("prefill", 500.0, {"tokens": 8, "dispatch_ms": 100.0})], tid=5))
+    assert mon2.drift_events == {}
 
 
 # --------------------------------------------------------------------- #
@@ -562,6 +567,10 @@ def test_traced_disaggregated_fleet_end_to_end(tiny_llama):
             if sp["name"] == "kv_handoff" and sp.get("moved_bytes") is not None
         ]
         assert ho["moved_bytes"] == ho["predicted_bytes"] > 0
+        # prefill_detached streams chunk windows: each reports its enqueue
+        # time as dispatch_ms, never under a compute name
+        prefill = [sp for sp in tr["spans"] if sp["name"] == "prefill"]
+        assert prefill and all("dispatch_ms" in sp and "compute_ms" not in sp for sp in prefill)
         decode = [sp for sp in tr["spans"] if sp["name"] == "decode"]
         # the FIRST generated token is minted during prefill and rides
         # the handoff blob; decode windows cover the remaining three
