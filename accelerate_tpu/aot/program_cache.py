@@ -13,7 +13,10 @@ avals" three ways, cheapest first:
 Every outcome lands in telemetry: ``compile_cache_hit`` (with
 ``source: "memory"|"disk"`` and ``deserialize_ms``), ``compile_cache_miss``
 (with ``compile_ms``), ``compile_cache_store``, and ``compile_cache_reject``
-for a poisoned/stale entry that was healed. Counters mirror onto the
+for a poisoned/stale entry that was healed. A program that was compiled or
+loaded from disk also reports its executable's ``alias_bytes`` (argument
+bytes it updates in place: what its donations bought) and ``temp_bytes``,
+where the backend gives a memory analysis. Counters mirror onto the
 instance (``hits`` / ``misses`` / ``deserialized`` / ``rejected``) so code
 with no event log still has the numbers.
 
@@ -51,6 +54,16 @@ def _noop_log():
     from ..telemetry.eventlog import EventLog
 
     return EventLog(None)
+
+
+def _memory_fields(compiled) -> dict:
+    """``alias_bytes`` / ``temp_bytes`` of an executable for its compile
+    or load event; empty where the backend has no memory analysis."""
+    try:
+        m = compiled.memory_analysis()
+        return {"alias_bytes": int(m.alias_size_in_bytes), "temp_bytes": int(m.temp_size_in_bytes)}
+    except Exception:  # noqa: BLE001 — telemetry never fails a compile
+        return {}
 
 
 class ProgramCache:
@@ -146,7 +159,7 @@ class ProgramCache:
                     self._mem[key] = compiled
                     self.log.event(
                         "compile_cache_hit", program=name, key=key[:16], source="disk",
-                        deserialize_ms=round(ms, 3),
+                        deserialize_ms=round(ms, 3), **_memory_fields(compiled),
                     )
                     self.log.counter("compile_cache.deserialize_ms", round(ms, 3), program=name)
                     return compiled
@@ -156,7 +169,9 @@ class ProgramCache:
         ms = (time.perf_counter() - t0) * 1000.0
         self.misses += 1
         self._mem[key] = compiled
-        self.log.event("compile_cache_miss", program=name, key=key[:16], compile_ms=round(ms, 3))
+        self.log.event(
+            "compile_cache_miss", program=name, key=key[:16], compile_ms=round(ms, 3), **_memory_fields(compiled)
+        )
         self.log.counter("compile_cache.compile_ms", round(ms, 3), program=name)
         if self.store is not None and not self._serialize_broken:
             try:

@@ -546,13 +546,22 @@ class LlamaLayer(nn.Module):
 
 
 class _ScanLayer(nn.Module):
-    """scan-compatible wrapper: carry-in/carry-out signature."""
+    """scan-compatible wrapper: carry-in/carry-out signature. With a
+    ``layer`` index the carry is ``(hidden, key_pools, value_pools)``: the
+    paged pools of all layers, which this layer updates in place."""
 
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, hidden, positions, decode: bool = False):
-        return LlamaLayer(self.config, name="block")(hidden, positions, decode), None
+    def __call__(self, carry, positions, decode: bool = False, layer=None):
+        if layer is None:
+            return LlamaLayer(self.config, name="block")(carry, positions, decode), None
+        from ..ops.paged_kv import layer_view
+
+        hidden, key_pools, value_pools = carry
+        with layer_view(key_pools, value_pools, layer) as view:
+            hidden = LlamaLayer(self.config, name="block")(hidden, positions, decode)
+        return (hidden, view.key_pool, view.value_pool), None
 
 
 class LlamaModel(nn.Module):
@@ -586,16 +595,36 @@ class LlamaModel(nn.Module):
                 f"{cfg.num_hidden_layers} layers"
             )
         if cfg.scan_layers:
+            # A paged decode step carries the pools of all layers through the
+            # loop (scanned over like the rest of the cache, every layer would
+            # slice its pool out of the stack and write it back: two passes
+            # over the whole pool a token); block tables and frontiers are
+            # small and stay scanned.
+            pools = None
+            if decode:
+                from ..ops.paged_kv import declare_pool_stack
+
+                head_dim = cfg.head_dim or cfg.hidden_size // cfg.num_attention_heads
+                pools = declare_pool_stack(
+                    self, cfg.num_hidden_layers, cfg.num_key_value_heads, head_dim, hidden.dtype
+                )
             layer_cls = nn.remat(_ScanLayer, prevent_cse=False, static_argnums=(3,)) if cfg.remat else _ScanLayer
             scanned = nn.scan(
                 layer_cls,
                 variable_axes={"params": 0, "cache": 0, "fp8": 0},
                 split_rngs={"params": True},
-                in_axes=(nn.broadcast, nn.broadcast),
+                in_axes=(nn.broadcast, nn.broadcast) + (() if pools is None else (0,)),
                 length=cfg.num_hidden_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )
-            hidden, _ = scanned(cfg, name="layers")(hidden, positions, decode)
+            if pools is None:
+                hidden, _ = scanned(cfg, name="layers")(hidden, positions, decode)
+            else:
+                key_pools, value_pools = pools
+                (hidden, key_pools.value, value_pools.value), _ = scanned(cfg, name="layers")(
+                    (hidden, key_pools.value, value_pools.value), positions, decode,
+                    jnp.arange(cfg.num_hidden_layers),
+                )
         else:
             layer_cls = nn.remat(LlamaLayer, prevent_cse=False, static_argnums=(3,)) if cfg.remat else LlamaLayer
             for i in range(cfg.num_hidden_layers):

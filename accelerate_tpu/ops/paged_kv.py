@@ -6,8 +6,9 @@ requests are short. Paging (the vLLM design, shaped for XLA's static
 shapes) allocates cache in fixed-size *blocks* from one shared pool:
 
 * ``key_pool`` / ``value_pool``: ``[num_blocks, block_size, H_kv, D]``
-  per layer — the only large buffers, sized by *expected total tokens in
-  flight*, not ``slots x max_len``;
+  per layer (one ``[L, ...]`` stack on the model under a layer scan) — the
+  only large buffers, sized by *expected total tokens in flight*, not
+  ``slots x max_len``;
 * ``block_table``: ``[B, max_blocks]`` int32 per row — position ``p`` of
   row ``b`` lives at ``pool[table[b, p // bs], p % bs]``;
 * block 0 is a reserved **trash sink**: padded table entries and the
@@ -17,6 +18,18 @@ shapes) allocates cache in fixed-size *blocks* from one shared pool:
   also re-points the whole retired row at the sink);
 * shared prompt prefixes alias their *full* blocks into many tables
   (refcounted host-side) — prefix reuse without copying cache rows.
+
+The pool is ONE device buffer for the engine's life. Every program that
+takes the paged cache (the decode tick, :func:`paste_row`,
+:func:`paste_blocks`, :func:`clear_slot`, :func:`set_table_row`) is jitted
+with the cache donated, writes into it in place and hands the same buffer
+back; inside the tick a scanned layer stack carries the pools of all its
+layers through the layer loop as one ``[L, NB, bs, H_kv, D]`` stack per K
+and V (:func:`declare_pool_stack`, :func:`layer_view`) instead of scanning
+over them, so storing a token's rows never moves the pool. For code that
+holds a cache reference this means: the array passed to such a program is
+deleted by the call — rebind to what it returns (``cache = f(cache, ...)``)
+and never read the old value again.
 
 Everything stays static-shape. On TPU the decode step dispatches to the
 Pallas kernel in :mod:`.pallas_paged_attention`, which DMAs each page
@@ -83,6 +96,66 @@ def paged_mode(cfg: PagedConfig):
         _ACTIVE = prev
 
 
+@dataclasses.dataclass
+class _LayerView:
+    """One layer's share of pools that a layer scan carries as loop
+    state: the flattened stacks ``[L * NB, bs, H_kv, D]`` and the layer's
+    first block in them. Lives for the trace of one scan-body call."""
+
+    key_pool: jax.Array
+    value_pool: jax.Array
+    base: jax.Array  # layer * NB
+
+
+_LAYER_VIEW: Optional[_LayerView] = None
+
+
+def declare_pool_stack(module, num_layers: int, kv_heads: int, head_dim: int, dtype):
+    """The pools of ``num_layers`` scanned layers as ONE pair of ``cache``
+    variables ``[L, NB, bs, H_kv, D]`` on ``module``, the module that owns
+    the layer scan — or None when no paged layout is active.
+
+    Scanning over the ``cache`` collection would hand each layer a fresh
+    slice of the stack and collect the updated slices into a second stack:
+    two passes over the whole pool per token to store one row per slot. A
+    scan *carries* the stack instead (a carried value cannot be born
+    inside the body, hence this declaration outside it) and every layer
+    writes and reads its own ``NB`` blocks of it in place, through
+    :func:`layer_view`."""
+    cfg = _ACTIVE
+    if cfg is None:
+        return None
+    shape = (num_layers, cfg.num_blocks, cfg.block_size, kv_heads, head_dim)
+    return (
+        module.variable("cache", "key_pool", jnp.zeros, shape, dtype),
+        module.variable("cache", "value_pool", jnp.zeros, shape, dtype),
+    )
+
+
+@contextlib.contextmanager
+def layer_view(key_pool, value_pool, layer):
+    """Inside a scan body: route this layer's :func:`paged_cached_attention`
+    to the carried stacks ``[L, NB, bs, H_kv, D]`` instead of ``cache``
+    variables of its own. Yields the view; after the layer ran, its
+    ``key_pool`` / ``value_pool`` are the updated stacks to carry on.
+
+    The stack is viewed as ``[L * NB, bs, H_kv, D]`` (a bitcast) and the
+    layer addresses block ``i`` as ``layer * NB + i``, so the pool keeps
+    rank 4 for ``POOL_KV_SPEC`` and the kernel, and each layer keeps a
+    trash sink of its own (its block 0)."""
+    global _LAYER_VIEW
+    shape = key_pool.shape
+    flat = (shape[0] * shape[1], *shape[2:])
+    view = _LayerView(key_pool.reshape(flat), value_pool.reshape(flat), layer * shape[1])
+    prev, _LAYER_VIEW = _LAYER_VIEW, view
+    try:
+        yield view
+    finally:
+        _LAYER_VIEW = prev
+    view.key_pool = view.key_pool.reshape(shape)
+    view.value_pool = view.value_pool.reshape(shape)
+
+
 # Pool layout on a mesh: heads over ``tensor`` (same TP decode layout as
 # the dense CACHE_KV_SPEC); the block axis is NOT batch — the pool is
 # shared by every row — so it stays unsharded.
@@ -104,9 +177,10 @@ def paged_cached_attention(
     ``block_table`` ``[B, MB]`` and a PER-ROW ``index`` ``[B]`` — ragged
     row positions are native here (the dense branch's scalar frontier
     forces the serving engine to vmap row-wise; the paged tick runs one
-    batched program instead). Prefill always runs dense and is pasted
-    into the pool by :func:`paste_row`, so only ``S_new == 1`` decode
-    steps ever trace this branch.
+    batched program instead). Inside a :func:`layer_view` the pools are
+    the layer's blocks of the carried stack instead. Prefill always runs
+    dense and is pasted into the pool by :func:`paste_row`, so only
+    ``S_new == 1`` decode steps ever trace this branch.
     """
     b, s_new, h_kv, d = k.shape
     if s_new != 1:
@@ -120,8 +194,18 @@ def paged_cached_attention(
     mb = -(-max_len // bs_)
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
 
-    kp = module.variable("cache", "key_pool", jnp.zeros, (nb, bs_, h_kv, d), k.dtype)
-    vp = module.variable("cache", "value_pool", jnp.zeros, (nb, bs_, h_kv, d), v.dtype)
+    view = _LAYER_VIEW
+    if view is None:
+        kp = module.variable("cache", "key_pool", jnp.zeros, (nb, bs_, h_kv, d), k.dtype)
+        vp = module.variable("cache", "value_pool", jnp.zeros, (nb, bs_, h_kv, d), v.dtype)
+        key_pool, value_pool = kp.value, vp.value
+    else:
+        key_pool, value_pool = view.key_pool, view.value_pool
+        if (key_pool.dtype, value_pool.dtype) != (k.dtype, v.dtype):
+            raise TypeError(
+                f"the carried pool stack is {key_pool.dtype}/{value_pool.dtype} but the layer's "
+                f"keys/values are {k.dtype}/{v.dtype}: declare_pool_stack was given the wrong dtype"
+            )
     bt = module.variable("cache", "block_table", jnp.zeros, (b, mb), jnp.int32)
     idx = module.variable("cache", "index", jnp.zeros, (b,), jnp.int32)
 
@@ -131,10 +215,15 @@ def paged_cached_attention(
     # growing cur; past the table it clamps to the last entry (its own
     # reserved block or the trash sink — never another row's block)
     blk = jnp.minimum(cur // bs_, mb - 1)
-    dest = bt.value[rows, blk]  # [B] pool block ids
+    table = bt.value if view is None else bt.value + view.base  # this layer's blocks of the stack
+    dest = table[rows, blk]  # [B] pool block ids
     off = cur % bs_
-    kp.value = _constrain_pool(kp.value.at[dest, off].set(k[:, 0]))
-    vp.value = _constrain_pool(vp.value.at[dest, off].set(v[:, 0]))
+    key_pool = _constrain_pool(key_pool.at[dest, off].set(k[:, 0]))
+    value_pool = _constrain_pool(value_pool.at[dest, off].set(v[:, 0]))
+    if view is None:
+        kp.value, vp.value = key_pool, value_pool
+    else:
+        view.key_pool, view.value_pool = key_pool, value_pool
     idx.value = cur + 1
 
     on_tpu = jax.default_backend() == "tpu"
@@ -151,10 +240,10 @@ def paged_cached_attention(
         )
         run = _kernel_runner(fn, q.shape[2], h_kv)
         if run is not None:  # None: TP mesh the heads can't split -> XLA path
-            return run(q[:, 0], kp.value, vp.value, bt.value, cur)[:, None]
+            return run(q[:, 0], key_pool, value_pool, table, cur)[:, None]
 
     return paged_gather_attention(
-        q, kp.value, vp.value, bt.value, cur, scale=scale, sliding_window=sliding_window
+        q, key_pool, value_pool, table, cur, scale=scale, sliding_window=sliding_window
     )
 
 
@@ -238,11 +327,20 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates):
     returns None)."""
     dense = {_path_names(p): leaf for p, leaf in jax.tree_util.tree_flatten_with_path(row_cache)[0]}
 
+    def rows_of(prefix, name):
+        """The dense leaf a pool holds the rows of: the one ``key`` /
+        ``value`` at or below the pool's module (a layer's own pool sits
+        beside it; a scan's stack sits on the module that owns the scan)."""
+        below = [leaf for p, leaf in dense.items() if p[: len(prefix)] == prefix and p[-1] == name]
+        if len(below) != 1:
+            raise ValueError(f"{len(below)} dense '{name}' leaves under {'/'.join(prefix) or '<root>'}, expected 1")
+        return below[0]
+
     def write(path, leaf):
         names = _path_names(path)
         name, prefix = names[-1], names[:-1]
         if name in ("key_pool", "value_pool"):
-            row = dense[prefix + (name[: -len("_pool")],)]  # key_pool -> key
+            row = rows_of(prefix, name[: -len("_pool")])  # key_pool -> key
             lead = leaf.ndim - 4  # leading layer-scan axes (0 or 1)
             bs_ = leaf.shape[lead + 1]
             mb = write_row.shape[0]

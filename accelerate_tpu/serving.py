@@ -285,7 +285,7 @@ class ServingEngine:
 
         sampler = _make_sampler(temperature, top_k)
 
-        def ctx_jit(fn, name=None):
+        def ctx_jit(fn, name=None, donate_argnums=()):
             """jit + re-enter the model's mesh context around every call:
             a shard_model'ed model pins ITS mesh for the cache sharding
             constraints and the paged kernel's shard_map (constraints
@@ -296,7 +296,9 @@ class ServingEngine:
             layouts are honoured exactly like lazy jit): with a
             persistent store attached, a restarted replica deserializes
             these programs instead of recompiling them."""
-            jitted = self._pc.wrap_jit(jax.jit(fn), name=name or getattr(fn, "__name__", "program"))
+            jitted = self._pc.wrap_jit(
+                jax.jit(fn, donate_argnums=donate_argnums), name=name or getattr(fn, "__name__", "program")
+            )
 
             def call(*args):
                 with self._trace_ctx():
@@ -600,24 +602,33 @@ class ServingEngine:
             # to whatever input shardings GSPMD propagates onto the pool
             # between pastes — an eagerly .lower()ed program would pin the
             # shardings it saw at construction and reject the real ones.
+            #
+            # The pool is ONE buffer for the engine's life: every program
+            # that takes the paged cache donates it, writes in place and
+            # hands the same buffer back (the callers all rebind
+            # ``self.slot_caches``); the array passed in is deleted by the
+            # call, so nothing may keep a reference to it across one.
             raw_tick = make_tick(paged_step)
-            tick = self._pc.wrap_jit(jax.jit(named(raw_tick, "paged_decode_tick")), name="paged_decode_tick")
+            tick = self._pc.wrap_jit(
+                jax.jit(named(raw_tick, "paged_decode_tick"), donate_argnums=(1,)), name="paged_decode_tick"
+            )
             pcfg = self._pcfg
 
             def decode_tick(*args):
                 with paged_mode(pcfg), self._trace_ctx():
                     return tick(*args)
 
+            decode_tick.__wrapped__ = tick.__wrapped__  # the jit object, donation and all
             self._decode_tick = decode_tick
             self._perf_programs["decode_tick"] = (
                 raw_tick,
                 lambda b: (params, self.slot_caches, self.slot_tok, self.slot_pos, self._slot_keys),
                 (lambda: paged_mode(pcfg), self._trace_ctx),
             )
-            self._paste = ctx_jit(paste_row)
-            self._paste_blocks = ctx_jit(paste_blocks)
-            self._clear_slot = ctx_jit(clear_slot)
-            self._set_table = ctx_jit(set_table_row)
+            self._paste = ctx_jit(paste_row, donate_argnums=(0,))
+            self._paste_blocks = ctx_jit(paste_blocks, donate_argnums=(0,))
+            self._clear_slot = ctx_jit(clear_slot, donate_argnums=(0,))
+            self._set_table = ctx_jit(set_table_row, donate_argnums=(0,))
         else:
             def one_step(params, cache_row, tok, pos, key):
                 logits, cache_row = apply_fn(

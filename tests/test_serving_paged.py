@@ -360,3 +360,301 @@ def test_block_allocator():
         alloc.free([0])  # the trash sink is never allocatable/freeable
     with pytest.raises(ValueError):
         BlockAllocator(1)
+
+
+# --------------------------------------------------------------------- #
+# the pool is one buffer: carried through the layer loop, donated at
+# every program boundary
+# --------------------------------------------------------------------- #
+
+
+@pytest.fixture(scope="module")
+def tiny_models():
+    """Tiny llama (``window=None``) or mistral by (scan_layers, window), built once."""
+    from accelerate_tpu.models import MistralConfig, create_mistral_model
+
+    built = {}
+
+    def get(scan_layers, window=None):
+        if (scan_layers, window) not in built:
+            if window is None:
+                model = create_llama_model(LlamaConfig.tiny(scan_layers=scan_layers), seq_len=16)
+            else:
+                cfg = MistralConfig.tiny(sliding_window=window, scan_layers=scan_layers)
+                model = create_mistral_model(cfg, seq_len=32)
+            built[scan_layers, window] = model
+        return built[scan_layers, window]
+
+    return get
+
+
+def _drain(eng, uids):
+    eng.run()
+    return [(eng.poll(u), eng.logprobs(u)) for u in uids]
+
+
+def _readmit(eng):
+    # one slot: admit -> decode -> retire -> the next request into the same slot
+    prompts = [np.arange(1, 1 + n, dtype=np.int32) for n in (5, 9, 3)]
+    return _drain(eng, [eng.submit(p, max_new_tokens=6) for p in prompts])
+
+
+def _prefix(eng):
+    pid = eng.register_prefix((np.arange(9) % 250 + 3).astype(np.int32))
+    return _drain(eng, [eng.submit(np.asarray(s, np.int32), max_new_tokens=5, prefix_id=pid) for s in ([5, 6], [9])])
+
+
+def _window_expiry(eng):
+    # window 4, 16 new tokens: blocks expire behind the frontier while decoding
+    return _drain(eng, [eng.submit(np.arange(1, 5, dtype=np.int32), max_new_tokens=16)])
+
+
+def _preempt_resume(eng):
+    victim = eng.submit((np.arange(6) % 250 + 1).astype(np.int32), max_new_tokens=10, priority=1)
+    eng.step()
+    urgent = eng.submit(np.asarray([3, 1, 4, 1, 5], np.int32), max_new_tokens=4, priority=0)
+    out = _drain(eng, [victim, urgent])
+    assert eng.metrics.decode_preemptions == 1 and eng.metrics.resumes == 1
+    return out
+
+
+_SCENARIOS = {
+    "readmit": (_readmit, dict(num_slots=1, prompt_buckets=(4, 8, 16)), None),
+    "prefix": (_prefix, dict(num_slots=2, prompt_buckets=(4, 8)), None),
+    "window_expiry": (_window_expiry, dict(num_slots=1, prompt_buckets=(8,)), 4),
+    "preempt_resume": (_preempt_resume, dict(num_slots=1, prompt_buckets=(8,)), None),
+}
+
+
+@pytest.mark.parametrize("sampling", [False, True], ids=["greedy", "sampling"])
+@pytest.mark.parametrize("scan_layers", [True, False], ids=["scan", "unrolled"])
+@pytest.mark.parametrize("scenario", list(_SCENARIOS))
+def test_paged_tokens_and_logprobs_equal_dense(tiny_models, scenario, scan_layers, sampling):
+    """The in-place paged engine against the dense engine, through every
+    path that hands the pool to a donated program (paste, tick, clear,
+    paste_blocks, set_table_row): tokens AND logprobs are the same bits."""
+    from accelerate_tpu.scheduling import SchedulerConfig
+
+    drive, kw, window = _SCENARIOS[scenario]
+    kw = dict(kw, tick_block=2, **(dict(temperature=0.9, top_k=5, seed=11) if sampling else {}))
+    if scenario == "preempt_resume":
+        kw["scheduler"] = SchedulerConfig(enable_preemption=True)
+    model = tiny_models(scan_layers, window)
+    dense = drive(ServingEngine(model, **kw))
+    paged = drive(ServingEngine(model, paged_block_size=4, **kw))
+    for (d_tok, d_lp), (p_tok, p_lp) in zip(dense, paged, strict=True):
+        np.testing.assert_array_equal(p_tok, d_tok)
+        np.testing.assert_array_equal(p_lp, d_lp)
+
+
+def _backend_donates():
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.zeros((8,))
+    jax.jit(lambda a: a + 1, donate_argnums=0)(x)
+    return x.is_deleted()
+
+
+@pytest.mark.parametrize("scan_layers", [True, False], ids=["scan", "unrolled"])
+def test_every_cache_program_donates_the_pool_and_updates_it_in_place(tiny_models, scan_layers):
+    """Admission (paste_row), the tick, expiry (set_table_row), retirement
+    (clear_slot) and prefix registration (paste_blocks) each delete the
+    cache they were given, and the pools stay in the buffers they were
+    born in."""
+    import jax
+
+    if not _backend_donates():
+        pytest.skip("this backend does not donate")
+    eng = ServingEngine(tiny_models(scan_layers, 4), num_slots=1, prompt_buckets=(8,), paged_block_size=4, tick_block=2)
+
+    def pools(cache):
+        flat = jax.tree_util.tree_flatten_with_path(cache)[0]
+        return [leaf for path, leaf in flat if str(path[-1].key).endswith("_pool")]
+
+    born = [p.unsafe_buffer_pointer() for p in pools(eng.slot_caches)]
+    seen = set()
+    for name in ("_paste", "_paste_blocks", "_clear_slot", "_set_table", "_decode_tick"):
+        program = getattr(eng, name)
+
+        def checked(*args, _program=program, _name=name):
+            given = args[1] if _name == "_decode_tick" else args[0]
+            out = _program(*args)
+            assert all(leaf.is_deleted() for leaf in jax.tree.leaves(given)), _name
+            seen.add(_name)
+            return out
+
+        setattr(eng, name, checked)
+    pid = eng.register_prefix((np.arange(8) % 250 + 3).astype(np.int32))
+    eng.submit(np.asarray([5], np.int32), max_new_tokens=12, prefix_id=pid)
+    before = eng.slot_caches
+    eng.step()
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(before))
+    eng.run()
+    assert seen == {"_paste", "_paste_blocks", "_clear_slot", "_set_table", "_decode_tick"}
+    assert [p.unsafe_buffer_pointer() for p in pools(eng.slot_caches)] == born
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+@pytest.mark.parametrize("scan_layers", [True, False], ids=["scan", "unrolled"])
+def test_pools_are_loop_state_in_the_tick_never_scanned_over(tiny_models, scan_layers):
+    """On the tick's jaxpr: whatever has the shape of a layer's pool or of
+    the stack enters and leaves every loop as carry, never as a scanned
+    input (a slice per iteration) or a stacked output (a second stack)."""
+    import contextlib
+
+    import jax
+
+    model = tiny_models(scan_layers)
+    eng = ServingEngine(model, num_slots=2, prompt_buckets=(8,), paged_block_size=4, tick_block=2)
+    raw_tick, tick_args, contexts = eng._perf_programs["decode_tick"]
+    with contextlib.ExitStack() as stack:
+        for ctx in contexts:
+            stack.enter_context(ctx())
+        jaxpr = jax.make_jaxpr(raw_tick)(*tick_args(None)).jaxpr
+    cfg, pcfg = model.config, eng._pcfg
+    block = (pcfg.block_size, cfg.num_key_value_heads, cfg.hidden_size // cfg.num_attention_heads)
+
+    def is_pool(var):
+        shape = tuple(var.aval.shape)
+        return shape[-3:] == block and len(shape) >= 4 and shape[-4] % pcfg.num_blocks == 0
+
+    carried = []
+    for eqn in _scans(jaxpr):
+        n_const, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+        xs, ys = eqn.invars[n_const + n_carry:], eqn.outvars[n_carry:]
+        assert not [v for v in (*xs, *ys) if is_pool(v)], f"a pool is scanned over by a loop of {eqn.params['length']}"
+        carried.append((eqn.params["length"], sum(map(is_pool, eqn.invars[n_const:n_const + n_carry]))))
+    layers = cfg.num_hidden_layers
+    # the tick's loop carries K and V (per layer when unrolled); so does the layer loop
+    assert (eng.tick_block, 2 if scan_layers else 2 * layers) in carried
+    assert ((layers, 2) in carried) == scan_layers
+
+
+def test_tick_compiled_for_a_v5e_keeps_the_pool_in_one_buffer(monkeypatch):
+    """The engine's own tick (its jit object, with its donation) compiled
+    for a described v5e at Mistral-7B widths, depth 2: nothing in the
+    program copies, slices out or writes back an array the size of a
+    layer's pool, the pool's bytes are aliased to the output, and the
+    temporaries stay under one layer's pool."""
+    import contextlib
+    import os
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from accelerate_tpu.models import MistralConfig
+    from accelerate_tpu.models.llama import LlamaModel, _wrap_llama
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    chip = SingleDeviceSharding(topo.devices[0])
+    layers, blocks, block_size, slots = 2, 4096, 16, 32
+    cfg = MistralConfig(num_hidden_layers=layers, max_position_embeddings=4096)  # 7B widths are the defaults
+    module = LlamaModel(cfg)
+    shapes = jax.eval_shape(lambda: module.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16), shapes)
+    eng = ServingEngine(
+        _wrap_llama(module, shapes, cfg), num_slots=slots, prompt_buckets=(64,), max_len=4096,
+        paged_block_size=block_size, pool_blocks=blocks,
+    )
+    _, tick_args, contexts = eng._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    # the program asks the default backend whether to lower the Pallas kernel or interpret it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # a program compiled for a described chip cannot be read back from the persistent cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with contextlib.ExitStack() as stack:
+            for ctx in contexts:
+                stack.enter_context(ctx())
+            compiled = eng._decode_tick.__wrapped__.lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the paged decode kernel is in the tick"
+    kv_heads, head_dim = cfg.num_key_value_heads, cfg.hidden_size // cfg.num_attention_heads
+    layer_pool = blocks * block_size * kv_heads * head_dim  # elements of one layer's K (or V) pool
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = bf16\[([\d,]+)\]\S* (copy|dynamic-slice|dynamic-update-slice)\(", line)
+        if m:
+            dims = [int(d) for d in m.group(1).split(",")]
+            if dims[-3:] == [block_size, kv_heads, head_dim] and np.prod(dims) >= layer_pool:
+                moved.append(line.strip()[:160])
+    assert not moved, "the tick moves a whole pool:\n" + "\n".join(moved)
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * layers * layer_pool * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2 * layer_pool * 2, f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries"
+
+
+def test_train_step_jaxpr_does_not_see_the_paged_branch(tiny_models, monkeypatch):
+    """The layer scan serves training too: its jaxpr is the same with the
+    paged hooks made to raise, and the same again while a paged layout is
+    active (only a decode step asks for the pools)."""
+    import jax
+    import jax.numpy as jnp
+
+    import accelerate_tpu.ops.paged_kv as pkv
+    from accelerate_tpu.models.llama import causal_lm_loss
+
+    model = tiny_models(True)
+    batch = {"input_ids": jnp.ones((2, 16), jnp.int32)}
+
+    def train_jaxpr():
+        step = lambda p, b: jax.value_and_grad(causal_lm_loss)(p, b, model.apply_fn)  # noqa: E731
+        return str(jax.make_jaxpr(step)(model.params, batch))
+
+    base = train_jaxpr()
+    with pkv.paged_mode(pkv.PagedConfig(block_size=4, num_blocks=9)):
+        assert train_jaxpr() == base
+
+    def never(*a, **k):
+        raise AssertionError("the paged branch was taken")
+
+    monkeypatch.setattr(pkv, "declare_pool_stack", never)
+    monkeypatch.setattr(pkv, "layer_view", never)
+    assert train_jaxpr() == base
+
+
+def test_setup_log_shows_the_tick_aliasing_its_pool(tiny_llama, tmp_path):
+    """The ProgramCache event of a compiled program carries the
+    executable's alias_bytes / temp_bytes: the tick's covers the pool."""
+    import jax
+
+    from accelerate_tpu.telemetry.eventlog import EventLog, read_events
+
+    log_path = str(tmp_path / "serve.jsonl")
+    log = EventLog(log_path, rank=0)
+    eng = ServingEngine(tiny_llama, num_slots=2, prompt_buckets=(8,), paged_block_size=4, telemetry_log=log)
+    pool_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(eng.slot_caches) if leaf.ndim >= 4)
+    eng.generate_many([np.arange(1, 6, dtype=np.int32)], max_new_tokens=3)
+    log.close()
+    compiled = {e["program"]: e for e in read_events(log_path) if e.get("name") == "compile_cache_miss"}
+    if "alias_bytes" not in compiled["paged_decode_tick"]:
+        pytest.skip("this backend gives no memory analysis")
+    for program in ("paged_decode_tick", "paste_row", "clear_slot"):
+        assert compiled[program]["alias_bytes"] >= pool_bytes, program
+        assert compiled[program]["temp_bytes"] >= 0
