@@ -9,12 +9,11 @@ class Trainer:
     """One compiled step with its state, built once and driven by set-up and window alike."""
 
     def __init__(self, *, accelerator, model, step, table, layers, b1, batches, device_batch, tokens_per_step,
-                 flops_per_step, family, ref_batch):
+                 ref_batch):
         self.accelerator, self.model, self._step = accelerator, model, step
         self._table, self._layers, self._b1 = table, layers, b1
         self._batches, self._device_batch = batches, device_batch
-        self.tokens_per_step, self.flops_per_step, self.family = tokens_per_step, flops_per_step, family
-        self._ref_batch = ref_batch
+        self.tokens_per_step, self._ref_batch = tokens_per_step, ref_batch
 
     def feed(self, i: int):
         """Batch ``i`` of the seeded stream, placed as the program's own data path places it."""

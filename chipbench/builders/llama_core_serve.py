@@ -3,47 +3,9 @@ the configuration's widths: the deployment the configuration file states."""
 
 from __future__ import annotations
 
-import gc
-
-from ._tree import check_same_shapes, to_tree
-from .llama_core_train import TABLE, abstract_params, mistral_config
-
-
-class Server:
-    """The engine behind the four calls the ``open_loop_rounds`` generator makes."""
-
-    family = "mistral"
-
-    def __init__(self, engine, config: dict):
-        self.engine, self.config = engine, config
-        self.tick_block = engine.tick_block
-
-    def submit(self, prompt, new_tokens: int) -> int:
-        return self.engine.submit(prompt, max_new_tokens=new_tokens)
-
-    def step(self) -> None:
-        self.engine.step()
-
-    def tokens_so_far(self, uid: int):
-        return self.engine.partial(uid)
-
-    def finished(self, uid: int) -> bool:
-        return self.engine.poll(uid) is not None
-
-    def busy(self) -> bool:
-        return bool(self.engine.queue) or self.engine.active_count > 0
-
-    def counters(self) -> dict:
-        m = self.engine.metrics
-        return {"prefills": m.prefills, "queue_wait_ms": list(m.queue_wait_ms), "queue_len": len(self.engine.queue),
-                "active": self.engine.active_count}
-
-    def reset_counters(self) -> None:
-        self.engine.metrics.queue_wait_ms.clear()
-
-    def free(self) -> None:
-        self.engine = None
-        gc.collect()
+from chipbench.builders._server import Server
+from chipbench.builders._tree import check_same_shapes, to_tree
+from chipbench.builders.llama_core_train import TABLE, abstract_params, mistral_config
 
 
 def build(config: dict, traffic: dict, seed: int, make_weights) -> Server:

@@ -3,8 +3,8 @@
 
 from __future__ import annotations
 
-from ._trainer import Trainer
-from ._tree import check_same_shapes, reset_accelerator_state, to_tree
+from chipbench.builders._trainer import Trainer
+from chipbench.builders._tree import check_same_shapes, reset_accelerator_state, to_tree
 
 _BLOCK = "layers|block|"
 TABLE = [
@@ -49,8 +49,7 @@ def build(config: dict, traffic: dict, seed: int, make_weights) -> Trainer:
     from accelerate_tpu.parallel.sharding import infer_shardings
     from accelerate_tpu.utils import ParallelismPlugin
 
-    from .. import costs
-    from ._tree import _get
+    from chipbench.builders._tree import _get
 
     bench = config["bench"]
     reset_accelerator_state()
@@ -79,6 +78,5 @@ def build(config: dict, traffic: dict, seed: int, make_weights) -> Trainer:
     return Trainer(
         accelerator=accelerator, model=model, step=step, table=TABLE, layers=cfg.num_hidden_layers, b1=opt["b1"],
         batches=batches, device_batch=lambda b: jax.device_put(b, sharding), tokens_per_step=batch * seq,
-        flops_per_step=costs.mistral_train_flops(config, batch, seq), family="mistral",
         ref_batch=lambda b: b["input_ids"],
     )

@@ -17,9 +17,10 @@ from typing import Optional
 
 import numpy as np
 
-from .. import stats
+from chipbench import stats
 
 SPANS = ("submit", "step", "stamp", "wait")
+FAMILY_GIVES = ("spec", "logits_at")  # what this generator asks of a cell's family
 
 
 @dataclasses.dataclass
@@ -180,8 +181,7 @@ def _compare_with_reference(ctx, done: list) -> list:
     token's logit is held against the reference's best at that position."""
     import jax.numpy as jnp
 
-    from ..reference import mistral as reference
-
+    reference = ctx.family()
     if not done:
         return [ctx.check("logit_gap_max", float("inf"), ctx.limit("logit_gap_max"))]
     rng = np.random.default_rng(ctx.seed + 2)

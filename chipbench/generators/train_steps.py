@@ -10,9 +10,11 @@ from __future__ import annotations
 import math
 import time
 
-from .. import stats
+from chipbench import stats
+from chipbench.reference import train as reference
 
 SPANS = ("feed", "step", "fence")
+FAMILY_GIVES = ("spec", "loss_fn", "LAYER_NAMES", "train_flops")  # what this generator asks of a cell's family
 CHECK_STEPS = 3
 MIN_FENCED_SECONDS = 0.25  # a host-clock reading is off by some half a millisecond
 
@@ -20,18 +22,17 @@ MIN_FENCED_SECONDS = 0.25  # a host-clock reading is off by some half a millisec
 def run(ctx) -> dict:
     import jax
 
-    from ..reference import train as reference
-
+    family = ctx.family()
     trainer = ctx.build()
     losses, at = [], 0
     for at in range(CHECK_STEPS):
         losses.append(float(trainer.step(trainer.feed(at))))
         if at == 0:
-            first_gradient = trainer.first_gradient_norms(_layer_names(trainer.family))
+            first_gradient = trainer.first_gradient_norms(family.LAYER_NAMES)
             first_gradient_tree = trainer.first_gradient_on_host()
     shardings = trainer.param_shardings()
     start = ctx.fresh_weights(shardings=shardings)
-    change = trainer.change_norms(start, _layer_names(trainer.family))
+    change = trainer.change_norms(start, family.LAYER_NAMES)
     del start
 
     # how many steps one fenced reading spans, from two steps timed together
@@ -76,7 +77,8 @@ def run(ctx) -> dict:
     memory_peak = ctx.memory_peak()
     ctx.say("steps", steps=steps, fences=len(groups), elapsed_s=elapsed, last_loss=last_loss)
 
-    family, flops, tokens = trainer.family, trainer.flops_per_step, trainer.tokens_per_step
+    tokens, seq = trainer.tokens_per_step, ctx.traffic["seq"]
+    flops = family.train_flops(ctx.config, tokens // seq, seq)
     batches = [trainer.reference_batch(i) for i in range(CHECK_STEPS)]
     trainer.free()
     t0 = time.perf_counter()
@@ -105,18 +107,10 @@ def run(ctx) -> dict:
     }
 
 
-def _layer_names(family: str):
-    from ..reference import train as reference
-
-    return reference.FAMILIES[family].LAYER_NAMES
-
-
 def _compare(ctx, got: dict, ref: dict, say) -> list:
-    from ..reference import train as reference
-
     loss_gap = max(abs(a - b) / abs(b) for a, b in zip(got["losses"], ref["losses"]))
-    family = ctx.config["bench"]["reference"]
-    matrices = reference.matrix_leaves(ctx.spec(), _layer_names(family))
+    layer_names = ctx.family().LAYER_NAMES
+    matrices = reference.matrix_leaves(ctx.spec(), layer_names)
     vectors = set(ref["change"]) - matrices
     noise = reference.all_but_zero_leaves(ref["first_gradient"])
     numbers, worst = {"loss_gap": loss_gap}, {}
@@ -126,7 +120,7 @@ def _compare(ctx, got: dict, ref: dict, say) -> list:
         numbers[f"change_{which}_gap"], worst[f"change_{which}"] = reference.worst_leaf_gap(
             got["change"], ref["change"], skip | noise)
     # the first gradient itself, leaf by leaf: rounding that a norm hides shows in the difference
-    difference = reference.leaf_difference_norms(got["first_gradient_tree"], ref["first_gradient_tree"], _layer_names(family))
+    difference = reference.leaf_difference_norms(got["first_gradient_tree"], ref["first_gradient_tree"], layer_names)
     numbers["first_gradient_difference"], worst["difference"] = reference.worst_leaf_difference(
         difference, ref["first_gradient"], vectors)
     if say:

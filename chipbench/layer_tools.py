@@ -1,5 +1,6 @@
 """Arithmetic the per-layer readers share. A reader gets ``observed``: what the generator
-recorded, the reduced trace under ``"trace"``, the configuration, the traffic and the device."""
+recorded, the reduced trace under ``"trace"``, the configuration, its family's module under
+``"family"``, the traffic and the device."""
 
 from __future__ import annotations
 
@@ -21,36 +22,20 @@ def decode_only_ticks(observed: dict) -> list:
     return [t for t in observed.get("ticks", ()) if t["prefills"] == 0 and t["decoding"] > 0 and t["start"] >= 0]
 
 
-def decode_step_ms(observed: dict):
-    ticks = decode_only_ticks(observed)
-    if not ticks:
-        return None
-    return stats.median([(t["end"] - t["start"]) * 1e3 for t in ticks]) / observed["tick_block"]
-
-
 def decode_roofline_share(observed: dict):
     """Bytes a decode step must read (weights once, the live cache once) over the chip's
     memory bandwidth, over the step time measured: mean over the decode-only ticks."""
     ticks = decode_only_ticks(observed)
     if not ticks:
         return None
-    cfg, k, bw = observed["config"], observed["tick_block"], _peaks(observed)["hbm_bytes_per_s"]
+    cfg, family, k, bw = observed["config"], observed["family"], observed["tick_block"], _peaks(observed)["hbm_bytes_per_s"]
     shares = []
     for t in ticks:
         live = t["live_tokens"] - t["decoding"] * k / 2.0  # the tick's mean: a slot grows by k over it
-        need = costs.mistral_weight_bytes_per_decode_step(cfg, t["decoding"]) + \
-            costs.mistral_cache_bytes_per_decode_step(cfg, live, t["decoding"])
+        need = family.weight_bytes_per_decode_step(cfg, t["decoding"]) + \
+            family.cache_bytes_per_decode_step(cfg, live, t["decoding"])
         shares.append(need / bw / ((t["end"] - t["start"]) / k))
     return 100.0 * sum(shares) / len(shares)
-
-
-def prefill_ms_per_ktok(observed: dict):
-    """A tick that prefilled, less a decode-only tick, over the prompt tokens it prefilled."""
-    base_ticks = decode_only_ticks(observed)
-    base = stats.median([t["end"] - t["start"] for t in base_ticks]) if base_ticks else 0.0
-    rates = [((t["end"] - t["start"]) - (base if t["decoding"] else 0.0)) * 1e3 / (t["first_token_prompt_tokens"] / 1e3)
-             for t in observed.get("ticks", ()) if t["first_token_prompt_tokens"] > 0 and t["start"] >= 0]
-    return stats.median(rates) if rates else None
 
 
 def kernel_seconds(observed: dict, needle: str):
@@ -70,7 +55,8 @@ def paged_decode_attention_roofline(observed: dict):
     k = observed["tick_block"]
     live = sum(t["live_tokens"] - t["decoding"] * k / 2.0 for t in traced) / len(traced)
     slots = sum(t["decoding"] for t in traced) / len(traced)
-    need = calls * costs.paged_decode_attention_bytes(observed["config"], live, slots)
+    heads, kv_heads, d = observed["family"].attention_shape(observed["config"])
+    need = calls * costs.paged_decode_attention_bytes(heads * d, kv_heads * d, live, slots)
     return 100.0 * need / _peaks(observed)["hbm_bytes_per_s"] / seconds
 
 
@@ -80,9 +66,3 @@ def train_mfu(observed: dict):
     seconds = stats.median(observed["step_ms"]) / 1e3
     return 100.0 * observed["flops_per_step"] / seconds / (observed["chips"] * _peaks(observed)["bf16_flops"])
 
-
-def idle_within_span(observed: dict, name: str):
-    t = observed.get("trace")
-    if not t or not t["span_seconds"].get(name):
-        return None
-    return 100.0 * t["idle_by_span"].get(name, 0.0) / t["span_seconds"][name]

@@ -11,7 +11,7 @@ def read(observed):
     cfg, traffic = observed["config"], observed["traffic"]
     if "seq" not in traffic:
         return None
-    heads, d = cfg["num_attention_heads"], cfg["hidden_size"] // cfg["num_attention_heads"]
+    heads, _, d = observed["family"].attention_shape(cfg)
     need = seconds = 0.0
     for which, needle in KERNELS.items():
         s, calls = kernel_seconds(observed, needle)

@@ -5,6 +5,9 @@ token and a linear classifier; mean cross-entropy. Float32 throughout.
 
 Departures: dropout is off, as in the timed step. The classifier head is the
 fine-tuning head (``num_labels``), not the masked-LM head of the checkpoint.
+
+This file is the family: its seeded weights (``spec``), its plain reference (``loss_fn`` and
+``LAYER_NAMES``: a train cell; no serve path) and a train step's operations (``train_flops``).
 """
 
 from __future__ import annotations
@@ -12,10 +15,42 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .lowprec import DOTS
+from chipbench.reference.lowprec import DOTS
 
 LAYER_NAMES = ("q_w", "q_b", "k_w", "k_b", "v_w", "v_b", "o_w", "o_b", "attn_ln_g", "attn_ln_b",
                "ff1_w", "ff1_b", "ff2_w", "ff2_b", "ffn_ln_g", "ffn_ln_b")
+
+
+def spec(cfg: dict) -> dict:
+    layers, hidden, ff = cfg["num_hidden_layers"], cfg["hidden_size"], cfg["intermediate_size"]
+    std = cfg.get("initializer_range", 0.02)
+    normal, scale = ("normal", std), ("one_plus", 0.1)
+    spec = {
+        "word_emb": ((cfg["vocab_size"], hidden), normal),
+        "pos_emb": ((cfg["max_position_embeddings"], hidden), normal),
+        "type_emb": ((cfg["type_vocab_size"], hidden), normal),
+        "emb_ln_g": ((hidden,), scale), "emb_ln_b": ((hidden,), normal),
+        "ff1_w": ((layers, hidden, ff), normal), "ff1_b": ((layers, ff), normal),
+        "ff2_w": ((layers, ff, hidden), normal), "ff2_b": ((layers, hidden), normal),
+        "pooler_w": ((hidden, hidden), normal), "pooler_b": ((hidden,), normal),
+        "cls_w": ((hidden, cfg["bench"]["num_labels"]), normal), "cls_b": ((cfg["bench"]["num_labels"],), normal),
+    }
+    for name in ("q", "k", "v", "o"):
+        spec[f"{name}_w"] = ((layers, hidden, hidden), normal)
+        spec[f"{name}_b"] = ((layers, hidden), normal)
+    for name in ("attn_ln", "ffn_ln"):
+        spec[f"{name}_g"] = ((layers, hidden), scale)
+        spec[f"{name}_b"] = ((layers, hidden), normal)
+    return spec
+
+
+def train_flops(cfg: dict, batch: int, seq: int) -> float:
+    """Forward and backward of one step: 6 per matmul parameter and token, plus full attention."""
+    hidden, ff, layers = cfg["hidden_size"], cfg["intermediate_size"], cfg["num_hidden_layers"]
+    tokens = batch * seq
+    matmul = 2.0 * tokens * layers * (4 * hidden * hidden + 2 * hidden * ff)
+    attention = layers * 4.0 * batch * seq * seq * hidden
+    return 3.0 * (matmul + attention)
 
 
 def _layer_norm(x, g, b, eps):

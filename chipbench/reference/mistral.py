@@ -4,9 +4,12 @@ arXiv:2104.09864, eq. 34), grouped-query attention under a causal band of
 ``sliding_window`` keys, SwiGLU feed-forward, untied output head. Float32, one layer
 at a time, so that a 16-layer model's float32 copy never exists whole.
 
-Departure: none in the mathematics. Weights come in the benchmark's names
-(``chipbench.weights.mistral_spec``), stacked over layers, in the served type, and
-are widened to float32 a layer at a time.
+Departure: none in the mathematics. Weights come in the benchmark's names (``spec``),
+stacked over layers, in the served type, and are widened to float32 a layer at a time.
+
+This file is the family: its seeded weights (``spec``), its plain reference (``logits_at``
+for a serve cell, ``loss_fn`` and ``LAYER_NAMES`` for a train cell) and what its work
+requires from shapes alone (``train_flops``, ``*_bytes_per_decode_step``, ``attention_shape``).
 """
 
 from __future__ import annotations
@@ -16,7 +19,56 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .lowprec import DOTS
+from chipbench import costs
+from chipbench.reference.lowprec import DOTS
+
+
+def attention_shape(cfg: dict) -> tuple:
+    """Query heads, key/value heads, and the size of one."""
+    return cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["hidden_size"] // cfg["num_attention_heads"]
+
+
+def spec(cfg: dict) -> dict:
+    layers, hidden, ff, vocab = cfg["num_hidden_layers"], cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
+    heads, kv_heads, dim = attention_shape(cfg)
+    q_out, kv_out = heads * dim, kv_heads * dim
+    std = cfg.get("initializer_range", 0.02)
+    normal, scale = ("normal", std), ("one_plus", 0.1)
+    return {
+        "embed": ((vocab, hidden), normal),
+        "wq": ((layers, hidden, q_out), normal), "wk": ((layers, hidden, kv_out), normal),
+        "wv": ((layers, hidden, kv_out), normal), "wo": ((layers, q_out, hidden), normal),
+        "w_gate": ((layers, hidden, ff), normal), "w_up": ((layers, hidden, ff), normal),
+        "w_down": ((layers, ff, hidden), normal),
+        "norm_attn": ((layers, hidden), scale), "norm_mlp": ((layers, hidden), scale),
+        "norm_final": ((hidden,), scale), "lm_head": ((hidden, vocab), normal),
+    }
+
+
+def _layer_matmul_params(cfg: dict) -> int:
+    hidden, (heads, kv_heads, d) = cfg["hidden_size"], attention_shape(cfg)
+    return hidden * (heads * d + 2 * kv_heads * d) + heads * d * hidden + 3 * hidden * cfg["intermediate_size"]
+
+
+def train_flops(cfg: dict, batch: int, seq: int) -> float:
+    """Forward and backward of one step: 6 per matmul parameter and token, plus causal attention."""
+    heads, _, d = attention_shape(cfg)
+    layers = cfg["num_hidden_layers"]
+    matmul = 2.0 * batch * seq * (layers * _layer_matmul_params(cfg) + cfg["hidden_size"] * cfg["vocab_size"])
+    return 3.0 * (matmul + layers * costs.flash_attention_flops(batch, seq, heads, d, "fwd"))
+
+
+def weight_bytes_per_decode_step(cfg: dict, slots: int, itemsize: int = 2) -> float:
+    """Every layer's weights, the final norm and the output head, read once; one embedding row a slot."""
+    hidden = cfg["hidden_size"]
+    params = cfg["num_hidden_layers"] * (_layer_matmul_params(cfg) + 2 * hidden) + hidden + hidden * cfg["vocab_size"]
+    return float(itemsize) * (params + slots * hidden)
+
+
+def cache_bytes_per_decode_step(cfg: dict, live_tokens: float, slots: int, itemsize: int = 2) -> float:
+    """Every layer's live keys and values, queries and output: the paged decode kernel's bytes a layer."""
+    heads, kv_heads, d = attention_shape(cfg)
+    return cfg["num_hidden_layers"] * costs.paged_decode_attention_bytes(heads * d, kv_heads * d, live_tokens, slots, itemsize)
 
 
 def _rms_norm(x, scale, eps):
@@ -35,8 +87,7 @@ def _rotate(x, positions, theta):
 
 def layer(x, w, cfg: dict, dot):
     """One decoder layer over one sequence ``x`` [T, hidden]; ``w`` holds this layer's float32 weights."""
-    heads, kv_heads = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    d = cfg["hidden_size"] // heads
+    heads, kv_heads, d = attention_shape(cfg)
     t = x.shape[0]
     pos = jnp.arange(t)
     h = _rms_norm(x, w["norm_attn"], cfg["rms_norm_eps"])

@@ -9,10 +9,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import bert, mistral
-
-FAMILIES = {"bert": bert, "mistral": mistral}
-
 
 def leaf_norms(tree: dict, layer_names) -> dict:
     """One norm per tensor, and per layer for the tensors stacked over layers."""
@@ -35,10 +31,10 @@ def _n_rows(batch) -> int:
     return jax.tree.leaves(batch)[0].shape[0]
 
 
-def follow(family: str, cfg: dict, weights: dict, batches, opt: dict, row_block: int, dot_name: str = "exact") -> dict:
+def follow(module, cfg: dict, weights: dict, batches, opt: dict, row_block: int, dot_name: str = "exact") -> dict:
     """Three (``len(batches)``) AdamW steps from ``weights`` (float32). Returns each step's
-    loss, the per-leaf norms of the first gradient and of the parameters' change."""
-    module = FAMILIES[family]
+    loss, the per-leaf norms of the first gradient and of the parameters' change. ``module`` is
+    the family's: its ``loss_fn`` and ``LAYER_NAMES``."""
 
     @jax.jit
     def grad_block(w, block):

@@ -1,9 +1,10 @@
 """One cell, one process: ``python3 -m chipbench --workload <name> --seed <n> --seconds <s> --trace <0|1>``.
 
-Reads the cell from ``BENCHMARK.json``, its configuration file, its traffic file and
-the readers of its per-layer metrics by name; makes the weights on the device from the
-seed; warms the cell's own programs; measures for ``--seconds``; checks what the timed
-path produced against the plain reference; prints one JSON object as its last line.
+Reads the cell from ``BENCHMARK.json``, its configuration file, its traffic file, its
+family, builder, generator and the readers of its per-layer metrics by name; makes the
+weights on the device from the seed; warms the cell's own programs; measures for
+``--seconds``; checks what the timed path produced against the plain reference; prints
+one JSON object as its last line.
 Without a TPU (or with fewer chips than the cell asks for) it exits non-zero and prints
 no result. ``--rehearsal`` runs the same control flow on the host's CPU and says so.
 """
@@ -16,10 +17,11 @@ _T_START = time.perf_counter()
 
 import argparse
 import contextlib
-import importlib
 import importlib.util
 import json
+import math
 import os
+import re
 import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -55,11 +57,16 @@ def applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def load_reader(path: str):
-    spec = importlib.util.spec_from_file_location("chipbench_layer_" + os.path.basename(path)[:-3].replace(".", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+def load(manifest: dict, kind: str, name: str):
+    """The module ``<path>/<kind>/<name>.py``: a reader (``layers``), a family (``reference``), a builder
+    or a generator. One module a file a process, so that what it has jitted is jitted once."""
+    path = find_file(manifest, kind, name, (".py",))
+    key = "chipbench_file_" + re.sub(r"\W", "_", path)
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        sys.modules[key] = module = importlib.util.module_from_spec(spec)  # dataclasses look their module up there
+        spec.loader.exec_module(module)
+    return sys.modules[key]
 
 
 class Compiles:
@@ -79,9 +86,9 @@ class Compiles:
 class Context:
     """What the harness hands a generator."""
 
-    def __init__(self, args, cell, config, traffic, limits):
+    def __init__(self, args, manifest, cell, config, traffic, limits):
         self.seed, self.seconds, self.trace, self.control = args.seed, args.seconds, bool(args.trace), bool(args.control)
-        self.cell, self.config, self.traffic, self._limits = cell, config, traffic, limits
+        self.manifest, self.cell, self.config, self.traffic, self._limits = manifest, cell, config, traffic, limits
         self.trace_seconds = TRACE_SECONDS
         self.trace_dir = os.path.join(ROOT, ".cache", "chipbench_trace", cell["name"])
         self.compiles = Compiles()
@@ -94,11 +101,18 @@ class Context:
     def say(self, what: str, **fields) -> None:
         print(json.dumps({"note": what, **fields}), flush=True)
 
-    def spec(self):
-        from . import weights
+    def family(self, *gives):
+        """The module of the cell's family (``bench.reference``), which has to give every name in ``gives``."""
+        name = self.config["bench"]["reference"]
+        module = load(self.manifest, "reference", name)
+        missing = [g for g in gives if not hasattr(module, g)]
+        if missing:
+            raise SystemExit(f"chipbench: the family {name!r} ({module.__file__}) gives no {', '.join(missing)}, "
+                             f"which the cell {self.cell['name']!r} asks of it")
+        return module
 
-        family = self.config["bench"]["reference"]
-        return weights.SPECS[family](self.config)
+    def spec(self):
+        return self.family("spec").spec(self.config)
 
     def _make(self, shardings=None, dtype=None):
         from . import weights
@@ -106,7 +120,7 @@ class Context:
         return weights.make(self.spec(), self.seed, dtype or self.config["bench"]["param_dtype"], shardings)
 
     def build(self):
-        builder = importlib.import_module(f"chipbench.builders.{self.config['bench']['builder']}")
+        builder = load(self.manifest, "builders", self.config["bench"]["builder"])
 
         def make_weights(shardings=None):
             import jax
@@ -245,8 +259,9 @@ def main(argv=None) -> int:
     for item in args.set:
         key, _, value = item.partition("=")
         traffic[key] = json.loads(value)
-    generator = importlib.import_module(f"chipbench.generators.{traffic['generator']}")
-    ctx = Context(args, cell, config, traffic, traffic.get("limits", {}).get(cell["config"], {}))
+    generator = load(manifest, "generators", traffic["generator"])
+    ctx = Context(args, manifest, cell, config, traffic, traffic.get("limits", {}).get(cell["config"], {}))
+    ctx.family(*generator.FAMILY_GIVES)  # a family without the path this cell takes fails here, before any weights
     ctx.say("start", workload=cell["name"], seed=args.seed, seconds=args.seconds, trace=args.trace, device=device)
 
     result = generator.run(ctx)
@@ -266,14 +281,14 @@ def main(argv=None) -> int:
         from .peaks import UnknownDevice
 
         observed = dict(result["observed"], config=config, traffic=traffic, device=device, chips=cell["chips"],
-                        warm_programs=ctx.warm_programs, end_to_end=measured)
+                        warm_programs=ctx.warm_programs, end_to_end=measured, family=ctx.family())
         raw = trace.load(trace.newest_xplane(ctx.trace_dir), observed["spans"])
         observed["trace"] = reduced = trace.reduce(raw)
         metrics = {}
         for m in manifest["per_layer"]:
             if applies(m, cell["name"]):
                 try:
-                    value = load_reader(find_file(manifest, "layers", m["name"], (".py",)))(observed)
+                    value = load(manifest, "layers", m["name"]).read(observed)
                 except UnknownDevice:
                     if not args.rehearsal:
                         raise
@@ -284,7 +299,12 @@ def main(argv=None) -> int:
         device["busy_s"], device["window_s"] = reduced["busy_s"], reduced["window_s"]
         line["breakdown"] = trace.breakdown(reduced)
     line["device"] = device
+    # every number compared beside its limit, last in the line and last on standard error
+    line["compared"] = {c["name"]: {"value": c["value"] if math.isfinite(c["value"]) else repr(c["value"]),
+                                    "limit": c["limit"]} for c in result["checks"]}
     print(json.dumps(line), flush=True)
+    for name, c in line["compared"].items():
+        print(f"chipbench: compared {name} {c['value']} limit {c['limit']}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -74,15 +74,17 @@ def test_cell_files_resolve_by_name(cell):
     assert not any(WIDTH.search(k) for k in config["reduced"])
     with open(run.find_file(M, "traffic", cell["traffic"], (".json",))) as f:
         traffic = json.load(f)
-    assert os.path.isfile(os.path.join(ROOT, "chipbench", "generators", traffic["generator"] + ".py"))
-    assert os.path.isfile(os.path.join(ROOT, "chipbench", "builders", stated["bench"]["builder"] + ".py"))
+    generator = run.load(M, "generators", traffic["generator"])
+    assert callable(generator.run) and callable(run.load(M, "builders", stated["bench"]["builder"]).build)
+    family = run.load(M, "reference", stated["bench"]["reference"])
+    assert all(hasattr(family, name) for name in generator.FAMILY_GIVES), "the family gives what its cell's generator asks"
     assert cell["config"] in traffic["limits"], "a cell's limits are set from readings, in its traffic file"
     reported = [m for m in M["end_to_end"] if run.applies(m, cell["name"])]
     assert "setup_s" in [m["name"] for m in reported] and len(reported) >= 2
     layers = [m for m in M["per_layer"] if run.applies(m, cell["name"])]
     assert layers
     for m in layers:
-        assert callable(run.load_reader(run.find_file(M, "layers", m["name"], (".py",))))
+        assert callable(run.load(M, "layers", m["name"]).read)
 
 
 def test_every_config_is_used_and_four_chip_cells_are_few():

@@ -160,7 +160,6 @@ def test_a_trace_of_a_program_without_spans_keeps_the_benchmarks_thread_and_the_
 NEW_METRICS = [
     ("first_token_hold_p50_ms", "ms", "program_span", "engine host loop", "ttft_p90_ms", "tiny-serve-chat"),
     ("engine_prefill_ms_per_ktok", "ms", "program_span", "jitted programs", "ttft_p90_ms", "tiny-serve-chat"),
-    ("engine_decode_step_ms", "ms", "program_span", "jitted programs", "tpot_p90_ms", "tiny-serve-chat"),
     ("tick_host_ms", "ms", "program_span", "engine host loop", "tpot_p90_ms", "tiny-serve-chat"),
     ("chat_prefill_device_share", "%", "device_trace", "jitted programs", "tpot_p90_ms", "tiny-serve-chat"),
     ("train_dispatch_ms", "ms", "program_span", "jitted programs", "train_tokens_per_s", "tiny-train"),
@@ -183,9 +182,13 @@ def rehearse_under(tmp_path, monkeypatch, capsys):
     manifest["paths"] = [os.path.join(ROOT, p) for p in manifest["paths"]]
     for c in manifest["configs"]:
         c["file"] = os.path.join(ROOT, c["file"])
+    listed = {m["name"]: m for m in manifest["per_layer"]}
     for name, unit, source, layer, moves, cell in NEW_METRICS:
-        manifest["per_layer"].append({"name": name, "unit": unit, "better": "lower", "source": source, "layer": layer,
-                                      "moves": moves, "workloads": [cell]})
+        if name not in listed:  # the toy docqa cell lists ``engine_prefill_ms_per_ktok`` already
+            listed[name] = {"name": name, "unit": unit, "better": "lower", "source": source, "layer": layer,
+                            "moves": moves, "workloads": []}
+            manifest["per_layer"].append(listed[name])
+        listed[name]["workloads"].append(cell)
     path = tmp_path / "BENCHMARK.json"
     path.write_text(json.dumps(manifest))
     monkeypatch.setattr(run, "ROOT", str(tmp_path))

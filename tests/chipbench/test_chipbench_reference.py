@@ -23,7 +23,7 @@ def config(name):
 
 def test_mistral_reference_matches_the_llama_core():
     cfg = config("mistral-tiny")
-    flat = weights.make(weights.mistral_spec(cfg), seed=7, dtype="float32")
+    flat = weights.make(mistral.spec(cfg), seed=7, dtype="float32")
     module, shapes = llama_core_train.abstract_params(llama_core_train.mistral_config(cfg))
     tree = _tree.to_tree(flat, llama_core_train.TABLE, cfg["num_hidden_layers"])
     _tree.check_same_shapes(tree, shapes)
@@ -41,7 +41,7 @@ def test_bert_reference_matches_the_bert_model():
     from accelerate_tpu.models import BertConfig, bert_classification_loss, create_bert_model
 
     cfg = config("bert-tiny")
-    flat = weights.make(weights.bert_spec(cfg), seed=11, dtype="float32")
+    flat = weights.make(bert.spec(cfg), seed=11, dtype="float32")
     fields = ("vocab_size", "hidden_size", "num_hidden_layers", "num_attention_heads", "intermediate_size",
               "max_position_embeddings", "type_vocab_size", "layer_norm_eps")
     model = create_bert_model(BertConfig(num_labels=2, **{k: cfg[k] for k in fields}), seed=0, seq_len=16)
@@ -73,13 +73,13 @@ def test_int8_dot_rounds_and_differentiates():
 
 def test_adamw_follow_and_worst_leaf_gap():
     cfg = config("bert-tiny")
-    flat = weights.make(weights.bert_spec(cfg), seed=3, dtype="float32")
+    flat = weights.make(bert.spec(cfg), seed=3, dtype="float32")
     rng = np.random.default_rng(2)
     batches = [{"input_ids": rng.integers(5, 1000, size=(8, 16)).astype(np.int32),
                 "labels": rng.integers(0, 2, size=(8,)).astype(np.int32)} for _ in range(3)]
     opt = cfg["bench"]["optimizer"]
-    whole = train.follow("bert", cfg, flat, batches, opt, row_block=8)
-    blocks = train.follow("bert", cfg, flat, batches, opt, row_block=2)  # blocks of rows give the same gradient
+    whole = train.follow(bert, cfg, flat, batches, opt, row_block=8)
+    blocks = train.follow(bert, cfg, flat, batches, opt, row_block=2)  # blocks of rows give the same gradient
     assert whole["losses"] == pytest.approx(blocks["losses"], rel=1e-5)
     gap, _ = train.worst_leaf_gap(blocks["first_gradient"], whole["first_gradient"])
     assert gap < 1e-3
@@ -103,7 +103,7 @@ def test_seeds_beyond_32_bits_make_weights():
 
 def test_matrix_leaves_split_vectors_from_matrices():
     cfg = config("bert-tiny")
-    spec = weights.bert_spec(cfg)
+    spec = bert.spec(cfg)
     matrices = train.matrix_leaves(spec, bert.LAYER_NAMES)
     assert "word_emb" in matrices and "q_w[0]" in matrices and "ff2_w[3]" in matrices and "cls_w" in matrices
     assert not {"cls_b", "q_b[0]", "attn_ln_g[2]", "emb_ln_g"} & matrices
