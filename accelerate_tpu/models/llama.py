@@ -105,6 +105,30 @@ class LlamaConfig:
     # ``load_and_quantize_model``, not by hand)
     quant_method: Optional[str] = None
     quant_group_size: Optional[int] = None
+    # Multi-head latent attention (DeepSeek-V2/V3-style checkpoints publish
+    # these keys): with ``kv_lora_rank`` set, every layer's attention is
+    # :class:`LatentAttention` and the cache holds one row of
+    # ``kv_lora_rank + qk_rope_head_dim`` values a token, shared by all
+    # heads. Layers are then unrolled (``scan_layers=False``).
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    # Routed experts (same checkpoints): with ``n_routed_experts`` set, the
+    # layers after the first ``first_k_dense_replace`` replace the MLP by
+    # :class:`RoutedFFN`: ``num_experts_per_tok`` SwiGLU experts of width
+    # ``moe_intermediate_size`` a token, chosen without capacity (no token
+    # is dropped), plus ``n_shared_experts`` that every token takes.
+    # Leading dense layers keep ``intermediate_size``.
+    n_routed_experts: Optional[int] = None
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: Optional[int] = None
+    n_shared_experts: int = 0
+    first_k_dense_replace: int = 0
+    routed_scaling_factor: float = 1.0
+    scoring_func: str = "sigmoid"
+    norm_topk_prob: bool = True
 
     @classmethod
     def llama2_7b(cls, **kw) -> "LlamaConfig":
@@ -504,45 +528,149 @@ class LlamaMLP(nn.Module):
         return _dense(cfg, cfg.hidden_size, "down_proj", hidden.dtype)(act * up)
 
 
-class LlamaLayer(nn.Module):
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA; DeepSeek-V2, arXiv:2405.04434, as the
+    V3-style checkpoints publish it). Queries through a rank-``q_lora_rank``
+    bottleneck with an RMSNorm; keys and values from ONE compressed row a
+    token, ``[c_kv ; k_rope]`` (``kv_lora_rank`` normed values and a rotary
+    key of ``qk_rope_head_dim`` shared by all heads), which is all the cache
+    holds. Rotary pairs are ``(2i, 2i+1)`` (``rope_interleave``).
+
+    Two paths, the same mathematics. Without a cache, and for a cold prefill
+    (``decode=True`` with no cache yet), K and V are decompressed per head
+    (``kv_b_proj``: ``qk_nope_head_dim + v_head_dim`` a head) and ordinary
+    causal attention runs over the new tokens; a cold prefill also stores
+    the rows. Against a cache (decode steps, warm chunk windows) ``W_UK`` is
+    absorbed into the query and ``W_UV`` into the output, so the cache is
+    never decompressed: multi-query attention at head size ``kv_lora_rank +
+    qk_rope_head_dim`` over the shared rows."""
+
     config: LlamaConfig
 
     @nn.compact
     def __call__(self, hidden, positions, decode: bool = False):
         cfg = self.config
+        heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+        nope, rot, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        dt = hidden.dtype
+        if cfg.q_lora_rank is None:
+            raise NotImplementedError("latent attention with a full-rank query projection (q_lora_rank None)")
+        c_q = RMSNorm(cfg.rms_norm_eps, name="q_a_norm")(_dense(cfg, cfg.q_lora_rank, "q_a_proj", dt)(hidden))
+        q = _dense(cfg, heads * (nope + rot), "q_b_proj", dt)(c_q)
+        q = q.reshape(*q.shape[:-1], heads, nope + rot)
+        rope_kw = dict(max_pos=cfg.max_position_embeddings, orig_max=cfg.original_max_position_embeddings,
+                       seq_len=cfg.max_position_embeddings if decode else hidden.shape[1])
+        q_nope, q_rope = q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta, cfg.rope_scaling, **rope_kw)
+        kv_a = _dense(cfg, rank + rot, "kv_a_proj", dt)(hidden)
+        c_kv = RMSNorm(cfg.rms_norm_eps, name="kv_a_norm")(kv_a[..., :rank])
+        k_rope = rope(kv_a[..., None, rank:], positions, cfg.rope_theta, cfg.rope_scaling, **rope_kw)  # [B, S, 1, rot]
+        # W_kvb, a head at a time: [rank, H, nope + v]; W_UK its first nope columns, W_UV the rest
+        w_kvb = self.param("kv_b_proj", nn.initializers.lecun_normal(), (rank, heads, nope + vd)).astype(dt)
+        scale = float(nope + rot) ** -0.5
+        rows = jnp.concatenate([c_kv, k_rope[..., 0, :]], axis=-1)  # [B, S, rank + rot]: what the cache holds
+        from ..ops import kv_cache, paged_kv
+
+        cold = decode and paged_kv.active_paged_config() is None and not self.has_variable("cache", "latent")
+        if decode and not cold:
+            with jax.named_scope("mla.absorb"):
+                q_lat = jnp.concatenate([jnp.einsum("bshn,chn->bshc", q_nope, w_kvb[..., :nope]), q_rope], axis=-1)
+            o_lat = kv_cache.cached_latent_attention(
+                self, q_lat, rows, cfg.max_position_embeddings, value_width=rank, scale=scale
+            )
+            with jax.named_scope("mla.absorb"):
+                out = jnp.einsum("bshc,chv->bshv", o_lat, w_kvb[..., nope:])
+        else:
+            if cold:  # store the rows; attention itself needs only the new tokens
+                cache, idx = kv_cache.latent_cache_variables(
+                    self, rows.shape[0], cfg.max_position_embeddings, rank + rot, dt
+                )
+                cache.value = jax.lax.dynamic_update_slice(cache.value, rows, (0, 0, 0))
+                idx.value = jnp.asarray(rows.shape[1], jnp.int32)
+            kv = jnp.einsum("bsc,chd->bshd", c_kv, w_kvb)
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_rope, (*kv.shape[:-1], rot))], axis=-1)
+            q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
+            # one head size for the dispatched kernels: v padded with zeros to the keys' width, cut after
+            v = jnp.pad(kv[..., nope:], ((0, 0),) * 3 + ((0, nope + rot - vd),))
+            out = _dispatch_attention(q_full, k, v, cfg.attention_impl, scale=scale)[..., :vd]
+        out = out.reshape(*out.shape[:-2], heads * vd)
+        return _dense(cfg, cfg.hidden_size, "o_proj", dt)(out)
+
+
+class RoutedFFN(nn.Module):
+    """Routed SwiGLU experts with shared experts (DeepSeek-V3-style
+    ``noaux_tc`` routing with one group): scores in float32, a selection
+    bias that chooses and does not weigh, normalised top-k weights times
+    ``routed_scaling_factor``, no capacity (:mod:`accelerate_tpu.ops.moe`
+    ``dropless_moe_ffn``). It sows the experts' load of each call into the
+    ``expert_load`` collection, for whoever makes that mutable."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, hidden):
+        from ..ops.moe import EXPERT_LOAD, dropless_moe_ffn, expert_load, sigmoid_topk_routing
+
+        cfg = self.config
+        if cfg.scoring_func != "sigmoid":
+            raise NotImplementedError(f"routed experts score by sigmoid only, got scoring_func={cfg.scoring_func!r}")
+        d, e, ff = cfg.hidden_size, cfg.n_routed_experts, cfg.moe_intermediate_size
+        init = nn.initializers.lecun_normal()
+        router = self.param("router/kernel", init, (d, e))
+        bias = self.param("router/e_score_correction_bias", nn.initializers.zeros, (e,))
+        gate = self.param("experts/gate_proj", init, (e, d, ff))
+        up = self.param("experts/up_proj", init, (e, d, ff))
+        down = self.param("experts/down_proj", init, (e, ff, d))
+        flat = hidden.reshape(-1, d)
+        with jax.named_scope("moe.route"):
+            # float32 as published; ``highest`` because a TPU's default float32 product is one bfloat16 pass
+            logits = jnp.matmul(flat.astype(jnp.float32), router.astype(jnp.float32), precision="highest")
+            experts, weights = sigmoid_topk_routing(
+                logits, bias, cfg.num_experts_per_tok, cfg.norm_topk_prob, cfg.routed_scaling_factor
+            )
+        out, group_sizes = dropless_moe_ffn(flat, experts, weights, gate, up, down)
+        if self.is_mutable_collection(EXPERT_LOAD):
+            self.sow(EXPERT_LOAD, "counts", expert_load(group_sizes))
+        out = out.reshape(hidden.shape)
+        if cfg.n_shared_experts:
+            with jax.named_scope("moe.shared"):
+                shared = dataclasses.replace(cfg, intermediate_size=ff * cfg.n_shared_experts)
+                out = out + LlamaMLP(shared, name="shared_experts")(hidden)
+        return out
+
+
+class LlamaLayer(nn.Module):
+    config: LlamaConfig
+    routed: bool = False  # the FFN is RoutedFFN (a layer past ``first_k_dense_replace`` of a config with experts)
+
+    @nn.compact
+    def __call__(self, hidden, positions, decode: bool = False):
+        cfg = self.config
+        attn_cls = LlamaAttention if cfg.kv_lora_rank is None else LatentAttention
+
+        def attn(x):
+            return attn_cls(cfg, name="attn")(x, positions, decode)
+
+        def mlp(x):
+            if self.routed:
+                return RoutedFFN(cfg, name="mlp")(x)
+            return LlamaMLP(cfg, name="mlp")(x)
+
+        def norm(name):
+            return RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name=name)
+
         if cfg.norm_after:
             # OLMo2 convention: normalize each sublayer's OUTPUT before the
             # residual add (no input norms); HF key post_attention_layernorm
             # maps to post_attn_norm, post_feedforward_layernorm to
             # post_ffn_norm
-            hidden = hidden + RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="post_attn_norm")(
-                LlamaAttention(cfg, name="attn")(hidden, positions, decode)
-            )
-            hidden = hidden + RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="post_ffn_norm")(
-                LlamaMLP(cfg, name="mlp")(hidden)
-            )
-            return hidden
+            hidden = hidden + norm("post_attn_norm")(attn(hidden))
+            return hidden + norm("post_ffn_norm")(mlp(hidden))
         if cfg.sandwich_norm:
             # Gemma2 convention: pre- AND post-norm around each sublayer
-            hidden = hidden + RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="post_attn_norm")(
-                LlamaAttention(cfg, name="attn")(
-                    RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="input_norm")(hidden),
-                    positions, decode,
-                )
-            )
-            hidden = hidden + RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="post_ffn_norm")(
-                LlamaMLP(cfg, name="mlp")(
-                    RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="pre_ffn_norm")(hidden)
-                )
-            )
-            return hidden
-        hidden = hidden + LlamaAttention(cfg, name="attn")(
-            RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="input_norm")(hidden), positions, decode
-        )
-        hidden = hidden + LlamaMLP(cfg, name="mlp")(
-            RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="post_attn_norm")(hidden)
-        )
-        return hidden
+            hidden = hidden + norm("post_attn_norm")(attn(norm("input_norm")(hidden)))
+            return hidden + norm("post_ffn_norm")(mlp(norm("pre_ffn_norm")(hidden)))
+        hidden = hidden + attn(norm("input_norm")(hidden))
+        return hidden + mlp(norm("post_attn_norm")(hidden))
 
 
 class _ScanLayer(nn.Module):
@@ -594,6 +722,12 @@ class LlamaModel(nn.Module):
                 f"layer_types has {len(cfg.layer_types)} entries for "
                 f"{cfg.num_hidden_layers} layers"
             )
+        routed = cfg.n_routed_experts is not None
+        if cfg.scan_layers and (routed or cfg.kv_lora_rank is not None):
+            raise NotImplementedError(
+                "latent attention and routed experts are built with scan_layers=False: a leading dense "
+                "layer differs from the expert layers, and the carried pool stack holds K/V pools only"
+            )
         if cfg.scan_layers:
             # A paged decode step carries the pools of all layers through the
             # loop (scanned over like the rest of the cache, every layer would
@@ -626,6 +760,8 @@ class LlamaModel(nn.Module):
                     jnp.arange(cfg.num_hidden_layers),
                 )
         else:
+            # the first ``first_k_dense_replace`` layers of a config with routed experts keep the dense MLP
+            n_lead = cfg.first_k_dense_replace if routed else 0
             layer_cls = nn.remat(LlamaLayer, prevent_cse=False, static_argnums=(3,)) if cfg.remat else LlamaLayer
             for i in range(cfg.num_hidden_layers):
                 lcfg = cfg
@@ -639,7 +775,7 @@ class LlamaModel(nn.Module):
                         overrides["rope_theta"] = cfg.rope_local_theta
                         overrides["rope_scaling"] = None
                     lcfg = dataclasses.replace(cfg, **overrides)
-                hidden = layer_cls(lcfg, name=f"layer_{i}")(hidden, positions, decode)
+                hidden = layer_cls(lcfg, routed and i >= n_lead, name=f"layer_{i}")(hidden, positions, decode)
         hidden = RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="final_norm")(hidden)
         if cfg.tie_word_embeddings:
             # true weight tying: reuse the embedding table (no lm_head
@@ -667,9 +803,16 @@ def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> 
                 variables["cache"] = cache
             # non-param collections (fp8 amax histories) must be mutable
             # too — their per-step updates are discarded during decode
-            logits, mutated = module.apply(
-                variables, input_ids, positions, True, mutable=["cache", *(state or {})]
-            )
+            mutable = ["cache", *(state or {})]
+            from ..ops.moe import EXPERT_LOAD, requested_expert_load
+
+            # routed experts' counts, for a caller inside ``ops.moe.expert_load_counts()``
+            loads = requested_expert_load()
+            if loads is not None:
+                mutable.append(EXPERT_LOAD)
+            logits, mutated = module.apply(variables, input_ids, positions, True, mutable=mutable)
+            if loads is not None:
+                loads.extend(jax.tree_util.tree_leaves(mutated.get(EXPERT_LOAD, {})))
             return logits, mutated["cache"]
         if state:
             variables = {"params": p, **state}

@@ -30,6 +30,15 @@ from ..parallel.mesh import BATCH_AXES
 CACHE_KV_SPEC = P(BATCH_AXES, None, "tensor", None)
 
 
+def _leaf_name(path) -> str:
+    return str(path[-1].key) if hasattr(path[-1], "key") else str(path[-1])
+
+
+def leaf_names(cache) -> set:
+    """The names of ``cache``'s leaves (``key``, ``latent``, ``index``, ...)."""
+    return {_leaf_name(p) for p, _ in jax.tree_util.tree_flatten_with_path(cache)[0]}
+
+
 def _constrain(x):
     from ..parallel.sharding import maybe_shard
 
@@ -116,16 +125,48 @@ def cached_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
 
 
+def cached_latent_attention(module, q_lat, rows, max_len: int, *, value_width: int, scale: float):
+    """Incremental absorbed latent attention (MLA) against a growing dense
+    cache of latent rows ``[B, max_len, W]`` (``W = kv_lora_rank +
+    qk_rope_head_dim``; one row a token, shared by every head).
+
+    ``q_lat`` ``[B, S_new, H, W]`` are the queries with ``W_UK`` absorbed,
+    ``rows`` ``[B, S_new, W]`` the new tokens' ``[c_kv ; k_rope]``. Returns
+    ``sum_j p_j c_kv_j`` ``[B, S_new, H, value_width]``; the caller applies
+    ``W_UV``. Under the serving engine's paged layout the pool takes the
+    place of the dense buffer (:func:`.paged_kv.paged_latent_attention`)."""
+    from . import paged_kv
+
+    pcfg = paged_kv.active_paged_config()
+    if pcfg is not None:
+        return paged_kv.paged_latent_attention(
+            module, q_lat, rows, max_len, value_width=value_width, scale=scale, cfg=pcfg
+        )
+    cache, idx = latent_cache_variables(module, rows.shape[0], max_len, rows.shape[-1], rows.dtype)
+    cur = idx.value
+    s_new = rows.shape[1]
+    cache.value = jax.lax.dynamic_update_slice(cache.value, rows, (0, cur, 0))
+    idx.value = cur + s_new
+    live = jnp.arange(max_len)[None, :] <= (cur + jnp.arange(s_new))[:, None]  # [S_new, max_len]
+    scores = jnp.einsum("bqhw,bkw->bhqk", q_lat, cache.value).astype(jnp.float32) * scale
+    probs = jax.nn.softmax(jnp.where(live[None, None], scores, -jnp.inf), axis=-1).astype(q_lat.dtype)
+    return jnp.einsum("bhqk,bkc->bqhc", probs, cache.value[..., :value_width])
+
+
+def latent_cache_variables(module, batch: int, max_len: int, width: int, dtype):
+    """The dense latent cache of ``module``: ``latent`` ``[B, max_len, W]`` and the scalar ``index``."""
+    cache = module.variable("cache", "latent", jnp.zeros, (batch, max_len, width), dtype)
+    idx = module.variable("cache", "index", lambda: jnp.zeros((), jnp.int32))
+    return cache, idx
+
+
 def reset_cache_index(cache, new_index):
     """Set every ``index`` leaf of a cache pytree to ``new_index`` — the
     frontier reset shared by the serving engine's padded prefill and
     speculative decoding's accept/reject step: rows past the new frontier
     are stale but sit beyond the causal mask until overwritten."""
-    import jax
-
     def fix(path, leaf):
-        name = str(path[-1].key) if hasattr(path[-1], "key") else str(path[-1])
-        if name == "index":
+        if _leaf_name(path) == "index":
             return jnp.full(leaf.shape, new_index, leaf.dtype)
         return leaf
 

@@ -18,6 +18,7 @@ subsystem here, built the GSPMD way (GShard/Mesh-TF idiom):
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -154,3 +155,92 @@ class MoEBlock(nn.Module):
         )
         self.sow("intermediates", "moe_aux_loss", aux)
         return out.reshape(b, s, d)
+
+
+def sigmoid_topk_routing(
+    router_logits: jax.Array,  # [T, E]
+    selection_bias: Optional[jax.Array],  # [E] or None
+    num_selected: int,
+    norm_topk: bool = True,
+    scaling_factor: float = 1.0,
+):
+    """Dropless top-k routing as DeepSeek-V3-style checkpoints publish it
+    (``topk_method="noaux_tc"`` with one group): scores are
+    ``sigmoid(logits)`` in float32; the ``num_selected``
+    experts of a token are the top-k of ``score + selection_bias`` — the
+    bias *chooses* and does not weigh; the weights are the scores at
+    those experts, divided by their sum when ``norm_topk``, times
+    ``scaling_factor``. Returns ``(experts [T, k] int32, weights [T, k]
+    float32)``. No capacity: every token keeps all its experts."""
+    scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    choose = scores if selection_bias is None else scores + selection_bias.astype(jnp.float32)
+    _, experts = jax.lax.top_k(choose, num_selected)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scaling_factor
+
+
+def dropless_moe_ffn(
+    x: jax.Array,  # [T, d]
+    experts: jax.Array,  # [T, k] int32: the experts each token goes to
+    weights: jax.Array,  # [T, k]: their weights
+    wi_gate: jax.Array,  # [E, d, ff]
+    wi_up: jax.Array,  # [E, d, ff]
+    wo: jax.Array,  # [E, ff, d]
+):
+    """Routed SwiGLU experts with no capacity and no ``[T, E, C]`` mask:
+    the ``T * k`` token-expert pairs are sorted by expert and the three
+    products run as grouped matmuls (``jax.lax.ragged_dot``: on TPU one
+    Mosaic kernel a product, which reads an expert's weights only if a
+    pair reached it), so a decode step of 64 tokens and a prefill of 4096
+    take the same path and no token is ever dropped, at any skew.
+
+    Returns ``(out [T, d], group_sizes [E])``; ``group_sizes`` (pairs per
+    expert) is what :func:`expert_load` reads."""
+    t, k = experts.shape
+    e = wi_gate.shape[0]
+    flat = experts.reshape(t * k)
+    order = jnp.argsort(flat, stable=True)  # pairs grouped by expert
+    group_sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        xs = x[order // k]  # [T*k, d]: pair i of the sorted list belongs to token order[i] // k
+        h = nn.silu(jax.lax.ragged_dot(xs, wi_gate.astype(x.dtype), group_sizes))
+        h = h * jax.lax.ragged_dot(xs, wi_up.astype(x.dtype), group_sizes)
+        ys = jax.lax.ragged_dot(h, wo.astype(x.dtype), group_sizes)  # [T*k, d]
+        # back to token order by a gather (the inverse permutation), then the weighted sum
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k, dtype=order.dtype))
+        ys = ys[back].reshape(t, k, -1)
+        out = jnp.einsum("tkd,tk->td", ys.astype(jnp.float32), weights.astype(jnp.float32)).astype(x.dtype)
+    return out, group_sizes
+
+
+EXPERT_LOAD = "expert_load"  # the flax collection a routed FFN sows its counts into
+_LOAD_COUNTS: Optional[list] = None
+
+
+def expert_load(group_sizes: jax.Array) -> jax.Array:
+    """``[distinct experts with a pair, most pairs on one expert]`` (``[2]`` int32) of one routed FFN call."""
+    return jnp.stack([jnp.sum(group_sizes > 0), group_sizes.max()])
+
+
+@contextlib.contextmanager
+def expert_load_counts():
+    """Trace-time request, in the manner of ``paged_kv.paged_mode``: a
+    model's ``apply_fn`` called inside it makes the ``expert_load``
+    collection mutable and appends what its routed FFNs sowed there
+    (:func:`expert_load` of each call) to the list this yields. The values
+    belong to the caller's trace, so the caller returns them as outputs of
+    its own program (the serving engine's decode tick does, beside the
+    tokens). Outside the context nothing is sown and nothing computed."""
+    global _LOAD_COUNTS
+    prev, _LOAD_COUNTS = _LOAD_COUNTS, []
+    try:
+        yield _LOAD_COUNTS
+    finally:
+        _LOAD_COUNTS = prev
+
+
+def requested_expert_load() -> Optional[list]:
+    """The list of the innermost :func:`expert_load_counts`, or None."""
+    return _LOAD_COUNTS

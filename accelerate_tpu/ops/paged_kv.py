@@ -9,6 +9,18 @@ shapes) allocates cache in fixed-size *blocks* from one shared pool:
   per layer (one ``[L, ...]`` stack on the model under a layer scan) — the
   only large buffers, sized by *expected total tokens in flight*, not
   ``slots x max_len``;
+* or, for latent attention (MLA), ONE ``latent_pool``: ``[num_blocks, W,
+  block_size]`` per layer, a column the compressed ``[c_kv ; k_rope]`` of
+  a token (``W = kv_lora_rank + qk_rope_head_dim``), shared by every
+  head: the decode step reads it as keys (all ``W`` values) and as
+  values (the first ``kv_lora_rank``). A page is stored transposed (tokens
+  along the minor axis) because ``W`` is no multiple of the 128 lanes: the
+  TPU compiler gives a ``[.., block_size, 576]`` array that layout anyway
+  and then re-lays the whole pool out around every program that wants
+  rows. Read through :func:`paged_latent_attention` and the kernel in
+  :mod:`.pallas_latent_attention`; everything below (tables, the sink,
+  prefix aliasing, donation) holds for it as for K/V, the carried stack
+  of a layer scan apart: latent layers are unrolled, a pool each;
 * ``block_table``: ``[B, max_blocks]`` int32 per row — position ``p`` of
   row ``b`` lives at ``pool[table[b, p // bs], p % bs]``;
 * block 0 is a reserved **trash sink**: padded table entries and the
@@ -108,6 +120,9 @@ class _LayerView:
 
 
 _LAYER_VIEW: Optional[_LayerView] = None
+
+# a pool leaf -> the dense row-cache leaf it holds the rows of
+POOL_ROWS = {"key_pool": "key", "value_pool": "value", "latent_pool": "latent"}
 
 
 def declare_pool_stack(module, num_layers: int, kv_heads: int, head_dim: int, dtype):
@@ -279,8 +294,69 @@ def paged_gather_attention(q, key_pool, value_pool, block_table, cur, *, scale, 
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
 
 
-def _kernel_runner(fn, heads: int, kv_heads: int):
-    """How to invoke the paged kernel under the active mesh. A
+def paged_latent_attention(module, q_lat, row, max_len: int, *, value_width: int, scale: float, cfg: PagedConfig):
+    """Single-token absorbed latent attention (MLA) against the paged pool.
+
+    ``q_lat`` ``[B, 1, H, W]`` are the queries with ``W_UK`` absorbed
+    (``[q_nope W_UK^T ; q_rope]``), ``row`` ``[B, 1, W]`` this token's
+    latent row ``[c_kv ; k_rope]``. Declares (per layer) ``latent_pool``
+    ``[NB, W, bs]``, ``block_table`` ``[B, MB]`` and a per-row ``index``
+    ``[B]``, stores the row at each slot's frontier, and returns ``sum_j p_j c_kv_j`` ``[B, 1, H, value_width]``: every head
+    reads the same row, as key over all ``W`` columns and as value over
+    the first ``value_width``. The caller applies ``W_UV``."""
+    b, s_new, width = row.shape
+    if s_new != 1:
+        raise ValueError(
+            f"paged attention is decode-only (S_new == 1, got {s_new}); "
+            "prefill runs the dense path and is pasted into the pool"
+        )
+    bs_, nb = cfg.block_size, cfg.num_blocks
+    mb = -(-max_len // bs_)
+    lp = module.variable("cache", "latent_pool", jnp.zeros, (nb, width, bs_), row.dtype)
+    bt = module.variable("cache", "block_table", jnp.zeros, (b, mb), jnp.int32)
+    idx = module.variable("cache", "index", jnp.zeros, (b,), jnp.int32)
+    pool, table, cur = lp.value, bt.value, idx.value
+    blk = jnp.minimum(cur // bs_, mb - 1)  # overshoot clamp, as in paged_cached_attention
+    # A token is one column of its page. The column is written a page at a time (read the frontier
+    # page of every slot, set the column, write the pages back: 2 x B pages a step) because a scatter of
+    # single columns makes the TPU compiler re-lay the whole pool out. Idle slots all name the sink.
+    dest = table[jnp.arange(b), blk]
+    pages = pool[dest]  # [B, W, bs]
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs_), 2) == (cur % bs_)[:, None, None]
+    pool = lp.value = pool.at[dest].set(jnp.where(at, row[:, 0, :, None], pages))
+    idx.value = cur + 1
+
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu or FORCE_KERNEL_INTERPRET:
+        import functools
+
+        from .pallas_latent_attention import latent_paged_decode
+
+        fn = functools.partial(latent_paged_decode, value_width=value_width, scale=scale, interpret=not on_tpu)
+        run = _kernel_runner(fn, q_lat.shape[2], 1, pool_specs=(P(None, None, None),))
+        if run is not None:
+            return run(q_lat[:, 0], pool, table, cur)[:, None]
+    return paged_latent_gather_attention(q_lat, pool, table, cur, value_width=value_width, scale=scale)
+
+
+def paged_latent_gather_attention(q_lat, latent_pool, block_table, cur, *, value_width: int, scale: float):
+    """The plain XLA paged latent decode step: gather each row's pages into
+    a contiguous ``[B, L, W]`` copy and attend to it. What the Pallas
+    kernel is checked against, and what runs where it cannot."""
+    b = q_lat.shape[0]
+    _, width, bs_ = latent_pool.shape
+    mb = block_table.shape[1]
+    rows = latent_pool[block_table].transpose(0, 1, 3, 2).reshape(b, mb * bs_, width)
+    live = jnp.arange(mb * bs_)[None, :] <= cur[:, None]  # [B, L]
+    scores = jnp.einsum("bqhw,bkw->bhqk", q_lat, rows).astype(jnp.float32) * scale
+    probs = jax.nn.softmax(jnp.where(live[:, None, None, :], scores, -jnp.inf), axis=-1).astype(q_lat.dtype)
+    return jnp.einsum("bhqk,bkc->bqhc", probs, rows[..., :value_width])
+
+
+def _kernel_runner(fn, heads: int, kv_heads: int, pool_specs=None):
+    """How to invoke a paged kernel ``fn(q, *pools, table, cur)`` under the
+    active mesh (``pool_specs``: one spec a pool; default the K and V
+    pools, heads over ``tensor``; a latent pool is replicated). A
     ``pallas_call`` is an opaque custom call XLA's partitioner cannot
     split, so a tensor-parallel pool must be fed per-shard via
     ``shard_map`` over the ``tensor`` axis (heads are independent in
@@ -303,14 +379,15 @@ def _kernel_runner(fn, heads: int, kv_heads: int):
     n_t = axis_size(mesh, "tensor")
     if n_t <= 1:
         return fn
-    if heads % n_t or kv_heads % n_t:
+    if heads % n_t or (kv_heads % n_t and pool_specs is None):
         return None
     qspec = P(None, "tensor", None)
-    pspec = P(None, None, "tensor", None)
+    if pool_specs is None:
+        pool_specs = (P(None, None, "tensor", None),) * 2
     return jax.shard_map(
         fn,
         mesh=mesh,
-        in_specs=(qspec, pspec, pspec, P(None, None), P(None)),
+        in_specs=(qspec, *pool_specs, P(None, None), P(None)),
         out_specs=qspec,
         check_vma=False,
     )
@@ -329,8 +406,8 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates):
 
     def rows_of(prefix, name):
         """The dense leaf a pool holds the rows of: the one ``key`` /
-        ``value`` at or below the pool's module (a layer's own pool sits
-        beside it; a scan's stack sits on the module that owns the scan)."""
+        ``value`` / ``latent`` at or below the pool's module (a layer's own
+        pool sits beside it; a scan's stack sits on the module that owns the scan)."""
         below = [leaf for p, leaf in dense.items() if p[: len(prefix)] == prefix and p[-1] == name]
         if len(below) != 1:
             raise ValueError(f"{len(below)} dense '{name}' leaves under {'/'.join(prefix) or '<root>'}, expected 1")
@@ -339,18 +416,22 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates):
     def write(path, leaf):
         names = _path_names(path)
         name, prefix = names[-1], names[:-1]
-        if name in ("key_pool", "value_pool"):
-            row = rows_of(prefix, name[: -len("_pool")])  # key_pool -> key
-            lead = leaf.ndim - 4  # leading layer-scan axes (0 or 1)
-            bs_ = leaf.shape[lead + 1]
+        if name in POOL_ROWS:
+            row = rows_of(prefix, POOL_ROWS[name])
+            latent = name == "latent_pool"  # pages [W, bs]; K/V pages [bs, H_kv, D]
+            tail = 1 if latent else 2  # axes of one token's row
+            lead = leaf.ndim - 2 - tail  # leading layer-scan axes (0 or 1)
+            bs_ = leaf.shape[-1] if latent else leaf.shape[lead + 1]
             mb = write_row.shape[0]
             max_len = row.shape[lead + 1]
             pad = mb * bs_ - max_len
             if pad:
-                widths = [(0, 0)] * (lead + 1) + [(0, pad), (0, 0), (0, 0)]
+                widths = [(0, 0)] * (lead + 1) + [(0, pad)] + [(0, 0)] * tail
                 row = jnp.pad(row, widths)
             # absorb the B=1 row axis while blockifying
-            blocks = row.reshape(*leaf.shape[:lead], mb, bs_, *leaf.shape[-2:])
+            blocks = row.reshape(*leaf.shape[:lead], mb, bs_, *row.shape[-tail:])
+            if latent:
+                blocks = blocks.swapaxes(-1, -2)
             sel = (slice(None),) * lead + (write_row,)
             return leaf.at[sel].set(blocks.astype(leaf.dtype))
         if name in ("block_table", "index"):

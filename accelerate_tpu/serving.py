@@ -91,6 +91,20 @@ def _row_axis(shape: tuple, cap: int):
     return None
 
 
+def check_handoff_layout(row_cache) -> None:
+    """KV hand-off ships per-head K/V rows. A latent (MLA) row cache — one
+    ``latent`` row a token, shared by all heads — is not carried yet: say so
+    instead of sizing or packing it as K/V."""
+    from .ops.kv_cache import leaf_names
+
+    if "latent" in leaf_names(row_cache):
+        raise NotImplementedError(
+            "KV hand-off (kv_handoff_dims, prefill_detached, HandoffCodec) cannot carry a latent "
+            "cache yet: its rows are [kv_lora_rank + qk_rope_head_dim] latents shared by all heads, "
+            "not per-head keys and values; serve latent-attention models without disaggregated prefill"
+        )
+
+
 class _LazyBuckets:
     """dict-like ``bucket -> compiled program`` that compiles on FIRST
     use instead of eagerly at engine construction: startup pays only for
@@ -560,21 +574,25 @@ class ServingEngine:
             jax.random.key(seed), jnp.arange(num_slots)
         )
 
+        self._tick_expert_load = (0, 0)
+
         def make_tick(step_body):
             """K-step tick scaffold shared by both cache layouts:
             ``step_body(params, caches, toks, poss, keys) -> (caches,
-            next_toks, logprobs, keys)`` advances every slot one token."""
+            next_toks, logprobs, keys, load)`` advances every slot one
+            token; ``load`` is None, or the routed experts' counts of the
+            step (``[expert layers, 2]``, ops/moe.py ``expert_load_counts``)."""
 
             def decode_tick(params, slot_caches, toks, poss, keys):
                 def block_step(carry, _):
                     caches, toks, poss, keys = carry
-                    caches, nxt, lps, keys = step_body(params, caches, toks, poss, keys)
-                    return (caches, nxt, poss + 1, keys), (nxt, lps)
+                    caches, nxt, lps, keys, load = step_body(params, caches, toks, poss, keys)
+                    return (caches, nxt, poss + 1, keys), (nxt, lps, load)
 
-                (slot_caches, _, _, keys), (toks_k, lps_k) = jax.lax.scan(
+                (slot_caches, _, _, keys), (toks_k, lps_k, load_k) = jax.lax.scan(
                     block_step, (slot_caches, toks, poss, keys), None, length=tick_block
                 )
-                return slot_caches, toks_k, lps_k, keys  # each [K, slots]
+                return slot_caches, toks_k, lps_k, keys, load_k  # each [K, slots]; load_k [K, layers, 2] or None
 
             return decode_tick
 
@@ -583,15 +601,19 @@ class ServingEngine:
             # [B], not a scalar), so the tick is ONE batched program — no
             # per-row vmap. Same key-split order as the dense one_step,
             # so outputs stay token-exact across layouts.
+            from .ops.moe import expert_load_counts
+
             def paged_step(params, cache, toks, poss, keys):
-                logits, cache = apply_fn(
-                    params, toks[:, None], positions=poss[:, None], decode=True, cache=cache
-                )
+                # one program sees the whole batch, so routed experts can count their step's load
+                with expert_load_counts() as loads:
+                    logits, cache = apply_fn(
+                        params, toks[:, None], positions=poss[:, None], decode=True, cache=cache
+                    )
                 split = jax.vmap(jax.random.split)(keys)
                 keys, subs = split[:, 0], split[:, 1]
                 nxt = jax.vmap(lambda lg, s: sampler(lg[None], s)[0])(logits[:, -1], subs)
                 lps = jax.vmap(pick_lp)(logits[:, -1], nxt)
-                return cache, nxt, lps, keys
+                return cache, nxt, lps, keys, jnp.stack(loads) if loads else None
 
             from .ops.paged_kv import clear_slot, paged_mode, paste_blocks, paste_row, set_table_row
 
@@ -640,7 +662,7 @@ class ServingEngine:
                 return cache_row, nxt, pick_lp(row, nxt), key
 
             def dense_step(params, caches, toks, poss, keys):
-                return jax.vmap(one_step, in_axes=(None, 0, 0, 0, 0))(params, caches, toks, poss, keys)
+                return *jax.vmap(one_step, in_axes=(None, 0, 0, 0, 0))(params, caches, toks, poss, keys), None
 
             if draft_model is None:
                 raw_dense_tick = make_tick(dense_step)
@@ -653,8 +675,8 @@ class ServingEngine:
                 # stream regardless of what the draft proposes; staleness
                 # costs acceptance rate, never tokens.
                 def pair_step(params, caches, toks, poss, keys):
-                    t_caches, nxt, lps, keys = dense_step(params, caches["t"], toks, poss, keys)
-                    return {"t": t_caches, "d": caches["d"]}, nxt, lps, keys
+                    t_caches, nxt, lps, keys, load = dense_step(params, caches["t"], toks, poss, keys)
+                    return {"t": t_caches, "d": caches["d"]}, nxt, lps, keys, load
 
                 raw_dense_tick = make_tick(pair_step)
             self._decode_tick = ctx_jit(raw_dense_tick)
@@ -999,6 +1021,7 @@ class ServingEngine:
         jax = _jax()
         if self.draft_model is not None:
             raise NotImplementedError("disaggregated prefill does not compose with speculative serving")
+        check_handoff_layout(self._row_template)
         cap = self.model.config.max_position_embeddings
         per_tok = fixed = 0
         for leaf in jax.tree_util.tree_leaves(self._row_template):
@@ -1079,6 +1102,7 @@ class ServingEngine:
         jax = _jax()
         if self.draft_model is not None:
             raise NotImplementedError("disaggregated prefill does not compose with speculative serving")
+        check_handoff_layout(self._row_template)
         prompt = np.asarray(prompt_ids, np.int32).ravel()
         if len(prompt) == 0:
             raise ValueError("empty prompt")
@@ -1460,6 +1484,7 @@ class ServingEngine:
         m = self.metrics
         admitted, tokens_was, completed_was = 0, m.tokens_generated, m.requests_completed
         self._tick_prefill_tokens = 0
+        self._tick_expert_load = (0, 0)
         with phase("engine.schedule"):
             now = time.monotonic()
             self._pool_blocked = False
@@ -1516,7 +1541,8 @@ class ServingEngine:
             "engine.tick.done", admitted=admitted, prefill_tokens=self._tick_prefill_tokens,
             emitted=m.tokens_generated - tokens_was, retired=m.requests_completed - completed_was,
             pool_blocked=int(self._pool_blocked), free_blocks=self._alloc.free_count if self.paged else -1,
-            queue_len=len(self.queue),
+            queue_len=len(self.queue), experts_touched=self._tick_expert_load[0],
+            expert_pairs_max=self._tick_expert_load[1],
         ):
             pass
 
@@ -1845,13 +1871,17 @@ class ServingEngine:
         crash_point("mid_decode", replica=self.metrics.replica)
         jnp = _jax().numpy
         with self._decode_dispatch_phase():
-            self.slot_caches, toks_k, lps_k, self._slot_keys = self._decode_tick(
+            self.slot_caches, toks_k, lps_k, self._slot_keys, load_k = self._decode_tick(
                 self.model.params, self.slot_caches,
                 jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos), self._slot_keys
             )
         with phase("engine.decode.sync"):
             toks_k = np.asarray(toks_k)  # [K, slots] — ONE host sync per block
             lps_k = np.asarray(lps_k)
+            if load_k is not None:
+                load_k = np.asarray(load_k)
+                self._tick_expert_load = (int(load_k[..., 0].sum()), int(load_k[..., 1].max()))
+                self.metrics.on_expert_load(*self._tick_expert_load)
         with phase("engine.decode.walk"):
             for slot, req in enumerate(self.slot_req):
                 if req is None or self.slot_phase[slot] != "decode":
@@ -2181,6 +2211,10 @@ class ServingEngine:
                 if self._shared_refs.get(bid, 0) < 2:
                     raise RuntimeError(f"shared block {bid} over-freed")
         self.slot_req[slot] = None
+        # a free slot still computes in the static tick: every free slot feeds the same token at the same
+        # position, so that a routed FFN sends them all to the same few experts and not each to 8 of its own
+        self.slot_tok[slot] = 0
+        self.slot_pos[slot] = 0
         if self.paged:
             # free this request's blocks and re-point the whole row at the
             # trash sink — the static tick keeps computing for every slot,

@@ -159,6 +159,9 @@ class HandoffCodec:
     @staticmethod
     def encode(handoff: dict) -> bytes:
         jax = _jax()
+        from .serving import check_handoff_layout
+
+        check_handoff_layout(handoff["cache"])
         leaves = jax.tree_util.tree_leaves(handoff["cache"])
         arrays = {
             "prompt": np.asarray(handoff["prompt"], np.int32),
@@ -195,6 +198,9 @@ class HandoffCodec:
         (leaf dtypes + tree structure); the result feeds
         ``engine.submit_prefilled`` unchanged."""
         jax = _jax()
+        from .serving import check_handoff_layout
+
+        check_handoff_layout(engine._row_template)
         with np.load(io.BytesIO(data)) as z:
             imeta = z["imeta"]
             n_leaves = int(imeta[5])

@@ -122,6 +122,12 @@ class ServingMetrics:
         self.requests_deprioritized = 0
         self.decode_preemptions = 0  # decoding slots evicted + requeued
         self.resumes = 0  # preempted requests resumed by recompute
+        # routed experts (models with a RoutedFFN; 0 otherwise), from the
+        # decode ticks' ``expert_load``: distinct experts that got a token,
+        # summed over expert layers and decode steps, and the most tokens
+        # any one expert got in a step
+        self.experts_touched = 0
+        self.expert_pairs_max = 0
         # cross-request prefix reuse (serving_fleet.RadixPrefixCache):
         # a hit means the request skipped re-prefilling that many shared
         # preamble tokens — the fleet's dominant p95-TTFT lever
@@ -180,6 +186,10 @@ class ServingMetrics:
     def on_tokens(self, n: int = 1):
         self.tokens_generated += n
         self._token_marks.append((self._clock(), self.tokens_generated))
+
+    def on_expert_load(self, touched: int, pairs_max: int):
+        self.experts_touched += touched
+        self.expert_pairs_max = max(self.expert_pairs_max, pairs_max)
 
     def on_tick_tokens(self, uid: int, n: int):
         """ITL sample: ``n`` tokens delivered to ``uid`` this tick."""

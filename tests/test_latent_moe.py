@@ -1,0 +1,186 @@
+"""Latent attention (MLA) and dropless routed experts on the llama core, at a toy size on the CPU:
+the routed FFN against a loop over experts, absorbed against non-absorbed attention, the model through
+``ServingEngine`` with the paged latent cache (XLA gather path and the interpreted Pallas kernel), a
+warm chunk window against a cold prefill, and what the engine cannot carry yet. The comparison with the
+benchmark's plain reference is in tests/chipbench/test_chipbench_latent_moe.py."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from accelerate_tpu.models.joyai_llm_flash import JoyAIFlashConfig, create_joyai_flash_model
+from accelerate_tpu.ops import paged_kv
+from accelerate_tpu.ops.moe import dropless_moe_ffn, sigmoid_topk_routing
+from accelerate_tpu.serving import ServingEngine
+
+
+def _experts(seed, e=8, d=16, ff=24):
+    k = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(k[0], (e, d, ff)) * 0.3, jax.random.normal(k[1], (e, d, ff)) * 0.3,
+            jax.random.normal(k[2], (e, ff, d)) * 0.3, k[3])
+
+
+def _expert_loop(x, experts, weights, gate, up, down):
+    """Every token through each of its experts, one pair at a time."""
+    out = np.zeros(x.shape, np.float32)
+    for t in range(x.shape[0]):
+        for e, w in zip(np.asarray(experts[t]), np.asarray(weights[t])):
+            h = jax.nn.silu(x[t] @ gate[e]) * (x[t] @ up[e])
+            out[t] += w * np.asarray(h @ down[e])
+    return out
+
+
+@pytest.mark.parametrize("case", ["balanced", "one_expert", "single_token"])
+def test_dropless_ffn_is_the_expert_loop(case):
+    """float32 on the CPU: the grouped products add the same terms in another order, so 1e-5."""
+    gate, up, down, key = _experts(0)
+    t = 1 if case == "single_token" else 12
+    x = jax.random.normal(key, (t, 16))
+    if case == "one_expert":  # every token on expert 5 (and 2): nothing is dropped at any skew
+        experts = jnp.tile(jnp.array([[5, 2]], jnp.int32), (t, 1))
+    else:
+        experts = jnp.stack([jnp.arange(t) % 8, (jnp.arange(t) + 3) % 8], axis=1).astype(jnp.int32)
+    weights = jax.random.uniform(jax.random.key(7), (t, 2)) + 0.1
+    out, sizes = dropless_moe_ffn(x, experts, weights, gate, up, down)
+    assert int(sizes.sum()) == 2 * t and (case != "one_expert" or int(sizes[5]) == t)
+    np.testing.assert_allclose(np.asarray(out), _expert_loop(x, experts, weights, gate, up, down), atol=1e-5)
+
+
+def test_selection_bias_changes_the_choice_and_not_the_weight():
+    logits = jnp.array([[2.0, 1.0, 0.5, -1.0]])
+    plain_e, plain_w = sigmoid_topk_routing(logits, None, 2, norm_topk=False, scaling_factor=1.0)
+    assert sorted(np.asarray(plain_e[0])) == [0, 1]
+    bias = jnp.array([0.0, -1.0, 0.0, 1.0])  # expert 3 now beats expert 1
+    e, w = sigmoid_topk_routing(logits, bias, 2, norm_topk=False, scaling_factor=1.0)
+    assert sorted(np.asarray(e[0])) == [0, 3]
+    scores = np.asarray(jax.nn.sigmoid(logits[0]))
+    np.testing.assert_allclose(np.asarray(w[0]), scores[np.asarray(e[0])], rtol=1e-6)  # the scores, without the bias
+    _, normed = sigmoid_topk_routing(logits, bias, 2, norm_topk=True, scaling_factor=2.5)
+    np.testing.assert_allclose(float(normed.sum()), 2.5, rtol=1e-6)
+
+
+@pytest.fixture(scope="module")
+def model():
+    return create_joyai_flash_model(JoyAIFlashConfig.tiny(), seed=3, seq_len=16)
+
+
+def test_leading_dense_layer_then_routed_layers(model):
+    lead = model.params["layer_0"]
+    assert "gate_proj" in lead["mlp"] and "kv_b_proj" in lead["attn"]
+    for routed in (model.params["layer_1"], model.params["layer_2"]):
+        assert "experts/gate_proj" in routed["mlp"] and "shared_experts" in routed["mlp"]
+
+
+@pytest.mark.parametrize(
+    "change, names",
+    [
+        ({"scan_layers": True}, "scan_layers=False"),  # no carried stack holds a latent pool, and layer 0 differs
+        ({"scoring_func": "softmax"}, "sigmoid"),  # no configuration, and so no reference, scores otherwise
+        ({"q_lora_rank": None}, "q_lora_rank"),
+    ],
+    ids=["scanned", "softmax_scores", "full_rank_queries"],
+)
+def test_what_no_configuration_runs_is_refused_by_name(change, names):
+    with pytest.raises(NotImplementedError, match=names):
+        create_joyai_flash_model(JoyAIFlashConfig.tiny(**change), seed=3, seq_len=16)
+
+
+def test_absorbed_decode_is_the_non_absorbed_forward(model):
+    """Cold prefill (K and V decompressed per head) then absorbed steps over the dense latent cache,
+    against the plain forward, in float32: the same sums in another order, 2e-5 on logits of size 1."""
+    ids = (np.arange(1, 25, dtype=np.int32)[None] * 7) % 250
+    full = model.apply_fn(model.params, jnp.asarray(ids))
+    logits, cache = model.apply_fn(model.params, jnp.asarray(ids[:, :10]), positions=jnp.arange(10)[None], decode=True, cache=None)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(full[:, :10]), atol=2e-5)
+    latent = [l for p, l in jax.tree_util.tree_flatten_with_path(cache)[0] if str(p[-1].key) == "latent"]
+    assert latent and all(l.shape[-2:] == (128, 32 + 16) for l in latent), "one row of rank + rope values a token"
+    for t in range(10, 24):
+        logits, cache = model.apply_fn(model.params, jnp.asarray(ids[:, t:t + 1]), positions=jnp.full((1, 1), t), decode=True, cache=cache)
+        np.testing.assert_allclose(np.asarray(logits[:, 0]), np.asarray(full[:, t]), atol=2e-5)
+
+
+def test_warm_chunk_window_is_the_cold_prefill(model):
+    """A window over a cache that already holds rows attends through the absorbed path."""
+    ids = (np.arange(3, 27, dtype=np.int32)[None] * 5) % 250
+    cold, _ = model.apply_fn(model.params, jnp.asarray(ids), positions=jnp.arange(24)[None], decode=True, cache=None)
+    _, cache = model.apply_fn(model.params, jnp.asarray(ids[:, :8]), positions=jnp.arange(8)[None], decode=True, cache=None)
+    warm, _ = model.apply_fn(model.params, jnp.asarray(ids[:, 8:]), positions=jnp.arange(8, 24)[None], decode=True, cache=cache)
+    np.testing.assert_allclose(np.asarray(warm), np.asarray(cold[:, 8:]), atol=2e-5)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla_gather", "pallas_interpreted"])
+def test_engine_serves_from_the_paged_latent_cache(model, kernel, monkeypatch):
+    """Bucketed prefill, paste into the latent pool, decode ticks, a prompt over the largest bucket
+    (chunk windows) and retirement: every served token is the plain forward's greedy token, and its
+    logit is within 1e-4 of the forward's best (float32; a tie broken otherwise would show there)."""
+    monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", kernel)
+    engine = ServingEngine(model, num_slots=3, prompt_buckets=(8, 16), max_len=64, paged_block_size=8, tick_block=4)
+    pool = [l for p, l in jax.tree_util.tree_flatten_with_path(engine.slot_caches)[0] if str(p[-1].key) == "latent_pool"]
+    assert pool and all(l.shape[-2:] == (48, 8) for l in pool), "pages of [rank + rope, block] values"
+    prompts = [np.arange(1, 6, dtype=np.int32), np.arange(3, 17, dtype=np.int32), np.arange(7, 10, dtype=np.int32),
+               np.arange(2, 30, dtype=np.int32)]
+    for prompt, out in zip(prompts, engine.generate_many(prompts, max_new_tokens=9)):
+        out = np.asarray(out)
+        ref = np.asarray(model.apply_fn(model.params, jnp.asarray(out[None])))[0, len(prompt) - 1:-1]
+        served = out[len(prompt):]
+        assert len(served) == 9
+        assert (ref.max(-1) - ref[np.arange(9), served]).max() < 1e-4
+    m = engine.metrics
+    layers = model.config.num_hidden_layers - 1
+    assert 0 < m.expert_pairs_max <= 3 * 2 and m.experts_touched > 0
+    assert m.experts_touched <= engine._tick * engine.tick_block * layers * 8
+
+
+def test_expert_load_counts_are_a_ticks(model):
+    """The counts leave the tick beside its tokens (``[steps, expert layers, 2]``), and the cache holds
+    nothing but rows, tables and frontiers: no cache program has to know of them."""
+    engine = ServingEngine(model, num_slots=2, prompt_buckets=(8,), max_len=32, paged_block_size=8, tick_block=2)
+    names = {str(p[-1].key) for p, _ in jax.tree_util.tree_flatten_with_path(engine.slot_caches)[0]}
+    assert names == {"latent_pool", "block_table", "index"}
+    engine.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=6)
+    seen = []
+    while engine.queue or engine.active_count:
+        engine.step()
+        seen.append(engine._tick_expert_load)
+    layers = model.config.num_hidden_layers - 1
+    # two slots (one idle) route a token each in each of 2 steps of each expert layer: 2..4 experts a layer a step
+    assert all(2 * layers * 2 <= touched <= 2 * layers * 4 and 1 <= most <= 2 for touched, most in seen)
+    assert engine.metrics.experts_touched == sum(touched for touched, _ in seen)
+
+
+def test_expert_load_is_collected_only_inside_its_context(model):
+    """Through ``nn.remat`` too (the toy model rematerialises its layers): the counts are sown, not leaked."""
+    from accelerate_tpu.ops.moe import expert_load, expert_load_counts
+
+    assert np.asarray(expert_load(jnp.array([4, 0, 1, 2, 0]))).tolist() == [3, 4]
+    ids, pos = jnp.asarray([[5, 9, 5]]), jnp.arange(3)[None]
+    with expert_load_counts() as loads:
+        jax.jit(lambda p: model.apply_fn(p, ids, positions=pos, decode=True, cache=None)[0])(model.params)
+        assert len(loads) == model.config.num_hidden_layers - 1 and all(l.shape == (2,) for l in loads)
+    _, cache = model.apply_fn(model.params, ids, positions=pos, decode=True, cache=None)
+    assert len(loads) == 2 and "expert_load" not in cache
+
+
+def test_kv_handoff_says_it_cannot_carry_a_latent_cache(model):
+    from accelerate_tpu.serving_fleet import HandoffCodec
+
+    engine = ServingEngine(model, num_slots=2, prompt_buckets=(8,), max_len=32)
+    with pytest.raises(NotImplementedError, match="latent"):
+        engine.kv_handoff_dims()
+    with pytest.raises(NotImplementedError, match="latent"):
+        engine.prefill_detached(np.arange(1, 6, dtype=np.int32), 4)
+    with pytest.raises(NotImplementedError, match="latent"):
+        HandoffCodec.decode(b"", engine)
+
+
+def test_generate_works_through_the_dense_latent_cache():
+    from accelerate_tpu.generation import generate
+
+    model = create_joyai_flash_model(JoyAIFlashConfig.tiny(), seed=3, seq_len=16)
+    prompt = jnp.asarray((np.arange(1, 9, dtype=np.int32)[None] * 3) % 250)
+    out = np.asarray(generate(model, prompt, max_new_tokens=6))
+    ref = np.asarray(model.apply_fn(model.params, jnp.asarray(out)))[0, 7:-1]
+    assert (ref.max(-1) - ref[np.arange(6), out[0, 8:]]).max() < 1e-4

@@ -95,6 +95,68 @@ def test_paged_decode_attention_compiles_for_v5e(v5e, window):
     )
 
 
+def test_latent_paged_decode_compiles_for_v5e(v5e):
+    """The latent (MLA) decode kernel at JoyAI-LLM-Flash widths: 32 heads against one shared row of
+    512 + 64 values, 64 slots of 40 pages of 128 tokens."""
+    from accelerate_tpu.ops.pallas_latent_attention import latent_paged_decode
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    slots, block, table, width, rank = 64, 128, 40, 576, 512
+    fn = functools.partial(latent_paged_decode, value_width=rank, scale=192**-0.5, interpret=False)
+    text = _compile(
+        fn, _on(chip, (slots, HEADS, width)), _on(chip, (slots * table + 1, width, block)),
+        _on(chip, (slots, table), jnp.int32), _on(chip, (slots,), jnp.int32),
+    )
+    assert "latent_paged_decode" in text and "paged_decode_attention" not in text, "found by a name of its own"
+
+
+def test_latent_moe_decode_tick_fits_one_v5e_chip_and_moves_no_pool(v5e, monkeypatch):
+    """The 64-slot decode tick of the ``joyai-flash-serve-longchat`` cell at its real size: 5.56 B
+    parameters and a 1.89 GB latent pool as arguments, the pool aliased to the output, and no
+    operation that copies or re-lays a whole layer's pool (the compiler did both around a
+    ``[.., 128, 576]`` pool: ops/paged_kv.py). A compile is not a chip run."""
+    import contextlib
+    import json
+    import re
+
+    from accelerate_tpu.models.llama import _wrap_llama
+    from accelerate_tpu.serving import ServingEngine
+    from chipbench import run
+
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    with open(os.path.join(REPO, "chipbench", "configs", "joyai-llm-flash-l5.json")) as f:
+        config = json.load(f)
+    builder = run.load(manifest, "builders", config["bench"]["builder"])
+    cfg = builder.core_config(config)
+    module, shapes = builder.abstract_params(cfg)
+    shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, BF16), shapes)
+    s = config["bench"]["serving"]
+    engine = ServingEngine(
+        _wrap_llama(module, shapes, cfg), num_slots=s["num_slots"], prompt_buckets=tuple(s["prompt_buckets"]),
+        max_len=s["max_len"], paged_block_size=s["paged_block_size"], pool_blocks=s["pool_blocks"],
+    )
+    chip = SingleDeviceSharding(v5e.devices[0])
+    raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernel
+    with contextlib.ExitStack() as stack:
+        for ctx in contexts:
+            stack.enter_context(ctx())
+        compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("latent_paged_decode") >= 5 and "ragged-dot" in text
+    pool = f"{s['pool_blocks']},576,{s['paged_block_size']}"
+    moved = [l.strip()[:140] for l in text.splitlines() if re.search(rf"= bf16\[{pool}\]\S* (copy|transpose)\(", l)]
+    assert not moved, "the tick moves a whole pool:\n" + "\n".join(moved)
+    m = compiled.memory_analysis()
+    pool_bytes = 5 * s["pool_blocks"] * 576 * s["paged_block_size"] * 2
+    assert m.alias_size_in_bytes >= pool_bytes and m.temp_size_in_bytes < 0.5 * 2**30
+    assert m.argument_size_in_bytes > 11.9 * 2**30, "weights and the pool are arguments at their real size"
+    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert total <= 15.75 * 2**30, f"the 64-slot tick needs {total / 2**30:.2f} GiB"
+
+
 @pytest.mark.parametrize(
     "n_in,n_out,group",
     [(4096, 14336, 128), (14336, 4096, 128), (4096, 4096, 64)],
