@@ -44,9 +44,12 @@ deleted by the call — rebind to what it returns (``cache = f(cache, ...)``)
 and never read the old value again.
 
 Everything stays static-shape. On TPU the decode step dispatches to the
-Pallas kernel in :mod:`.pallas_paged_attention`, which DMAs each page
-into VMEM exactly once via scalar-prefetched table indexing (under a
-``shard_map`` over ``tensor`` when the pool is TP-sharded — a
+Pallas kernel in :mod:`.pallas_paged_attention`, which leaves the pool
+in HBM and walks each row's LIVE pages only (first page of the band to
+the frontier's, read from the scalar-prefetched table and frontier):
+async copies a chunk of pages at a time into two VMEM buffers, one
+product a chunk; reserved and pad table entries are never visited
+(under a ``shard_map`` over ``tensor`` when the pool is TP-sharded — a
 ``pallas_call`` can't be auto-partitioned). The XLA fallback (CPU, or
 head counts the tensor axis can't split) gathers ``pool[table]`` into a
 contiguous ``[B, L, H_kv, D]`` copy — dense-equivalent read bytes plus
@@ -243,9 +246,10 @@ def paged_cached_attention(
 
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu or FORCE_KERNEL_INTERPRET:
-        # Pallas kernel: reads each page once via scalar-prefetched table
-        # indexing — no [B, L, H_kv, D] gather materialisation (the XLA
-        # fallback below writes+rereads one; ~3x the attention traffic)
+        # Pallas kernel: reads each row's live pages once, out of the pool
+        # in HBM, through the scalar-prefetched table and frontier — no
+        # [B, L, H_kv, D] gather materialisation (the XLA fallback below
+        # writes+rereads one; ~3x the attention traffic)
         import functools
 
         from .pallas_paged_attention import paged_decode_attention
@@ -255,7 +259,12 @@ def paged_cached_attention(
         )
         run = _kernel_runner(fn, q.shape[2], h_kv)
         if run is not None:  # None: TP mesh the heads can't split -> XLA path
-            return run(q[:, 0], key_pool, value_pool, table, cur)[:, None]
+            # A row that stores this token in the sink is idle, or finished and overshooting: nothing
+            # reads what it attends to, and its frontier has grown a step a token since clear_slot
+            # zeroed it. The kernel walks a row's pages up to the frontier it is given: one page, then.
+            sink = 0 if view is None else view.base
+            walk_to = jnp.where(dest == sink, 0, cur)
+            return run(q[:, 0], key_pool, value_pool, table, walk_to)[:, None]
 
     return paged_gather_attention(
         q, key_pool, value_pool, table, cur, scale=scale, sliding_window=sliding_window

@@ -10,8 +10,8 @@ pool and used twice, as keys and as values. A page is ``[W, block_size]``,
 its tokens along the lanes (``ops/paged_kv.py`` says why): the scores are a
 plain product and the values contract over the lanes of both operands.
 
-Built like :mod:`.pallas_paged_attention`: the grid walks ``(row,
-table_entry)``, the block table is a scalar-prefetch operand, pages
+Built as :mod:`.pallas_paged_attention` was until it took to walking a row's live pages inside one grid step:
+the grid walks ``(row, table_entry)``, the block table is a scalar-prefetch operand, pages
 beyond a row's frontier are skipped with ``pl.when`` (and their index
 stays on the last live page, so the repeated index elides the DMA too),
 and an online softmax folds every page into a ``[H, value_width]``

@@ -1,29 +1,51 @@
 """Pallas TPU kernel: paged-attention decode over a shared block pool.
 
-The XLA paged path (:func:`accelerate_tpu.ops.paged_kv.paged_cached_attention`)
+The XLA paged path (:func:`accelerate_tpu.ops.paged_kv.paged_gather_attention`)
 gathers each row's pages into a contiguous ``[B, L, H_kv, D]`` copy
 every step — the gather WRITES a full cache-sized array and the two
 attention einsums read it back, roughly tripling the per-step HBM
 traffic of the (bandwidth-bound) decode attention. This kernel reads
-each page exactly once: the grid walks ``(row, table_entry)``, the
-block table is a scalar-prefetch operand so each step's ``index_map``
-DMAs the right pool block directly into VMEM, and an online-softmax
-accumulator (flash-attention style) folds every page into ``[H, D]``
-scratch without materialising the gathered cache.
+each LIVE page once and nothing else:
 
-* pages fully beyond the row's frontier (or entirely outside the
-  sliding-window band) are skipped with ``pl.when`` — and because pad
-  table entries all point at the trash-sink block, their repeated index
-  elides the DMA as well;
-* GQA runs as one batched ``dot_general`` over KV heads (queries
-  reshaped ``[H_kv, G, D]``), never repeating K/V;
-* the decode contract matches the XLA branch bit-for-bit in masking:
-  keys at positions ``> cur - W`` and ``<= cur``.
+* one grid step is one row (slot), not one table entry. The pools stay
+  in HBM; inside the step a loop walks the row's live pages, ``first ..
+  cur // block_size`` (``first`` is 0, or under a sliding window the page
+  that holds the band's oldest key), a *chunk* of pages at a time. Its
+  trip count comes from the scalar-prefetched frontier, so table entries
+  past the frontier (blocks reserved for tokens not yet decoded, pad
+  entries at the trash sink) are neither visited nor fetched, and an idle
+  row (frontier 0) costs one page;
+* a chunk is fetched with one async copy a page, addressed through the
+  scalar-prefetched table, into one of two VMEM buffers: chunk ``i + 1``
+  is in flight while chunk ``i`` is folded, and the first chunk of row
+  ``b + 1`` is started before row ``b`` is finished, so the copies'
+  latency is paid once a call and not once a row;
+* the fold is one MXU-shaped product a chunk with no relayout. A page
+  ``[bs, H_kv, D]`` is, byte for byte, ``[bs * H_kv, D]``; ``q [H, D]``
+  against a chunk of them gives scores ``[H, pages * bs * H_kv]`` in
+  which head ``h`` keeps the columns of its own key/value head (``col %
+  H_kv == h // G``); the rest are masked with the positions past the
+  frontier or before the band, and the masked probabilities contract
+  against the value chunk in the same view. ``H_kv`` times the
+  arithmetic of a grouped product, all of it on full tiles, and K/V are
+  never repeated, transposed or cast;
+* operands go to the MXU in the pool's type with float32 accumulation:
+  queries cast to it, and the probabilities as two terms of it, what
+  rounding keeps and what it drops, stacked into one product so that the
+  value chunk is loaded once (``paged_gather_attention`` keeps the first
+  term alone; a token whose two best logits lie closer than that
+  rounding moves them then goes the other way, which the benchmark's toy
+  cell counts). Scores, running maximum, sum and accumulator are float32;
+* the decode contract matches the XLA branch in masking: keys at
+  positions ``> cur - W`` and ``<= cur``.
 
-The public paged-attention kernel in ``jax.experimental`` follows the
-same scalar-prefetch shape; this one is written for THIS engine's
-layout (trash-sink block 0, per-row frontiers, optional band) and is
-dispatched from ``paged_cached_attention`` on TPU.
+How many pages a chunk holds follows from the shapes the kernel is
+traced with (:func:`_pages_per_chunk`), never from an argument. The
+public paged-attention kernel in ``jax.experimental`` walks its pages
+the same way (``pages_per_compute_block``); this one is written for
+THIS engine's layout (token-major pages, trash-sink block 0, per-row
+frontiers, optional band, a flattened layer stack addressed as ``table +
+layer * NB``) and is dispatched from ``paged_cached_attention`` on TPU.
 """
 
 from __future__ import annotations
@@ -36,78 +58,142 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# A chunk aims at this many tokens: large enough that a chunk's copies and its two products pay for the
+# loop around them, small enough that a row of a few hundred tokens does not fold mostly padding.
+_CHUNK_TOKENS = 256
+# The two K and two V chunk buffers together stay under this much VMEM (of 16 MiB scoped by default).
+_CHUNK_VMEM_BYTES = 4 << 20
+# Running maximum before any key: finite, so a chunk with no live key folds to zeros and not to NaN.
+_M_INIT = -1e30
+
+
+def _pages_per_chunk(block_size: int, kv_heads: int, head_dim: int, dtype) -> int:
+    """Pages fetched and folded together. The flat view stacks a chunk's pages ``[pages, bs * H_kv, D]``
+    into ``[pages * bs * H_kv, D]``, which is free only where a page is whole tiles (8 rows of 32 bits:
+    16 of bf16): other shapes take one page a chunk, which needs no stacking."""
+    itemsize = jnp.dtype(dtype).itemsize
+    rows = block_size * kv_heads
+    if rows % (8 * max(1, 4 // itemsize)):
+        return 1
+    fit = _CHUNK_VMEM_BYTES // (4 * rows * head_dim * itemsize)
+    return max(1, min(_CHUNK_TOKENS // block_size, fit))
+
 
 def _kernel(
     tbl_ref,  # [B, MB] int32 (scalar prefetch)
     cur_ref,  # [B] int32 (scalar prefetch)
     q_ref,  # [1, H, D]
-    k_ref,  # [1, bs, Hkv, D]
-    v_ref,  # [1, bs, Hkv, D]
+    k_hbm,  # [NB, bs * Hkv, D], left in HBM
+    v_hbm,  # [NB, bs * Hkv, D], left in HBM
     o_ref,  # [1, H, D]
-    m_ref,  # [H, 1] f32 scratch
-    l_ref,  # [H, 1] f32 scratch
-    acc_ref,  # [H, D] f32 scratch
+    k_buf,  # [2, pages, bs * Hkv, D] VMEM
+    v_buf,  # [2, pages, bs * Hkv, D] VMEM
+    sems,  # DMA semaphores [2 (K, V), 2 (buffer)]
+    side_ref,  # [1] int32 SMEM: the buffer that holds this row's first chunk
     *,
+    pages: int,
     block_size: int,
     kv_heads: int,
     window: Optional[int],
     scale: float,
 ):
-    b, j = pl.program_id(0), pl.program_id(1)
+    from jax.experimental.pallas import tpu as pltpu
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    b, nrows = pl.program_id(0), pl.num_programs(0)
+    max_blocks = tbl_ref.shape[1]
+    heads, dim = q_ref.shape[1:]
+    cols = pages * block_size * kv_heads
+
+    def span(row):
+        """First live page of ``row`` and how many follow it: the clamp keeps a frontier that overshot
+        the table (a slot that finished mid-tick) on the row's own last entry."""
+        cur = cur_ref[row]
+        last = jnp.minimum(jax.lax.div(cur, block_size), max_blocks - 1)
+        first = 0 if window is None else jax.lax.div(jnp.maximum(cur - window + 1, 0), block_size)
+        return first, jnp.maximum(last - first + 1, 0)
+
+    def chunk_copies(row, chunk, side, act):
+        """``act`` (start or wait) on the copy of every live page of ``row``'s chunk ``chunk``."""
+        first, count = span(row)
+        at = chunk * pages
+
+        def one(i, _):
+            page = tbl_ref[row, first + at + i]
+            act(pltpu.make_async_copy(k_hbm.at[page], k_buf.at[side, i], sems.at[0, side]))
+            act(pltpu.make_async_copy(v_hbm.at[page], v_buf.at[side, i], sems.at[1, side]))
+
+        jax.lax.fori_loop(0, jnp.clip(count - at, 0, pages), one, None)
+
+    start = functools.partial(chunk_copies, act=lambda copy: copy.start())
+    wait = functools.partial(chunk_copies, act=lambda copy: copy.wait())
+
+    @pl.when(b == 0)
+    def _first_row():
+        # a page slot no copy has filled yet is folded under a zero probability: it must hold numbers
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        side_ref[0] = 0
+        start(0, 0, 0)
 
     cur = cur_ref[b]
-    lo = j * block_size
-    live = lo <= cur  # any causal-live key in this page
-    if window is not None:
-        live &= lo + block_size - 1 > cur - window  # any in-band key
+    first, count = span(b)
+    chunks = jnp.maximum(pl.cdiv(count, pages), 1)  # a row with nothing live still takes its turn
+    side0 = side_ref[0]
+    q = q_ref[0].astype(k_buf.dtype)
+    # column c of a chunk is token c // Hkv of it and key/value head c % Hkv: head h reads its own
+    col = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 1)
+    own = jax.lax.rem(col, kv_heads) == jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 0), heads // kv_heads
+    )
+    token = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1), kv_heads)
+    newest = jnp.minimum(cur, max_blocks * block_size - 1)
 
-    @pl.when(live)
-    def _page():
-        q = q_ref[0].astype(jnp.float32)  # [H, D]
-        k = k_ref[0].astype(jnp.float32)  # [bs, Hkv, D]
-        v = v_ref[0].astype(jnp.float32)
-        heads, dim = q.shape
-        groups = heads // kv_heads
-        # [Hkv, G, D] x [Hkv, bs, D] -> [Hkv, G, bs]: one batched matmul,
-        # K/V never repeated (the GQA traffic argument, in-kernel)
-        qg = q.reshape(kv_heads, groups, dim)
-        kt = k.transpose(1, 0, 2)  # [Hkv, bs, D]
-        s = jax.lax.dot_general(
-            qg, kt, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-        )
-        s = s.reshape(heads, block_size) * scale
-        pos = lo + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)  # [1, bs]
-        mask = pos <= cur
+    def fold(j, carry):
+        m_prev, l_prev, acc = carry
+        side = jax.lax.rem(side0 + j, 2)
+
+        @pl.when(j + 1 < chunks)
+        def _next_chunk():
+            start(b, j + 1, 1 - side)
+
+        @pl.when((j + 1 == chunks) & (b + 1 < nrows))
+        def _next_row():
+            start(b + 1, 0, 1 - side)
+
+        wait(b, j, side)
+        k = k_buf[side].reshape(cols, dim)
+        v = v_buf[side].reshape(cols, dim)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        pos = (first + j * pages) * block_size + token  # [1, cols]
+        live = pos <= newest
         if window is not None:
-            mask &= pos > cur - window
-        s = jnp.where(mask, s, -jnp.inf)
+            live &= pos > cur - window
+        s = jnp.where(own & live, s, -jnp.inf)  # [H, cols]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        # the probabilities go to the MXU in the pool's type as two terms, what rounding keeps and what it
+        # drops (nothing, for a float32 pool), stacked so that the value chunk is loaded once for both
+        kept = p.astype(v.dtype)
+        if v.dtype == jnp.float32:
+            pv = jnp.dot(kept, v, preferred_element_type=jnp.float32)
+        else:
+            terms = jnp.concatenate([kept, (p - kept.astype(jnp.float32)).astype(v.dtype)], axis=0)
+            both = jnp.dot(terms, v, preferred_element_type=jnp.float32)
+            pv = both[:heads] + both[heads:]
+        return m_new, l_new, acc * alpha + pv
 
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))  # finite: live page has a live key
-        alpha = jnp.exp(m_prev - m_new)  # 0 when m_prev == -inf
-        p = jnp.exp(s - m_new[:, None])  # [H, bs]
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        pv = jax.lax.dot_general(
-            p.reshape(kv_heads, groups, block_size),
-            v.transpose(1, 0, 2),  # [Hkv, bs, D]
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ).reshape(heads, dim)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + pv
-        m_ref[:, 0] = m_new
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        # l can be 0 for a long-retired slot whose windowed frontier moved
-        # past every live page: its output is discarded host-side, but an
-        # unguarded 0/0 would trip jax_debug_nans / NaN-scan tooling.
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, 0], 1.0)[:, None]).astype(o_ref.dtype)
+    init = (
+        jnp.full((heads, 1), _M_INIT, jnp.float32),
+        jnp.zeros((heads, 1), jnp.float32),
+        jnp.zeros((heads, dim), jnp.float32),
+    )
+    _, l, acc = jax.lax.fori_loop(0, chunks, fold, init)
+    side_ref[0] = jax.lax.rem(side0 + chunks, 2)
+    # l is 0 for a row with nothing live (a long-retired slot whose windowed frontier moved past its
+    # table): its output is discarded host-side, but an unguarded 0/0 would trip jax_debug_nans
+    o_ref[0] = (acc / jnp.maximum(l, 1.0)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("sliding_window", "scale", "interpret"))
@@ -132,26 +218,29 @@ def paged_decode_attention(
 
     b, heads, dim = q.shape
     nb, block_size, kv_heads, _ = key_pool.shape
-    mb = block_table.shape[1]
     scale = (1.0 / math.sqrt(dim)) if scale is None else scale
+    pages = _pages_per_chunk(block_size, kv_heads, dim, key_pool.dtype)
+    rows = block_size * kv_heads
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, mb),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, heads, dim), lambda b, j, tbl, cur: (b, 0, 0)),
-            pl.BlockSpec((1, block_size, kv_heads, dim), lambda b, j, tbl, cur: (tbl[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, block_size, kv_heads, dim), lambda b, j, tbl, cur: (tbl[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, heads, dim), lambda b, tbl, cur: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, heads, dim), lambda b, j, tbl, cur: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, heads, dim), lambda b, tbl, cur: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((heads, 1), jnp.float32),
-            pltpu.VMEM((heads, 1), jnp.float32),
-            pltpu.VMEM((heads, dim), jnp.float32),
+            pltpu.VMEM((2, pages, rows, dim), key_pool.dtype),
+            pltpu.VMEM((2, pages, rows, dim), value_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     kernel = functools.partial(
         _kernel,
+        pages=pages,
         block_size=block_size,
         kv_heads=kv_heads,
         window=sliding_window,
@@ -161,5 +250,15 @@ def paged_decode_attention(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, heads, dim), q.dtype),
         grid_spec=grid_spec,
+        # rows run in order on one core: each starts the next one's first copies
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), cur.astype(jnp.int32), q, key_pool, value_pool)
+        name="paged_decode_attention",
+    )(
+        block_table.astype(jnp.int32),
+        cur.astype(jnp.int32),
+        q,
+        # a page as rows of the flat view: the same bytes in the same order
+        key_pool.reshape(nb, rows, dim),
+        value_pool.reshape(nb, rows, dim),
+    )

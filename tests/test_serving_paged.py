@@ -168,23 +168,81 @@ def test_busy_slots_then_drain_is_not_deadlock(tiny_llama):
 def test_kernel_in_engine_matches_dense(tiny_llama):
     """The exact composition TPU serving runs — ServingEngine paged tick
     through the Pallas paged-attention kernel — in interpret mode on
-    CPU, token-exact vs the dense engine (tiny shapes: interpret mode
-    executes the grid in Python)."""
+    CPU, token-exact vs the paged engine on the XLA gather path and vs
+    the dense engine (tiny shapes: interpret mode executes the grid in
+    Python). Three requests over two slots, pages of 4 tokens: each
+    decodes across a page boundary, and the short one retires while the
+    long one is mid-page — its row goes back to the sink, the third
+    request takes the slot, and the kernel walks the new row beside the
+    old one's growing frontier."""
     import accelerate_tpu.ops.paged_kv as pkv
 
-    prompts = [np.arange(1, 1 + n, dtype=np.int32) for n in (3, 6)]
-    dense = ServingEngine(tiny_llama, num_slots=2, prompt_buckets=(8,), tick_block=2)
-    want = dense.generate_many(prompts, max_new_tokens=3)
+    prompts = [np.arange(1, 1 + n, dtype=np.int32) for n in (3, 6, 5)]
+    new_tokens = (3, 9, 4)
+
+    def serve(**kwargs):
+        eng = ServingEngine(tiny_llama, num_slots=2, prompt_buckets=(8,), tick_block=2, **kwargs)
+        uids = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new_tokens)]
+        eng.run()
+        return [eng.poll(uid) for uid in uids]
+
+    dense = serve()
+    gather = serve(paged_block_size=4)
     pkv.FORCE_KERNEL_INTERPRET = True
     try:
-        eng = ServingEngine(
-            tiny_llama, num_slots=2, prompt_buckets=(8,), tick_block=2, paged_block_size=4
-        )
-        got = eng.generate_many(prompts, max_new_tokens=3)
+        got = serve(paged_block_size=4)
     finally:
         pkv.FORCE_KERNEL_INTERPRET = False
-    for w, g in zip(want, got):
-        np.testing.assert_array_equal(w, g)
+    for d, x, g, p, n in zip(dense, gather, got, prompts, new_tokens):
+        assert len(g) == len(p) + n
+        np.testing.assert_array_equal(x, g)
+        np.testing.assert_array_equal(d, g)
+
+
+def test_idle_rows_hand_the_kernel_frontier_zero(monkeypatch):
+    """The static tick computes every slot, so an idle slot's frontier grows a step a token after
+    ``clear_slot`` zeroed it, its whole row at the sink. The kernel walks a row up to the frontier it
+    is given: a row that stores its token in the sink (idle, or finished and overshooting) is given 0
+    and costs one page; a live row keeps its own frontier and its result."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    import accelerate_tpu.ops.paged_kv as pkv
+    import accelerate_tpu.ops.pallas_paged_attention as kernel_file
+
+    cfg = pkv.PagedConfig(block_size=4, num_blocks=9)
+
+    class Attend(nn.Module):
+        @nn.compact
+        def __call__(self, q, k, v):
+            return pkv.paged_cached_attention(self, q, k, v, max_len=16, sliding_window=None, cfg=cfg)
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(keys[0], (3, 1, 2, 8))
+    k, v = jax.random.normal(keys[1], (3, 1, 2, 8)), jax.random.normal(keys[2], (3, 1, 2, 8))
+    pool = jax.random.normal(keys[3], (9, 4, 2, 8))
+    cache = {
+        "key_pool": pool, "value_pool": pool[::-1],
+        # row 0 decodes at position 6 of blocks 3, 5; row 1 has been idle for 1000 steps; row 2
+        # finished at the end of its one block and overshoots into the pad entries
+        "block_table": jnp.asarray([[3, 5, 0, 0], [0, 0, 0, 0], [7, 0, 0, 0]], jnp.int32),
+        "index": jnp.asarray([6, 1000, 5], jnp.int32),
+    }
+    want, _ = Attend().apply({"cache": cache}, q, k, v, mutable=["cache"])
+
+    seen = []
+    real = kernel_file.paged_decode_attention
+
+    def spy(q, key_pool, value_pool, block_table, cur, **kwargs):
+        seen.append(np.asarray(cur))
+        return real(q, key_pool, value_pool, block_table, cur, **kwargs)
+
+    monkeypatch.setattr(kernel_file, "paged_decode_attention", spy)
+    monkeypatch.setattr(pkv, "FORCE_KERNEL_INTERPRET", True)
+    got, _ = Attend().apply({"cache": cache}, q, k, v, mutable=["cache"])
+    np.testing.assert_array_equal(seen[0], [6, 0, 0])
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), atol=2e-5, rtol=2e-5)
 
 
 def test_kernel_in_engine_tp_sharded(tiny_llama):

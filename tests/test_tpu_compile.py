@@ -82,17 +82,35 @@ def test_flash_attention_compiles_for_v5e(v5e, seq, window, grad):
 
 
 @pytest.mark.parametrize("window", [4096, None], ids=["window4096", "full"])
-def test_paged_decode_attention_compiles_for_v5e(v5e, window):
+@pytest.mark.parametrize("slots,layers", [(8, 1), (32, 16)], ids=["serve_phase", "chat_cell"])
+def test_paged_decode_attention_compiles_for_v5e(v5e, slots, layers, window):
+    """The walk over live pages (async copies out of a pool left in HBM, a dynamic trip count, pages
+    stacked into one product) is where Mosaic refuses a slice off the tiling or VMEM over the scoped
+    limit. ``serve_phase``: 8 slots x 4096 tokens on a pool of its own, as ``chip_smoke.py`` serves.
+    ``chat_cell``: ``mistral7b-serve-chat``'s call, 32 slots on one layer of the carried stack of 16 x
+    2048 blocks, flattened and addressed as ``table + layer * NB``; the stack reaches the kernel as a
+    bitcast, never a copy."""
     from accelerate_tpu.ops.pallas_paged_attention import paged_decode_attention
 
     chip = SingleDeviceSharding(v5e.devices[0])
-    slots, block, table = 8, 16, 256  # 8 slots x 4096 tokens, as the serve phase
-    pool = _on(chip, (slots * table + 1, block, KV_HEADS, DIM))
-    fn = functools.partial(paged_decode_attention, sliding_window=window, interpret=False)
-    _compile(
+    block, table = 16, 256
+    blocks = slots * table + 1 if layers == 1 else 2048
+    pool = _on(chip, (layers, blocks, block, KV_HEADS, DIM))
+
+    def fn(q, key_stack, value_stack, tbl, cur, layer):
+        flat = (layers * blocks, block, KV_HEADS, DIM)
+        return paged_decode_attention(
+            q, key_stack.reshape(flat), value_stack.reshape(flat), tbl + layer * blocks, cur,
+            sliding_window=window, interpret=False,
+        )
+
+    text = _compile(
         fn, _on(chip, (slots, HEADS, DIM)), pool, pool,
-        _on(chip, (slots, table), jnp.int32), _on(chip, (slots,), jnp.int32),
+        _on(chip, (slots, table), jnp.int32), _on(chip, (slots,), jnp.int32), _on(chip, (), jnp.int32),
     )
+    assert "paged_decode_attention" in text, "chipbench's readers find the kernel by this name"
+    pools = [line for line in text.splitlines() if f"[{layers * blocks}," in line and " copy(" in line]
+    assert not pools, f"the pool is copied or re-laid on its way to the kernel: {pools[0][:200]}"
 
 
 def test_latent_paged_decode_compiles_for_v5e(v5e):
