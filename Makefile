@@ -1,7 +1,7 @@
 # Repo quality/test targets (reference analogue: the reference Makefile's
 # quality/style/test tiers).
 
-.PHONY: quality style lint lint-sarif divergence flight-check perf-check numerics-check pipe-check fleet-check kernel-check tune-selfcheck tune-bench pipeline-bench telemetry-selfcheck trace-selfcheck trace-bench ft-selfcheck aot-selfcheck test test-slow test-all test-cli check-imports bench dryrun api-docs cache-pack cache-seed
+.PHONY: quality style lint lint-sarif divergence flight-check perf-check numerics-check pipe-check fleet-check kernel-check tune-selfcheck telemetry-selfcheck trace-selfcheck ft-selfcheck aot-selfcheck test test-slow test-all test-cli check-imports dryrun api-docs cache-pack cache-seed
 
 # Persistent XLA compile cache (tests/conftest.py points every run and its
 # subprocess children here). cache-pack snapshots a warm cache into a
@@ -115,15 +115,6 @@ tune-selfcheck:
 		examples/by_feature/tune.py::train_workload --mesh data=8 \
 		--meshes "data=8;data=4,tensor=2" --compressions none,int8 --generation cpu
 
-# Autotuner oracle A/B on CPU (committed evidence: BENCH_TUNE.json):
-# static ranking vs StepTelemetry-measured step time on the train
-# (mesh x zero x compression) and serving (buckets x token budget)
-# toy workloads, exact predicted-vs-HLO wire agreement, the TPU701
-# prune exercised, zero post-warmup recompiles. Exits nonzero unless
-# report.ok.
-tune-bench:
-	env JAX_PLATFORMS=cpu python benchmarks/bench_tune.py --smoke
-
 # Pipeline tier (pipemodel): prove TPU801-805 fire on their seeded
 # schedule defects, every clean twin stays silent, and the bubble /
 # roofline arithmetic matches the hand-computed reference exactly — then
@@ -172,14 +163,6 @@ kernel-check:
 	env JAX_PLATFORMS=cpu python -m accelerate_tpu.commands.cli kernel-check \
 		accelerate_tpu/kernels examples
 
-# Pipeline analyzer A/B on CPU (committed evidence: BENCH_PIPE.json):
-# pipemodel's bubble-adjusted prediction vs StepTelemetry-measured step
-# time across num_microbatches x stage counts on a real pipeline_apply
-# workload: the predicted-best schedule must be the measured-best, with
-# zero post-warmup recompiles. Exits nonzero unless report.ok.
-pipeline-bench:
-	env JAX_PLATFORMS=cpu python benchmarks/bench_pipeline.py --smoke
-
 # SPMD flight-check: prove TPU301/302/303 fire on their seeded defects,
 # then report the example step (peak HBM + collective traffic) on a fake
 # 8-device CPU mesh.
@@ -199,16 +182,6 @@ telemetry-selfcheck:
 # flight-recorder pipeline. Pure stdlib, no jax.
 trace-selfcheck:
 	env JAX_PLATFORMS=cpu python -m accelerate_tpu.commands.cli trace selfcheck
-
-# Tracing A/B on CPU (committed evidence: BENCH_TRACE.json): a traced
-# disaggregated fleet under a control arm and a mid-decode crash arm;
-# every request traced, frontier-contiguous segments reconcile with e2e
-# latency, handoff/failover span bytes match the price models exactly,
-# failover tokens+logprobs match the control arm, zero drift latched,
-# and the dead replica's flight dump holds the injected fault. Exits
-# nonzero unless report.ok.
-trace-bench:
-	env JAX_PLATFORMS=cpu python benchmarks/bench_serving.py --trace --smoke
 
 # Fault tolerance: seeded good/uncommitted/corrupt/recoverable checkpoint
 # fixtures -> prove manifest verify (crc32 + sizes), discovery walk-back,
@@ -241,9 +214,6 @@ test-cli:
 
 api-docs:
 	python scripts/gen_api_docs.py
-
-bench:
-	python bench.py
 
 dryrun:
 	python __graft_entry__.py 8

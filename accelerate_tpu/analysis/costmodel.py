@@ -356,7 +356,7 @@ def price_kv_handoff(
     ``transport`` (``"ici"`` within a slice / host, ``"dcn"`` across).
     Returns ``{"bytes", "time_us", "transport"}``; plain host math, no
     jax — the router's accounting and this prediction must agree
-    byte-for-byte (asserted by ``bench_serving --fleet``)."""
+    byte-for-byte."""
     if transport not in (ICI, DCN):
         raise ValueError(f"transport must be {ICI!r}|{DCN!r}, got {transport!r}")
     total = int(bytes_per_token) * int(tokens) + int(fixed_bytes)
@@ -397,7 +397,7 @@ def price_failover(
     (a :func:`price_kv_handoff` dict), "recompute_us", "path"}`` with
     ``path`` the cheaper leg — forced to ``"recompute"`` when the dying
     replica cannot export (``kv_exportable=False``: poisoned numerics, or
-    a paged/speculative layout with no dense row export). Plain host
+    a paged layout with no dense row export). Plain host
     math, no jax; when the handoff leg runs, the router's post-migration
     byte accounting must equal ``handoff["bytes"]`` exactly."""
     rows = max(1, int(prompt_tokens) + max(0, int(generated_tokens) - 1))

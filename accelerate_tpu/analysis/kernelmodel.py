@@ -33,7 +33,13 @@ import os
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
-from ..kernels.contracts import KernelCostSpec, eqn_kernel_name, registered_spec
+from ..kernels.contracts import (
+    KernelCostSpec,
+    block_array_aval,
+    eqn_kernel_name,
+    pallas_in_avals,
+    registered_spec,
+)
 from .rules import Finding, filter_findings
 
 #: memory-ref primitives inside a kernel body: loads/stores, not FLOPs
@@ -154,7 +160,7 @@ def _index_map_fn(index_map_jaxpr, n_args: int) -> Optional[Callable]:
 
 
 def _block_info(bm, n_grid: int) -> BlockInfo:
-    aval = getattr(bm, "array_shape_dtype", None)
+    aval = block_array_aval(bm)
     # block dims arrive wrapped: Blocked(n) / Element(n) carry the size,
     # Squeezed() is a dim the kernel does not see (None here)
     block_shape = tuple(
@@ -212,9 +218,7 @@ def _site_from_eqn(eqn, count: int) -> KernelSite:
         dynamic_index_maps=int(getattr(gm, "num_index_operands", 0) or 0) > 0,
         spec=registered_spec(name),
         inner_jaxpr=params.get("jaxpr"),
-        in_avals=tuple(
-            getattr(bm, "array_shape_dtype", None) for bm in mappings[:n_in]
-        ),
+        in_avals=pallas_in_avals(params),
     )
 
 

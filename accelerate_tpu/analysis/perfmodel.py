@@ -418,7 +418,7 @@ def eqn_path_line(eqn) -> tuple[Optional[str], Optional[int]]:
     try:
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is None:
             return None, None
         path = getattr(frame, "file_name", None)

@@ -25,7 +25,7 @@ milliseconds:
 * **TPU704** — quantized wire upcast: the requested compression's wire
   dtype is known (or measured, via ``telemetry.wire``) to be upcast by
   the platform's collective lowering — XLA:CPU runs bf16 all-reduces
-  in f32 (the BENCH_ZERO1 finding), so the wire saving the scheme was
+  in f32, so the wire saving the scheme was
   chosen for never happens there. TPU backends keep the narrow dtype.
 * **TPU705** — ZeRO-1 with a knowably non-elementwise optax transform:
   the static twin of the runtime fallback (``Accelerator`` demotes
@@ -46,8 +46,8 @@ from .rules import Finding
 
 #: platforms whose collective lowering is known to upcast narrow wire
 #: dtypes (requested compression name -> the dtype actually moved).
-#: XLA:CPU runs bf16 all-reduces in f32 — measured by
-#: ``telemetry.wire.wire_dtype_upcast`` and recorded in BENCH_ZERO1;
+#: XLA:CPU runs bf16 all-reduces in f32 — what
+#: ``telemetry.wire.wire_dtype_upcast`` reads off the compiled HLO;
 #: int8/fp8 travel as int8 bit-patterns and stay narrow everywhere.
 KNOWN_WIRE_UPCASTS: dict[str, dict[str, str]] = {
     "cpu": {"bf16": "float32"},

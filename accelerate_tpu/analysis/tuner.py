@@ -27,9 +27,7 @@ cross-check is the trust anchor; the ``make tune-trust`` contract in
    agreement (top-1 + Spearman). On a single-core host the measured
    side can only express knobs that change *total* compute (buckets,
    token budgets, padding); cross-device parallelism and wire savings
-   time-share one core there — the serving/training benchmark
-   (``benchmarks/bench_tune.py``) picks its criteria per hardware and
-   says so in the report.
+   time-share one core there.
 
 Workload conventions (the flight-check CLI's target conventions, plus
 one extension for config-dependent shapes):

@@ -64,7 +64,7 @@ def fleet_parser(subparsers=None):
     p_fo.add_argument("--params", type=float, required=True,
                       help="model parameter count (for the recompute arm)")
     p_fo.add_argument("--no-kv", dest="kv_exportable", action="store_false",
-                      help="KV not exportable (paged/speculative/poisoned): recompute only")
+                      help="KV not exportable (paged/poisoned): recompute only")
     p_fo.add_argument("--transport", choices=("ici", "dcn"), default="ici")
     p_fo.add_argument("--generation", default="v5e")
     p_fo.add_argument("--format", choices=("text", "json"), default="text")

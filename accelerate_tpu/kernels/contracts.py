@@ -117,14 +117,23 @@ def unregister_kernel_cost(name: str) -> None:
 
 def eqn_kernel_name(params: dict) -> Optional[str]:
     """The kernel body function name a traced ``pallas_call`` equation
-    carries (``name_and_src_info.name``), or None. Works on the params
-    dict alone — no jax import."""
-    nsi = params.get("name_and_src_info")
-    name = getattr(nsi, "name", None)
-    if name:
-        return str(name)
-    name = params.get("name")
+    carries, or None: ``name_and_src_info.name`` (jax <= 0.8), the
+    ``name=`` the call was given, else the body jaxpr's
+    ``debug_info.func_name`` (jax 0.9). Works on the params dict alone —
+    no jax import. With :func:`pallas_in_avals`, the one place that knows
+    how jax shapes a ``pallas_call``'s params."""
+    name = getattr(params.get("name_and_src_info"), "name", None) or params.get("name")
+    if not name:
+        debug_info = getattr(params.get("jaxpr"), "debug_info", None)
+        name = getattr(debug_info, "func_name", None)
     return str(name) if name else None
+
+
+def block_array_aval(bm):
+    """The whole-array aval one ``BlockMapping`` tiles (``array_aval`` on
+    jax 0.9, ``array_shape_dtype`` before), or None."""
+    aval = getattr(bm, "array_aval", None)
+    return aval if aval is not None else getattr(bm, "array_shape_dtype", None)
 
 
 def pallas_in_avals(params: dict) -> tuple:
@@ -135,9 +144,7 @@ def pallas_in_avals(params: dict) -> tuple:
     gm = params.get("grid_mapping")
     n_in = int(getattr(gm, "num_inputs", 0) or 0)
     mappings = list(getattr(gm, "block_mappings", ()) or ())
-    return tuple(
-        getattr(bm, "array_shape_dtype", None) for bm in mappings[:n_in]
-    )
+    return tuple(block_array_aval(bm) for bm in mappings[:n_in])
 
 
 # -- satellite: audible blindness ------------------------------------------

@@ -11,8 +11,7 @@ decisions a production scheduler makes every tick:
   ticks instead of stalling every running decode for its whole prefill
   (the vLLM/Sarathi "chunked prefill" discipline). ``token_budget=None``
   disables interleaving — every admitted prefill runs to completion in
-  its admission tick (the pre-scheduler behavior, and what
-  ``mode="fifo"`` pins for A/B benchmarking);
+  its admission tick;
 * **priority-class admission**: ``submit(..., priority=...)`` — lower
   value admits sooner; ties admit FIFO by submission order. Preempted
   requests requeue with their original order key, so a resumed request
@@ -28,15 +27,11 @@ decisions a production scheduler makes every tick:
   important request waiting), the youngest lowest-priority decode
   releases its slot and KV blocks and requeues with its
   generated-so-far tokens; it resumes by prefix-style recomputation —
-  token- and logprob-exact, because the recomputed K/V equals what the
-  evicted cache held and the sampling key chain is carried across the
-  preemption;
-* **speculative gating**: with a draft model attached,
-  ``speculative_priorities`` restricts the speculative tick to ticks
-  where every decoding slot's priority opted in (greedy speculative
-  decoding is target-exact regardless of draft-cache staleness, so
-  mixing plain and speculative ticks costs only acceptance rate, never
-  tokens).
+  token-exact, because the sampling key chain is carried across the
+  preemption and the recomputed K/V equals what the evicted cache held
+  to float32 rounding (chunk windows rebuild it, not the programs that
+  first wrote it, so the resumed logprobs agree to rounding, not bit
+  for bit).
 
 Everything here is host-side policy over plain Python state — no jax.
 """
@@ -86,11 +81,6 @@ class SchedulerConfig:
     shedding, no preemption — ``ServingEngine`` without a config decodes
     exactly as before.
 
-    ``mode``: ``"continuous"`` (token-budget interleaving, priorities,
-    SLOs) or ``"fifo"`` (strict submission order, full prefill at
-    admission, every other knob ignored — the A/B baseline the serving
-    benchmark measures against).
-
     ``token_budget``: model-compute tokens one tick may spend; decodes
     claim ``n_decoding x tick_block`` first, prefill chunks fill the
     remainder. Size it above ``num_slots x tick_block`` plus at least
@@ -110,14 +100,8 @@ class SchedulerConfig:
     resumed later by recompute) when a strictly more important request
     cannot be admitted — pool exhaustion in paged mode, no free slot in
     dense mode.
-
-    ``speculative_priorities``: with a draft model, run the speculative
-    tick only when every decoding slot's priority is in this set
-    (``None`` = all priorities speculate — the engine's historical
-    behavior).
     """
 
-    mode: str = "continuous"
     token_budget: Optional[int] = None
     max_queue_depth: Optional[int] = None
     max_queue_wait_s: Optional[float] = None
@@ -126,11 +110,8 @@ class SchedulerConfig:
     deprioritize_to: int = 99
     enable_preemption: bool = False
     preempt_priority_floor: int = 1
-    speculative_priorities: Optional[tuple] = None
 
     def __post_init__(self):
-        if self.mode not in ("continuous", "fifo"):
-            raise ValueError(f"mode must be continuous|fifo, got {self.mode!r}")
         if self.token_budget is not None and self.token_budget < 1:
             raise ValueError(f"token_budget must be >= 1, got {self.token_budget}")
         if self.max_queue_depth is not None and self.max_queue_depth < 1:
@@ -139,8 +120,6 @@ class SchedulerConfig:
             raise ValueError(f"max_queue_wait_s must be >= 0, got {self.max_queue_wait_s}")
         if self.shed_action not in ("reject", "deprioritize"):
             raise ValueError(f"shed_action must be reject|deprioritize, got {self.shed_action!r}")
-        if self.speculative_priorities is not None:
-            self.speculative_priorities = tuple(int(p) for p in self.speculative_priorities)
 
 
 @dataclasses.dataclass
@@ -236,24 +215,22 @@ class Scheduler:
 
     def order_key(self, priority: int, uid: int) -> tuple:
         """Queue position: priority class first (lower admits sooner),
-        submission order within a class. FIFO mode ignores priority."""
-        if self.config.mode == "fifo":
-            return (0, uid)
+        submission order within a class."""
         return (int(priority), uid)
 
     # ---- token budget -------------------------------------------------
 
     def tick_budget(self, n_decoding: int, tick_block: int) -> float:
         """Prefill-token budget for this tick after active decodes claim
-        theirs. ``inf`` when budgeting is off (fifo / no budget)."""
-        if self.config.mode == "fifo" or self.config.token_budget is None:
+        theirs. ``inf`` when no budget is set."""
+        if self.config.token_budget is None:
             return math.inf
         return max(0, self.config.token_budget - n_decoding * tick_block)
 
     # ---- SLO shedding -------------------------------------------------
 
     def sheddable(self, priority: int) -> bool:
-        return self.config.mode != "fifo" and priority >= self.config.shed_priority_floor
+        return priority >= self.config.shed_priority_floor
 
     def shed_on_submit(self, priority: int, queue_depth: int) -> Optional[str]:
         """Reason string if a new request must be rejected at submit."""
@@ -285,7 +262,7 @@ class Scheduler:
         less important than the incoming request, so equal-priority
         traffic never churns itself.
         """
-        if self.config.mode == "fifo" or not self.config.enable_preemption:
+        if not self.config.enable_preemption:
             return None
         candidates = [
             (prio, uid, slot)
@@ -295,13 +272,3 @@ class Scheduler:
         if not candidates:
             return None
         return max(candidates)[2]
-
-    # ---- speculative gating -------------------------------------------
-
-    def use_speculative(self, decoding_priorities) -> bool:
-        """Whether this tick's decode pass may run the speculative tick
-        (only consulted when the engine has a draft model)."""
-        allowed = self.config.speculative_priorities
-        if allowed is None:
-            return True
-        return all(p in allowed for p in decoding_priorities)

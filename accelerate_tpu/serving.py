@@ -51,9 +51,9 @@ parity-plus. Design notes:
   admission, SLO-aware load shedding (structured :class:`ShedError` +
   ``shed`` events instead of silent queueing), and decode preemption
   (the youngest low-priority decode releases its slot and KV blocks,
-  requeues, and resumes by prefix-style recomputation — token- and
-  logprob-exact) ride on the same tick loop. The default config is
-  behavior-preserving: unlimited budget, one priority class, no
+  requeues, and resumes by prefix-style recomputation — token-exact,
+  logprobs to float32 rounding) ride on the same tick loop. The default
+  config is behavior-preserving: unlimited budget, one priority class, no
   shedding, no preemption.
 """
 
@@ -148,7 +148,7 @@ class _Request:
     # admits sooner), submit timestamp (queue-wait SLO + metrics), and the
     # preemption/resume carry — a preempted decode requeues with its
     # generated-so-far tokens plus its sampling key so the resumed stream
-    # is token- and logprob-exact
+    # is token-exact (logprobs to float32 rounding: chunk windows rebuild the K/V)
     priority: int = 0
     submit_ts: float = 0.0
     preempted: bool = False
@@ -198,8 +198,6 @@ class ServingEngine:
         seed: int = 0,
         paged_block_size: Optional[int] = None,
         pool_blocks: Optional[int] = None,
-        draft_model=None,
-        gamma: int = 4,
         telemetry_log=None,
         program_cache=None,
         auto_bucketing: bool = False,
@@ -257,31 +255,6 @@ class ServingEngine:
         if hasattr(scheduler, "to_scheduler_config"):
             scheduler = scheduler.to_scheduler_config()
         self._sched = scheduler if isinstance(scheduler, Scheduler) else Scheduler(scheduler)
-        if draft_model is not None and self._sched.config.enable_preemption:
-            raise NotImplementedError(
-                "decode preemption does not compose with speculative serving yet "
-                "(resume recomputes only the target cache)"
-            )
-        # Speculative continuous batching: a draft model proposes gamma
-        # tokens per slot, ONE target forward verifies them (greedy
-        # accept-prefix; emitted tokens are exactly the target's own
-        # greedy stream). Constraints are enforced below: dense layout,
-        # temperature 0, bucket-sized prompts, no prefix caching.
-        self.draft_model = draft_model
-        self.gamma = int(gamma)
-        if draft_model is not None:
-            if paged_block_size is not None:
-                raise NotImplementedError("speculative serving is dense-layout only (no paged cache yet)")
-            if temperature != 0.0:
-                raise NotImplementedError("speculative serving is greedy-only (temperature=0)")
-            if self.gamma < 1:
-                raise ValueError(f"gamma must be >= 1, got {gamma}")
-            draft_cap = draft_model.config.max_position_embeddings
-            if self.max_len > draft_cap:
-                raise ValueError(
-                    f"max_len {self.max_len} exceeds the draft cache "
-                    f"(max_position_embeddings={draft_cap})"
-                )
         if self.max_len > model.config.max_position_embeddings:
             raise ValueError(
                 f"max_len {self.max_len} exceeds the model cache "
@@ -322,6 +295,16 @@ class ServingEngine:
 
         params = model.params
         apply_fn = model.apply_fn
+
+        # the dense per-row cache template (a 1-token dummy prefill outside
+        # paged_mode): a dense slot's rows, what chunk windows run against
+        # in both layouts, and what a KV handoff ships (trimmed to true_len
+        # rows) and the receiving replica pads back before its paste/insert
+        _, self._row_template = jax.eval_shape(
+            lambda p, i: apply_fn(p, i, positions=jnp.zeros((1, 1), jnp.int32), decode=True, cache=None),
+            params,
+            jnp.zeros((1, 1), jnp.int32),
+        )
 
         # Cache layout: dense = leading slot axis over the per-row cache
         # pytree (each slot reserves max_len rows); paged = one shared
@@ -371,26 +354,8 @@ class ServingEngine:
         elif pool_blocks is not None:
             raise ValueError("pool_blocks requires paged_block_size (paged mode)")
         else:
-            # empty per-row cache template from a 1-token dummy prefill,
-            # then a leading slot axis over the per-row cache pytree
-            _, cache0 = jax.eval_shape(
-                lambda p, i: apply_fn(p, i, positions=jnp.zeros((1, 1), jnp.int32), decode=True, cache=None),
-                params,
-                jnp.zeros((1, 1), jnp.int32),
-            )
-            if draft_model is not None:
-                # the slot cache pytree becomes a {target, draft} pair; all
-                # the slot machinery (insert, tree zeros) is pytree-generic
-                _, d_cache0 = jax.eval_shape(
-                    lambda p, i: draft_model.apply_fn(
-                        p, i, positions=jnp.zeros((1, 1), jnp.int32), decode=True, cache=None
-                    ),
-                    draft_model.params,
-                    jnp.zeros((1, 1), jnp.int32),
-                )
-                cache0 = {"t": cache0, "d": d_cache0}
             self.slot_caches = jax.tree.map(
-                lambda l: jnp.zeros((num_slots, *l.shape), l.dtype), cache0
+                lambda l: jnp.zeros((num_slots, *l.shape), l.dtype), self._row_template
             )
 
         # host-side slot state
@@ -458,30 +423,29 @@ class ServingEngine:
             return next_tok, pick_lp(row, next_tok), cache, key
 
         key_aval = jax.eval_shape(lambda: jax.random.key(0))
-        if draft_model is None:  # speculative admits route to _spec_prefill
 
-            def _build_prefill(b):
-                t0 = time.perf_counter()
-                with self._trace_ctx():
-                    prog = self._pc.compile(
-                        named(prefill, f"prefill_b{b}"), params, jax.ShapeDtypeStruct((1, b), jnp.int32),
-                        jax.ShapeDtypeStruct((), jnp.int32), key_aval,
-                        name=f"prefill_b{b}",
-                    )
-                self._note_bucket_compile("prefill", b, (time.perf_counter() - t0) * 1000.0)
-                return prog
+        def _build_prefill(b):
+            t0 = time.perf_counter()
+            with self._trace_ctx():
+                prog = self._pc.compile(
+                    named(prefill, f"prefill_b{b}"), params, jax.ShapeDtypeStruct((1, b), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32), key_aval,
+                    name=f"prefill_b{b}",
+                )
+            self._note_bucket_compile("prefill", b, (time.perf_counter() - t0) * 1000.0)
+            return prog
 
-            self._prefill = _LazyBuckets(_build_prefill)
-            self._perf_programs["prefill"] = (
-                prefill,
-                lambda b: (
-                    params,
-                    jax.ShapeDtypeStruct((1, b), jnp.int32),
-                    jax.ShapeDtypeStruct((), jnp.int32),
-                    key_aval,
-                ),
-                (self._trace_ctx,),
-            )
+        self._prefill = _LazyBuckets(_build_prefill)
+        self._perf_programs["prefill"] = (
+            prefill,
+            lambda b: (
+                params,
+                jax.ShapeDtypeStruct((1, b), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32),
+                key_aval,
+            ),
+            (self._trace_ctx,),
+        )
 
         # ---- chunked-prefill programs (long prompts / prefix suffixes) ----
         # one chunk size (the largest bucket) x {cold, warm}: compile count
@@ -515,35 +479,21 @@ class ServingEngine:
 
         self._reset_idx = ctx_jit(reset_idx)
 
-        if draft_model is None:
-            # The resume-recompute program (preempt -> requeue -> resume
-            # rebuilds the evicted KV by warm chunk windows) registered for
-            # perf_check()/numerics_check(): the analysis stack must cover
-            # every program the scheduler can launch, and this one is the
-            # only engine program that reads AND extends a warm row cache.
-            # The row-cache aval is the dense per-row template (chunk
-            # windows run outside paged_mode in both layouts).
-            _, row_aval = jax.eval_shape(
-                lambda p, i: apply_fn(
-                    p, i, positions=jnp.zeros((1, 1), jnp.int32), decode=True, cache=None
-                ),
+        # The resume-recompute program (preempt -> requeue -> resume
+        # rebuilds the evicted KV by warm chunk windows) registered for
+        # perf_check()/numerics_check(): the analysis stack must cover
+        # every program the scheduler can launch, and this one is the
+        # only engine program that reads AND extends a warm row cache.
+        self._perf_programs["resume_recompute"] = (
+            chunk_warm,
+            lambda b: (
                 params,
-                jnp.zeros((1, 1), jnp.int32),
-            )
-            # the dense per-row cache template: what a KV handoff ships
-            # (trimmed to true_len rows) and what the receiving replica
-            # pads back before its paste/insert
-            self._row_template = row_aval
-            self._perf_programs["resume_recompute"] = (
-                chunk_warm,
-                lambda b: (
-                    params,
-                    jax.ShapeDtypeStruct((1, self._chunk), jnp.int32),
-                    jax.ShapeDtypeStruct((), jnp.int32),
-                    row_aval,
-                ),
-                (self._trace_ctx,),
-            )
+                jax.ShapeDtypeStruct((1, self._chunk), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32),
+                self._row_template,
+            ),
+            (self._trace_ctx,),
+        )
 
         # registered shared prefixes: id -> {"len", "cache", "tokens"}
         self._prefixes: dict[int, dict] = {}
@@ -664,105 +614,13 @@ class ServingEngine:
             def dense_step(params, caches, toks, poss, keys):
                 return *jax.vmap(one_step, in_axes=(None, 0, 0, 0, 0))(params, caches, toks, poss, keys), None
 
-            if draft_model is None:
-                raw_dense_tick = make_tick(dense_step)
-            else:
-                # the spec engine's PLAIN tick (scheduler gating can route
-                # ticks away from speculation): advance only the target
-                # half of the {t, d} slot pytree. The draft cache goes
-                # stale for plainly-decoded tokens — harmless, because
-                # greedy speculative emission is the target's own argmax
-                # stream regardless of what the draft proposes; staleness
-                # costs acceptance rate, never tokens.
-                def pair_step(params, caches, toks, poss, keys):
-                    t_caches, nxt, lps, keys, load = dense_step(params, caches["t"], toks, poss, keys)
-                    return {"t": t_caches, "d": caches["d"]}, nxt, lps, keys, load
-
-                raw_dense_tick = make_tick(pair_step)
+            raw_dense_tick = make_tick(dense_step)
             self._decode_tick = ctx_jit(raw_dense_tick)
             self._perf_programs["decode_tick"] = (
                 raw_dense_tick,
                 lambda b: (params, self.slot_caches, self.slot_tok, self.slot_pos, self._slot_keys),
                 (self._trace_ctx,),
             )
-
-        if draft_model is not None:
-            # ---- speculative programs (dense layout; greedy) ----------
-            # One tick iteration per slot: speculative.py's shared
-            # draft-propose / target-verify core, vmapped over the slot
-            # axis — emitted tokens are exactly the target's greedy stream.
-            d_apply = draft_model.apply_fn
-            g = self.gamma
-            from .speculative import build_spec_step
-
-            _spec_core = build_spec_step(apply_fn, d_apply, g)
-
-            def spec_row_step(t_params, d_params, row_caches, tok, pos):
-                t_cache, d_cache, emit, lps, n_emit = _spec_core(
-                    t_params, d_params, row_caches["t"], row_caches["d"], tok, pos
-                )
-                # the slot's next fed token is the last emitted one
-                return {"t": t_cache, "d": d_cache}, emit, lps, n_emit, emit[n_emit - 1], pos + n_emit
-
-            def spec_tick(t_params, d_params, slot_caches, toks, poss):
-                def block_step(carry, _):
-                    caches, toks, poss = carry
-                    caches, emits, lps, n_emits, last, poss = jax.vmap(
-                        spec_row_step, in_axes=(None, None, 0, 0, 0)
-                    )(t_params, d_params, caches, toks, poss)
-                    return (caches, last, poss), (emits, lps, n_emits)
-
-                (slot_caches, _, poss), (emits_k, lps_k, n_k) = jax.lax.scan(
-                    block_step, (slot_caches, toks, poss), None, length=tick_block
-                )
-                # [K, slots, g+1] tokens/lps; [K, slots] emit counts
-                return slot_caches, emits_k, lps_k, n_k
-
-            self._spec_tick = ctx_jit(spec_tick)
-            # the spec engine decodes through spec_tick, not the dense tick
-            self._perf_programs["decode_tick"] = (
-                spec_tick,
-                lambda b: (params, draft_model.params, self.slot_caches, self.slot_tok, self.slot_pos),
-                (self._trace_ctx,),
-            )
-
-            from .ops.kv_cache import reset_cache_index
-
-            def spec_prefill(t_params, d_params, ids, true_len):
-                b_len = ids.shape[1]
-                positions = jnp.broadcast_to(jnp.arange(b_len), (1, b_len))
-                t_logits, t_cache = apply_fn(t_params, ids, positions=positions, decode=True, cache=None)
-                _, d_cache = d_apply(d_params, ids, positions=positions, decode=True, cache=None)
-                row = t_logits[0, true_len - 1].astype(jnp.float32)
-                first = jnp.argmax(row).astype(jnp.int32)
-                t_cache = reset_cache_index(t_cache, true_len)
-                d_cache = reset_cache_index(d_cache, true_len)
-                return first, jax.nn.log_softmax(row)[first], {"t": t_cache, "d": d_cache}
-
-            def _build_spec_prefill(b):
-                t0 = time.perf_counter()
-                with self._trace_ctx():
-                    prog = self._pc.compile(
-                        named(spec_prefill, f"spec_prefill_b{b}"), params, draft_model.params,
-                        jax.ShapeDtypeStruct((1, b), jnp.int32), jax.ShapeDtypeStruct((), jnp.int32),
-                        name=f"spec_prefill_b{b}",
-                    )
-                self._note_bucket_compile("spec_prefill", b, (time.perf_counter() - t0) * 1000.0)
-                return prog
-
-            self._spec_prefill = _LazyBuckets(_build_spec_prefill)
-            self._perf_programs["prefill"] = (
-                spec_prefill,
-                lambda b: (
-                    params,
-                    draft_model.params,
-                    jax.ShapeDtypeStruct((1, b), jnp.int32),
-                    jax.ShapeDtypeStruct((), jnp.int32),
-                ),
-                (self._trace_ctx,),
-            )
-            # accept-rate telemetry: {"steps", "accepted", "emitted"}
-            self.spec_stats = {"steps": 0, "accepted": 0, "emitted": 0}
 
     # ---- chunked prefill (host driver) ----------------------------------
 
@@ -853,8 +711,6 @@ class ServingEngine:
         returned ``prefix_id`` copy its KV cache and prefill only their
         suffix. The finished output includes the prefix tokens."""
         toks = np.asarray(prefix_ids, np.int32).ravel()
-        if self.draft_model is not None:
-            raise NotImplementedError("speculative serving does not compose with prefix caching yet")
         if len(toks) == 0:
             raise ValueError("empty prefix")
         if len(toks) + 1 > self.max_len:
@@ -956,21 +812,6 @@ class ServingEngine:
         stops = tuple(tuple(int(t) for t in s) for s in (stop_sequences or ()))
         if any(len(s) == 0 for s in stops):
             raise ValueError("empty stop sequence")
-        if self.draft_model is not None:
-            if prefix_id is not None:
-                raise NotImplementedError("speculative serving does not compose with prefix caching yet")
-            if self.bucketer is None and len(prompt) > max(self.prompt_buckets):
-                # auto-bucketing mints a covering bucket instead; the
-                # max_len headroom check below still bounds the prompt
-                raise ValueError(
-                    f"speculative serving needs bucket-sized prompts "
-                    f"(len {len(prompt)} > largest bucket {max(self.prompt_buckets)})"
-                )
-            if len(prompt) + max_new_tokens + self.gamma > self.max_len:
-                raise ValueError(
-                    f"prompt ({len(prompt)}) + max_new_tokens ({max_new_tokens}) + gamma "
-                    f"({self.gamma}) headroom exceeds the slot cache ({self.max_len})"
-                )
         plen = 0
         if prefix_id is not None:
             if prefix_id not in self._prefixes:
@@ -1019,8 +860,6 @@ class ServingEngine:
         prediction and a router's post-transfer accounting
         (``handoff["wire_bytes"]``) must agree byte-for-byte."""
         jax = _jax()
-        if self.draft_model is not None:
-            raise NotImplementedError("disaggregated prefill does not compose with speculative serving")
         check_handoff_layout(self._row_template)
         cap = self.model.config.max_position_embeddings
         per_tok = fixed = 0
@@ -1100,8 +939,6 @@ class ServingEngine:
         chunk windows (radix-cache reuse composes with disaggregation on
         the prefill side)."""
         jax = _jax()
-        if self.draft_model is not None:
-            raise NotImplementedError("disaggregated prefill does not compose with speculative serving")
         check_handoff_layout(self._row_template)
         prompt = np.asarray(prompt_ids, np.int32).ravel()
         if len(prompt) == 0:
@@ -1151,8 +988,6 @@ class ServingEngine:
         exact vs a local prefill by construction. A later preemption
         resumes by ordinary prefix recompute — the handoff payload is
         consumed at first admission."""
-        if self.draft_model is not None:
-            raise NotImplementedError("disaggregated prefill does not compose with speculative serving")
         prompt = np.asarray(handoff["prompt"], np.int32).ravel()
         total, max_new = int(handoff["total"]), int(handoff["max_new_tokens"])
         if total != len(prompt):
@@ -1221,15 +1056,15 @@ class ServingEngine:
         ``key_data``, so :meth:`import_inflight` on a survivor continues
         token- and logprob-exactly; decoding slots additionally export
         their trimmed KV rows (``cache`` + ``rows``) when ``include_kv``
-        and the layout allows (dense, non-speculative — paged/speculative
-        slots fail over by prefix recompute, which is equally exact).
+        and the layout allows (dense — paged slots fail over by prefix
+        recompute, which is equally exact).
 
         Safe at every labeled serving crash point by construction: the
         crash hooks fire BEFORE the jitted tick calls, so the host
         bookkeeping (out_tokens, slot_pos, slot keys, unconsumed
         handoffs) is always consistent when a failover export runs."""
         jax = _jax()
-        kv_ok = include_kv and not self.paged and self.draft_model is None
+        kv_ok = include_kv and not self.paged
         snaps = []
 
         def handoff_snap(req, h):
@@ -1289,8 +1124,6 @@ class ServingEngine:
         admission once; shedding it now would LOSE it. Returns this
         engine's local uid for the request."""
         jax = _jax()
-        if self.draft_model is not None:
-            raise NotImplementedError("failover import does not compose with speculative serving")
         prompt = np.asarray(snap["prompt"], np.int32).ravel()
         out = [int(t) for t in snap.get("out_tokens") or []]
         lps = [float(v) for v in snap.get("out_lps") or []]
@@ -1529,12 +1362,7 @@ class ServingEngine:
             budget = self._advance_prefill(slot, budget, force=force)
             force = False
         if any(ph == "decode" for ph in self.slot_phase):
-            if self.draft_model is not None and self._sched.use_speculative(
-                [p for _, p, _ in self._decoding_info()]
-            ):
-                self._spec_decode_pass()
-            else:
-                self._plain_decode_pass()
+            self._decode_pass()
         with phase("engine.expire"):
             self._expire_window_blocks()
         with phase(
@@ -1550,7 +1378,7 @@ class ServingEngine:
 
     def _decoding_info(self) -> list:
         """``[(slot, priority, uid), ...]`` for decode-phase slots — the
-        scheduler's victim-selection / speculative-gating view."""
+        scheduler's victim-selection view."""
         return [
             (slot, req.priority, req.uid)
             for slot, req in enumerate(self.slot_req)
@@ -1679,8 +1507,6 @@ class ServingEngine:
             st["handoff"] = req.handoff
             st["key"] = jax.random.wrap_key_data(jax.numpy.asarray(req.handoff["key_data"]))
             req.handoff = None
-        elif self.draft_model is not None:
-            st["bucket"], st["spec"] = self._bucket_for(len(req.prompt)), True
         elif not resume and req.prefix_id is None and (b := self._bucket_for(len(req.prompt))) is not None:
             # short prompt, no prefix: the one-shot fused program
             # (auto-bucketing: the bucketer can mint a new covering
@@ -1756,17 +1582,9 @@ class ServingEngine:
                 # the request's ``prefill`` span closes at the first-token
                 # sync in _finalize_prefill: dispatch to there is compute
                 st["dispatched"] = (time.perf_counter(), int(b))
-                if st.get("spec"):
-                    # speculative admit: both models prefill the prompt (greedy)
-                    next_tok, lp, row_cache = self._spec_prefill[b](
-                        self.model.params, self.draft_model.params,
-                        jnp.asarray(padded), jnp.int32(len(req.prompt)),
-                    )
-                    key = st["key"]
-                else:
-                    next_tok, lp, row_cache, key = self._prefill[b](
-                        self.model.params, jnp.asarray(padded), jnp.int32(len(req.prompt)), st["key"]
-                    )
+                next_tok, lp, row_cache, key = self._prefill[b](
+                    self.model.params, jnp.asarray(padded), jnp.int32(len(req.prompt)), st["key"]
+                )
             self._tick_prefill_tokens += b
             self._finalize_prefill(slot, row_cache, len(req.prompt), next_tok, lp, key)
             return budget - b
@@ -1863,14 +1681,18 @@ class ServingEngine:
         if self.tracer is not None:
             self.tracer.seg(req.trace, "preempt", generated=len(req.out_tokens))
 
-    def _plain_decode_pass(self) -> None:
+    def _decode_pass(self) -> None:
         """ONE jitted K-step tick for every decode-phase slot, then the
         host walk that streams tokens/logprobs out. Prefilling slots
         compute garbage rows by construction (static shapes) — their
         caches are fully replaced at prefill paste/insert."""
         crash_point("mid_decode", replica=self.metrics.replica)
         jnp = _jax().numpy
-        with self._decode_dispatch_phase():
+        decoding = [ph == "decode" for ph in self.slot_phase]
+        with phase(
+            "engine.decode.dispatch", decoding=sum(decoding), tick_block=self.tick_block,
+            live_tokens=int(self.slot_pos[decoding].sum()),
+        ):
             self.slot_caches, toks_k, lps_k, self._slot_keys, load_k = self._decode_tick(
                 self.model.params, self.slot_caches,
                 jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos), self._slot_keys
@@ -1904,14 +1726,6 @@ class ServingEngine:
                         self.tracer.window(req.trace, "decode", tokens=n_new)
                 if retired:
                     self._retire(slot)
-
-    def _decode_dispatch_phase(self):
-        """``engine.decode.dispatch`` with the tick's counts at its entry."""
-        decoding = [ph == "decode" for ph in self.slot_phase]
-        return phase(
-            "engine.decode.dispatch", decoding=sum(decoding), tick_block=self.tick_block,
-            live_tokens=int(self.slot_pos[decoding].sum()),
-        )
 
     def _expire_window_blocks(self) -> None:
         """Sliding-window models: expire blocks the band can no longer
@@ -1969,58 +1783,6 @@ class ServingEngine:
         return [self.done[u] for u in uids]
 
     # ---- internals ------------------------------------------------------
-
-    def _spec_decode_pass(self) -> int:
-        """The speculative tick's host half: run ``tick_block`` draft+verify
-        iterations on device, then walk the variable per-slot emit counts
-        (``n_emit = accepted + 1`` tokens per iteration) exactly like the
-        one-token tick walks its block — overshoot past retirement is
-        discarded identically."""
-        jnp = _jax().numpy
-        with self._decode_dispatch_phase():
-            self.slot_caches, emits_k, lps_k, n_k = self._spec_tick(
-                self.model.params, self.draft_model.params, self.slot_caches,
-                jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos),
-            )
-        with phase("engine.decode.sync"):
-            emits_k = np.asarray(emits_k)  # [K, slots, gamma+1]
-            lps_k = np.asarray(lps_k)
-            n_k = np.asarray(n_k)  # [K, slots]
-        with phase("engine.decode.walk"):
-            for slot, req in enumerate(self.slot_req):
-                if req is None or self.slot_phase[slot] != "decode":
-                    continue
-                retired, n_new = False, 0
-                for k in range(self.tick_block):
-                    n = int(n_k[k, slot])
-                    self.spec_stats["steps"] += 1  # one target forward spent
-                    walked = 0
-                    for j in range(n):
-                        tok = int(emits_k[k, slot, j])
-                        req.out_tokens.append(tok)
-                        req.out_lps.append(float(lps_k[k, slot, j]))
-                        self.metrics.on_tokens(1)
-                        walked += 1
-                        n_new += 1
-                        self.slot_pos[slot] += 1
-                        self.slot_tok[slot] = tok
-                        if self._finished(req, tok):
-                            retired = True
-                            break
-                    # only USED tokens count (a mid-run EOS discards the rest;
-                    # the correction/bonus token is target-sourced, not a
-                    # draft acceptance) — matches speculative_generate's stats
-                    self.spec_stats["emitted"] += walked
-                    self.spec_stats["accepted"] += min(walked, n - 1)
-                    if retired:
-                        break
-                if n_new:
-                    self.metrics.on_tick_tokens(req.uid, n_new)
-                    if self.tracer is not None:
-                        self.tracer.window(req.trace, "decode", tokens=n_new)
-                if retired:
-                    self._retire(slot)
-        return self.active_count
 
     def _finished(self, req: _Request, tok: int) -> bool:
         if self.eos_token_id is not None and tok == self.eos_token_id:

@@ -27,8 +27,7 @@ the layer above the engine:
   under ``handoff="auto"`` the router compares that against
   :func:`~accelerate_tpu.analysis.costmodel.prefill_compute_us` — short
   prompts decode locally, long ones ship their blocks. The router's
-  post-transfer accounting must equal the prediction byte-for-byte
-  (``bench_serving --fleet`` asserts it);
+  post-transfer accounting must equal the prediction byte-for-byte;
 
 * **radix prefix cache** — :class:`RadixPrefixCache` is a compressed
   token trie over observed prompts. When ``promote_after`` prompts share
@@ -45,8 +44,8 @@ the layer above the engine:
 * **zero-compile spin-up** — replicas built over one shared
   :class:`~accelerate_tpu.aot.ExecutableStore` deserialize every engine
   program a sibling already compiled: :meth:`FleetRouter.spin_up` warms
-  a new replica and reports its compile count (asserted 0 in the bench
-  and the fleet tests — the PR-7 warm-replica story at fleet level);
+  a new replica and reports its compile count (asserted 0 in the fleet
+  tests — the PR-7 warm-replica story at fleet level);
 
 * **fault tolerance** — every :class:`Replica` runs a ``healthy →
   degraded → quarantined → dead`` health state machine driven by error
@@ -494,7 +493,7 @@ class FleetConfig:
 
     ``handoff``: ``"auto"`` ships KV blocks only when the priced
     transfer beats the priced local re-prefill, ``"always"`` /
-    ``"never"`` pin the decision (the bench's A/B arms).
+    ``"never"`` pin the decision.
 
     ``transport`` / ``generation``: what the cost model prices the
     replica-to-replica link as (``"ici"`` within a slice or host,
@@ -651,7 +650,7 @@ class FleetRouter:
             raise ValueError("disaggregated fleet needs at least one decode-capable replica")
         if self.config.prefix_reuse:
             for rep in self.replicas:
-                if rep.can_prefill() and rep.engine.draft_model is None:
+                if rep.can_prefill():
                     rep.radix = RadixPrefixCache(
                         rep.engine,
                         min_prefix_tokens=self.config.min_prefix_tokens,
@@ -672,7 +671,7 @@ class FleetRouter:
         self._replica_seq = len(self.replicas)  # monotonic spin_up naming
         # KV-handoff accounting: predictions are priced BEFORE each
         # transfer; moved bytes are what actually shipped — the two must
-        # agree exactly (bench-asserted)
+        # agree exactly
         self.handoffs = 0
         self.handoffs_local = 0  # auto-decision chose local re-prefill
         self.handoff_bytes_predicted = 0
@@ -766,8 +765,8 @@ class FleetRouter:
         """Add one replica at runtime and warm its serving programs.
         Returns ``{"replica", "spinup_ms", "compiles", "deserialized"}``
         — over a shared store the compile count is 0 (every program
-        deserializes; the zero-compile spin-up contract the fleet bench
-        asserts). Only available on a :meth:`from_model` router."""
+        deserializes: the zero-compile spin-up contract). Only available
+        on a :meth:`from_model` router."""
         if self._mk_engine is None:
             raise ValueError("spin_up needs a from_model router (an engine factory)")
         with self._lock:

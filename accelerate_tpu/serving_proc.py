@@ -233,7 +233,7 @@ class EngineWorker:
             prompt = (np.arange(1, ln + 1) % max(2, vocab - 2) + 1).astype(np.int32)
             self.engine.submit(prompt, max_new_tokens=n_new)
         self.engine.run()
-        if not self.engine.paged and self.engine.draft_model is None:
+        if not self.engine.paged:
             ln = min(lens) if lens else 4
             prompt = (np.arange(2, ln + 2) % max(2, vocab - 2) + 1).astype(np.int32)
             h = self.engine.prefill_detached(
@@ -252,7 +252,7 @@ class EngineWorker:
         import jax
 
         per_tok = fixed = 0
-        if not self.engine.paged and self.engine.draft_model is None:
+        if not self.engine.paged:
             per_tok, fixed = self.engine.kv_handoff_dims()
         devices = jax.devices()
         return {
@@ -312,8 +312,7 @@ class EngineWorker:
                 "tokens": [int(t) for t in np.asarray(toks).ravel()],
                 "lps": [float(v) for v in np.asarray(self.engine.logprobs(uid)).ravel()],
             }
-        include_kv = bool(obj.get("shadow_kv")) and not self.engine.paged \
-            and self.engine.draft_model is None
+        include_kv = bool(obj.get("shadow_kv")) and not self.engine.paged
         snaps = self.engine.export_inflight(include_kv=include_kv)
         meta, blob = encode_snapshots(snaps)
         progress = {
@@ -402,8 +401,7 @@ class EngineWorker:
                 kv_bytes[str(snap["uid"])] = int(moved)
             return {"uids": uids, "kv_bytes": kv_bytes}, b""
         if op == "export":
-            include_kv = bool(obj.get("include_kv", True)) and not self.engine.paged \
-                and self.engine.draft_model is None
+            include_kv = bool(obj.get("include_kv", True)) and not self.engine.paged
             snaps = self.engine.export_inflight(include_kv=include_kv)
             meta, blob_out = encode_snapshots(snaps)
             return {"snaps": meta}, blob_out

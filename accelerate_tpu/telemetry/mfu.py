@@ -6,7 +6,7 @@ table the static cost model prices against, so static predictions and
 runtime measurements can never disagree about what "peak" means). The
 model FLOPs per step come from whichever source the caller has:
 
-* an analytic count (``6 * params * tokens`` — what ``bench.py`` uses);
+* an analytic count (``6 * params * tokens``);
 * ``flops_from_compiled(step._jitted...)`` when XLA's
   ``compiled.cost_analysis()`` is available (exact, includes attention);
 

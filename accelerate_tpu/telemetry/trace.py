@@ -14,10 +14,9 @@ crossed router -> prefill replica -> KV handoff -> decode replica ->
   ``resume``, ``failover``, and ``drain`` migration;
 * segments are **frontier-contiguous**: each new segment covers the gap
   since the trace's last covered timestamp, so the segment sum
-  reconciles with the request's end-to-end latency by construction (the
-  property ``bench_serving.py --trace`` gates on). A ``prefill`` span of
-  a fused bucket program carries ``compute_ms``, dispatch to the
-  first-token sync, which a predictor cross-check reads
+  reconciles with the request's end-to-end latency by construction. A
+  ``prefill`` span of a fused bucket program carries ``compute_ms``,
+  dispatch to the first-token sync, which a predictor cross-check reads
   (:mod:`~accelerate_tpu.telemetry.critpath`); a chunk window has no
   sync and carries ``dispatch_ms``, the enqueue time, under that name;
 * the trace id rides the request record through

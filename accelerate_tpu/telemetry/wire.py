@@ -13,7 +13,7 @@ both sides price through the SAME ring formulas
 (``analysis.costmodel.ring_wire_bytes``) so a disagreement means missing
 or phantom traffic, never unit drift.
 
-Usage (what ``benchmarks/bench_zero1.py`` does)::
+Usage::
 
     compiled = step._jitted.lower(*sample_args).compile()
     measured = hlo_wire_bytes(compiled.as_text())

@@ -280,12 +280,8 @@ class ServingSchedulerKwargs(KwargsHandler):
     demote-to-``deprioritize_to``). ``enable_preemption``: evict the
     youngest decode with priority >= ``preempt_priority_floor`` when a
     strictly more important request cannot admit; it requeues and
-    resumes token-exactly by recompute. ``speculative_priorities``:
-    with a draft model, restrict the speculative tick to these classes.
-    ``mode="fifo"`` pins the legacy strict-FIFO behavior (benchmark
-    baseline)."""
+    resumes token-exactly by recompute."""
 
-    mode: str = "continuous"
     token_budget: Optional[int] = None
     max_queue_depth: Optional[int] = None
     max_queue_wait_s: Optional[float] = None
@@ -294,7 +290,6 @@ class ServingSchedulerKwargs(KwargsHandler):
     deprioritize_to: int = 99
     enable_preemption: bool = False
     preempt_priority_floor: int = 1
-    speculative_priorities: Optional[tuple] = None
 
     def to_scheduler_config(self):
         """The :class:`~accelerate_tpu.scheduling.SchedulerConfig` the
@@ -472,11 +467,10 @@ class MixedPrecisionPolicy(KwargsHandler):
     output_dtype: str = "float32"
     # Attention softmax math dtype. None (default) keeps the f32 logits /
     # softmax chain — the numerically conservative choice. "bfloat16" skips
-    # the f32 materialisation of the [B, H, S, S] logits: measured 1.10x on
-    # the BERT-base v5e step (170.4 -> 154.8 ms, loss trajectory within
-    # 1.5e-4 after 20 steps; benchmarks/README.md "step breakdown") — the
-    # step is HBM-bound and the f32 score tensors are its biggest
-    # avoidable traffic. Opt in when your convergence gates pass with it.
+    # the f32 materialisation of the [B, H, S, S] logits, the biggest
+    # avoidable HBM traffic of a short-sequence encoder step (the
+    # benchmark's BERT-base cell runs with it). Opt in when your
+    # convergence gates pass with it.
     softmax_dtype: Optional[str] = None
     # fp8 mode: the blanket cast stays bf16 (casting raw params/activations
     # to e4m3 without per-tensor scaling destroys training); hot matmuls use

@@ -275,7 +275,7 @@ def phase_front_door(sizes: dict, seed: int, rehearsal: bool) -> dict:
 
 
 # --------------------------------------------------------------------------- #
-# train: BERT-base exactly as bench.py builds it
+# train: BERT-base
 # --------------------------------------------------------------------------- #
 
 

@@ -34,24 +34,6 @@ def main():
     )
     print(f"perfect-draft bound: {best['tokens_per_target_forward']:.2f} tok/forward")
 
-    # speculative CONTINUOUS BATCHING: the same draft/verify core drives
-    # the serving engine's slot pool (accepted+1 tokens per target pass,
-    # per slot) — streams stay exactly the target's greedy output
-    from accelerate_tpu.serving import ServingEngine
-
-    eng = ServingEngine(
-        # tick_block ~= max_new/(gamma+1): each tick iteration emits up to
-        # gamma+1 tokens per slot (serving.md sizing note)
-        target, num_slots=2, prompt_buckets=(8, 16), draft_model=target, gamma=4, tick_block=3
-    )
-    prompts = [ids[0, :8], ids[0, :5]]
-    for p, got in zip(prompts, eng.generate_many(prompts, max_new_tokens=12)):
-        np.testing.assert_array_equal(got, np.asarray(generate(target, p[None], max_new_tokens=12))[0])
-    s = eng.spec_stats
-    print(
-        f"speculative serving: {s['emitted']} tokens in {s['steps']} slot-forwards "
-        f"({s['emitted'] / max(1, s['steps']):.2f} tokens per slot-forward, bound {4 + 1})"
-    )
     print("speculative decoding example OK")
 
 

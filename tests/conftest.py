@@ -19,6 +19,11 @@ force_host_platform(8)
 # scripts (CLI/examples tests) share it.
 from accelerate_tpu.aot import configure_persistent_cache
 
+# The package is not installed: subprocess-launched CLI tests that run with
+# ``cwd`` outside the checkout must still find it.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+os.environ["PYTHONPATH"] = os.pathsep.join(filter(None, [_CHECKOUT, os.environ.get("PYTHONPATH")]))
+
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
 os.environ["JAX_COMPILATION_CACHE_DIR"] = configure_persistent_cache(min_compile_time_secs=0.5)
 
@@ -50,6 +55,27 @@ def reset_singletons():
     AcceleratorState._reset_state()
     GradientState._reset_state()
     PartialState._reset_state()
+
+
+@pytest.fixture
+def no_persistent_compile_cache():
+    """Disable jax's persistent compilation cache for one test.
+
+    XLA:CPU's restore-from-disk-cache can hand back a non-self-contained
+    executable (the PR-7 bug class): a step that carries state (overflow
+    latches, error-feedback residuals) can be poisoned to NaN by it, and
+    an executable serialized again from it into an ``ExecutableStore``
+    loses functions. Tests of such semantics run against the freshly
+    compiled executable; the disk cache is a wall-clock optimisation,
+    not part of the contract."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()  # an initialised cache outlives the flag
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
 
 
 @pytest.fixture

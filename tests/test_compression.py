@@ -316,26 +316,6 @@ def test_powersgd_training_converges():
     assert psgd[-1] < psgd[0] / 100
 
 
-@pytest.fixture
-def no_persistent_compile_cache():
-    """Disable jax's persistent compilation cache for one test.
-
-    The fp16+powersgd train step is numerically reliable when freshly
-    compiled (0 failures in 20+ runs) but NONDETERMINISTICALLY poisons
-    its carried state to NaN in ~25% of runs when XLA:CPU restores the
-    executable from the persistent disk cache — the same class of
-    non-self-contained deserialized-executable bug PR 7 documented for
-    `serialize_executable` (aot/ routes around it by compiling fresh
-    once). Until the XLA:CPU cache restore is trustworthy for this
-    program, the overflow-recovery semantics are tested against the
-    freshly-compiled executable."""
-    import jax
-
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    yield
-    jax.config.update("jax_compilation_cache_dir", old)
-
 
 def test_powersgd_fp16_overflow_does_not_poison_state(no_persistent_compile_cache):
     """A loss-scale overflow step must leave the carried residual/Q finite

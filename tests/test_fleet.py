@@ -449,11 +449,11 @@ def test_fleet_watchdog_silent_across_radix_hits_and_misses(tiny_llama):
     assert eng.program_cache.misses - c0 == 0, "radix traffic must not compile"
 
 
-def test_fleet_spin_up_warm_starts_from_shared_store(tiny_llama, tmp_path):
+def test_fleet_spin_up_warm_starts_from_shared_store(tiny_llama, tmp_path, no_persistent_compile_cache):
     """In-process spin-up over a shared store: every program either
     deserializes or is a reject-and-heal recompile — never a silent cold
     compile. (The STRICT 0-compile contract holds for fresh-process
-    replicas — bench_serving --fleet and the subprocess test below — and
+    replicas — the subprocess test below — and
     in-process under a single-device backend; under the suite's 8-device
     fake mesh XLA:CPU can emit non-self-contained blobs from a long-lived
     process, the PR-7-documented class the reject path heals.)"""

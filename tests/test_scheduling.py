@@ -1,8 +1,9 @@
 """Token-budget continuous-batching scheduler (scheduling.py + the
 ServingEngine tick loop): budget-interleaved prefill chunks stay
 token-exact, priority admission orders the queue, SLO shedding raises
-structured rejections, decode preemption + recompute-resume is token-
-and logprob-exact, and the uid index keeps streaming accessors O(1)."""
+structured rejections, decode preemption + recompute-resume is
+token-exact (logprobs to float32 rounding), and the uid index keeps
+streaming accessors O(1)."""
 
 import numpy as np
 import pytest
@@ -29,8 +30,6 @@ def _reference(model, prompt, n):
 
 
 def test_scheduler_config_validation():
-    with pytest.raises(ValueError, match="mode"):
-        SchedulerConfig(mode="lifo")
     with pytest.raises(ValueError, match="token_budget"):
         SchedulerConfig(token_budget=0)
     with pytest.raises(ValueError, match="max_queue_depth"):
@@ -42,15 +41,12 @@ def test_scheduler_config_validation():
 def test_scheduler_policy_decisions():
     s = Scheduler(SchedulerConfig(token_budget=64, max_queue_depth=2,
                                   max_queue_wait_s=1.0, enable_preemption=True))
-    # ordering: class first, then submission order; fifo ignores class
+    # ordering: class first, then submission order
     assert s.order_key(0, 7) < s.order_key(1, 3)
     assert s.order_key(1, 3) < s.order_key(1, 4)
-    fifo = Scheduler(SchedulerConfig(mode="fifo", token_budget=64))
-    assert fifo.order_key(5, 3) < fifo.order_key(0, 4)
-    # budget: decodes claim theirs first; fifo is unbudgeted
+    # budget: decodes claim theirs first
     assert s.tick_budget(4, 8) == 32
     assert s.tick_budget(100, 8) == 0
-    assert fifo.tick_budget(100, 8) == float("inf")
     # shedding: floor protects priority 0; thresholds gate
     assert s.shed_on_submit(0, 99) is None
     assert s.shed_on_submit(1, 2) is not None
@@ -63,10 +59,10 @@ def test_scheduler_policy_decisions():
     assert s.pick_victim(2, decoding) is None  # nothing strictly below
     off = Scheduler(SchedulerConfig())
     assert off.pick_victim(0, decoding) is None  # preemption disabled
-    # speculative gating
-    gated = Scheduler(SchedulerConfig(speculative_priorities=(0,)))
-    assert gated.use_speculative([0, 0]) and not gated.use_speculative([0, 1])
-    assert Scheduler(SchedulerConfig()).use_speculative([3, 7])
+    # the default config: one class admits in submission order, no budget, no shedding
+    assert sorted([7, 3, 5], key=lambda uid: off.order_key(0, uid)) == [3, 5, 7]
+    assert off.tick_budget(100, 8) == float("inf")
+    assert off.shed_on_submit(1, 10**6) is None and off.shed_on_wait(1, 1e9) is None
 
 
 def test_serving_scheduler_kwargs_handler():
@@ -123,7 +119,7 @@ def test_tiny_budget_cannot_livelock(tiny_llama):
 
 def test_priority_orders_admission(tiny_llama):
     """With one slot, a later high-priority submission admits before
-    earlier low-priority ones (and fifo mode ignores priority)."""
+    earlier low-priority ones."""
     p_lo = np.asarray([5, 6, 7], np.int32)
     p_hi = np.asarray([9, 9], np.int32)
     eng = ServingEngine(tiny_llama, num_slots=1, prompt_buckets=(4,))
@@ -199,7 +195,9 @@ def test_deprioritize_action_demotes_instead_of_rejecting(tiny_llama):
 def test_preempt_resume_token_and_logprob_exact(tiny_llama):
     """A high-priority arrival evicts the decoding low-priority request
     (dense slot pressure); the victim resumes by recompute and its FULL
-    output + logprobs equal an unpreempted control run."""
+    output equals an unpreempted control run, its logprobs to float32
+    rounding: the resumed stream decodes over K/V rows a chunk-window
+    program rebuilt, the control over rows prefill and the tick wrote."""
     p_victim = (np.arange(6) % 250 + 1).astype(np.int32)
     p_urgent = np.asarray([3, 1, 4, 1, 5], np.int32)
     eng = ServingEngine(
@@ -221,11 +219,14 @@ def test_preempt_resume_token_and_logprob_exact(tiny_llama):
     assert eng.metrics.resumes == 1
     np.testing.assert_array_equal(eng.poll(urgent), _reference(tiny_llama, p_urgent, 4))
     np.testing.assert_array_equal(eng.poll(victim), _reference(tiny_llama, p_victim, 10))
-    # logprob-exact vs an unpreempted control engine (same uid -> same chain)
+    # logprobs vs an unpreempted control engine (same uid -> same chain):
+    # what streamed before the eviction is untouched, the rest agrees to rounding
     control = ServingEngine(tiny_llama, num_slots=1, prompt_buckets=(8,), tick_block=2)
     c = control.submit(p_victim, max_new_tokens=10, priority=1)
     control.run()
-    np.testing.assert_array_equal(eng.logprobs(victim), control.logprobs(c))
+    n = streamed.size
+    np.testing.assert_array_equal(eng.logprobs(victim)[:n], control.logprobs(c)[:n])
+    np.testing.assert_allclose(eng.logprobs(victim), control.logprobs(c), rtol=1e-6)
 
 
 def test_preempt_resume_exact_under_sampling(tiny_llama):
@@ -246,7 +247,7 @@ def test_preempt_resume_exact_under_sampling(tiny_llama):
     c = control.submit(p_victim, max_new_tokens=9, priority=1)
     control.run()
     np.testing.assert_array_equal(eng.poll(victim), control.poll(c))
-    np.testing.assert_array_equal(eng.logprobs(victim), control.logprobs(c))
+    np.testing.assert_allclose(eng.logprobs(victim), control.logprobs(c), rtol=1e-6)
 
 
 def test_paged_pool_pressure_preempts_youngest_low_priority(tiny_llama):
@@ -295,15 +296,6 @@ def test_cancel_preempted_and_requeued_request(tiny_llama):
         eng.cancel(victim)
 
 
-def test_preemption_rejected_with_draft_model(tiny_llama):
-    draft = create_llama_model(LlamaConfig.tiny(num_hidden_layers=1), seq_len=32, seed=1)
-    with pytest.raises(NotImplementedError, match="preemption"):
-        ServingEngine(
-            tiny_llama, num_slots=1, prompt_buckets=(8,), draft_model=draft,
-            scheduler=SchedulerConfig(enable_preemption=True),
-        )
-
-
 # --------------------------------------------------------------------- #
 # stop sequences across a tick-block boundary
 # --------------------------------------------------------------------- #
@@ -347,42 +339,6 @@ def test_stop_sequence_on_resumed_request(tiny_llama):
     got = eng.poll(victim)
     assert len(got) == len(prompt) + first + 2
     np.testing.assert_array_equal(got, full[: len(got)])
-
-
-# --------------------------------------------------------------------- #
-# speculative gating (per-priority opt-in)
-# --------------------------------------------------------------------- #
-
-
-def test_speculative_gating_plain_tick_stays_exact(tiny_llama):
-    """speculative_priorities=() routes every tick through the PLAIN
-    target tick of a draft-equipped engine — outputs must still equal
-    target greedy (the {t,d} pair tick advances only the target half)."""
-    draft = create_llama_model(LlamaConfig.tiny(num_hidden_layers=1), seq_len=32, seed=1)
-    rng = np.random.default_rng(2)
-    prompts = [rng.integers(1, 250, size=n).astype(np.int32) for n in (5, 8)]
-    eng = ServingEngine(
-        tiny_llama, num_slots=2, prompt_buckets=(8,), tick_block=2,
-        draft_model=draft, gamma=3,
-        scheduler=SchedulerConfig(speculative_priorities=()),
-    )
-    for p, got in zip(prompts, eng.generate_many(prompts, max_new_tokens=6)):
-        np.testing.assert_array_equal(got, _reference(tiny_llama, p, 6))
-    assert eng.spec_stats["steps"] == 0  # never speculated
-
-
-def test_speculative_gating_opted_in_class_speculates(tiny_llama):
-    draft = create_llama_model(LlamaConfig.tiny(num_hidden_layers=1), seq_len=32, seed=1)
-    p = (np.arange(5) % 250 + 1).astype(np.int32)
-    eng = ServingEngine(
-        tiny_llama, num_slots=2, prompt_buckets=(8,), tick_block=2,
-        draft_model=draft, gamma=3,
-        scheduler=SchedulerConfig(speculative_priorities=(0,)),
-    )
-    uid = eng.submit(p, max_new_tokens=6, priority=0)
-    eng.run()
-    np.testing.assert_array_equal(eng.poll(uid), _reference(tiny_llama, p, 6))
-    assert eng.spec_stats["steps"] > 0
 
 
 # --------------------------------------------------------------------- #
@@ -445,24 +401,3 @@ def test_scheduler_events_land_in_telemetry_and_summarize(tiny_llama, tmp_path):
     sched = report["scheduler"]
     assert sched["admitted"] >= 2 and sched["preempted"] == 1 and sched["resumed"] == 1
     assert "scheduler:" in render_text(report)
-
-
-def test_fifo_mode_matches_legacy_behavior(tiny_llama):
-    """mode='fifo' ignores priorities and budgets: strict submission
-    order, outputs exact — the A/B baseline bench_serving measures."""
-    rng = np.random.default_rng(5)
-    prompts = [rng.integers(1, 250, size=n).astype(np.int32) for n in (3, 9, 5)]
-    eng = ServingEngine(
-        tiny_llama, num_slots=1, prompt_buckets=(4, 8),
-        scheduler=SchedulerConfig(mode="fifo", token_budget=4, enable_preemption=True),
-    )
-    uids = [eng.submit(p, max_new_tokens=4, priority=pr) for p, pr in zip(prompts, (1, 1, 0))]
-    done_order = []
-    while eng.queue or eng.active_count:
-        eng.step()
-        for u in uids:
-            if eng.poll(u) is not None and u not in done_order:
-                done_order.append(u)
-    assert done_order == uids  # submission order, priority ignored
-    for p, u in zip(prompts, uids):
-        np.testing.assert_array_equal(eng.poll(u), _reference(tiny_llama, p, 4))

@@ -3,6 +3,7 @@ tests/test_scheduler.py, test_optimizer.py, test_memory_utils.py,
 test_logging.py, test_kwargs_handlers.py)."""
 
 import logging
+import re
 
 import jax
 import numpy as np
@@ -224,7 +225,7 @@ def test_api_docs_generator_is_deterministic():
 
     page = mod.render_module("accelerate_tpu.accelerator")
     assert page == mod.render_module("accelerate_tpu.accelerator")  # deterministic
-    assert "0x" not in page
+    assert not re.search(r"0x[0-9a-f]{6,}", page)  # an address, not the word "TPU10xx"
     assert "build_train_step" in page and "gather_for_metrics" in page
     ops_page = mod.render_module("accelerate_tpu.ops.qdense")
-    assert "QuantDense" in ops_page and "0x" not in ops_page
+    assert "QuantDense" in ops_page and not re.search(r"0x[0-9a-f]{6,}", ops_page)

@@ -46,21 +46,6 @@ def bound_live_executables_per_test():
     jax.clear_caches()
 
 
-@pytest.fixture
-def no_persistent_compile_cache():
-    """Disable jax's persistent compilation cache for one test.
-
-    Same contract as the fixture of the same name in test_compression.py:
-    steps that carry error-feedback state are numerically reliable when
-    freshly compiled but XLA:CPU's restore-from-disk-cache can poison the
-    carried residuals to NaN (the PR-7 non-self-contained
-    deserialized-executable bug class) — so the quantized-carry semantics
-    are tested against the freshly-compiled executable."""
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    yield
-    jax.config.update("jax_compilation_cache_dir", old)
-
 
 def _reset():
     AcceleratorState._reset_state()
