@@ -629,7 +629,7 @@ class RoutedFFN(nn.Module):
             )
         out, group_sizes = dropless_moe_ffn(flat, experts, weights, gate, up, down)
         if self.is_mutable_collection(EXPERT_LOAD):
-            self.sow(EXPERT_LOAD, "counts", expert_load(group_sizes))
+            self.sow(EXPERT_LOAD, "counts", expert_load(group_sizes, experts.size))
         out = out.reshape(hidden.shape)
         if cfg.n_shared_experts:
             with jax.named_scope("moe.shared"):

@@ -19,6 +19,7 @@ subsystem here, built the GSPMD way (GShard/Mesh-TF idiom):
 from __future__ import annotations
 
 import contextlib
+import functools
 from typing import Optional
 
 import jax
@@ -181,6 +182,34 @@ def sigmoid_topk_routing(
     return experts.astype(jnp.int32), weights * scaling_factor
 
 
+def _ragged_swiglu_ffn(xs, wi_gate, wi_up, wo, group_sizes):
+    """The experts' three grouped products as ``jax.lax.ragged_dot`` calls: rows ``xs [m, d]`` sorted by group."""
+    h = nn.silu(jax.lax.ragged_dot(xs, wi_gate.astype(xs.dtype), group_sizes))
+    h = h * jax.lax.ragged_dot(xs, wi_up.astype(xs.dtype), group_sizes)
+    return jax.lax.ragged_dot(h, wo.astype(xs.dtype), group_sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kernel_swiglu_ffn(xs, wi_gate, wi_up, wo, group_sizes, interpret):
+    from .pallas_grouped_matmul import grouped_swiglu_ffn
+
+    return grouped_swiglu_ffn(xs, wi_gate, wi_up, wo, group_sizes, interpret=interpret)
+
+
+def _kernel_swiglu_ffn_fwd(*args):
+    return _kernel_swiglu_ffn(*args), args[:5]  # all but ``interpret``
+
+
+def _kernel_swiglu_ffn_bwd(interpret, residuals, g):
+    # the ragged_dot formulation's gradients: no cell trains this family, so the backward has no kernel
+    *operands, group_sizes = residuals
+    _, vjp = jax.vjp(lambda *ops: _ragged_swiglu_ffn(*ops, group_sizes), *operands)
+    return (*vjp(g), None)
+
+
+_kernel_swiglu_ffn.defvjp(_kernel_swiglu_ffn_fwd, _kernel_swiglu_ffn_bwd)
+
+
 def dropless_moe_ffn(
     x: jax.Array,  # [T, d]
     experts: jax.Array,  # [T, k] int32: the experts each token goes to
@@ -191,23 +220,37 @@ def dropless_moe_ffn(
 ):
     """Routed SwiGLU experts with no capacity and no ``[T, E, C]`` mask:
     the ``T * k`` token-expert pairs are sorted by expert and the three
-    products run as grouped matmuls (``jax.lax.ragged_dot``: on TPU one
-    Mosaic kernel a product, which reads an expert's weights only if a
-    pair reached it), so a decode step of 64 tokens and a prefill of 4096
+    products run as grouped matmuls, which read an expert's weights only if
+    a pair reached it, so a decode step of 64 tokens and a prefill of 4096
     take the same path and no token is ever dropped, at any skew.
+
+    **On a TPU** the products run in the Pallas kernels of
+    :mod:`.pallas_grouped_matmul` (``gate`` and ``up`` in one call, ``down``
+    in a second), whose row tile follows the pairs an expert gets
+    (``row_tile(T * k, E)``: 16 rows at a decode tick, up to 128 in a
+    prefill). **Off it**, and under a mesh of several devices, they are
+    three ``jax.lax.ragged_dot`` calls, as is the backward everywhere.
+    ``paged_kv.FORCE_KERNEL_INTERPRET`` runs the kernels interpreted
+    (tests). Operands in ``x.dtype``, float32 accumulation, either way.
 
     Returns ``(out [T, d], group_sizes [E])``; ``group_sizes`` (pairs per
     expert) is what :func:`expert_load` reads."""
+    from . import paged_kv
+    from .attention import active_mesh
+
     t, k = experts.shape
     e = wi_gate.shape[0]
     flat = experts.reshape(t * k)
     order = jnp.argsort(flat, stable=True)  # pairs grouped by expert
     group_sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+    on_tpu = jax.default_backend() == "tpu"
+    mesh = active_mesh()  # XLA's partitioner cannot split a pallas_call: over several devices, ragged_dot
     with jax.named_scope("moe.experts"):
         xs = x[order // k]  # [T*k, d]: pair i of the sorted list belongs to token order[i] // k
-        h = nn.silu(jax.lax.ragged_dot(xs, wi_gate.astype(x.dtype), group_sizes))
-        h = h * jax.lax.ragged_dot(xs, wi_up.astype(x.dtype), group_sizes)
-        ys = jax.lax.ragged_dot(h, wo.astype(x.dtype), group_sizes)  # [T*k, d]
+        if (on_tpu or paged_kv.FORCE_KERNEL_INTERPRET) and (mesh is None or mesh.size == 1):
+            ys = _kernel_swiglu_ffn(xs, wi_gate, wi_up, wo, group_sizes, not on_tpu)  # [T*k, d]
+        else:
+            ys = _ragged_swiglu_ffn(xs, wi_gate, wi_up, wo, group_sizes)
         # back to token order by a gather (the inverse permutation), then the weighted sum
         back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k, dtype=order.dtype))
         ys = ys[back].reshape(t, k, -1)
@@ -219,9 +262,15 @@ EXPERT_LOAD = "expert_load"  # the flax collection a routed FFN sows its counts 
 _LOAD_COUNTS: Optional[list] = None
 
 
-def expert_load(group_sizes: jax.Array) -> jax.Array:
-    """``[distinct experts with a pair, most pairs on one expert]`` (``[2]`` int32) of one routed FFN call."""
-    return jnp.stack([jnp.sum(group_sizes > 0), group_sizes.max()])
+def expert_load(group_sizes: jax.Array, pairs: int) -> jax.Array:
+    """``[distinct experts with a pair, most pairs on one expert, (expert, row tile) visits of one
+    product]`` (``[3]`` int32) of one routed FFN call over ``pairs = T * k`` pairs. The third is the
+    grid of the grouped kernel (:mod:`.pallas_grouped_matmul`) at the row tile that pair count gives:
+    over the first it says how many row tiles an expert's pairs lie in, 1.0 where none straddles."""
+    from .pallas_grouped_matmul import row_tile, tile_visits
+
+    visits = tile_visits(group_sizes, row_tile(pairs, group_sizes.shape[0]))
+    return jnp.stack([jnp.sum(group_sizes > 0), group_sizes.max(), jnp.sum(visits)])
 
 
 @contextlib.contextmanager

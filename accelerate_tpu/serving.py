@@ -524,14 +524,14 @@ class ServingEngine:
             jax.random.key(seed), jnp.arange(num_slots)
         )
 
-        self._tick_expert_load = (0, 0)
+        self._tick_expert_load = (0, 0, 0)
 
         def make_tick(step_body):
             """K-step tick scaffold shared by both cache layouts:
             ``step_body(params, caches, toks, poss, keys) -> (caches,
             next_toks, logprobs, keys, load)`` advances every slot one
             token; ``load`` is None, or the routed experts' counts of the
-            step (``[expert layers, 2]``, ops/moe.py ``expert_load_counts``)."""
+            step (``[expert layers, 3]``, ops/moe.py ``expert_load_counts``)."""
 
             def decode_tick(params, slot_caches, toks, poss, keys):
                 def block_step(carry, _):
@@ -542,7 +542,7 @@ class ServingEngine:
                 (slot_caches, _, _, keys), (toks_k, lps_k, load_k) = jax.lax.scan(
                     block_step, (slot_caches, toks, poss, keys), None, length=tick_block
                 )
-                return slot_caches, toks_k, lps_k, keys, load_k  # each [K, slots]; load_k [K, layers, 2] or None
+                return slot_caches, toks_k, lps_k, keys, load_k  # each [K, slots]; load_k [K, layers, 3] or None
 
             return decode_tick
 
@@ -1317,7 +1317,7 @@ class ServingEngine:
         m = self.metrics
         admitted, tokens_was, completed_was = 0, m.tokens_generated, m.requests_completed
         self._tick_prefill_tokens = 0
-        self._tick_expert_load = (0, 0)
+        self._tick_expert_load = (0, 0, 0)
         with phase("engine.schedule"):
             now = time.monotonic()
             self._pool_blocked = False
@@ -1370,7 +1370,7 @@ class ServingEngine:
             emitted=m.tokens_generated - tokens_was, retired=m.requests_completed - completed_was,
             pool_blocked=int(self._pool_blocked), free_blocks=self._alloc.free_count if self.paged else -1,
             queue_len=len(self.queue), experts_touched=self._tick_expert_load[0],
-            expert_pairs_max=self._tick_expert_load[1],
+            expert_pairs_max=self._tick_expert_load[1], expert_tile_visits=self._tick_expert_load[2],
         ):
             pass
 
@@ -1702,7 +1702,8 @@ class ServingEngine:
             lps_k = np.asarray(lps_k)
             if load_k is not None:
                 load_k = np.asarray(load_k)
-                self._tick_expert_load = (int(load_k[..., 0].sum()), int(load_k[..., 1].max()))
+                touched, most, visits = load_k[..., 0], load_k[..., 1], load_k[..., 2]
+                self._tick_expert_load = (int(touched.sum()), int(most.max()), int(visits.sum()))
                 self.metrics.on_expert_load(*self._tick_expert_load)
         with phase("engine.decode.walk"):
             for slot, req in enumerate(self.slot_req):

@@ -124,10 +124,13 @@ class ServingMetrics:
         self.resumes = 0  # preempted requests resumed by recompute
         # routed experts (models with a RoutedFFN; 0 otherwise), from the
         # decode ticks' ``expert_load``: distinct experts that got a token,
-        # summed over expert layers and decode steps, and the most tokens
-        # any one expert got in a step
+        # summed over expert layers and decode steps, the most tokens any
+        # one expert got in a step, and the (expert, row tile) visits of one
+        # grouped product, summed likewise: over experts_touched, how many
+        # row tiles an expert's tokens lie in
         self.experts_touched = 0
         self.expert_pairs_max = 0
+        self.expert_tile_visits = 0
         # cross-request prefix reuse (serving_fleet.RadixPrefixCache):
         # a hit means the request skipped re-prefilling that many shared
         # preamble tokens — the fleet's dominant p95-TTFT lever
@@ -187,9 +190,10 @@ class ServingMetrics:
         self.tokens_generated += n
         self._token_marks.append((self._clock(), self.tokens_generated))
 
-    def on_expert_load(self, touched: int, pairs_max: int):
+    def on_expert_load(self, touched: int, pairs_max: int, tile_visits: int):
         self.experts_touched += touched
         self.expert_pairs_max = max(self.expert_pairs_max, pairs_max)
+        self.expert_tile_visits += tile_visits
 
     def on_tick_tokens(self, uid: int, n: int):
         """ITL sample: ``n`` tokens delivered to ``uid`` this tick."""

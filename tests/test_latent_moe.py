@@ -33,20 +33,103 @@ def _expert_loop(x, experts, weights, gate, up, down):
     return out
 
 
-@pytest.mark.parametrize("case", ["balanced", "one_expert", "single_token"])
-def test_dropless_ffn_is_the_expert_loop(case):
-    """float32 on the CPU: the grouped products add the same terms in another order, so 1e-5."""
-    gate, up, down, key = _experts(0)
-    t = 1 if case == "single_token" else 12
-    x = jax.random.normal(key, (t, 16))
+def _routing(case, e=8):
+    """``[T, 2]`` experts of a case: the first three as they were, the rest what the grouped kernel's
+    bookkeeping can get wrong at its row tile (16 rows for all of them but ``one_expert_many_tiles``: 64)."""
+    if case == "single_token":
+        return jnp.array([[0, 3]], jnp.int32)
     if case == "one_expert":  # every token on expert 5 (and 2): nothing is dropped at any skew
-        experts = jnp.tile(jnp.array([[5, 2]], jnp.int32), (t, 1))
-    else:
-        experts = jnp.stack([jnp.arange(t) % 8, (jnp.arange(t) + 3) % 8], axis=1).astype(jnp.int32)
+        return jnp.tile(jnp.array([[5, 2]], jnp.int32), (12, 1))
+    if case == "balanced":
+        return jnp.stack([jnp.arange(12) % e, (jnp.arange(12) + 3) % e], axis=1).astype(jnp.int32)
+    if case == "empty_between":  # experts 1, 2, 4 and 6 get nothing, between experts that do
+        return jnp.array([[0, 3], [0, 5], [3, 7], [5, 0], [7, 3]], jnp.int32)
+    if case == "straddles_a_tile":  # 32 pairs: expert 1 holds rows 10..21, across the tile edge at 16
+        return jnp.array([[0, 1]] * 10 + [[1, 2]] * 2 + [[2, 3]] * 4, jnp.int32)
+    if case == "one_expert_many_tiles":  # expert 4 takes every one of 80 pairs: more rows than the 64 of a tile
+        return jnp.tile(jnp.array([[4, 4]], jnp.int32), (40, 1))
+    if case == "pairs_not_a_multiple_of_the_tile":  # 26 pairs on a 16-row tile: the last tile is cut
+        return jnp.stack([jnp.arange(13) % 5, 5 + jnp.arange(13) % 3], axis=1).astype(jnp.int32)
+    raise ValueError(case)
+
+
+CASES = ["balanced", "one_expert", "single_token", "empty_between", "straddles_a_tile", "one_expert_many_tiles",
+         "pairs_not_a_multiple_of_the_tile"]
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["ragged_dot", "pallas_interpreted"])
+@pytest.mark.parametrize("case", CASES)
+def test_dropless_ffn_is_the_expert_loop(case, kernel, monkeypatch):
+    """float32 on the CPU: the grouped products add the same terms in another order, so 1e-5, through
+    ``jax.lax.ragged_dot`` and through the grouped Pallas kernel the chip runs, interpreted."""
+    monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", kernel)
+    gate, up, down, key = _experts(0)
+    experts = _routing(case)
+    t = experts.shape[0]
+    x = jax.random.normal(key, (t, 16))
     weights = jax.random.uniform(jax.random.key(7), (t, 2)) + 0.1
     out, sizes = dropless_moe_ffn(x, experts, weights, gate, up, down)
     assert int(sizes.sum()) == 2 * t and (case != "one_expert" or int(sizes[5]) == t)
     np.testing.assert_allclose(np.asarray(out), _expert_loop(x, experts, weights, gate, up, down), atol=1e-5)
+
+
+def test_kernel_path_differentiates_as_ragged_dot_does(monkeypatch):
+    """The kernel path's ``custom_vjp`` hands back the ``ragged_dot`` formulation's gradients, for the
+    rows and for each of the three stacks."""
+    gate, up, down, key = _experts(1)
+    experts = _routing("straddles_a_tile")
+    x = jax.random.normal(key, (experts.shape[0], 16))
+    weights = jax.random.uniform(jax.random.key(7), experts.shape) + 0.1
+
+    def loss(x, gate, up, down):
+        return jnp.sum(dropless_moe_ffn(x, experts, weights, gate, up, down)[0] ** 2)
+
+    grads = {}
+    for kernel in (False, True):
+        monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", kernel)
+        grads[kernel] = jax.grad(loss, argnums=(0, 1, 2, 3))(x, gate, up, down)
+    for plain, through_kernel in zip(grads[False], grads[True]):
+        assert float(jnp.abs(plain).max()) > 1e-3
+        np.testing.assert_allclose(np.asarray(through_kernel), np.asarray(plain), rtol=1e-5, atol=1e-5)
+
+
+def test_under_a_mesh_of_several_devices_the_products_stay_ragged_dot(monkeypatch):
+    """XLA's partitioner cannot split a ``pallas_call``: the kernel is for one device."""
+    from jax.sharding import Mesh
+
+    from accelerate_tpu.parallel.sharding import mesh_context
+
+    monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", True)
+    gate, up, down, key = _experts(0)
+    experts = _routing("balanced")
+    x, weights = jax.random.normal(key, (12, 16)), jnp.ones((12, 2))
+
+    def trace():  # a function of its own each time: the active mesh is no part of a trace cache's key
+        return str(jax.make_jaxpr(lambda *a: dropless_moe_ffn(*a))(x, experts, weights, gate, up, down))
+
+    assert "pallas_call" in trace() and "ragged_dot" not in trace()
+    with mesh_context(Mesh(np.array(jax.devices()[:2]), ("data",))):
+        assert "ragged_dot" in trace() and "pallas_call" not in trace()
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_expert_tile_visits_is_the_kernels_grid(case):
+    """The third count of ``expert_load`` against a count made in numpy: the (expert, row tile) pairs
+    in which a sorted pair of that expert lies in that tile; and the plan walks exactly those."""
+    from accelerate_tpu.ops.moe import expert_load
+    from accelerate_tpu.ops.pallas_grouped_matmul import row_tile, visit_plan
+
+    flat = np.sort(np.asarray(_routing(case)).reshape(-1))
+    tile = row_tile(flat.size, 8)
+    assert tile == (64 if case == "one_expert_many_tiles" else 16)
+    want = sorted({(int(g), i // tile) for i, g in enumerate(flat)})
+    sizes = jnp.asarray(np.bincount(flat, minlength=8), jnp.int32)
+    touched, most, visits = np.asarray(expert_load(sizes, flat.size)).tolist()
+    assert (touched, most, visits) == (len(set(flat.tolist())), int(np.bincount(flat).max()), len(want))
+    rows = -(-flat.size // tile) * tile
+    _, group_of, tile_of, n = visit_plan(sizes, rows, tile)
+    walked = list(zip(np.asarray(group_of)[:n].tolist(), np.asarray(tile_of)[:n].tolist()))
+    assert int(n) == len(want) and walked == want
 
 
 def test_selection_bias_changes_the_choice_and_not_the_weight():
@@ -132,10 +215,11 @@ def test_engine_serves_from_the_paged_latent_cache(model, kernel, monkeypatch):
     layers = model.config.num_hidden_layers - 1
     assert 0 < m.expert_pairs_max <= 3 * 2 and m.experts_touched > 0
     assert m.experts_touched <= engine._tick * engine.tick_block * layers * 8
+    assert m.experts_touched <= m.expert_tile_visits <= m.experts_touched + engine._tick * engine.tick_block * layers
 
 
 def test_expert_load_counts_are_a_ticks(model):
-    """The counts leave the tick beside its tokens (``[steps, expert layers, 2]``), and the cache holds
+    """The counts leave the tick beside its tokens (``[steps, expert layers, 3]``), and the cache holds
     nothing but rows, tables and frontiers: no cache program has to know of them."""
     engine = ServingEngine(model, num_slots=2, prompt_buckets=(8,), max_len=32, paged_block_size=8, tick_block=2)
     names = {str(p[-1].key) for p, _ in jax.tree_util.tree_flatten_with_path(engine.slot_caches)[0]}
@@ -147,19 +231,22 @@ def test_expert_load_counts_are_a_ticks(model):
         seen.append(engine._tick_expert_load)
     layers = model.config.num_hidden_layers - 1
     # two slots (one idle) route a token each in each of 2 steps of each expert layer: 2..4 experts a layer a step
-    assert all(2 * layers * 2 <= touched <= 2 * layers * 4 and 1 <= most <= 2 for touched, most in seen)
-    assert engine.metrics.experts_touched == sum(touched for touched, _ in seen)
+    assert all(2 * layers * 2 <= touched <= 2 * layers * 4 and 1 <= most <= 2 for touched, most, _ in seen)
+    # 4 pairs a call lie in one 16-row tile: an expert's pairs lie in one tile each, visits == experts touched
+    assert all(visits == touched for touched, _, visits in seen)
+    assert engine.metrics.experts_touched == sum(touched for touched, _, _ in seen)
+    assert engine.metrics.expert_tile_visits == sum(visits for _, _, visits in seen)
 
 
 def test_expert_load_is_collected_only_inside_its_context(model):
     """Through ``nn.remat`` too (the toy model rematerialises its layers): the counts are sown, not leaked."""
     from accelerate_tpu.ops.moe import expert_load, expert_load_counts
 
-    assert np.asarray(expert_load(jnp.array([4, 0, 1, 2, 0]))).tolist() == [3, 4]
+    assert np.asarray(expert_load(jnp.array([4, 0, 1, 2, 0]), 7)).tolist() == [3, 4, 3]
     ids, pos = jnp.asarray([[5, 9, 5]]), jnp.arange(3)[None]
     with expert_load_counts() as loads:
         jax.jit(lambda p: model.apply_fn(p, ids, positions=pos, decode=True, cache=None)[0])(model.params)
-        assert len(loads) == model.config.num_hidden_layers - 1 and all(l.shape == (2,) for l in loads)
+        assert len(loads) == model.config.num_hidden_layers - 1 and all(l.shape == (3,) for l in loads)
     _, cache = model.apply_fn(model.params, ids, positions=pos, decode=True, cache=None)
     assert len(loads) == 2 and "expert_load" not in cache
 

@@ -128,6 +128,34 @@ def test_latent_paged_decode_compiles_for_v5e(v5e):
     assert "latent_paged_decode" in text and "paged_decode_attention" not in text, "found by a name of its own"
 
 
+def _expert_products(text):
+    """The HLO instructions named for the routed experts' products, as chipbench's reader finds them."""
+    import re
+
+    return [line.strip() for line in text.splitlines() if re.match(r"\s*(ROOT )?%ragged-dot[\w.\-]* = ", line)]
+
+
+@pytest.mark.parametrize(
+    "pairs", [512, 2048, 8192, 32768], ids=["tick_64x8", "prefill_b256", "prefill_b1024", "prefill_b4096"]
+)
+def test_grouped_expert_products_compile_for_v5e(v5e, pairs):
+    """The routed experts' grouped kernels at JoyAI-LLM-Flash widths (256 experts of ``[2048, 768]`` twice
+    and ``[768, 2048]``), at the pair counts of the decode tick and the three prefill buckets: the row
+    tile each gets (16 / 32 / 128 / 128), two Mosaic calls named ``ragged-dot-*``, and no product of XLA's."""
+    from accelerate_tpu.ops.pallas_grouped_matmul import grouped_swiglu_ffn, row_tile
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    experts, d, ff = 256, 2048, 768
+    assert row_tile(pairs, experts) == {512: 16, 2048: 32, 8192: 128, 32768: 128}[pairs]
+    text = _compile(
+        grouped_swiglu_ffn, _on(chip, (pairs, d)), _on(chip, (experts, d, ff)), _on(chip, (experts, d, ff)),
+        _on(chip, (experts, ff, d)), _on(chip, (experts,), jnp.int32),
+    )
+    products = _expert_products(text)
+    assert len(products) == 2 and all("tpu_custom_call" in p for p in products), products
+    assert "ragged-dot-swiglu" in products[0] and "ragged-dot-down" in products[1]
+
+
 def test_latent_moe_decode_tick_fits_one_v5e_chip_and_moves_no_pool(v5e, monkeypatch):
     """The 64-slot decode tick of the ``joyai-flash-serve-longchat`` cell at its real size: 5.56 B
     parameters and a 1.89 GB latent pool as arguments, the pool aliased to the output, and no
@@ -164,6 +192,8 @@ def test_latent_moe_decode_tick_fits_one_v5e_chip_and_moves_no_pool(v5e, monkeyp
         compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
     text = compiled.as_text()
     assert text.count("latent_paged_decode") >= 5 and "ragged-dot" in text
+    products = _expert_products(text)  # four expert layers of two grouped kernels; XLA's own 512-row lowering of none
+    assert len(products) >= 8 and all("tpu_custom_call" in p for p in products), [p[:160] for p in products]
     pool = f"{s['pool_blocks']},576,{s['paged_block_size']}"
     moved = [l.strip()[:140] for l in text.splitlines() if re.search(rf"= bf16\[{pool}\]\S* (copy|transpose)\(", l)]
     assert not moved, "the tick moves a whole pool:\n" + "\n".join(moved)
