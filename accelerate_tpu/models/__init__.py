@@ -1,5 +1,5 @@
 """Model zoo: TPU-first flax implementations with mesh sharding rules
-(bert/gpt2/gptneox/t5/llama/mistral/joyai_llm_flash/qwen2/qwen3/olmo2/gemma/gemma2/gemma3/phi3/mixtral/qwen3moe/resnet/vit/whisper/clip/unet/vae)
+(bert/gpt2/gptneox/t5/llama/mistral/joyai_llm_flash/jamba/qwen2/qwen3/olmo2/gemma/gemma2/gemma3/phi3/mixtral/qwen3moe/resnet/vit/whisper/clip/unet/vae)
 + HF safetensors weight import. The reference delegates models to
 transformers; here they ship in-tree (SURVEY hard-part #3: torch-free
 model story)."""
@@ -41,6 +41,12 @@ from .joyai_llm_flash import (
     JoyAIFlashConfig,
     JoyAIFlashModel,
     create_joyai_flash_model,
+)
+from .jamba import (
+    JAMBA_SHARDING_RULES,
+    JambaConfig,
+    JambaModel,
+    create_jamba_model,
 )
 from .gemma import (
     GEMMA_SHARDING_RULES,

@@ -38,7 +38,10 @@ class LlamaConfig:
     num_key_value_heads: int = 32
     max_position_embeddings: int = 4096
     rms_norm_eps: float = 1e-5
-    rope_theta: float = 10000.0
+    # None: the family publishes no rotary (or other) position encoding, and
+    # attention applies none (Jamba: positions reach the model through its
+    # state-space layers)
+    rope_theta: Optional[float] = 10000.0
     # HF-style rope_scaling dict ({"rope_type": "llama3"|"linear"|"yarn"|
     # "longrope", "factor": ..., ...}); Llama-3.1/3.2 checkpoints require
     # the llama3 rescale
@@ -129,6 +132,24 @@ class LlamaConfig:
     routed_scaling_factor: float = 1.0
     scoring_func: str = "sigmoid"
     norm_topk_prob: bool = True
+    # State-space layers beside attention (Jamba-style checkpoints publish
+    # these keys): with ``attn_layer_period`` set, layer ``i`` attends where
+    # ``i % attn_layer_period == attn_layer_offset`` and every other layer's
+    # mixer is a :class:`MambaMixer` (Mamba-1: ``d_inner = mamba_expand *
+    # hidden_size``, a state of ``[mamba_d_state, d_inner]`` and the last
+    # ``mamba_d_conv - 1`` inputs of its convolution a sequence, whatever
+    # the length). Layers are then unrolled (``scan_layers=False``).
+    attn_layer_period: Optional[int] = None
+    attn_layer_offset: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: Optional[int] = None  # None: ceil(hidden_size / 16)
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+
+    def is_mamba_layer(self, i: int) -> bool:
+        return self.attn_layer_period is not None and i % self.attn_layer_period != self.attn_layer_offset
 
     @classmethod
     def llama2_7b(cls, **kw) -> "LlamaConfig":
@@ -455,7 +476,7 @@ class LlamaAttention(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, hidden, positions, decode: bool = False):
+    def __call__(self, hidden, positions, decode: bool = False, new_span=None):
         cfg = self.config
         head_dim = cfg.head_dim or cfg.hidden_size // cfg.num_attention_heads
         q = _dense(cfg, cfg.num_attention_heads * head_dim, "q_proj", hidden.dtype, cfg.qkv_bias)(hidden)
@@ -479,17 +500,18 @@ class LlamaAttention(nn.Module):
         # prefill uses the (static) input length like HF's runtime switch;
         # decode sees S=1, so the cache capacity stands in for it
         rope_len = cfg.max_position_embeddings if decode else hidden.shape[1]
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling,
-                 max_pos=cfg.max_position_embeddings, seq_len=rope_len,
-                 orig_max=cfg.original_max_position_embeddings)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling,
-                 max_pos=cfg.max_position_embeddings, seq_len=rope_len,
-                 orig_max=cfg.original_max_position_embeddings)
+        if cfg.rope_theta is not None:
+            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling,
+                     max_pos=cfg.max_position_embeddings, seq_len=rope_len,
+                     orig_max=cfg.original_max_position_embeddings)
+            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling,
+                     max_pos=cfg.max_position_embeddings, seq_len=rope_len,
+                     orig_max=cfg.original_max_position_embeddings)
         scale = None  # attention default: head_dim**-0.5
         if cfg.query_pre_attn_scalar is not None:
             scale = float(cfg.query_pre_attn_scalar) ** -0.5  # Gemma2
         if decode:
-            out = self._cached_attention(q, k, v, scale)
+            out = self._cached_attention(q, k, v, scale, new_span)
         else:
             out = _dispatch_attention(
                 q, k, v, cfg.attention_impl, cfg.sliding_window,
@@ -498,7 +520,7 @@ class LlamaAttention(nn.Module):
         out = out.reshape(*out.shape[:-2], cfg.num_attention_heads * head_dim)
         return _dense(cfg, cfg.hidden_size, "o_proj", hidden.dtype)(out)
 
-    def _cached_attention(self, q, k, v, scale=None):
+    def _cached_attention(self, q, k, v, scale=None, new_span=None):
         """KV-cache incremental attention (generation path; shared cache
         machinery in :mod:`accelerate_tpu.ops.kv_cache`)."""
         from ..ops.kv_cache import cached_attention
@@ -508,6 +530,7 @@ class LlamaAttention(nn.Module):
             scale=scale,
             sliding_window=self.config.sliding_window,
             logit_softcap=self.config.attn_logit_softcap,
+            keep_rows_before=None if new_span is None else new_span[0],
         )
 
 
@@ -638,16 +661,120 @@ class RoutedFFN(nn.Module):
         return out
 
 
+class MambaMixer(nn.Module):
+    """Selective state-space mixer (Mamba-1, arXiv:2312.00752) as the Jamba
+    checkpoints run it: ``[u, z] = in_proj(x)``; ``u = silu(conv1d(u))``
+    (depthwise, causal, ``mamba_d_conv`` taps); ``[dt, B, C] = x_proj(u)``,
+    each through an RMSNorm of its own (Jamba's ``dt_layernorm``,
+    ``b_layernorm``, ``c_layernorm``); ``delta = softplus(dt_proj(dt))``;
+    ``A = -exp(A_log)``; the recurrence of :mod:`accelerate_tpu.ops.selective_scan`;
+    ``out_proj(y * silu(z))``. ``exp``, ``softplus`` and the state are
+    float32; weights and activations keep the stream's type.
+
+    With ``decode=True`` the layer keeps, in the ``cache`` collection and in
+    the dense and the paged serving layout alike, ``ssm_state`` ``[B,
+    d_state, d_inner]`` float32 (``d_inner`` along the lanes) and
+    ``conv_state`` ``[B, (d_conv - 1) * d_inner]`` (the last inputs of the
+    convolution, oldest first, one row a sequence so that no minor axis of 3
+    is padded to a tile): one row a sequence whatever its length, no pages.
+    ``new_span`` ``(lo, hi)`` names the window's new tokens; the others (a
+    bucket's right pad, a chunk window's overlapped head) leave the state
+    as it was. Without a cache the same scan runs from a zero state."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, hidden, decode: bool = False, new_span=None):
+        from ..ops import paged_kv
+        from ..ops.selective_scan import causal_conv1d, selective_scan
+
+        cfg = self.config
+        dt, f32 = hidden.dtype, jnp.float32
+        bsz, t, _ = hidden.shape
+        d_in, n, k = cfg.mamba_expand * cfg.hidden_size, cfg.mamba_d_state, cfg.mamba_d_conv
+        rank = cfg.mamba_dt_rank or -(-cfg.hidden_size // 16)
+        lecun = nn.initializers.lecun_normal()
+        conv_w = self.param("conv_kernel", lecun, (k, d_in))
+        conv_b = self.param("conv_bias", nn.initializers.zeros, (d_in,)) if cfg.mamba_conv_bias else None
+        w_dt = self.param("dt_proj", lecun, (rank, d_in))
+        # Mamba's own initialiser: softplus(dt_bias) log-uniform in [0.001, 0.1], A = -(1 .. d_state), D = 1
+        dt_bias = self.param("dt_bias", _mamba_dt_bias_init, (d_in,))
+        a_log = self.param("A_log", lambda _k, shape: jnp.broadcast_to(jnp.log(jnp.arange(1.0, n + 1))[:, None], shape), (n, d_in))
+        d_skip = self.param("D", nn.initializers.ones, (d_in,))
+
+        if decode:
+            ssm = self.variable("cache", "ssm_state", jnp.zeros, (bsz, n, d_in), f32)
+            conv = self.variable("cache", "conv_state", jnp.zeros, (bsz, (k - 1) * d_in), dt)
+            h0, carried = ssm.value, conv.value
+        else:
+            h0, carried = jnp.zeros((bsz, n, d_in), f32), jnp.zeros((bsz, (k - 1) * d_in), dt)
+        lo, hi = (0, t) if new_span is None else new_span
+
+        with jax.named_scope("ssm.proj"):
+            xz = _dense(cfg, 2 * d_in, "in_proj", dt, cfg.mamba_proj_bias)(hidden)
+            u, z = xz[..., :d_in], xz[..., d_in:]
+        with jax.named_scope("ssm.conv"):
+            u, carried = causal_conv1d(u, conv_w, conv_b, carried, lo, hi)
+            u = nn.silu(u)
+        with jax.named_scope("ssm.proj"):
+            dbc = _dense(cfg, rank + 2 * n, "x_proj", dt)(u)
+            step = RMSNorm(cfg.rms_norm_eps, name="dt_norm")(dbc[..., :rank])
+            b_t = RMSNorm(cfg.rms_norm_eps, name="b_norm")(dbc[..., rank : rank + n])
+            c_t = RMSNorm(cfg.rms_norm_eps, name="c_norm")(dbc[..., rank + n :])
+            delta = jax.nn.softplus(
+                jnp.matmul(step, w_dt.astype(dt), preferred_element_type=f32) + dt_bias.astype(f32)
+            )
+            a = -jnp.exp(a_log.astype(f32))
+        on_tpu = jax.default_backend() == "tpu"
+        if decode and t == 1 and paged_kv.active_paged_config() is not None and (
+            on_tpu or paged_kv.FORCE_KERNEL_INTERPRET
+        ) and _one_device():
+            # the serving tick's step: one pass over the state, in place (a vmapped dense tick and
+            # generate() take the plain step below: no kernel under vmap)
+            from ..ops.pallas_selective_scan import ssm_state_step
+
+            with jax.named_scope("ssm.step"):
+                y, h = ssm_state_step(h0, u[:, 0], delta[:, 0], b_t[:, 0], c_t[:, 0], a, d_skip, interpret=not on_tpu)
+                y = y[:, None]
+        else:
+            with jax.named_scope("ssm.step" if t == 1 else "ssm.scan"):
+                y, h = selective_scan(u, delta, a, b_t, c_t, d_skip, h0, lo, hi)
+        if decode:
+            ssm.value, conv.value = h, carried
+        with jax.named_scope("ssm.proj"):
+            return _dense(cfg, cfg.hidden_size, "out_proj", dt, cfg.mamba_proj_bias)(y.astype(dt) * nn.silu(z))
+
+
+def _mamba_dt_bias_init(key, shape, dtype=jnp.float32):
+    step = jnp.exp(jax.random.uniform(key, shape) * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+    return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)  # softplus^-1
+
+
+def _one_device() -> bool:
+    """XLA's partitioner cannot split a ``pallas_call``: a kernel with no ``shard_map`` of its own is for one device."""
+    from ..ops.attention import active_mesh
+
+    mesh = active_mesh()
+    return mesh is None or mesh.size == 1
+
+
 class LlamaLayer(nn.Module):
     config: LlamaConfig
     routed: bool = False  # the FFN is RoutedFFN (a layer past ``first_k_dense_replace`` of a config with experts)
+    mamba: bool = False  # the mixer is MambaMixer (a layer off the attention period of a config with state-space layers)
 
     @nn.compact
-    def __call__(self, hidden, positions, decode: bool = False):
+    def __call__(self, hidden, positions, decode: bool = False, new_span=None):
         cfg = self.config
         attn_cls = LlamaAttention if cfg.kv_lora_rank is None else LatentAttention
 
         def attn(x):
+            if self.mamba:
+                return MambaMixer(cfg, name="mamba")(x, decode, new_span)
+            if cfg.attn_layer_period is not None:
+                # beside state-space layers, attention is told the window's new tokens too: an overlapped
+                # head's hidden states come out of layers that did not advance, so its rows are kept
+                return attn_cls(cfg, name="attn")(x, positions, decode, new_span)
             return attn_cls(cfg, name="attn")(x, positions, decode)
 
         def mlp(x):
@@ -696,7 +823,7 @@ class LlamaModel(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, input_ids, positions=None, decode: bool = False):
+    def __call__(self, input_ids, positions=None, decode: bool = False, new_span=None):
         cfg = self.config
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens")
         hidden = embed(input_ids)
@@ -727,6 +854,16 @@ class LlamaModel(nn.Module):
             raise NotImplementedError(
                 "latent attention and routed experts are built with scan_layers=False: a leading dense "
                 "layer differs from the expert layers, and the carried pool stack holds K/V pools only"
+            )
+        if cfg.scan_layers and cfg.attn_layer_period is not None:
+            raise NotImplementedError(
+                "state-space (Mamba) layers are built with scan_layers=False: a scanned block shares one "
+                "mixer across layers, and the carried pool stack holds K/V pools only, no ssm_state"
+            )
+        if cfg.attn_layer_period is not None and (routed or cfg.kv_lora_rank is not None):
+            raise NotImplementedError(
+                "state-space (Mamba) layers beside latent attention or routed experts (n_routed_experts, "
+                "kv_lora_rank): no configuration runs them together"
             )
         if cfg.scan_layers:
             # A paged decode step carries the pools of all layers through the
@@ -775,7 +912,9 @@ class LlamaModel(nn.Module):
                         overrides["rope_theta"] = cfg.rope_local_theta
                         overrides["rope_scaling"] = None
                     lcfg = dataclasses.replace(cfg, **overrides)
-                hidden = layer_cls(lcfg, routed and i >= n_lead, name=f"layer_{i}")(hidden, positions, decode)
+                hidden = layer_cls(lcfg, routed and i >= n_lead, cfg.is_mamba_layer(i), name=f"layer_{i}")(
+                    hidden, positions, decode, new_span
+                )
         hidden = RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="final_norm")(hidden)
         if cfg.tie_word_embeddings:
             # true weight tying: reuse the embedding table (no lm_head
@@ -792,11 +931,14 @@ class LlamaModel(nn.Module):
 
 
 def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> Model:
-    def apply_fn(p, input_ids, positions=None, decode=False, cache=None, state=None):
+    def apply_fn(p, input_ids, positions=None, decode=False, cache=None, state=None, new_span=None):
         """decode=True threads the KV cache: pass ``cache`` (or None to
         initialise) and receive ``(logits, new_cache)``. ``state`` threads
         non-param collections (the fp8 amax histories): returns
-        ``(logits, new_state)``."""
+        ``(logits, new_state)``. ``new_span`` ``(lo, hi)``: which of the
+        window's tokens are new and real (a bucket's right pad and a chunk
+        window's overlapped head are not); only a model with state-space
+        layers reads it, and None means every token."""
         if decode:
             variables = {"params": p, **(state or {})}
             if cache is not None:
@@ -810,7 +952,7 @@ def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> 
             loads = requested_expert_load()
             if loads is not None:
                 mutable.append(EXPERT_LOAD)
-            logits, mutated = module.apply(variables, input_ids, positions, True, mutable=mutable)
+            logits, mutated = module.apply(variables, input_ids, positions, True, new_span, mutable=mutable)
             if loads is not None:
                 loads.extend(jax.tree_util.tree_leaves(mutated.get(EXPERT_LOAD, {})))
             return logits, mutated["cache"]

@@ -46,7 +46,8 @@ def _constrain(x):
 
 
 def cached_attention(
-    module, q, k, v, max_len: int, scale=None, bias_fn=None, sliding_window=None, logit_softcap=None
+    module, q, k, v, max_len: int, scale=None, bias_fn=None, sliding_window=None, logit_softcap=None,
+    keep_rows_before=None,
 ):
     """Incremental causal attention against a growing cache.
 
@@ -62,6 +63,11 @@ def cached_attention(
     ``sliding_window``: Mistral-style band — each query attends only the
     last ``sliding_window`` keys (the cache still stores ``max_len`` rows;
     out-of-window rows are masked, matching the non-decode band mask).
+    ``keep_rows_before``: the first new token of the window (a model with
+    state-space layers names it): the rows of the tokens before it, the
+    overlapped head of an end-aligned chunk window, stay as the cache has
+    them. Their hidden states came through layers whose state did not
+    advance, so here the head recomputes nothing it could write back.
     """
     from . import paged_kv
 
@@ -84,6 +90,10 @@ def cached_attention(
     cv = module.variable("cache", "value", jnp.zeros, (b, max_len, h_kv, d), v.dtype)
     idx = module.variable("cache", "index", lambda: jnp.zeros((), jnp.int32))
     cur = idx.value
+    if keep_rows_before is not None and s_new > 1:
+        head = (jnp.arange(s_new) < keep_rows_before)[None, :, None, None]
+        k = jnp.where(head, jax.lax.dynamic_slice(ck.value, (0, cur, 0, 0), k.shape), k)
+        v = jnp.where(head, jax.lax.dynamic_slice(cv.value, (0, cur, 0, 0), v.shape), v)
     ck.value = _constrain(jax.lax.dynamic_update_slice(ck.value, k, (0, cur, 0, 0)))
     cv.value = _constrain(jax.lax.dynamic_update_slice(cv.value, v, (0, cur, 0, 0)))
     idx.value = cur + s_new

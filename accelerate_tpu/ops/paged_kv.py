@@ -21,6 +21,11 @@ shapes) allocates cache in fixed-size *blocks* from one shared pool:
   :mod:`.pallas_latent_attention`; everything below (tables, the sink,
   prefix aliasing, donation) holds for it as for K/V, the carried stack
   of a layer scan apart: latent layers are unrolled, a pool each;
+* beside the pools, for a state-space layer (Mamba), NO pool: its state a
+  sequence is fixed in size, so the layer keeps ``ssm_state`` / ``conv_state``
+  leaves with one row a slot (:data:`STATE_LEAVES`), in this layout as in
+  the dense one; a hybrid model's cache tree holds both kinds, the K/V of
+  its attention layers paged, a pool a layer;
 * ``block_table``: ``[B, max_blocks]`` int32 per row — position ``p`` of
   row ``b`` lives at ``pool[table[b, p // bs], p % bs]``;
 * block 0 is a reserved **trash sink**: padded table entries and the
@@ -126,6 +131,23 @@ _LAYER_VIEW: Optional[_LayerView] = None
 
 # a pool leaf -> the dense row-cache leaf it holds the rows of
 POOL_ROWS = {"key_pool": "key", "value_pool": "value", "latent_pool": "latent"}
+
+# Leaves with a slot axis and no row axis: the recurrent state of a state-space layer (``ssm_state``
+# ``[B, d_state, d_inner]``, ``conv_state`` ``[B, (d_conv - 1) * d_inner]``), one row a slot whatever the
+# sequence's length, the same leaf in the dense row cache (``B`` 1) and in the paged cache (``B`` slots).
+# No pages: :func:`paste_row` writes a prefill's state over the slot's, :func:`paste_blocks` passes it
+# (a shared prefix shares blocks, not state: each request carries a copy of the prefix's), and
+# :func:`clear_slot` zeroes it. They are donated with the pools: the tick holds one copy.
+STATE_LEAVES = ("ssm_state", "conv_state")
+
+
+def state_bytes(cache) -> int:
+    """Bytes of the recurrent-state leaves of ``cache``: 0 for a model without state-space layers."""
+    return sum(
+        math.prod(leaf.shape) * jnp.dtype(leaf.dtype).itemsize
+        for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+        if _path_names(path)[-1] in STATE_LEAVES
+    )
 
 
 def declare_pool_stack(module, num_layers: int, kv_heads: int, head_dim: int, dtype):
@@ -406,11 +428,12 @@ def _path_names(path):
     return tuple(p.key if hasattr(p, "key") else str(p) for p in path)
 
 
-def _scatter_pools(paged_cache, row_cache, write_row, table_updates):
+def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None):
     """Blockify a dense per-row cache and scatter it into the pools at
     ``write_row``'s block ids; apply ``table_updates(name, leaf)`` to the
     ``block_table``/``index`` leaves (or leave them untouched if it
-    returns None)."""
+    returns None); with a ``slot``, write the row cache's state leaves
+    (:data:`STATE_LEAVES`) over that slot's."""
     dense = {_path_names(p): leaf for p, leaf in jax.tree_util.tree_flatten_with_path(row_cache)[0]}
 
     def rows_of(prefix, name):
@@ -446,6 +469,10 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates):
         if name in ("block_table", "index"):
             out = table_updates(name, leaf)
             return leaf if out is None else out
+        if name in STATE_LEAVES:
+            if slot is None:
+                return leaf
+            return jax.lax.dynamic_update_index_in_dim(leaf, dense[names][0].astype(leaf.dtype), slot, 0)
         raise ValueError(f"unexpected paged cache leaf {'/'.join(names)}")
 
     return jax.tree_util.tree_map_with_path(write, paged_cache)
@@ -463,7 +490,9 @@ def paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index):
     blocks point at the trash sink in ``write_row`` (shared content is
     written once, at registration — rewriting it per admit would race
     other slots decoding against it and waste the write traffic), while
-    ``table_row`` keeps the real ids for reads. Pure — jit once.
+    ``table_row`` keeps the real ids for reads. A state-space layer's
+    state (:data:`STATE_LEAVES`) replaces ``slot``'s whole: whatever an
+    idle slot stepped into it in the meantime is never read. Pure — jit once.
     """
 
     def tables(name, leaf):
@@ -473,13 +502,14 @@ def paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index):
         sel = (slice(None),) * (leaf.ndim - 1) + (slot,)
         return leaf.at[sel].set(jnp.asarray(new_index, leaf.dtype))
 
-    return _scatter_pools(paged_cache, row_cache, write_row, tables)
+    return _scatter_pools(paged_cache, row_cache, write_row, tables, slot=slot)
 
 
 def paste_blocks(paged_cache, row_cache, write_row):
     """Write pool content only (no slot table/index): used once per
     registered prefix to install its full blocks as the canonical shared
-    content every aliasing request reads. Pure — jit once."""
+    content every aliasing request reads; recurrent state is no block's
+    and stays as it is. Pure — jit once."""
     return _scatter_pools(paged_cache, row_cache, write_row, lambda name, leaf: None)
 
 
@@ -502,7 +532,10 @@ def clear_slot(paged_cache, slot):
     """Re-point ``slot``'s table row at the trash sink and zero its
     frontier. MUST run when a slot retires: the static decode tick keeps
     computing (and writing) for every slot, and a stale table would
-    corrupt blocks after they are freed and reallocated. Pure — jit it."""
+    corrupt blocks after they are freed and reallocated. A state-space
+    layer's state is zeroed too: the tick goes on stepping the free slot
+    (token 0 at position 0 from there: finite, and never read) until the
+    next :func:`paste_row` replaces it whole. Pure — jit it."""
 
     def write(path, leaf):
         name = _path_names(path)[-1]
@@ -512,6 +545,8 @@ def clear_slot(paged_cache, slot):
         if name == "index":
             sel = (slice(None),) * (leaf.ndim - 1) + (slot,)
             return leaf.at[sel].set(jnp.zeros((), leaf.dtype))
+        if name in STATE_LEAVES:
+            return jax.lax.dynamic_update_index_in_dim(leaf, jnp.zeros(leaf.shape[1:], leaf.dtype), slot, 0)
         return leaf
 
     return jax.tree_util.tree_map_with_path(write, paged_cache)

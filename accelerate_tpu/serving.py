@@ -15,15 +15,23 @@ parity-plus. Design notes:
 * per-slot KV caches are the model's ordinary cache pytree with a leading
   slot axis; the decode tick is ``jax.vmap`` of the single-sequence step,
   so per-slot positions/cache indices need NO model changes;
-* prompt prefill pads up to a size bucket (one compile per bucket). The
-  padded tail DOES write garbage rows into the cache at positions >=
-  true_len — harmless by construction: they sit beyond the causal
-  frontier (key_pos > q_pos masks them) and each decode step overwrites
-  the next one, because the cache write index is reset to ``true_len``
-  after prefill;
+* prompt prefill pads up to a size bucket (one compile per bucket). What
+  the padded tail does depends on the leaf. **K/V and latent rows**: it
+  DOES write garbage rows at positions >= true_len — harmless by
+  construction: they sit beyond the causal frontier (key_pos > q_pos
+  masks them) and each decode step overwrites the next one, because the
+  cache write index is reset to ``true_len`` after prefill. **Recurrent
+  state** (a state-space layer's ``ssm_state`` / ``conv_state``, one row a
+  slot and no row a token): a state after the bucket's last token would
+  include the pad, so every program that runs a window tells the model
+  which of its tokens are new and real (``new_span``), and the others
+  leave the state as it was (ops/selective_scan.py);
 * inactive slots still compute in the tick (static shapes; masking out
   their tokens is host-side bookkeeping). Their caches accumulate
-  garbage that the next prefill-insert fully replaces;
+  garbage that the next prefill-insert fully replaces: rows land in the
+  trash sink, and a recurrent state, which ``clear_slot`` zeroed when the
+  slot was freed, is stepped on (finite, never read) until ``paste_row``
+  writes the next request's over it whole;
 * **chunked prefill**: a prompt longer than the largest bucket streams
   through the decode path in largest-bucket-sized chunks against the
   growing cache (``cached_attention`` is the same program for S_new = 1
@@ -33,7 +41,9 @@ parity-plus. Design notes:
   prefix (e.g. a system prompt) ONCE and stores the row cache;
   ``submit(..., prefix_id=...)`` requests copy it and prefill only their
   suffix — the vLLM prefix-reuse win, token-exact by construction because
-  the copied cache is bit-identical to what a full prefill would write;
+  the copied cache is bit-identical to what a full prefill would write
+  (rows, and for a state-space layer the state at the prefix's end: paged
+  blocks are aliased, the state is each request's own copy);
 * **paged KV cache** (``paged_block_size=...``): slot caches live in one
   shared block pool addressed through per-slot block tables
   (:mod:`accelerate_tpu.ops.paged_kv`) instead of ``slots x max_len``
@@ -94,14 +104,30 @@ def _row_axis(shape: tuple, cap: int):
 def check_handoff_layout(row_cache) -> None:
     """KV hand-off ships per-head K/V rows. A latent (MLA) row cache — one
     ``latent`` row a token, shared by all heads — is not carried yet: say so
-    instead of sizing or packing it as K/V."""
+    instead of sizing or packing it as K/V. Neither is a state-space
+    layer's recurrent state (``ssm_state``), which has no rows to trim."""
     from .ops.kv_cache import leaf_names
 
+    check_no_state_leaf(row_cache, "KV hand-off (kv_handoff_dims, prefill_detached, HandoffCodec)")
     if "latent" in leaf_names(row_cache):
         raise NotImplementedError(
             "KV hand-off (kv_handoff_dims, prefill_detached, HandoffCodec) cannot carry a latent "
             "cache yet: its rows are [kv_lora_rank + qk_rope_head_dim] latents shared by all heads, "
             "not per-head keys and values; serve latent-attention models without disaggregated prefill"
+        )
+
+
+def check_no_state_leaf(row_cache, what: str) -> None:
+    """``what`` ships K/V rows trimmed to a frontier; a cache with a
+    state-space layer's ``ssm_state`` (one row a slot, no row a token)
+    is refused by name."""
+    from .ops.kv_cache import leaf_names
+
+    if "ssm_state" in leaf_names(row_cache):
+        raise NotImplementedError(
+            f"{what} cannot carry a recurrent state yet: ssm_state / conv_state are one row a sequence, "
+            "not rows a token to trim and pad; serve models with state-space layers without it "
+            "(a failover resumes by prefix recompute, which is exact)"
         )
 
 
@@ -306,6 +332,16 @@ class ServingEngine:
             jnp.zeros((1, 1), jnp.int32),
         )
 
+        # a model with state-space layers keeps recurrent state beside its K/V rows: its windows are told
+        # which of their tokens are new (``new_span``); no other model's programs take the argument
+        from .ops.paged_kv import state_bytes
+
+        self.metrics.state_bytes_per_slot = state_bytes(self._row_template)
+        self._has_state = self.metrics.state_bytes_per_slot > 0
+
+        def span(lo, hi):
+            return {"new_span": (lo, hi)} if self._has_state else {}
+
         # Cache layout: dense = leading slot axis over the per-row cache
         # pytree (each slot reserves max_len rows); paged = one shared
         # block pool + per-slot block tables (ops/paged_kv.py) — same
@@ -413,7 +449,7 @@ class ServingEngine:
             key)."""
             b_len = ids.shape[1]
             positions = jnp.broadcast_to(jnp.arange(b_len), (1, b_len))
-            logits, cache = apply_fn(params, ids, positions=positions, decode=True, cache=None)
+            logits, cache = apply_fn(params, ids, positions=positions, decode=True, cache=None, **span(0, true_len))
             key, sub = jax.random.split(key)
             row = logits[0, true_len - 1]
             next_tok = sampler(row[None], sub)[0]
@@ -453,13 +489,14 @@ class ServingEngine:
         chunk = max(self.prompt_buckets)
         self._chunk = chunk
 
-        def chunk_cold(params, ids):
+        # ``[lo, hi)``: the window's new tokens, from its own first (_run_window)
+        def chunk_cold(params, ids, lo, hi):
             positions = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
-            return apply_fn(params, ids, positions=positions, decode=True, cache=None)
+            return apply_fn(params, ids, positions=positions, decode=True, cache=None, **span(lo, hi))
 
-        def chunk_warm(params, ids, pos0, cache):
+        def chunk_warm(params, ids, pos0, cache, lo, hi):
             positions = pos0 + jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
-            return apply_fn(params, ids, positions=positions, decode=True, cache=cache)
+            return apply_fn(params, ids, positions=positions, decode=True, cache=cache, **span(lo, hi))
 
         self._chunk_cold = ctx_jit(chunk_cold)
         self._chunk_warm = ctx_jit(chunk_warm)
@@ -491,6 +528,8 @@ class ServingEngine:
                 jax.ShapeDtypeStruct((1, self._chunk), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32),
                 self._row_template,
+                jax.ShapeDtypeStruct((), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32),
             ),
             (self._trace_ctx,),
         )
@@ -525,6 +564,7 @@ class ServingEngine:
         )
 
         self._tick_expert_load = (0, 0, 0)
+        self._tick_state_idle = 0
 
         def make_tick(step_body):
             """K-step tick scaffold shared by both cache layouts:
@@ -636,10 +676,16 @@ class ServingEngine:
         stay inside ``[0, max_len)`` (a forward-padded tail would exceed it
         and ``dynamic_update_slice``'s start-clamping would silently corrupt
         the earliest rows). The overlapped head of a window recomputes
-        bit-identical K/V from the true tokens (positions are absolute), so
-        overlap is token-exact by construction; only a ``T < C`` window has
-        a pad tail, whose garbage rows sit beyond the causal frontier and
-        are overwritten by decode, exactly as in bucket prefill. Returns
+        bit-identical K/V (or latent) rows from the true tokens (positions
+        are absolute), so overlap is token-exact by construction for a
+        model whose cache is rows; only a ``T < C`` window has a pad tail,
+        whose garbage rows sit beyond the causal frontier and are
+        overwritten by decode, exactly as in bucket prefill. A recurrent
+        state would count the head twice and the tail once: a model with
+        state-space layers is told the window's new tokens ``[s - s_adj,
+        e - s_adj)``, steps its state over those alone, and keeps the
+        head's K/V rows as the cache has them (the head's hidden states
+        came through layers that did not advance). Returns
         ``(next_tok | None, cache, key)`` with the cache write index reset
         to ``len(full_tokens)``; sampling happens only when ``key`` is given
         (prefix registration skips it).
@@ -690,12 +736,13 @@ class ServingEngine:
         real = full_tokens[s_adj : s_adj + w]
         window[0, : len(real)] = real
         t0 = time.perf_counter()
+        new = (jnp.int32(s - s_adj), jnp.int32(e - s_adj))
         if row_cache is None:
-            logits, row_cache = self._chunk_cold(self.model.params, jnp.asarray(window))
+            logits, row_cache = self._chunk_cold(self.model.params, jnp.asarray(window), *new)
         else:
             row_cache = self._reset_idx(row_cache, jnp.int32(s_adj))
             logits, row_cache = self._chunk_warm(
-                self.model.params, jnp.asarray(window), jnp.int32(s_adj), row_cache
+                self.model.params, jnp.asarray(window), jnp.int32(s_adj), row_cache, *new
             )
         if self.tracer is not None and trace is not None:
             self.tracer.seg(
@@ -709,7 +756,10 @@ class ServingEngine:
     def register_prefix(self, prefix_ids) -> int:
         """Prefill a shared prompt prefix ONCE; requests submitted with the
         returned ``prefix_id`` copy its KV cache and prefill only their
-        suffix. The finished output includes the prefix tokens."""
+        suffix. The finished output includes the prefix tokens. For a model
+        with state-space layers the stored row cache also holds the
+        recurrent state at the prefix's end: a request's suffix windows
+        start from a copy of it (blocks are aliased, state is not)."""
         toks = np.asarray(prefix_ids, np.int32).ravel()
         if len(toks) == 0:
             raise ValueError("empty prefix")
@@ -1065,6 +1115,8 @@ class ServingEngine:
         handoffs) is always consistent when a failover export runs."""
         jax = _jax()
         kv_ok = include_kv and not self.paged
+        if kv_ok:
+            check_no_state_leaf(self._row_template, "export_inflight(include_kv=True)")
         snaps = []
 
         def handoff_snap(req, h):
@@ -1318,6 +1370,7 @@ class ServingEngine:
         admitted, tokens_was, completed_was = 0, m.tokens_generated, m.requests_completed
         self._tick_prefill_tokens = 0
         self._tick_expert_load = (0, 0, 0)
+        self._tick_state_idle = 0
         with phase("engine.schedule"):
             now = time.monotonic()
             self._pool_blocked = False
@@ -1371,6 +1424,7 @@ class ServingEngine:
             pool_blocked=int(self._pool_blocked), free_blocks=self._alloc.free_count if self.paged else -1,
             queue_len=len(self.queue), experts_touched=self._tick_expert_load[0],
             expert_pairs_max=self._tick_expert_load[1], expert_tile_visits=self._tick_expert_load[2],
+            state_slots_idle=self._tick_state_idle,
         ):
             pass
 
@@ -1697,6 +1751,10 @@ class ServingEngine:
                 self.model.params, self.slot_caches,
                 jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos), self._slot_keys
             )
+        if self._has_state:
+            # the tick steps every slot's recurrent state; this many slot-steps of it decode nothing
+            self._tick_state_idle = (self.num_slots - sum(decoding)) * self.tick_block
+            self.metrics.on_state_step(self._tick_state_idle)
         with phase("engine.decode.sync"):
             toks_k = np.asarray(toks_k)  # [K, slots] — ONE host sync per block
             lps_k = np.asarray(lps_k)
@@ -1961,7 +2019,13 @@ class ServingEngine:
 
     def _release(self, slot: int):
         """Free a slot's resources without publishing a result (shared by
-        retirement, cancellation, and decode preemption)."""
+        retirement, cancellation, and decode preemption). The static tick
+        goes on computing for the free slot: in the paged layout
+        ``clear_slot`` points its rows at the trash sink and zeroes its
+        recurrent state (a state-space layer then steps token 0 from zero:
+        finite, never read); in the dense layout rows and state alike keep
+        accumulating garbage. Either way the next prefill's paste / insert
+        replaces the slot whole, state leaves included."""
         self.slot_phase[slot] = None
         self._prefill_state[slot] = None
         if slot in self._prefill_order:

@@ -131,6 +131,12 @@ class ServingMetrics:
         self.experts_touched = 0
         self.expert_pairs_max = 0
         self.expert_tile_visits = 0
+        # recurrent state (models with state-space layers; 0 otherwise): the
+        # bytes of ssm_state + conv_state one slot holds over all layers, and
+        # the slot-steps of it the decode ticks spent on slots in which no
+        # request decodes (the tick steps every slot's state)
+        self.state_bytes_per_slot = 0
+        self.state_slots_idle = 0
         # cross-request prefix reuse (serving_fleet.RadixPrefixCache):
         # a hit means the request skipped re-prefilling that many shared
         # preamble tokens — the fleet's dominant p95-TTFT lever
@@ -189,6 +195,9 @@ class ServingMetrics:
     def on_tokens(self, n: int = 1):
         self.tokens_generated += n
         self._token_marks.append((self._clock(), self.tokens_generated))
+
+    def on_state_step(self, slots_idle: int):
+        self.state_slots_idle += slots_idle
 
     def on_expert_load(self, touched: int, pairs_max: int, tile_visits: int):
         self.experts_touched += touched

@@ -59,6 +59,8 @@ MODULES = [
     "accelerate_tpu.ops.paged_kv",
     "accelerate_tpu.ops.pallas_paged_attention",
     "accelerate_tpu.ops.pallas_latent_attention",
+    "accelerate_tpu.ops.selective_scan",
+    "accelerate_tpu.ops.pallas_selective_scan",
     "accelerate_tpu.ops.pallas_grouped_matmul",
     "accelerate_tpu.ops.moe",
     "accelerate_tpu.ops.fp8",

@@ -156,14 +156,10 @@ def test_grouped_expert_products_compile_for_v5e(v5e, pairs):
     assert "ragged-dot-swiglu" in products[0] and "ragged-dot-down" in products[1]
 
 
-def test_latent_moe_decode_tick_fits_one_v5e_chip_and_moves_no_pool(v5e, monkeypatch):
-    """The 64-slot decode tick of the ``joyai-flash-serve-longchat`` cell at its real size: 5.56 B
-    parameters and a 1.89 GB latent pool as arguments, the pool aliased to the output, and no
-    operation that copies or re-lays a whole layer's pool (the compiler did both around a
-    ``[.., 128, 576]`` pool: ops/paged_kv.py). A compile is not a chip run."""
-    import contextlib
+def _cell_engine(config_name):
+    """A serve cell's ``ServingEngine`` over abstract bf16 parameters at the configuration's widths, through
+    the cell's own builder: ``(bench.serving, engine)``."""
     import json
-    import re
 
     from accelerate_tpu.models.llama import _wrap_llama
     from accelerate_tpu.serving import ServingEngine
@@ -171,17 +167,28 @@ def test_latent_moe_decode_tick_fits_one_v5e_chip_and_moves_no_pool(v5e, monkeyp
 
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    with open(os.path.join(REPO, "chipbench", "configs", "joyai-llm-flash-l5.json")) as f:
+    with open(os.path.join(REPO, "chipbench", "configs", config_name + ".json")) as f:
         config = json.load(f)
     builder = run.load(manifest, "builders", config["bench"]["builder"])
     cfg = builder.core_config(config)
     module, shapes = builder.abstract_params(cfg)
     shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, BF16), shapes)
     s = config["bench"]["serving"]
-    engine = ServingEngine(
+    return s, ServingEngine(
         _wrap_llama(module, shapes, cfg), num_slots=s["num_slots"], prompt_buckets=tuple(s["prompt_buckets"]),
         max_len=s["max_len"], paged_block_size=s["paged_block_size"], pool_blocks=s["pool_blocks"],
     )
+
+
+def test_latent_moe_decode_tick_fits_one_v5e_chip_and_moves_no_pool(v5e, monkeypatch):
+    """The 64-slot decode tick of the ``joyai-flash-serve-longchat`` cell at its real size: 5.56 B
+    parameters and a 1.89 GB latent pool as arguments, the pool aliased to the output, and no
+    operation that copies or re-lays a whole layer's pool (the compiler did both around a
+    ``[.., 128, 576]`` pool: ops/paged_kv.py). A compile is not a chip run."""
+    import contextlib
+    import re
+
+    s, engine = _cell_engine("joyai-llm-flash-l5")
     chip = SingleDeviceSharding(v5e.devices[0])
     raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
     args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
@@ -203,6 +210,53 @@ def test_latent_moe_decode_tick_fits_one_v5e_chip_and_moves_no_pool(v5e, monkeyp
     assert m.argument_size_in_bytes > 11.9 * 2**30, "weights and the pool are arguments at their real size"
     total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
     assert total <= 15.75 * 2**30, f"the 64-slot tick needs {total / 2**30:.2f} GiB"
+
+
+def test_hybrid_ssm_decode_tick_steps_the_state_in_place_on_one_v5e_chip(v5e, monkeypatch):
+    """The 128-slot decode tick of the ``jamba2-3b-serve-longanswer`` cell at its real size: 3.03 B
+    parameters, 26 states of ``[128, 16, 5120]`` float32 and two K/V pools as arguments, all aliased to
+    the output; 26 ``ssm_state_step`` kernels and two ``paged_decode_attention`` a step, and no operation
+    that copies or re-lays a state leaf or a pool. A compile is not a chip run."""
+    import contextlib
+    import re
+
+    s, engine = _cell_engine("ai21-jamba2-3b")
+    chip = SingleDeviceSharding(v5e.devices[0])
+    raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernels
+    with contextlib.ExitStack() as stack:
+        for ctx in contexts:
+            stack.enter_context(ctx())
+        compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l and "custom-call(" in l]
+    assert sum("ssm_state_step" in l for l in calls) == 26 and sum("paged_decode_attention" in l for l in calls) == 2
+    slots = s["num_slots"]
+    state = rf"(f32\[{slots},16,5120\]|bf16\[{slots},15360\]|bf16\[{s['pool_blocks']},{s['paged_block_size']},1,128\])"
+    moved = [l.strip()[:160] for l in text.splitlines() if re.search(rf"= {state}\S* (copy|transpose)\(", l)]
+    assert not moved, "the tick copies or re-lays a state leaf or a pool:\n" + "\n".join(moved)
+    m = compiled.memory_analysis()
+    state_bytes = 26 * slots * (16 * 5120 * 4 + 15360 * 2) + 2 * 2 * s["pool_blocks"] * s["paged_block_size"] * 128 * 2
+    assert m.alias_size_in_bytes >= state_bytes and m.temp_size_in_bytes < 0.5 * 2**30
+    assert m.argument_size_in_bytes > 6.05e9 + state_bytes, "weights, states and pools are arguments at their real size"
+    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert total <= 9.0 * 2**30, f"the 128-slot tick needs {total / 2**30:.2f} GiB"
+
+
+def test_hybrid_ssm_prefill_bucket_compiles_for_v5e(v5e):
+    """The 1024-token prefill of the same cell: 26 chunked scans whose temporaries stay far under one
+    window's ``[1024, 16, 5120]`` float32 (335 MB a layer), and a row cache whose state leaves are one row."""
+    _, engine = _cell_engine("ai21-jamba2-3b")
+    chip = SingleDeviceSharding(v5e.devices[0])
+    prefill, prefill_args, _ = engine._perf_programs["prefill"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), prefill_args(1024))
+    compiled = jax.jit(prefill).lower(*args).compile()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 1.5 * 2**30, f"the prefill's temporaries are {m.temp_size_in_bytes / 2**30:.2f} GiB"
+    cache = jax.eval_shape(prefill, *args)[2]
+    assert cache["layer_0"]["mamba"]["ssm_state"].shape == (1, 16, 5120)
+    assert cache["layer_7"]["attn"]["key"].shape == (1, 2304, 1, 128)
 
 
 @pytest.mark.parametrize(
