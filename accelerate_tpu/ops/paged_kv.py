@@ -18,9 +18,13 @@ shapes) allocates cache in fixed-size *blocks* from one shared pool:
   TPU compiler gives a ``[.., block_size, 576]`` array that layout anyway
   and then re-lays the whole pool out around every program that wants
   rows. Read through :func:`paged_latent_attention` and the kernel in
-  :mod:`.pallas_latent_attention`; everything below (tables, the sink,
-  prefix aliasing, donation) holds for it as for K/V, the carried stack
-  of a layer scan apart: latent layers are unrolled, a pool each;
+  :mod:`.pallas_latent_attention`, which walks a slot's live pages as the
+  K/V kernel does (one grid step a slot, two pages a chunk copied into the
+  lanes of one of two VMEM buffers, one product a chunk for the scores and
+  one for the values; a slot that stores into the sink is handed frontier
+  0 and costs one page); everything below (tables, the sink, prefix
+  aliasing, donation) holds for it as for K/V, the carried stack of a
+  layer scan apart: latent layers are unrolled, a pool each;
 * beside the pools, for a state-space layer (Mamba), NO pool: its state a
   sequence is fixed in size, so the layer keeps ``ssm_state`` / ``conv_state``
   leaves with one row a slot (:data:`STATE_LEAVES`), in this layout as in
@@ -49,11 +53,12 @@ deleted by the call — rebind to what it returns (``cache = f(cache, ...)``)
 and never read the old value again.
 
 Everything stays static-shape. On TPU the decode step dispatches to the
-Pallas kernel in :mod:`.pallas_paged_attention`, which leaves the pool
-in HBM and walks each row's LIVE pages only (first page of the band to
-the frontier's, read from the scalar-prefetched table and frontier):
-async copies a chunk of pages at a time into two VMEM buffers, one
-product a chunk; reserved and pad table entries are never visited
+Pallas kernel in :mod:`.pallas_paged_attention` (a latent pool's to
+:mod:`.pallas_latent_attention`; the walk both make is :mod:`.paged_walk`),
+which leaves the pool in HBM and walks each row's LIVE pages only (first
+page of the band to the frontier's, read from the scalar-prefetched table
+and frontier): async copies a chunk of pages at a time into two VMEM
+buffers, one product a chunk; reserved and pad table entries are never visited
 (under a ``shard_map`` over ``tensor`` when the pool is TP-sharded — a
 ``pallas_call`` can't be auto-partitioned). The XLA fallback (CPU, or
 head counts the tensor axis can't split) gathers ``pool[table]`` into a
@@ -366,7 +371,9 @@ def paged_latent_attention(module, q_lat, row, max_len: int, *, value_width: int
         fn = functools.partial(latent_paged_decode, value_width=value_width, scale=scale, interpret=not on_tpu)
         run = _kernel_runner(fn, q_lat.shape[2], 1, pool_specs=(P(None, None, None),))
         if run is not None:
-            return run(q_lat[:, 0], pool, table, cur)[:, None]
+            # a row that stores this token in the sink is idle, or finished and overshooting: frontier 0,
+            # one page, as in paged_cached_attention
+            return run(q_lat[:, 0], pool, table, jnp.where(dest == 0, 0, cur))[:, None]
     return paged_latent_gather_attention(q_lat, pool, table, cur, value_width=value_width, scale=scale)
 
 

@@ -10,14 +10,25 @@ pool and used twice, as keys and as values. A page is ``[W, block_size]``,
 its tokens along the lanes (``ops/paged_kv.py`` says why): the scores are a
 plain product and the values contract over the lanes of both operands.
 
-Built as :mod:`.pallas_paged_attention` was until it took to walking a row's live pages inside one grid step:
-the grid walks ``(row, table_entry)``, the block table is a scalar-prefetch operand, pages
-beyond a row's frontier are skipped with ``pl.when`` (and their index
-stays on the last live page, so the repeated index elides the DMA too),
-and an online softmax folds every page into a ``[H, value_width]``
-accumulator. Pages stay in the pool's type for both products (float32
-accumulation); the probabilities are rounded to it before the second,
-as the XLA path does.
+The walk is :mod:`.paged_walk`'s, the one :mod:`.pallas_paged_attention` makes:
+one grid step a row (slot), the pool left in HBM, and inside the step a loop
+over the row's live pages ``0 .. min(cur // block_size, MB - 1)``, its trip
+count from the scalar-prefetched frontier, a chunk of pages at a time into one
+of two VMEM buffers with one async copy a page addressed through the
+scalar-prefetched table; chunk ``i + 1`` in flight while chunk ``i`` folds, row
+``b + 1``'s first chunk started before row ``b`` ends. Table entries past the
+frontier are neither visited nor fetched, and a row handed frontier 0 (an idle
+slot: ``ops/paged_kv.py``) costs one page. The fold is this kernel's own: a
+chunk buffer is ``[W, pages * block_size]``, each page copied into its
+``block_size`` lanes, so a chunk folds as one product for the scores
+(``[H, W] x [W, pages * bs]``) and one for the values (contracting the lanes of
+the probabilities and of the buffer's first ``value_width`` rows), with no
+relayout; an online softmax folds every chunk into a ``[H, value_width]``
+accumulator. Pages and queries go to the MXU in the pool's type for both
+products (float32 accumulation); the probabilities are rounded to it before
+the second, as the XLA path does. Scores, running maximum, sum and accumulator
+are float32. How many pages a chunk holds follows from the traced shapes
+(:func:`_pages_per_chunk`), never from an argument.
 """
 
 from __future__ import annotations
@@ -28,53 +39,79 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .paged_walk import (
+    CHUNK_TOKENS,
+    CHUNK_VMEM_BYTES,
+    newest_position,
+    online_softmax,
+    online_softmax_init,
+    walk_live_pages,
+)
+
+
+def _pages_per_chunk(width: int, block_size: int, dtype) -> int:
+    """Pages fetched and folded together. A page lands in its own ``block_size`` lanes of the chunk
+    buffer, which a copy can address only where a page is whole lane tiles (128 tokens): other shapes
+    take one page a chunk, which fills the buffer whole."""
+    if block_size % 128:
+        return 1
+    fit = CHUNK_VMEM_BYTES // (2 * width * block_size * jnp.dtype(dtype).itemsize)  # two buffers
+    return max(1, min(CHUNK_TOKENS // block_size, fit))
+
 
 def _kernel(
     tbl_ref,  # [B, MB] int32 (scalar prefetch)
     cur_ref,  # [B] int32 (scalar prefetch)
     q_ref,  # [1, H, W]
-    page_ref,  # [1, W, bs]: a page holds its tokens as columns
+    pool_hbm,  # [NB, W, bs], left in HBM: a page holds its tokens as columns
     o_ref,  # [1, H, C]
-    m_ref,  # [H, 1] f32 scratch
-    l_ref,  # [H, 1] f32 scratch
-    acc_ref,  # [H, C] f32 scratch
+    buf,  # [2, W, pages * bs] VMEM
+    sems,  # DMA semaphores [2 (buffer)]
+    side_ref,  # [1] int32 SMEM: the buffer that holds this row's first chunk
     *,
+    pages: int,
     block_size: int,
     value_width: int,
     scale: float,
 ):
-    b, j = pl.program_id(0), pl.program_id(1)
+    from jax.experimental.pallas import tpu as pltpu
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    max_blocks = tbl_ref.shape[1]
+    heads = q_ref.shape[1]
+    cols = pages * block_size
 
-    cur = cur_ref[b]
-    lo = j * block_size
+    def page_copies(page, side, i):
+        lanes = slice(None) if pages == 1 else pl.ds(pl.multiple_of(i * block_size, block_size), block_size)
+        return (pltpu.make_async_copy(pool_hbm.at[page], buf.at[side, :, lanes], sems.at[side]),)
 
-    @pl.when(lo <= cur)  # some key of this page is at or before the frontier
-    def _page():
-        q = q_ref[0]  # [H, W]
-        page = page_ref[0]  # [W, bs]
-        s = jax.lax.dot_general(q, page, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32) * scale
-        pos = lo + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
-        s = jnp.where(pos <= cur, s, -jnp.inf)  # [H, bs]
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))  # finite: a live page has a live key
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(page.dtype), page[:value_width], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = m_new
+    def zero_buffers():
+        buf[...] = jnp.zeros_like(buf)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+    def make_fold(cur, first):
+        q = q_ref[0].astype(buf.dtype)  # [H, W]
+        token = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        newest = newest_position(cur, max_blocks, block_size)
+
+        def fold(j, side, carry):
+            m_prev, l_prev, acc = carry
+            chunk = buf[side]  # [W, cols]: keys over all its rows, values over the first value_width
+            s = jax.lax.dot_general(q, chunk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32) * scale
+            live = (first + j * pages) * block_size + token <= newest
+            s = jnp.where(live, s, -jnp.inf)  # [H, cols]
+            m_new, alpha, p, l_new = online_softmax(s, m_prev, l_prev)
+            pv = jax.lax.dot_general(
+                p.astype(chunk.dtype), chunk[:value_width], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            return m_new, l_new, acc * alpha + pv
+
+        return fold, online_softmax_init(heads, value_width)
+
+    _, l, acc = walk_live_pages(
+        tbl_ref, cur_ref, side_ref, pages=pages, block_size=block_size, window=None,
+        page_copies=page_copies, zero_buffers=zero_buffers, make_fold=make_fold,
+    )
+    # a live row's sum is at least 1 (its best key counts 1): the guard is for a row with nothing live
+    o_ref[0] = (acc / jnp.maximum(l, 1.0)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("value_width", "scale", "interpret"))
@@ -95,30 +132,28 @@ def latent_paged_decode(
 
     b, heads, width = q.shape
     _, _, block_size = latent_pool.shape
-    mb = block_table.shape[1]
+    pages = _pages_per_chunk(width, block_size, latent_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, mb),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, heads, width), lambda b, j, tbl, cur: (b, 0, 0)),
-            # past the frontier the index stays on the last live page: a repeated index elides the DMA,
-            # also over blocks that are reserved for tokens not yet decoded
-            pl.BlockSpec(
-                (1, width, block_size), lambda b, j, tbl, cur: (tbl[b, jnp.minimum(j, cur[b] // block_size)], 0, 0)
-            ),
+            pl.BlockSpec((1, heads, width), lambda b, tbl, cur: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, heads, value_width), lambda b, j, tbl, cur: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, heads, value_width), lambda b, tbl, cur: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((heads, 1), jnp.float32),
-            pltpu.VMEM((heads, 1), jnp.float32),
-            pltpu.VMEM((heads, value_width), jnp.float32),
+            pltpu.VMEM((2, width, pages * block_size), latent_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
-    kernel = functools.partial(_kernel, block_size=block_size, value_width=value_width, scale=scale)
+    kernel = functools.partial(_kernel, pages=pages, block_size=block_size, value_width=value_width, scale=scale)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, heads, value_width), q.dtype),
         grid_spec=grid_spec,
+        # rows run in order on one core: each starts the next one's first copies
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="latent_paged_decode",
     )(block_table.astype(jnp.int32), cur.astype(jnp.int32), q, latent_pool)

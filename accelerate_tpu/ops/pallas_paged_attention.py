@@ -39,8 +39,10 @@ each LIVE page once and nothing else:
 * the decode contract matches the XLA branch in masking: keys at
   positions ``> cur - W`` and ``<= cur``.
 
-How many pages a chunk holds follows from the shapes the kernel is
-traced with (:func:`_pages_per_chunk`), never from an argument. The
+The walk (the first two points) is :mod:`.paged_walk`'s, which the latent
+kernel (:mod:`.pallas_latent_attention`) makes too; the fold (the next two)
+is this kernel's own. How many pages a chunk holds follows from the shapes
+the kernel is traced with (:func:`_pages_per_chunk`), never from an argument. The
 public paged-attention kernel in ``jax.experimental`` walks its pages
 the same way (``pages_per_compute_block``); this one is written for
 THIS engine's layout (token-major pages, trash-sink block 0, per-row
@@ -58,13 +60,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# A chunk aims at this many tokens: large enough that a chunk's copies and its two products pay for the
-# loop around them, small enough that a row of a few hundred tokens does not fold mostly padding.
-_CHUNK_TOKENS = 256
-# The two K and two V chunk buffers together stay under this much VMEM (of 16 MiB scoped by default).
-_CHUNK_VMEM_BYTES = 4 << 20
-# Running maximum before any key: finite, so a chunk with no live key folds to zeros and not to NaN.
-_M_INIT = -1e30
+from .paged_walk import (
+    CHUNK_TOKENS,
+    CHUNK_VMEM_BYTES,
+    newest_position,
+    online_softmax,
+    online_softmax_init,
+    walk_live_pages,
+)
 
 
 def _pages_per_chunk(block_size: int, kv_heads: int, head_dim: int, dtype) -> int:
@@ -75,8 +78,8 @@ def _pages_per_chunk(block_size: int, kv_heads: int, head_dim: int, dtype) -> in
     rows = block_size * kv_heads
     if rows % (8 * max(1, 4 // itemsize)):
         return 1
-    fit = _CHUNK_VMEM_BYTES // (4 * rows * head_dim * itemsize)
-    return max(1, min(_CHUNK_TOKENS // block_size, fit))
+    fit = CHUNK_VMEM_BYTES // (4 * rows * head_dim * itemsize)  # two K and two V buffers
+    return max(1, min(CHUNK_TOKENS // block_size, fit))
 
 
 def _kernel(
@@ -99,98 +102,58 @@ def _kernel(
 ):
     from jax.experimental.pallas import tpu as pltpu
 
-    b, nrows = pl.program_id(0), pl.num_programs(0)
     max_blocks = tbl_ref.shape[1]
     heads, dim = q_ref.shape[1:]
     cols = pages * block_size * kv_heads
 
-    def span(row):
-        """First live page of ``row`` and how many follow it: the clamp keeps a frontier that overshot
-        the table (a slot that finished mid-tick) on the row's own last entry."""
-        cur = cur_ref[row]
-        last = jnp.minimum(jax.lax.div(cur, block_size), max_blocks - 1)
-        first = 0 if window is None else jax.lax.div(jnp.maximum(cur - window + 1, 0), block_size)
-        return first, jnp.maximum(last - first + 1, 0)
+    def page_copies(page, side, i):
+        return (
+            pltpu.make_async_copy(k_hbm.at[page], k_buf.at[side, i], sems.at[0, side]),
+            pltpu.make_async_copy(v_hbm.at[page], v_buf.at[side, i], sems.at[1, side]),
+        )
 
-    def chunk_copies(row, chunk, side, act):
-        """``act`` (start or wait) on the copy of every live page of ``row``'s chunk ``chunk``."""
-        first, count = span(row)
-        at = chunk * pages
-
-        def one(i, _):
-            page = tbl_ref[row, first + at + i]
-            act(pltpu.make_async_copy(k_hbm.at[page], k_buf.at[side, i], sems.at[0, side]))
-            act(pltpu.make_async_copy(v_hbm.at[page], v_buf.at[side, i], sems.at[1, side]))
-
-        jax.lax.fori_loop(0, jnp.clip(count - at, 0, pages), one, None)
-
-    start = functools.partial(chunk_copies, act=lambda copy: copy.start())
-    wait = functools.partial(chunk_copies, act=lambda copy: copy.wait())
-
-    @pl.when(b == 0)
-    def _first_row():
-        # a page slot no copy has filled yet is folded under a zero probability: it must hold numbers
+    def zero_buffers():
         k_buf[...] = jnp.zeros_like(k_buf)
         v_buf[...] = jnp.zeros_like(v_buf)
-        side_ref[0] = 0
-        start(0, 0, 0)
 
-    cur = cur_ref[b]
-    first, count = span(b)
-    chunks = jnp.maximum(pl.cdiv(count, pages), 1)  # a row with nothing live still takes its turn
-    side0 = side_ref[0]
-    q = q_ref[0].astype(k_buf.dtype)
-    # column c of a chunk is token c // Hkv of it and key/value head c % Hkv: head h reads its own
-    col = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 1)
-    own = jax.lax.rem(col, kv_heads) == jax.lax.div(
-        jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 0), heads // kv_heads
+    def make_fold(cur, first):
+        q = q_ref[0].astype(k_buf.dtype)
+        # column c of a chunk is token c // Hkv of it and key/value head c % Hkv: head h reads its own
+        col = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 1)
+        own = jax.lax.rem(col, kv_heads) == jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 0), heads // kv_heads
+        )
+        token = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1), kv_heads)
+        newest = newest_position(cur, max_blocks, block_size)
+
+        def fold(j, side, carry):
+            m_prev, l_prev, acc = carry
+            k = k_buf[side].reshape(cols, dim)
+            v = v_buf[side].reshape(cols, dim)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+            pos = (first + j * pages) * block_size + token  # [1, cols]
+            live = pos <= newest
+            if window is not None:
+                live &= pos > cur - window
+            s = jnp.where(own & live, s, -jnp.inf)  # [H, cols]
+            m_new, alpha, p, l_new = online_softmax(s, m_prev, l_prev)
+            # the probabilities go to the MXU in the pool's type as two terms, what rounding keeps and what
+            # it drops (nothing, for a float32 pool), stacked so that the value chunk is loaded once for both
+            kept = p.astype(v.dtype)
+            if v.dtype == jnp.float32:
+                pv = jnp.dot(kept, v, preferred_element_type=jnp.float32)
+            else:
+                terms = jnp.concatenate([kept, (p - kept.astype(jnp.float32)).astype(v.dtype)], axis=0)
+                both = jnp.dot(terms, v, preferred_element_type=jnp.float32)
+                pv = both[:heads] + both[heads:]
+            return m_new, l_new, acc * alpha + pv
+
+        return fold, online_softmax_init(heads, dim)
+
+    _, l, acc = walk_live_pages(
+        tbl_ref, cur_ref, side_ref, pages=pages, block_size=block_size, window=window,
+        page_copies=page_copies, zero_buffers=zero_buffers, make_fold=make_fold,
     )
-    token = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1), kv_heads)
-    newest = jnp.minimum(cur, max_blocks * block_size - 1)
-
-    def fold(j, carry):
-        m_prev, l_prev, acc = carry
-        side = jax.lax.rem(side0 + j, 2)
-
-        @pl.when(j + 1 < chunks)
-        def _next_chunk():
-            start(b, j + 1, 1 - side)
-
-        @pl.when((j + 1 == chunks) & (b + 1 < nrows))
-        def _next_row():
-            start(b + 1, 0, 1 - side)
-
-        wait(b, j, side)
-        k = k_buf[side].reshape(cols, dim)
-        v = v_buf[side].reshape(cols, dim)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-        pos = (first + j * pages) * block_size + token  # [1, cols]
-        live = pos <= newest
-        if window is not None:
-            live &= pos > cur - window
-        s = jnp.where(own & live, s, -jnp.inf)  # [H, cols]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        # the probabilities go to the MXU in the pool's type as two terms, what rounding keeps and what it
-        # drops (nothing, for a float32 pool), stacked so that the value chunk is loaded once for both
-        kept = p.astype(v.dtype)
-        if v.dtype == jnp.float32:
-            pv = jnp.dot(kept, v, preferred_element_type=jnp.float32)
-        else:
-            terms = jnp.concatenate([kept, (p - kept.astype(jnp.float32)).astype(v.dtype)], axis=0)
-            both = jnp.dot(terms, v, preferred_element_type=jnp.float32)
-            pv = both[:heads] + both[heads:]
-        return m_new, l_new, acc * alpha + pv
-
-    init = (
-        jnp.full((heads, 1), _M_INIT, jnp.float32),
-        jnp.zeros((heads, 1), jnp.float32),
-        jnp.zeros((heads, dim), jnp.float32),
-    )
-    _, l, acc = jax.lax.fori_loop(0, chunks, fold, init)
-    side_ref[0] = jax.lax.rem(side0 + chunks, 2)
     # l is 0 for a row with nothing live (a long-retired slot whose windowed frontier moved past its
     # table): its output is discarded host-side, but an unguarded 0/0 would trip jax_debug_nans
     o_ref[0] = (acc / jnp.maximum(l, 1.0)).astype(o_ref.dtype)

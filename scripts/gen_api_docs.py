@@ -58,6 +58,7 @@ MODULES = [
     "accelerate_tpu.ops.kv_cache",
     "accelerate_tpu.ops.paged_kv",
     "accelerate_tpu.ops.pallas_paged_attention",
+    "accelerate_tpu.ops.paged_walk",
     "accelerate_tpu.ops.pallas_latent_attention",
     "accelerate_tpu.ops.selective_scan",
     "accelerate_tpu.ops.pallas_selective_scan",

@@ -113,19 +113,78 @@ def test_paged_decode_attention_compiles_for_v5e(v5e, slots, layers, window):
     assert not pools, f"the pool is copied or re-laid on its way to the kernel: {pools[0][:200]}"
 
 
+def _mosaic_programs(text, name):
+    """The Mosaic programs of the custom calls named ``name`` in a compiled text, as MLIR without source
+    locations (a call's ``body`` is the serialized module, line numbers of the kernel's file and all)."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    programs = []
+    for line in text.splitlines():
+        body = re.search(r'"body":"([A-Za-z0-9+/=]+)"', line) if "tpu_custom_call" in line and name in line else None
+        if body:
+            ctx = mlir.make_ir_context()
+            ctx.allow_unregistered_dialects = True  # the serialized dialect is ``stable_mosaic``
+            with ctx:
+                module = ir.Module.parse(base64.b64decode(body.group(1)))
+                programs.append(module.operation.get_asm(enable_debug_info=False))
+    return programs
+
+
+@pytest.mark.parametrize(
+    "slots,heads,kv_heads,blocks,table,window,digest",
+    [
+        (32, 32, 8, 16 * 2048, 256, 4096, "465c74c7d5cf0c59afd2f67560f370d46a3493f33ca0c4b3da949e537b225db6"),
+        (128, 20, 1, 18433, 144, None, "77ee8c8ef5ce4a187ebbf7d62315cc78917ca272c515fe9817c022d000390b78"),
+    ],
+    ids=["chat_cell", "longanswer_cell"],
+)
+def test_paged_decode_attention_is_the_program_it_was_before_the_walk_was_shared(
+    v5e, slots, heads, kv_heads, blocks, table, window, digest
+):
+    """PR 32 moved the walk over a slot's live pages into ``ops/paged_walk.py`` for the latent kernel to
+    share, on the condition that the K/V kernel's cells pay nothing for it: at the shapes of
+    ``mistral7b-serve-chat`` and ``jamba2-3b-serve-longanswer`` the kernel's Mosaic program, source
+    locations apart, is the one PR 31's tree compiled (the digests are of that tree's). A change to the
+    walk that moves this is a change to the K/V kernel: measure those cells, then take the new digest."""
+    import hashlib
+
+    from accelerate_tpu.ops.pallas_paged_attention import paged_decode_attention
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    pool = _on(chip, (blocks, 16, kv_heads, DIM))
+    fn = functools.partial(paged_decode_attention, sliding_window=window, interpret=False)
+    text = _compile(
+        fn, _on(chip, (slots, heads, DIM)), pool, pool, _on(chip, (slots, table), jnp.int32), _on(chip, (slots,), jnp.int32)
+    )
+    (program,) = _mosaic_programs(text, "paged_decode_attention")
+    assert len(program) > 40_000, "the program was not read whole"
+    assert hashlib.sha256(program.encode()).hexdigest() == digest
+
+
 def test_latent_paged_decode_compiles_for_v5e(v5e):
-    """The latent (MLA) decode kernel at JoyAI-LLM-Flash widths: 32 heads against one shared row of
-    512 + 64 values, 64 slots of 40 pages of 128 tokens."""
+    """The latent (MLA) decode kernel at JoyAI-LLM-Flash widths, the ``joyai-flash-serve-longchat`` cell's
+    call: 32 heads against one shared row of 512 + 64 values, 64 slots of 40 pages of 128 tokens. The walk
+    (a page copied into its 128 lanes of a chunk buffer at a dynamic offset, a dynamic trip count) is where
+    Mosaic refuses a slice off the tiling; the pool reaches the kernel as it is, never copied or re-laid."""
     from accelerate_tpu.ops.pallas_latent_attention import latent_paged_decode
 
     chip = SingleDeviceSharding(v5e.devices[0])
     slots, block, table, width, rank = 64, 128, 40, 576, 512
+    blocks = slots * table + 1
     fn = functools.partial(latent_paged_decode, value_width=rank, scale=192**-0.5, interpret=False)
     text = _compile(
-        fn, _on(chip, (slots, HEADS, width)), _on(chip, (slots * table + 1, width, block)),
+        fn, _on(chip, (slots, HEADS, width)), _on(chip, (blocks, width, block)),
         _on(chip, (slots, table), jnp.int32), _on(chip, (slots,), jnp.int32),
     )
     assert "latent_paged_decode" in text and "paged_decode_attention" not in text, "found by a name of its own"
+    pools = [line for line in text.splitlines() if f"[{blocks}," in line and (" copy(" in line or " transpose(" in line)]
+    assert not pools, f"the pool is copied or re-laid on its way to the kernel: {pools[0][:200]}"
+    (program,) = _mosaic_programs(text, "latent_paged_decode")
+    assert "iteration_bounds = array<i64: 64>" in program, "one grid step a slot, whatever its table holds"
 
 
 def _expert_products(text):
