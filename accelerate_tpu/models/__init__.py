@@ -1,5 +1,5 @@
 """Model zoo: TPU-first flax implementations with mesh sharding rules
-(bert/gpt2/gptneox/t5/llama/mistral/joyai_llm_flash/jamba/qwen2/qwen3/olmo2/gemma/gemma2/gemma3/phi3/mixtral/qwen3moe/resnet/vit/whisper/clip/unet/vae)
+(bert/gpt2/gptneox/t5/llama/mistral/joyai_llm_flash/jamba/lfm2_moe/qwen2/qwen3/olmo2/gemma/gemma2/gemma3/phi3/mixtral/qwen3moe/resnet/vit/whisper/clip/unet/vae)
 + HF safetensors weight import. The reference delegates models to
 transformers; here they ship in-tree (SURVEY hard-part #3: torch-free
 model story)."""
@@ -47,6 +47,12 @@ from .jamba import (
     JambaConfig,
     JambaModel,
     create_jamba_model,
+)
+from .lfm2_moe import (
+    LFM2_MOE_SHARDING_RULES,
+    Lfm2MoeConfig,
+    Lfm2MoeModel,
+    create_lfm2_moe_model,
 )
 from .gemma import (
     GEMMA_SHARDING_RULES,

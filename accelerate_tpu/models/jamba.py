@@ -14,10 +14,13 @@ of :mod:`accelerate_tpu.models.joyai_llm_flash`: the module, the decode
 contract, the cache (paged K/V pools for the attention layers, one row of
 recurrent state a slot for the others) and the serving engine are the core's.
 
-Not here: routed experts in a hybrid layer (the larger Jambas'
-``num_experts`` 16; this checkpoint publishes ``num_experts`` 1, so
-``expert_layer_period`` / ``expert_layer_offset`` select nothing), refused
-by name.
+Not here: the larger Jambas' ``num_experts`` 16. The core now builds routed
+experts beside a stateful mixer (:mod:`accelerate_tpu.models.lfm2_moe`
+runs them), by ``first_k_dense_replace``; this family chooses its expert
+layers by ``expert_layer_period`` / ``expert_layer_offset`` and scores them
+by softmax, which the core does not build, and this checkpoint publishes
+``num_experts`` 1, so they select nothing: ``num_experts > 1`` is refused by
+name (no configuration runs it).
 """
 
 from __future__ import annotations

@@ -84,9 +84,11 @@ class LlamaConfig:
     # Gemma2: attention scale = query_pre_attn_scalar**-0.5 when set
     # (instead of head_dim**-0.5)
     query_pre_attn_scalar: Optional[float] = None
-    # per-layer attention kind ("sliding_attention"|"full_attention") for
-    # Gemma2's alternating local/global layers — requires scan_layers=False
-    # (a scanned block shares one static config across layers)
+    # per-layer kind as checkpoints publish it: "sliding_attention" |
+    # "full_attention" for Gemma2's alternating local/global layers, and
+    # "conv" for a layer whose mixer is a :class:`ShortConvMixer` (LFM2) —
+    # requires scan_layers=False (a scanned block shares one static config
+    # across layers)
     layer_types: Optional[tuple] = None
     # Gemma3: sliding layers rotate with THIS theta (10k) and no rope
     # scaling, while full layers use rope_theta (1M) + rope_scaling
@@ -147,9 +149,27 @@ class LlamaConfig:
     mamba_dt_rank: Optional[int] = None  # None: ceil(hidden_size / 16)
     mamba_conv_bias: bool = True
     mamba_proj_bias: bool = False
+    # Gated short convolutions beside attention (LFM2-style checkpoints
+    # publish these keys and name the layers in ``layer_types``): a "conv"
+    # layer's mixer is a :class:`ShortConvMixer` of ``conv_L_cache`` taps,
+    # whose state a sequence is its last ``conv_L_cache - 1`` inputs,
+    # whatever the length. Layers are then unrolled (``scan_layers=False``).
+    conv_L_cache: int = 3
+    conv_bias: bool = False
 
-    def is_mamba_layer(self, i: int) -> bool:
-        return self.attn_layer_period is not None and i % self.attn_layer_period != self.attn_layer_offset
+    def mixer_kind(self, i: int) -> str:
+        """The mixer of layer ``i``: ``"conv"`` where ``layer_types`` says so, ``"mamba"`` off the
+        attention period of a config with one, else ``"attention"`` (latent where ``kv_lora_rank`` is set)."""
+        if self.layer_types is not None and self.layer_types[i] == "conv":
+            return "conv"
+        if self.attn_layer_period is not None and i % self.attn_layer_period != self.attn_layer_offset:
+            return "mamba"
+        return "attention"
+
+    @property
+    def stateful(self) -> bool:
+        """Some layer's mixer keeps a recurrent state a sequence (:data:`ops.paged_kv.STATE_LEAVES`)."""
+        return self.attn_layer_period is not None or (self.layer_types is not None and "conv" in self.layer_types)
 
     @classmethod
     def llama2_7b(cls, **kw) -> "LlamaConfig":
@@ -745,6 +765,53 @@ class MambaMixer(nn.Module):
             return _dense(cfg, cfg.hidden_size, "out_proj", dt, cfg.mamba_proj_bias)(y.astype(dt) * nn.silu(z))
 
 
+class ShortConvMixer(nn.Module):
+    """Gated short convolution (LFM2's ``conv`` operator): ``[B, C, x] =
+    split3(in_proj(u))``, in that order; ``y = C * conv1d(B * x)``, a causal
+    depthwise convolution of ``conv_L_cache`` taps; ``out_proj(y)``. No
+    activation and no gate besides ``B`` and ``C``; ``conv_bias`` puts a
+    bias on the two projections and the convolution alike.
+
+    With ``decode=True`` the layer keeps ``conv_state`` ``[B, (conv_L_cache -
+    1) * hidden]`` in the ``cache`` collection, in the dense and the paged
+    serving layout alike: the last ``B * x`` rows before the next token,
+    oldest first, one lane-dense row a sequence whatever its length
+    (:func:`~accelerate_tpu.ops.selective_scan.causal_conv1d`). The published
+    code caches ``conv_L_cache`` columns; the convolution reads ``conv_L_cache
+    - 1`` of them beside the token itself, and that many are carried.
+    ``new_span`` as :class:`MambaMixer` takes it; without a cache the
+    convolution runs from zeros."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, hidden, decode: bool = False, new_span=None):
+        from ..ops.selective_scan import causal_conv1d
+
+        cfg = self.config
+        dt = hidden.dtype
+        bsz, t, d = hidden.shape
+        k = cfg.conv_L_cache
+        conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(), (k, d))
+        conv_b = self.param("conv_bias", nn.initializers.zeros, (d,)) if cfg.conv_bias else None
+        if decode:
+            conv = self.variable("cache", "conv_state", jnp.zeros, (bsz, (k - 1) * d), dt)
+            carried = conv.value
+        else:
+            carried = jnp.zeros((bsz, (k - 1) * d), dt)
+        lo, hi = (0, t) if new_span is None else new_span
+        with jax.named_scope("conv.proj"):
+            bcx = _dense(cfg, 3 * d, "in_proj", dt, cfg.conv_bias)(hidden)
+            b_gate, c_gate, x = bcx[..., :d], bcx[..., d : 2 * d], bcx[..., 2 * d :]
+        with jax.named_scope("conv.mix"):
+            y, carried = causal_conv1d(b_gate * x, conv_w, conv_b, carried, lo, hi)
+            y = c_gate * y
+        if decode:
+            conv.value = carried
+        with jax.named_scope("conv.out"):
+            return _dense(cfg, d, "out_proj", dt, cfg.conv_bias)(y)
+
+
 def _mamba_dt_bias_init(key, shape, dtype=jnp.float32):
     step = jnp.exp(jax.random.uniform(key, shape) * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
     return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)  # softplus^-1
@@ -761,7 +828,7 @@ def _one_device() -> bool:
 class LlamaLayer(nn.Module):
     config: LlamaConfig
     routed: bool = False  # the FFN is RoutedFFN (a layer past ``first_k_dense_replace`` of a config with experts)
-    mamba: bool = False  # the mixer is MambaMixer (a layer off the attention period of a config with state-space layers)
+    mixer: str = "attention"  # ``LlamaConfig.mixer_kind`` of this layer: "attention" | "mamba" | "conv"
 
     @nn.compact
     def __call__(self, hidden, positions, decode: bool = False, new_span=None):
@@ -769,11 +836,13 @@ class LlamaLayer(nn.Module):
         attn_cls = LlamaAttention if cfg.kv_lora_rank is None else LatentAttention
 
         def attn(x):
-            if self.mamba:
+            if self.mixer == "mamba":
                 return MambaMixer(cfg, name="mamba")(x, decode, new_span)
-            if cfg.attn_layer_period is not None:
-                # beside state-space layers, attention is told the window's new tokens too: an overlapped
-                # head's hidden states come out of layers that did not advance, so its rows are kept
+            if self.mixer == "conv":
+                return ShortConvMixer(cfg, name="conv")(x, decode, new_span)
+            if cfg.stateful:
+                # beside layers that keep a recurrent state, attention is told the window's new tokens too: an
+                # overlapped head's hidden states come out of layers that did not advance, so its rows are kept
                 return attn_cls(cfg, name="attn")(x, positions, decode, new_span)
             return attn_cls(cfg, name="attn")(x, positions, decode)
 
@@ -839,6 +908,12 @@ class LlamaModel(nn.Module):
 
         hidden = maybe_shard(hidden, ACTIVATION_SPEC)
 
+        if cfg.scan_layers and cfg.stateful:
+            raise NotImplementedError(
+                "layers with a recurrent state (Mamba, gated short convolution) are built with "
+                "scan_layers=False: a scanned block shares one mixer across layers, and the carried pool "
+                "stack holds K/V pools only, no ssm_state or conv_state"
+            )
         if cfg.layer_types is not None and cfg.scan_layers:
             raise ValueError(
                 "layer_types (per-layer sliding/full attention, Gemma2) requires "
@@ -855,15 +930,10 @@ class LlamaModel(nn.Module):
                 "latent attention and routed experts are built with scan_layers=False: a leading dense "
                 "layer differs from the expert layers, and the carried pool stack holds K/V pools only"
             )
-        if cfg.scan_layers and cfg.attn_layer_period is not None:
+        if cfg.stateful and cfg.kv_lora_rank is not None:
             raise NotImplementedError(
-                "state-space (Mamba) layers are built with scan_layers=False: a scanned block shares one "
-                "mixer across layers, and the carried pool stack holds K/V pools only, no ssm_state"
-            )
-        if cfg.attn_layer_period is not None and (routed or cfg.kv_lora_rank is not None):
-            raise NotImplementedError(
-                "state-space (Mamba) layers beside latent attention or routed experts (n_routed_experts, "
-                "kv_lora_rank): no configuration runs them together"
+                "layers with a recurrent state (Mamba, gated short convolution) beside latent attention "
+                "(kv_lora_rank): no configuration runs them together"
             )
         if cfg.scan_layers:
             # A paged decode step carries the pools of all layers through the
@@ -906,13 +976,14 @@ class LlamaModel(nn.Module):
                     # Gemma2/3 alternating local/global attention: the band
                     # only applies on "sliding_attention" layers, which in
                     # Gemma3 also rotate with the LOCAL theta and no scaling
+                    # (a "conv" layer has no attention: ``mixer_kind`` below)
                     windowed = cfg.layer_types[i] == "sliding_attention"
                     overrides = {"sliding_window": cfg.sliding_window if windowed else None}
                     if windowed and cfg.rope_local_theta is not None:
                         overrides["rope_theta"] = cfg.rope_local_theta
                         overrides["rope_scaling"] = None
                     lcfg = dataclasses.replace(cfg, **overrides)
-                hidden = layer_cls(lcfg, routed and i >= n_lead, cfg.is_mamba_layer(i), name=f"layer_{i}")(
+                hidden = layer_cls(lcfg, routed and i >= n_lead, cfg.mixer_kind(i), name=f"layer_{i}")(
                     hidden, positions, decode, new_span
                 )
         hidden = RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="final_norm")(hidden)
@@ -937,8 +1008,8 @@ def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> 
         non-param collections (the fp8 amax histories): returns
         ``(logits, new_state)``. ``new_span`` ``(lo, hi)``: which of the
         window's tokens are new and real (a bucket's right pad and a chunk
-        window's overlapped head are not); only a model with state-space
-        layers reads it, and None means every token."""
+        window's overlapped head are not); only a model whose layers keep
+        a recurrent state reads it, and None means every token."""
         if decode:
             variables = {"params": p, **(state or {})}
             if cache is not None:

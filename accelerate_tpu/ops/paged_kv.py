@@ -25,11 +25,12 @@ shapes) allocates cache in fixed-size *blocks* from one shared pool:
   0 and costs one page); everything below (tables, the sink, prefix
   aliasing, donation) holds for it as for K/V, the carried stack of a
   layer scan apart: latent layers are unrolled, a pool each;
-* beside the pools, for a state-space layer (Mamba), NO pool: its state a
-  sequence is fixed in size, so the layer keeps ``ssm_state`` / ``conv_state``
-  leaves with one row a slot (:data:`STATE_LEAVES`), in this layout as in
-  the dense one; a hybrid model's cache tree holds both kinds, the K/V of
-  its attention layers paged, a pool a layer;
+* beside the pools, for a layer with a recurrent state (Mamba, a gated
+  short convolution), NO pool: its state a sequence is fixed in size, so
+  the layer keeps ``ssm_state`` / ``conv_state`` leaves (a convolution layer
+  ``conv_state`` alone) with one row a slot (:data:`STATE_LEAVES`), in this
+  layout as in the dense one; a hybrid model's cache tree holds both kinds,
+  the K/V of its attention layers paged, a pool a layer;
 * ``block_table``: ``[B, max_blocks]`` int32 per row — position ``p`` of
   row ``b`` lives at ``pool[table[b, p // bs], p % bs]``;
 * block 0 is a reserved **trash sink**: padded table entries and the
@@ -138,7 +139,8 @@ _LAYER_VIEW: Optional[_LayerView] = None
 POOL_ROWS = {"key_pool": "key", "value_pool": "value", "latent_pool": "latent"}
 
 # Leaves with a slot axis and no row axis: the recurrent state of a state-space layer (``ssm_state``
-# ``[B, d_state, d_inner]``, ``conv_state`` ``[B, (d_conv - 1) * d_inner]``), one row a slot whatever the
+# ``[B, d_state, d_inner]``, ``conv_state`` ``[B, (d_conv - 1) * d_inner]``) and of a gated short
+# convolution (``conv_state`` ``[B, (conv_L_cache - 1) * hidden]`` alone), one row a slot whatever the
 # sequence's length, the same leaf in the dense row cache (``B`` 1) and in the paged cache (``B`` slots).
 # No pages: :func:`paste_row` writes a prefill's state over the slot's, :func:`paste_blocks` passes it
 # (a shared prefix shares blocks, not state: each request carries a copy of the prefix's), and
@@ -158,7 +160,8 @@ def state_bytes(cache) -> int:
 def declare_pool_stack(module, num_layers: int, kv_heads: int, head_dim: int, dtype):
     """The pools of ``num_layers`` scanned layers as ONE pair of ``cache``
     variables ``[L, NB, bs, H_kv, D]`` on ``module``, the module that owns
-    the layer scan — or None when no paged layout is active.
+    the layer scan — or None when no paged layout is active. (A head under
+    the lane width is folded: :func:`pool_lane_fold`.)
 
     Scanning over the ``cache`` collection would hand each layer a fresh
     slice of the stack and collect the updated slices into a second stack:
@@ -170,7 +173,8 @@ def declare_pool_stack(module, num_layers: int, kv_heads: int, head_dim: int, dt
     cfg = _ACTIVE
     if cfg is None:
         return None
-    shape = (num_layers, cfg.num_blocks, cfg.block_size, kv_heads, head_dim)
+    fold = pool_lane_fold(kv_heads, head_dim)
+    shape = (num_layers, cfg.num_blocks, cfg.block_size, kv_heads // fold, fold * head_dim)
     return (
         module.variable("cache", "key_pool", jnp.zeros, shape, dtype),
         module.variable("cache", "value_pool", jnp.zeros, shape, dtype),
@@ -213,13 +217,44 @@ def _constrain_pool(x):
     return maybe_shard(x, POOL_KV_SPEC)
 
 
+LANES, SUBLANES = 128, 8  # a TPU tile of 32-bit values: its minor axis and the axis before it
+
+
+def pool_lane_fold(kv_heads: int, head_dim: int) -> int:
+    """How many key/value heads of one token share a row of the pool: 1, or
+    ``128 // head_dim`` for a head under the lane width (LFM2's 64). A pool
+    ``[NB, bs, H_kv, 64]`` has half a lane tile as its minor axis: the TPU
+    compiler either pads it to 128 (twice the pool's bytes and the kernel's
+    traffic, and a page slice off the tiling, which Mosaic refuses) or lays
+    the block axis innermost (no page is contiguous). So such a pool is
+    declared ``[NB, bs, H_kv / f, f * D]``: the same bytes in the same order
+    (a token's heads are consecutive), heads ``f * i .. f * i + f - 1`` side
+    by side in row ``i``. Whoever writes a token reshapes it (free);
+    :func:`~accelerate_tpu.ops.pallas_paged_attention.paged_decode_attention`
+    reads the folded rows as they are. Not under a tensor-parallel mesh,
+    whose pool splits its head axis."""
+    from .attention import active_mesh
+
+    fold = LANES // head_dim if head_dim < LANES and LANES % head_dim == 0 else 1
+    if fold == 1 or kv_heads % fold:
+        return 1
+    mesh = active_mesh()
+    if mesh is not None:
+        from ..parallel.mesh import axis_size
+
+        if axis_size(mesh, "tensor") > 1:
+            return 1
+    return fold
+
+
 def paged_cached_attention(
     module, q, k, v, max_len: int, scale=None, bias_fn=None, sliding_window=None, cfg: PagedConfig = None
 ):
     """Single-token incremental attention against the paged pool.
 
-    Declares (per layer) ``key_pool``/``value_pool`` ``[NB, bs, H_kv, D]``,
-    ``block_table`` ``[B, MB]`` and a PER-ROW ``index`` ``[B]`` — ragged
+    Declares (per layer) ``key_pool``/``value_pool`` ``[NB, bs, H_kv, D]``
+    (``[NB, bs, H_kv / f, f * D]`` for a head under the lane width:
+    :func:`pool_lane_fold`), ``block_table`` ``[B, MB]`` and a PER-ROW ``index`` ``[B]`` — ragged
     row positions are native here (the dense branch's scalar frontier
     forces the serving engine to vmap row-wise; the paged tick runs one
     batched program instead). Inside a :func:`layer_view` the pools are
@@ -239,10 +274,12 @@ def paged_cached_attention(
     mb = -(-max_len // bs_)
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
 
+    fold = pool_lane_fold(h_kv, d)
+    row = (h_kv // fold, fold * d)  # one token of the pool: [H_kv, D], or heads side by side in 128 lanes
     view = _LAYER_VIEW
     if view is None:
-        kp = module.variable("cache", "key_pool", jnp.zeros, (nb, bs_, h_kv, d), k.dtype)
-        vp = module.variable("cache", "value_pool", jnp.zeros, (nb, bs_, h_kv, d), v.dtype)
+        kp = module.variable("cache", "key_pool", jnp.zeros, (nb, bs_, *row), k.dtype)
+        vp = module.variable("cache", "value_pool", jnp.zeros, (nb, bs_, *row), v.dtype)
         key_pool, value_pool = kp.value, vp.value
     else:
         key_pool, value_pool = view.key_pool, view.value_pool
@@ -263,8 +300,8 @@ def paged_cached_attention(
     table = bt.value if view is None else bt.value + view.base  # this layer's blocks of the stack
     dest = table[rows, blk]  # [B] pool block ids
     off = cur % bs_
-    key_pool = _constrain_pool(key_pool.at[dest, off].set(k[:, 0]))
-    value_pool = _constrain_pool(value_pool.at[dest, off].set(v[:, 0]))
+    key_pool = _constrain_pool(key_pool.at[dest, off].set(k[:, 0].reshape(b, *row)))
+    value_pool = _constrain_pool(value_pool.at[dest, off].set(v[:, 0].reshape(b, *row)))
     if view is None:
         kp.value, vp.value = key_pool, value_pool
     else:
@@ -301,11 +338,12 @@ def paged_cached_attention(
 def paged_gather_attention(q, key_pool, value_pool, block_table, cur, *, scale, sliding_window=None):
     """The plain XLA paged decode step: gather each row's pages into a
     contiguous copy and attend to it. ``q`` is ``[B, 1, H, D]``, the pools
-    ``[NB, bs, H_kv, D]``, ``block_table`` ``[B, MB]`` and ``cur`` the
+    ``[NB, bs, H_kv, D]`` (or lane-folded), ``block_table`` ``[B, MB]`` and ``cur`` the
     per-row frontier ``[B]``; returns ``[B, 1, H, D]``. What the Pallas
     kernel is checked against, and what runs where it cannot."""
-    b = q.shape[0]
-    _, bs_, h_kv, d = key_pool.shape
+    b, d = q.shape[0], q.shape[-1]
+    bs_ = key_pool.shape[1]
+    h_kv = key_pool.shape[2] * key_pool.shape[3] // d  # a lane-folded pool holds the same rows (pool_lane_fold)
     mb = block_table.shape[1]
     # gather each row's pages: [B, MB, bs, H_kv, D] -> [B, L, H_kv, D]
     k_all = key_pool[block_table].reshape(b, mb * bs_, h_kv, d)
@@ -468,10 +506,20 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None):
                 widths = [(0, 0)] * (lead + 1) + [(0, pad)] + [(0, 0)] * tail
                 row = jnp.pad(row, widths)
             # absorb the B=1 row axis while blockifying
-            blocks = row.reshape(*leaf.shape[:lead], mb, bs_, *row.shape[-tail:])
+            # a token's row as the pool lays it out: the dense leaf's [H_kv, D], or lane-folded (pool_lane_fold)
+            token = row.shape[-1:] if latent else leaf.shape[-2:]
+            blocks = row.reshape(*leaf.shape[:lead], mb, bs_, *token)
             if latent:
                 blocks = blocks.swapaxes(-1, -2)
             sel = (slice(None),) * lead + (write_row,)
+            if not latent and 1 < leaf.shape[-2] < SUBLANES:
+                # A head axis of 2..7 is under a sublane tile: around a scatter of whole ``[bs, H, W]`` blocks the
+                # TPU compiler re-lays the pool with ``bs`` innermost and back, two copies of the whole pool a
+                # paste (read in the compile for a described v5e; 15 ms a paste at 2.4 GB of pools on the chip).
+                # Through the flat view ``[NB, bs * H, W]``, which is a bitcast, it scatters in place.
+                flat = (*leaf.shape[: lead + 1], bs_ * leaf.shape[-2], leaf.shape[-1])
+                blocks = blocks.reshape(*blocks.shape[: lead + 1], *flat[-2:])
+                return leaf.reshape(flat).at[sel].set(blocks.astype(leaf.dtype)).reshape(leaf.shape)
             return leaf.at[sel].set(blocks.astype(leaf.dtype))
         if name in ("block_table", "index"):
             out = table_updates(name, leaf)

@@ -37,7 +37,14 @@ each LIVE page once and nothing else:
   rounding moves them then goes the other way, which the benchmark's toy
   cell counts). Scores, running maximum, sum and accumulator are float32;
 * the decode contract matches the XLA branch in masking: keys at
-  positions ``> cur - W`` and ``<= cur``.
+  positions ``> cur - W`` and ``<= cur``;
+* a pool whose heads are under the lane width comes lane-folded
+  (``paged_kv.pool_lane_fold``: ``[NB, bs, H_kv / f, f * D]``, ``f`` heads of
+  a token side by side in 128 lanes). The kernel runs on it as it is, at
+  ``H_kv / f`` heads of ``f * D``: :func:`paged_decode_attention` puts each
+  query into its own head's lanes with zeros in the others', so a score is
+  the query against its own keys alone, and keeps a head's own lanes of the
+  ``f * D`` wide values.
 
 The walk (the first two points) is :mod:`.paged_walk`'s, which the latent
 kernel (:mod:`.pallas_latent_attention`) makes too; the fold (the next two)
@@ -162,8 +169,8 @@ def _kernel(
 @functools.partial(jax.jit, static_argnames=("sliding_window", "scale", "interpret"))
 def paged_decode_attention(
     q: jax.Array,  # [B, H, D]
-    key_pool: jax.Array,  # [NB, bs, Hkv, D]
-    value_pool: jax.Array,  # [NB, bs, Hkv, D]
+    key_pool: jax.Array,  # [NB, bs, Hkv, D], or lane-folded [NB, bs, Hkv / f, f * D]
+    value_pool: jax.Array,  # as key_pool
     block_table: jax.Array,  # [B, MB] int32
     cur: jax.Array,  # [B] int32 — per-row frontier (attend to <= cur)
     *,
@@ -179,9 +186,22 @@ def paged_decode_attention(
     """
     from jax.experimental.pallas import tpu as pltpu
 
+    scale = (1.0 / math.sqrt(q.shape[-1])) if scale is None else scale
+    fold = key_pool.shape[-1] // q.shape[-1]
+    if fold > 1:
+        # a lane-folded pool (``paged_kv.pool_lane_fold``): ``fold`` key/value heads of a token side by side in
+        # one row. Each query sits in its own head's lanes with zeros in the others', so the kernel, run as it
+        # is at ``H_kv / fold`` heads of ``fold * D``, scores it against its own keys alone; the values come
+        # out ``fold * D`` wide and a head keeps its own lanes.
+        b, heads, d = q.shape
+        lane = (jnp.arange(heads) // (heads // (key_pool.shape[2] * fold))) % fold  # [H]: where a head's K/V lie
+        mine = lane[:, None] == jnp.arange(fold)[None, :]  # [H, fold]
+        wide = jnp.where(mine[None, :, :, None], q[:, :, None, :], 0).reshape(b, heads, fold * d)
+        out = paged_decode_attention(wide, key_pool, value_pool, block_table, cur, sliding_window=sliding_window,
+                                     scale=scale, interpret=interpret)
+        return jnp.sum(jnp.where(mine[None, :, :, None], out.reshape(b, heads, fold, d), 0), axis=2)
     b, heads, dim = q.shape
     nb, block_size, kv_heads, _ = key_pool.shape
-    scale = (1.0 / math.sqrt(dim)) if scale is None else scale
     pages = _pages_per_chunk(block_size, kv_heads, dim, key_pool.dtype)
     rows = block_size * kv_heads
 

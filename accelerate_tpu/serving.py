@@ -21,7 +21,8 @@ parity-plus. Design notes:
   construction: they sit beyond the causal frontier (key_pos > q_pos
   masks them) and each decode step overwrites the next one, because the
   cache write index is reset to ``true_len`` after prefill. **Recurrent
-  state** (a state-space layer's ``ssm_state`` / ``conv_state``, one row a
+  state** (``ops.paged_kv.STATE_LEAVES``: a state-space layer's ``ssm_state``
+  and ``conv_state``, a gated short convolution's ``conv_state``; one row a
   slot and no row a token): a state after the bucket's last token would
   include the pad, so every program that runs a window tells the model
   which of its tokens are new and real (``new_span``), and the others
@@ -104,8 +105,8 @@ def _row_axis(shape: tuple, cap: int):
 def check_handoff_layout(row_cache) -> None:
     """KV hand-off ships per-head K/V rows. A latent (MLA) row cache — one
     ``latent`` row a token, shared by all heads — is not carried yet: say so
-    instead of sizing or packing it as K/V. Neither is a state-space
-    layer's recurrent state (``ssm_state``), which has no rows to trim."""
+    instead of sizing or packing it as K/V. Neither is a recurrent state
+    (:data:`ops.paged_kv.STATE_LEAVES`), which has no rows to trim."""
     from .ops.kv_cache import leaf_names
 
     check_no_state_leaf(row_cache, "KV hand-off (kv_handoff_dims, prefill_detached, HandoffCodec)")
@@ -119,14 +120,18 @@ def check_handoff_layout(row_cache) -> None:
 
 def check_no_state_leaf(row_cache, what: str) -> None:
     """``what`` ships K/V rows trimmed to a frontier; a cache with a
-    state-space layer's ``ssm_state`` (one row a slot, no row a token)
-    is refused by name."""
+    recurrent-state leaf (:data:`ops.paged_kv.STATE_LEAVES`: a state-space
+    layer's ``ssm_state``, a convolution's ``conv_state``; one row a slot,
+    no row a token) is refused, by the leaves it holds."""
     from .ops.kv_cache import leaf_names
+    from .ops.paged_kv import STATE_LEAVES
 
-    if "ssm_state" in leaf_names(row_cache):
+    names = leaf_names(row_cache)
+    held = [name for name in STATE_LEAVES if name in names]
+    if held:
         raise NotImplementedError(
-            f"{what} cannot carry a recurrent state yet: ssm_state / conv_state are one row a sequence, "
-            "not rows a token to trim and pad; serve models with state-space layers without it "
+            f"{what} cannot carry a recurrent state yet: {' / '.join(held)} are one row a sequence, "
+            "not rows a token to trim and pad; serve models whose layers keep one without it "
             "(a failover resumes by prefix recompute, which is exact)"
         )
 
@@ -332,7 +337,7 @@ class ServingEngine:
             jnp.zeros((1, 1), jnp.int32),
         )
 
-        # a model with state-space layers keeps recurrent state beside its K/V rows: its windows are told
+        # a model whose layers keep a recurrent state (STATE_LEAVES) beside its K/V rows: its windows are told
         # which of their tokens are new (``new_span``); no other model's programs take the argument
         from .ops.paged_kv import state_bytes
 

@@ -111,13 +111,13 @@ def build_spec_step(t_apply, d_apply, gamma: int):
         lps = jax.vmap(lambda r, t: jax.nn.log_softmax(r)[t])(rows, emit)
 
         new_frontier = pos + n_emit
-        from .ops.paged_kv import state_bytes
+        from .ops.paged_kv import STATE_LEAVES, state_bytes
 
         if state_bytes(t_cache) or state_bytes(d_cache):
             raise NotImplementedError(
                 "speculative decoding takes a rejected draft back by resetting the cache's frontier; a "
-                "state-space layer's recurrent state (ssm_state) has stepped over the rejected tokens and "
-                "cannot be taken back: decode models with state-space layers without a draft"
+                f"recurrent state ({' / '.join(STATE_LEAVES)}) has stepped over the rejected tokens and "
+                "cannot be taken back: decode models whose layers keep one without a draft"
             )
         t_cache = reset_cache_index(t_cache, new_frontier)
         d_cache = reset_cache_index(d_cache, new_frontier)

@@ -130,12 +130,12 @@ def _state(cache):
 
 def test_layers_follow_the_attention_period(model):
     cfg = model.config
-    assert [cfg.is_mamba_layer(i) for i in range(4)] == [True, False, True, False]
+    assert [cfg.mixer_kind(i) for i in range(4)] == ["mamba", "attention", "mamba", "attention"]
     assert "mamba" in model.params["layer_0"] and "attn" in model.params["layer_1"] and "attn" not in model.params["layer_2"]
     assert model.params["layer_0"]["mamba"]["A_log"].shape == (8, 128), "d_inner along the lanes"
     assert "lm_head" not in model.params, "the head is the embedding"
     published = JambaConfig()
-    assert [i for i in range(28) if not published.is_mamba_layer(i)] == [7, 21] and published.rope_theta is None
+    assert [i for i in range(28) if published.mixer_kind(i) == "attention"] == [7, 21] and published.rope_theta is None
 
 
 def test_attention_has_no_position_encoding(model):
@@ -152,10 +152,10 @@ def test_attention_has_no_position_encoding(model):
     [
         (lambda: create_jamba_model(JambaConfig.tiny(scan_layers=True), seed=3, seq_len=16), "scan_layers=False"),
         (lambda: JambaConfig.tiny(num_experts=16), "num_experts=16"),
-        (lambda: create_jamba_model(JambaConfig.tiny(n_routed_experts=8, moe_intermediate_size=32), seed=3, seq_len=16),
-         "n_routed_experts"),
+        (lambda: create_jamba_model(JambaConfig.tiny(kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16, qk_rope_head_dim=16,
+                                                     v_head_dim=16), seed=3, seq_len=16), "kv_lora_rank"),
     ],
-    ids=["scanned", "experts_in_a_hybrid_layer", "routed_ffn_beside_mamba"],
+    ids=["scanned", "experts_in_a_hybrid_layer", "latent_attention_beside_mamba"],
 )
 def test_what_no_configuration_runs_is_refused_by_name(make, names):
     with pytest.raises(NotImplementedError, match=names):

@@ -132,6 +132,42 @@ def test_expert_tile_visits_is_the_kernels_grid(case):
     assert int(n) == len(want) and walked == want
 
 
+@pytest.mark.parametrize("pairs,tile,sizes", [
+    (512, 64, "even"), (512, 64, "skewed"), (2048, 128, "skewed"), (2048, 128, "one_empty_in_three"),
+], ids=lambda v: str(v))
+def test_grouped_kernel_at_32_groups_of_wide_matrices(pairs, tile, sizes):
+    """LFM2-8B-A1B's shapes scaled down: 32 groups, matrices in the ratio of ``[2048, 1792]`` (``[64, 56]``),
+    the tick's 128 slots x 4 (row tile 64: 16 rows a group, four groups a tile) and a prefill's 512 x 4
+    (row tile 128, groups that straddle tiles, groups with nothing). Interpreted, against ``ragged_dot``:
+    the same products, float32 sums in another order."""
+    from accelerate_tpu.ops.moe import _ragged_swiglu_ffn
+    from accelerate_tpu.ops.pallas_grouped_matmul import grouped_swiglu_ffn, row_tile, tile_visits
+
+    groups, d, ff = 32, 64, 56
+    assert row_tile(pairs, groups) == tile and row_tile(128 * 4, 32) == 64
+    rng = np.random.default_rng(pairs)
+    if sizes == "even":
+        share = np.ones(groups)
+    elif sizes == "skewed":
+        share = rng.gamma(0.6, size=groups) + 0.01
+    else:
+        share = np.where(np.arange(groups) % 3 == 1, 0.0, rng.uniform(0.5, 1.5, groups))
+    flat = np.sort(rng.choice(groups, size=pairs, p=share / share.sum()))
+    group_sizes = jnp.asarray(np.bincount(flat, minlength=groups), jnp.int32)
+    if sizes == "one_empty_in_three":
+        assert int((group_sizes == 0).sum()) >= 10
+    if sizes != "even":
+        assert int(tile_visits(group_sizes, tile).sum()) > int((group_sizes > 0).sum()), "some group straddles a tile"
+    k = jax.random.split(jax.random.key(pairs), 4)
+    xs = jax.random.normal(k[0], (pairs, d))
+    gate, up = jax.random.normal(k[1], (groups, d, ff)) * 0.2, jax.random.normal(k[2], (groups, d, ff)) * 0.2
+    down = jax.random.normal(k[3], (groups, ff, d)) * 0.2
+    got = grouped_swiglu_ffn(xs, gate, up, down, group_sizes, interpret=True)
+    want = _ragged_swiglu_ffn(xs, gate, up, down, group_sizes)
+    assert float(jnp.abs(want).max()) > 0.5
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)
+
+
 def test_selection_bias_changes_the_choice_and_not_the_weight():
     logits = jnp.array([[2.0, 1.0, 0.5, -1.0]])
     plain_e, plain_w = sigmoid_topk_routing(logits, None, 2, norm_topk=False, scaling_factor=1.0)

@@ -4,7 +4,9 @@ per-row frontiers, trash-sink pad entries, sliding-window bands, bf16
 inputs, and the walk itself: frontiers at page and chunk edges, rows of
 one chunk and of several, pages it must never read (reserved past the
 frontier, or before the band) holding NaN, an idle row between long ones,
-and a flattened layer stack addressed as ``table + layer * NB``."""
+a flattened layer stack addressed as ``table + layer * NB``, and a pool whose
+heads are under the lane width, folded two to a row of 128 lanes (LFM2's
+head size 64: ``paged_kv.pool_lane_fold``)."""
 
 import jax
 import jax.numpy as jnp
@@ -117,12 +119,14 @@ def test_window_excludes_old_pages_exactly():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
-def _walk(curs, *, h, hkv, d, bs, mb, window=None, reserve=0, poison=(), layers=1, layer=0, dtype=jnp.float32):
+def _walk(curs, *, h, hkv, d, bs, mb, window=None, reserve=0, poison=(), layers=1, layer=0, dtype=jnp.float32, fold=1):
     """Rows with the frontiers ``curs`` (None: an idle row, frontier 0, every entry at the sink), each
     holding real blocks for ``reserve`` tokens past its frontier as the engine reserves prompt + max_new.
     ``poison`` fills with NaN what the kernel must never fold: ``"reserved"`` the blocks wholly past a
     frontier, ``"before_band"`` those wholly before the window's band. With ``layers`` > 1 the pool is a
     flattened stack and the rows address ``table + layer * NB``; the other layers hold NaN throughout.
+    With ``fold`` > 1 the kernel is handed the pools lane-folded, ``[NB, bs, hkv / fold, fold * d]`` (the
+    same bytes), as ``paged_kv.pool_lane_fold`` declares them; the reference reads them unfolded.
     Returns the kernel's output and the reference's."""
     b = len(curs)
     nb = b * mb + 1
@@ -153,11 +157,15 @@ def _walk(curs, *, h, hkv, d, bs, mb, window=None, reserve=0, poison=(), layers=
         kp = stack.at[layer].set(kp).reshape(layers * nb, bs, hkv, d)
         vp = stack.at[layer].set(vp).reshape(layers * nb, bs, hkv, d)
         tbl = tbl + layer * nb
+    if fold > 1:
+        kp, vp = (x.reshape(x.shape[0], bs, hkv // fold, fold * d) for x in (kp, vp))
     return paged_decode_attention(q, kp, vp, tbl, cur, sliding_window=window, interpret=True), want
 
 
 # float32 pages of 16 tokens x 2 heads are whole tiles: a chunk is 16 pages, 256 tokens
 CHUNKED = dict(h=4, hkv=2, d=32, bs=16, mb=40)
+
+HEAD64 = dict(h=32, hkv=8, d=64, bs=16, mb=40, fold=2)
 
 WALKS = [
     pytest.param([15, 16, 31, 32], CHUNKED, id="frontier-on-last-token-of-a-page-and-first-of-the-next"),
@@ -174,6 +182,12 @@ WALKS = [
     pytest.param([270, 17, None], dict(h=4, hkv=1, d=32, bs=8, mb=40), id="one-kv-head"),
     pytest.param([270, 17, None], dict(h=2, hkv=2, d=32, bs=16, mb=20), id="one-query-head-a-kv-head"),
     pytest.param([21, 9], dict(h=2, hkv=1, d=16, bs=4, mb=8, reserve=6, poison=("reserved",)), id="page-under-a-tile-takes-one-page-a-chunk"),
+    # head size 64 on 8 key/value heads (LFM2-8B-A1B), two heads to a row of 128 lanes
+    pytest.param([15, 16, 255, 256], HEAD64, id="head64-folded-frontiers-at-page-and-chunk-edges"),
+    pytest.param([300, None, 639], dict(HEAD64, reserve=200, poison=("reserved",)), id="head64-folded-idle-row-and-nan-past-the-frontier"),
+    pytest.param([400, 130], dict(HEAD64, window=200, poison=("before_band",)), id="head64-folded-band"),
+    pytest.param([270, 40], dict(HEAD64, layers=2, layer=1), id="head64-folded-second-layer-of-a-stack"),
+    pytest.param([70, 5], dict(h=8, hkv=4, d=32, bs=8, mb=12, fold=4), id="head32-folded-four-to-a-row"),
 ]
 
 
@@ -194,6 +208,25 @@ def test_cell_widths_bf16():
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want, np.float32), atol=2e-2, rtol=2e-2)
 
 
+def test_lfm2_cell_widths_bf16_folded():
+    """The ``lfm2-8b-a1b-serve-longanswer`` cell's widths (32 query heads over 8 key/value heads of 64,
+    pages of 16 tokens, bf16, the pool folded two heads to a row) at a tiny table; and the same pool
+    handed over unfolded gives the same numbers (interpreted: the chip's compiler refuses a minor axis of 64)."""
+    shape = dict(h=32, hkv=8, d=64, bs=16, mb=24, reserve=40, poison=("reserved",), dtype=jnp.bfloat16)
+    out, want = _walk([300, None, 47, 2303 % 384], fold=2, **shape)
+    assert out.dtype == jnp.bfloat16 and out.shape == (4, 32, 64) and np.isfinite(np.asarray(out, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want, np.float32), atol=2e-2, rtol=2e-2)
+    unfolded, _ = _walk([300, None, 47, 2303 % 384], **shape)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(unfolded, np.float32), atol=1e-2)
+
+
+def test_pool_is_folded_only_where_a_head_is_under_the_lane_width():
+    from accelerate_tpu.ops.paged_kv import pool_lane_fold
+
+    assert (pool_lane_fold(8, 64), pool_lane_fold(8, 128), pool_lane_fold(1, 128), pool_lane_fold(2, 16)) == (2, 1, 1, 1)
+    assert pool_lane_fold(8, 32) == 4 and pool_lane_fold(1, 64) == 1 and pool_lane_fold(8, 96) == 1
+
+
 def test_chunk_follows_from_the_shapes():
     """The chunk is the kernel's own business: pages of whole tiles stack to 256 tokens, within the
     buffers' VMEM; a page that is no whole tile (8 rows of 32 bits) goes one a chunk."""
@@ -201,3 +234,4 @@ def test_chunk_follows_from_the_shapes():
     assert _pages_per_chunk(128, 8, 128, jnp.bfloat16) == 2
     assert _pages_per_chunk(16, 32, 256, jnp.float32) == 2  # 512 KiB a page: VMEM bounds it, not the tokens
     assert _pages_per_chunk(4, 1, 16, jnp.float32) == 1 and _pages_per_chunk(8, 1, 128, jnp.bfloat16) == 1
+    assert _pages_per_chunk(16, 4, 128, jnp.bfloat16) == 16  # the lfm2 cell, folded: 1024 rows of 128 lanes

@@ -318,6 +318,87 @@ def test_hybrid_ssm_prefill_bucket_compiles_for_v5e(v5e):
     assert cache["layer_7"]["attn"]["key"].shape == (1, 2304, 1, 128)
 
 
+def test_lfm2_moe_decode_tick_fits_one_v5e_chip_and_copies_no_pool_or_state(v5e, monkeypatch):
+    """The 128-slot decode tick of the ``lfm2-8b-a1b-serve-longanswer`` cell at its real size: 5.40 B
+    parameters, four K/V pools of heads of 64 **folded two to a row of 128 lanes** (2.42 GB, not the 4.83
+    a padded minor axis of 64 would take; unfolded, Mosaic refuses the page slice) and 12 convolution
+    states of ``[128, 4096]`` as arguments, all aliased to the output; four ``paged_decode_attention`` and
+    14 x 2 grouped expert kernels a step at ``[2048, 1792]`` with row tile 64, and no operation that
+    copies or re-lays a pool or a state leaf whole. A compile is not a chip run."""
+    import contextlib
+    import re
+
+    from accelerate_tpu.ops.pallas_grouped_matmul import row_tile
+
+    s, engine = _cell_engine("lfm2-8b-a1b-l16")
+    chip = SingleDeviceSharding(v5e.devices[0])
+    raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernels
+    with contextlib.ExitStack() as stack:
+        for ctx in contexts:
+            stack.enter_context(ctx())
+        compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    slots, blocks, bs = s["num_slots"], s["pool_blocks"], s["paged_block_size"]
+    assert row_tile(slots * 4, 32) == 64
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l and "custom-call(" in l]
+    assert sum("paged_decode_attention" in l for l in calls) == 4
+    products = _expert_products(text)  # 14 expert layers of two grouped kernels; XLA's own 512-row lowering of none
+    assert len(products) == 28 and all("tpu_custom_call" in p for p in products), [p[:160] for p in products]
+    assert f"bf16[{blocks},{bs},4,128]" in text and f"bf16[{blocks},{bs},8,64]" not in text, "two heads of 64 to a row"
+    leaf = rf"(bf16\[{slots},4096\]|bf16\[{blocks},\d+,\d+(,\d+)?\])"
+    moved = [l.strip()[:160] for l in text.splitlines() if re.search(rf"= {leaf}\S* (copy|transpose)\(", l)]
+    assert not moved, "the tick copies or re-lays a pool or a state leaf:\n" + "\n".join(moved)
+    m = compiled.memory_analysis()
+    pool_bytes = 4 * 2 * blocks * bs * 8 * 64 * 2
+    state_bytes = 12 * slots * 4096 * 2
+    assert 2.41e9 < pool_bytes < 2.42e9 and m.alias_size_in_bytes >= pool_bytes + state_bytes
+    assert m.alias_size_in_bytes < pool_bytes + state_bytes + 2**20, "the pools are their logical bytes: no padded lanes"
+    assert m.argument_size_in_bytes > 10.79e9 + pool_bytes and m.temp_size_in_bytes < 0.5 * 2**30
+    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert total <= 12.6 * 2**30, f"the 128-slot tick needs {total / 2**30:.2f} GiB"
+
+
+def test_lfm2_moe_paste_row_scatters_into_the_pools_in_place(v5e):
+    """``paste_row`` of the same cell, 8 pools of ``[18433, 16, 4, 128]`` donated: a head axis of 4 is under a
+    sublane tile, and around a scatter of whole blocks the compiler re-laid every pool with the block's
+    token axis innermost and back (16 whole-pool copies, 15 ms a paste on the chip: PR 34's first trace);
+    through the flat view ``[NB, 64, 128]`` it scatters in place. Mistral's 8 heads and Jamba's one never did."""
+    import re
+
+    from accelerate_tpu.ops.paged_kv import paste_row
+
+    s, engine = _cell_engine("lfm2-8b-a1b-l16")
+    chip = SingleDeviceSharding(v5e.devices[0])
+    on = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)  # noqa: E731
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)  # noqa: E731
+    compiled = jax.jit(paste_row, donate_argnums=(0,)).lower(
+        on(engine.slot_caches), on(engine._row_template), i32(engine._mb), i32(engine._mb), i32(), i32()).compile()
+    text = compiled.as_text()
+    moved = [l.strip()[:160] for l in text.splitlines() if re.search(rf"= bf16\[{s['pool_blocks']},[^\]]*\]\S* (copy|transpose)\(", l)]
+    assert not moved, "paste_row copies or re-lays a pool:\n" + "\n".join(moved)
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 64 * 2**20 and m.alias_size_in_bytes > 2.41e9
+
+
+def test_lfm2_moe_prefill_bucket_compiles_for_v5e(v5e, monkeypatch):
+    """The 1024-token prefill of the same cell: 14 x 2 grouped kernels at row tile 128, temporaries far
+    under a GiB, and a row cache whose state leaves are one row of two gated inputs a convolution layer."""
+    _, engine = _cell_engine("lfm2-8b-a1b-l16")
+    chip = SingleDeviceSharding(v5e.devices[0])
+    prefill, prefill_args, _ = engine._perf_programs["prefill"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), prefill_args(1024))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(prefill).lower(*args).compile()
+    assert len(_expert_products(compiled.as_text())) == 28
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 0.75 * 2**30, f"the prefill's temporaries are {m.temp_size_in_bytes / 2**30:.2f} GiB"
+    cache = jax.eval_shape(prefill, *args)[2]
+    assert cache["layer_0"]["conv"]["conv_state"].shape == (1, 4096)
+    assert cache["layer_2"]["attn"]["key"].shape == (1, 2304, 8, 64)
+
+
 @pytest.mark.parametrize(
     "n_in,n_out,group",
     [(4096, 14336, 128), (14336, 4096, 128), (4096, 4096, 64)],
