@@ -645,12 +645,15 @@ class RoutedFFN(nn.Module):
     bias that chooses and does not weigh, normalised top-k weights times
     ``routed_scaling_factor``, no capacity (:mod:`accelerate_tpu.ops.moe`
     ``dropless_moe_ffn``). It sows the experts' load of each call into the
-    ``expert_load`` collection, for whoever makes that mutable."""
+    ``expert_load`` collection, for whoever makes that mutable.
+    ``row_valid`` (bool, ``hidden``'s leading axes) names the tokens that
+    count: the others reach no routed expert and get the shared expert
+    alone; None means every token."""
 
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, hidden):
+    def __call__(self, hidden, row_valid=None):
         from ..ops.moe import EXPERT_LOAD, dropless_moe_ffn, expert_load, sigmoid_topk_routing
 
         cfg = self.config
@@ -670,7 +673,9 @@ class RoutedFFN(nn.Module):
             experts, weights = sigmoid_topk_routing(
                 logits, bias, cfg.num_experts_per_tok, cfg.norm_topk_prob, cfg.routed_scaling_factor
             )
-        out, group_sizes = dropless_moe_ffn(flat, experts, weights, gate, up, down)
+        out, group_sizes = dropless_moe_ffn(
+            flat, experts, weights, gate, up, down, None if row_valid is None else row_valid.reshape(-1)
+        )
         if self.is_mutable_collection(EXPERT_LOAD):
             self.sow(EXPERT_LOAD, "counts", expert_load(group_sizes, experts.size))
         out = out.reshape(hidden.shape)
@@ -831,7 +836,7 @@ class LlamaLayer(nn.Module):
     mixer: str = "attention"  # ``LlamaConfig.mixer_kind`` of this layer: "attention" | "mamba" | "conv"
 
     @nn.compact
-    def __call__(self, hidden, positions, decode: bool = False, new_span=None):
+    def __call__(self, hidden, positions, decode: bool = False, new_span=None, row_valid=None):
         cfg = self.config
         attn_cls = LlamaAttention if cfg.kv_lora_rank is None else LatentAttention
 
@@ -848,7 +853,7 @@ class LlamaLayer(nn.Module):
 
         def mlp(x):
             if self.routed:
-                return RoutedFFN(cfg, name="mlp")(x)
+                return RoutedFFN(cfg, name="mlp")(x, row_valid)
             return LlamaMLP(cfg, name="mlp")(x)
 
         def norm(name):
@@ -892,7 +897,7 @@ class LlamaModel(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, input_ids, positions=None, decode: bool = False, new_span=None):
+    def __call__(self, input_ids, positions=None, decode: bool = False, new_span=None, row_valid=None):
         cfg = self.config
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens")
         hidden = embed(input_ids)
@@ -984,7 +989,7 @@ class LlamaModel(nn.Module):
                         overrides["rope_scaling"] = None
                     lcfg = dataclasses.replace(cfg, **overrides)
                 hidden = layer_cls(lcfg, routed and i >= n_lead, cfg.mixer_kind(i), name=f"layer_{i}")(
-                    hidden, positions, decode, new_span
+                    hidden, positions, decode, new_span, row_valid
                 )
         hidden = RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="final_norm")(hidden)
         if cfg.tie_word_embeddings:
@@ -1002,14 +1007,18 @@ class LlamaModel(nn.Module):
 
 
 def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> Model:
-    def apply_fn(p, input_ids, positions=None, decode=False, cache=None, state=None, new_span=None):
+    def apply_fn(p, input_ids, positions=None, decode=False, cache=None, state=None, new_span=None, row_valid=None):
         """decode=True threads the KV cache: pass ``cache`` (or None to
         initialise) and receive ``(logits, new_cache)``. ``state`` threads
         non-param collections (the fp8 amax histories): returns
         ``(logits, new_state)``. ``new_span`` ``(lo, hi)``: which of the
         window's tokens are new and real (a bucket's right pad and a chunk
         window's overlapped head are not); only a model whose layers keep
-        a recurrent state reads it, and None means every token."""
+        a recurrent state reads it, and None means every token.
+        ``row_valid`` (bool, the shape of ``input_ids``): the tokens that
+        count, a decode tick's slots in which a request decodes; only the
+        routed experts read it (the others' rows reach none of them), and
+        None means every token."""
         if decode:
             variables = {"params": p, **(state or {})}
             if cache is not None:
@@ -1023,7 +1032,7 @@ def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> 
             loads = requested_expert_load()
             if loads is not None:
                 mutable.append(EXPERT_LOAD)
-            logits, mutated = module.apply(variables, input_ids, positions, True, new_span, mutable=mutable)
+            logits, mutated = module.apply(variables, input_ids, positions, True, new_span, row_valid, mutable=mutable)
             if loads is not None:
                 loads.extend(jax.tree_util.tree_leaves(mutated.get(EXPERT_LOAD, {})))
             return logits, mutated["cache"]

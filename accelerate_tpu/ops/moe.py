@@ -217,6 +217,7 @@ def dropless_moe_ffn(
     wi_gate: jax.Array,  # [E, d, ff]
     wi_up: jax.Array,  # [E, d, ff]
     wo: jax.Array,  # [E, ff, d]
+    row_valid: Optional[jax.Array] = None,  # [T] bool: the tokens that count; None = every one
 ):
     """Routed SwiGLU experts with no capacity and no ``[T, E, C]`` mask:
     the ``T * k`` token-expert pairs are sorted by expert and the three
@@ -233,14 +234,24 @@ def dropless_moe_ffn(
     ``paged_kv.FORCE_KERNEL_INTERPRET`` runs the kernels interpreted
     (tests). Operands in ``x.dtype``, float32 accumulation, either way.
 
+    ``row_valid`` names the tokens that count (a decode tick's slots in
+    which a request decodes). The pairs of the others get the expert id
+    ``E``: they sort behind every group, no group counts them, so no
+    product visits them, no expert's weights are read for them alone, and
+    their rows of ``out`` are exactly zero. The rows that count are what
+    the call without the mask gives, bit for bit.
+
     Returns ``(out [T, d], group_sizes [E])``; ``group_sizes`` (pairs per
-    expert) is what :func:`expert_load` reads."""
+    expert, summing to the pairs of the tokens that count) is what
+    :func:`expert_load` reads."""
     from . import paged_kv
     from .attention import active_mesh
 
     t, k = experts.shape
     e = wi_gate.shape[0]
     flat = experts.reshape(t * k)
+    if row_valid is not None:
+        flat = jnp.where(jnp.repeat(row_valid, k), flat, e)  # behind the last group, and in none
     order = jnp.argsort(flat, stable=True)  # pairs grouped by expert
     group_sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
     on_tpu = jax.default_backend() == "tpu"
@@ -254,6 +265,9 @@ def dropless_moe_ffn(
         # back to token order by a gather (the inverse permutation), then the weighted sum
         back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k, dtype=order.dtype))
         ys = ys[back].reshape(t, k, -1)
+        if row_valid is not None:
+            # a row no product visited is whatever the kernel's output buffer held: zero before the sum (0 x NaN is NaN)
+            ys = jnp.where(row_valid[:, None, None], ys, 0)
         out = jnp.einsum("tkd,tk->td", ys.astype(jnp.float32), weights.astype(jnp.float32)).astype(x.dtype)
     return out, group_sizes
 
@@ -264,13 +278,14 @@ _LOAD_COUNTS: Optional[list] = None
 
 def expert_load(group_sizes: jax.Array, pairs: int) -> jax.Array:
     """``[distinct experts with a pair, most pairs on one expert, (expert, row tile) visits of one
-    product]`` (``[3]`` int32) of one routed FFN call over ``pairs = T * k`` pairs. The third is the
-    grid of the grouped kernel (:mod:`.pallas_grouped_matmul`) at the row tile that pair count gives:
-    over the first it says how many row tiles an expert's pairs lie in, 1.0 where none straddles."""
+    product, pairs the products multiplied]`` (``[4]`` int32) of one routed FFN call over ``pairs = T * k``
+    sorted rows. The third is the grid of the grouped kernel (:mod:`.pallas_grouped_matmul`) at the row
+    tile that row count gives: over the first it says how many row tiles an expert's pairs lie in, 1.0
+    where none straddles. The fourth is ``pairs`` less the pairs of the tokens ``row_valid`` left out."""
     from .pallas_grouped_matmul import row_tile, tile_visits
 
     visits = tile_visits(group_sizes, row_tile(pairs, group_sizes.shape[0]))
-    return jnp.stack([jnp.sum(group_sizes > 0), group_sizes.max(), jnp.sum(visits)])
+    return jnp.stack([jnp.sum(group_sizes > 0), group_sizes.max(), jnp.sum(visits), jnp.sum(group_sizes)])
 
 
 @contextlib.contextmanager

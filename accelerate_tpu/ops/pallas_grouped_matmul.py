@@ -64,7 +64,11 @@ def visit_plan(group_sizes: jax.Array, rows: int, tile: int):
     visits`` multiplies row tile ``tile_of[v]`` by group ``group_of[v]``,
     groups in order and a group's tiles in order. ``V = rows / tile + E - 1``
     bounds the visits (every group but the first can straddle into one
-    tile more); entries past ``visits`` repeat the last visit."""
+    tile more); entries past ``visits`` repeat the last visit. Rows past
+    the last group (``group_sizes`` may sum to less than ``rows``) lie in
+    no visit; with no grouped row at all ``visits`` is 0, the grid has no
+    step and the entries name nothing (the serving engine runs no tick in
+    which no slot decodes)."""
     e = group_sizes.shape[0]
     per_group = tile_visits(group_sizes, tile)
     first = jnp.cumsum(per_group) - per_group  # the first visit of each group
@@ -137,13 +141,19 @@ def grouped_swiglu_ffn(
     wi_gate: jax.Array,  # [E, d, ff]
     wi_up: jax.Array,  # [E, d, ff]
     wo: jax.Array,  # [E, ff, d]
-    group_sizes: jax.Array,  # [E] int32, summing to m: every row belongs to a group
+    group_sizes: jax.Array,  # [E] int32, summing to m or less: the rows past the last group belong to none
     *,
     interpret: bool = False,
 ) -> jax.Array:
     """``(silu(xs @ gate[g]) * (xs @ up[g])) @ down[g]`` for the rows of each
     group ``g``: ``[m, d]`` in ``xs.dtype``. What three
-    ``jax.lax.ragged_dot`` calls give, in two kernels."""
+    ``jax.lax.ragged_dot`` calls give, in two kernels, for the rows of a
+    group. ``group_sizes`` may sum to less than ``m`` (``ops.moe``
+    sorts the pairs of tokens that do not count behind every group): the
+    rows past the last group are never visited and never stored, so a
+    tile that holds none but them costs no grid step, and those rows of
+    the result are whatever the output buffer held (``ragged_dot`` gives
+    zeros there): the caller discards them."""
     m, _ = xs.shape
     e = wi_gate.shape[0]
     tile = row_tile(m, e)

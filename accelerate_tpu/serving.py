@@ -352,6 +352,10 @@ class ServingEngine:
         # block pool + per-slot block tables (ops/paged_kv.py) — same
         # decode roofline, pool capacity decoupled from slots x max_len.
         self.paged = paged_block_size is not None
+        # the paged decode tick of a model with routed experts is told which slots decode (one ``[slots]`` bool
+        # argument more: ``_decoding_arg``): the stale token of every other slot reaches no expert. No other
+        # program takes it (the dense tick is a ``vmap`` of one slot's step, which cannot run routed experts)
+        self._mask_idle_rows = self.paged and getattr(model.config, "n_routed_experts", None) is not None
         if self.paged:
             from .ops.paged_kv import BlockAllocator, PagedConfig, paged_mode
 
@@ -568,26 +572,27 @@ class ServingEngine:
             jax.random.key(seed), jnp.arange(num_slots)
         )
 
-        self._tick_expert_load = (0, 0, 0)
+        self._tick_expert_load = (0, 0, 0, 0)
         self._tick_state_idle = 0
 
         def make_tick(step_body):
             """K-step tick scaffold shared by both cache layouts:
-            ``step_body(params, caches, toks, poss, keys) -> (caches,
-            next_toks, logprobs, keys, load)`` advances every slot one
-            token; ``load`` is None, or the routed experts' counts of the
-            step (``[expert layers, 3]``, ops/moe.py ``expert_load_counts``)."""
+            ``step_body(params, caches, toks, poss, keys, *decoding) ->
+            (caches, next_toks, logprobs, keys, load)`` advances every slot
+            one token; ``load`` is None, or the routed experts' counts of the
+            step (``[expert layers, 4]``, ops/moe.py ``expert_load_counts``).
+            ``decoding`` (:meth:`_decoding_arg`) is the same in every step."""
 
-            def decode_tick(params, slot_caches, toks, poss, keys):
+            def decode_tick(params, slot_caches, toks, poss, keys, *decoding):
                 def block_step(carry, _):
                     caches, toks, poss, keys = carry
-                    caches, nxt, lps, keys, load = step_body(params, caches, toks, poss, keys)
+                    caches, nxt, lps, keys, load = step_body(params, caches, toks, poss, keys, *decoding)
                     return (caches, nxt, poss + 1, keys), (nxt, lps, load)
 
                 (slot_caches, _, _, keys), (toks_k, lps_k, load_k) = jax.lax.scan(
                     block_step, (slot_caches, toks, poss, keys), None, length=tick_block
                 )
-                return slot_caches, toks_k, lps_k, keys, load_k  # each [K, slots]; load_k [K, layers, 3] or None
+                return slot_caches, toks_k, lps_k, keys, load_k  # each [K, slots]; load_k [K, layers, 4] or None
 
             return decode_tick
 
@@ -598,11 +603,12 @@ class ServingEngine:
             # so outputs stay token-exact across layouts.
             from .ops.moe import expert_load_counts
 
-            def paged_step(params, cache, toks, poss, keys):
+            def paged_step(params, cache, toks, poss, keys, decoding=None):
+                rows = {} if decoding is None else {"row_valid": decoding[:, None]}
                 # one program sees the whole batch, so routed experts can count their step's load
                 with expert_load_counts() as loads:
                     logits, cache = apply_fn(
-                        params, toks[:, None], positions=poss[:, None], decode=True, cache=cache
+                        params, toks[:, None], positions=poss[:, None], decode=True, cache=cache, **rows
                     )
                 split = jax.vmap(jax.random.split)(keys)
                 keys, subs = split[:, 0], split[:, 1]
@@ -639,7 +645,9 @@ class ServingEngine:
             self._decode_tick = decode_tick
             self._perf_programs["decode_tick"] = (
                 raw_tick,
-                lambda b: (params, self.slot_caches, self.slot_tok, self.slot_pos, self._slot_keys),
+                lambda b: (
+                    params, self.slot_caches, self.slot_tok, self.slot_pos, self._slot_keys, *self._decoding_arg()
+                ),
                 (lambda: paged_mode(pcfg), self._trace_ctx),
             )
             self._paste = ctx_jit(paste_row, donate_argnums=(0,))
@@ -1374,7 +1382,7 @@ class ServingEngine:
         m = self.metrics
         admitted, tokens_was, completed_was = 0, m.tokens_generated, m.requests_completed
         self._tick_prefill_tokens = 0
-        self._tick_expert_load = (0, 0, 0)
+        self._tick_expert_load = (0, 0, 0, 0)
         self._tick_state_idle = 0
         with phase("engine.schedule"):
             now = time.monotonic()
@@ -1429,7 +1437,7 @@ class ServingEngine:
             pool_blocked=int(self._pool_blocked), free_blocks=self._alloc.free_count if self.paged else -1,
             queue_len=len(self.queue), experts_touched=self._tick_expert_load[0],
             expert_pairs_max=self._tick_expert_load[1], expert_tile_visits=self._tick_expert_load[2],
-            state_slots_idle=self._tick_state_idle,
+            expert_pairs=self._tick_expert_load[3], state_slots_idle=self._tick_state_idle,
         ):
             pass
 
@@ -1747,26 +1755,27 @@ class ServingEngine:
         caches are fully replaced at prefill paste/insert."""
         crash_point("mid_decode", replica=self.metrics.replica)
         jnp = _jax().numpy
-        decoding = [ph == "decode" for ph in self.slot_phase]
+        decoding = self._decoding_slots()
+        n_decoding = int(decoding.sum())
         with phase(
-            "engine.decode.dispatch", decoding=sum(decoding), tick_block=self.tick_block,
+            "engine.decode.dispatch", decoding=n_decoding, tick_block=self.tick_block,
             live_tokens=int(self.slot_pos[decoding].sum()),
         ):
             self.slot_caches, toks_k, lps_k, self._slot_keys, load_k = self._decode_tick(
                 self.model.params, self.slot_caches,
-                jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos), self._slot_keys
+                jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos), self._slot_keys, *self._decoding_arg(decoding)
             )
         if self._has_state:
             # the tick steps every slot's recurrent state; this many slot-steps of it decode nothing
-            self._tick_state_idle = (self.num_slots - sum(decoding)) * self.tick_block
+            self._tick_state_idle = (self.num_slots - n_decoding) * self.tick_block
             self.metrics.on_state_step(self._tick_state_idle)
         with phase("engine.decode.sync"):
             toks_k = np.asarray(toks_k)  # [K, slots] — ONE host sync per block
             lps_k = np.asarray(lps_k)
             if load_k is not None:
                 load_k = np.asarray(load_k)
-                touched, most, visits = load_k[..., 0], load_k[..., 1], load_k[..., 2]
-                self._tick_expert_load = (int(touched.sum()), int(most.max()), int(visits.sum()))
+                touched, most, visits, pairs = (load_k[..., i] for i in range(4))
+                self._tick_expert_load = (int(touched.sum()), int(most.max()), int(visits.sum()), int(pairs.sum()))
                 self.metrics.on_expert_load(*self._tick_expert_load)
         with phase("engine.decode.walk"):
             for slot, req in enumerate(self.slot_req):
@@ -1790,6 +1799,19 @@ class ServingEngine:
                         self.tracer.window(req.trace, "decode", tokens=n_new)
                 if retired:
                     self._retire(slot)
+
+    def _decoding_slots(self) -> np.ndarray:
+        """``[slots]`` bool: the slots in which a request decodes. (A numpy array: ``jnp.asarray`` of a
+        Python list weighs every element's type, 0.7 ms a tick at 64 slots.)"""
+        return np.asarray([ph == "decode" for ph in self.slot_phase])
+
+    def _decoding_arg(self, decoding: Optional[np.ndarray] = None) -> tuple:
+        """The decode tick's last argument: ``([slots] bool,)``, :meth:`_decoding_slots` on the device, for
+        the paged tick of a model with routed experts (its ``RoutedFFN`` layers multiply those slots' rows
+        alone); ``()`` for every other tick, which takes no such argument."""
+        if not self._mask_idle_rows:
+            return ()
+        return (_jax().numpy.asarray(self._decoding_slots() if decoding is None else decoding),)
 
     def _expire_window_blocks(self) -> None:
         """Sliding-window models: expire blocks the band can no longer

@@ -127,10 +127,14 @@ class ServingMetrics:
         # summed over expert layers and decode steps, the most tokens any
         # one expert got in a step, and the (expert, row tile) visits of one
         # grouped product, summed likewise: over experts_touched, how many
-        # row tiles an expert's tokens lie in
+        # row tiles an expert's tokens lie in; and the token-expert pairs the
+        # grouped products multiplied, summed likewise: the decoding slots'
+        # alone (slots x experts a token x expert layers x steps less this
+        # is what the tick's row mask kept from the experts)
         self.experts_touched = 0
         self.expert_pairs_max = 0
         self.expert_tile_visits = 0
+        self.expert_pairs = 0
         # recurrent state (models with state-space layers; 0 otherwise): the
         # bytes of ssm_state + conv_state one slot holds over all layers, and
         # the slot-steps of it the decode ticks spent on slots in which no
@@ -199,10 +203,11 @@ class ServingMetrics:
     def on_state_step(self, slots_idle: int):
         self.state_slots_idle += slots_idle
 
-    def on_expert_load(self, touched: int, pairs_max: int, tile_visits: int):
+    def on_expert_load(self, touched: int, pairs_max: int, tile_visits: int, pairs: int):
         self.experts_touched += touched
         self.expert_pairs_max = max(self.expert_pairs_max, pairs_max)
         self.expert_tile_visits += tile_visits
+        self.expert_pairs += pairs
 
     def on_tick_tokens(self, uid: int, n: int):
         """ITL sample: ``n`` tokens delivered to ``uid`` this tick."""

@@ -71,8 +71,11 @@ SEGMENTS = (
 #: program phase -> the PERF.md layer it belongs to. One ``engine.tick``
 #: is tiled by its children (``engine.tick.done`` is a zero-length
 #: marker that carries the tick's counts: admissions, tokens, the pool,
-#: the routed experts' load and ``state_slots_idle``, the slot-steps of
-#: recurrent state the tick spent on slots in which no request decodes);
+#: the routed experts' load (``experts_touched``, ``expert_pairs_max``,
+#: ``expert_tile_visits`` and ``expert_pairs``, the token-expert pairs
+#: the grouped products multiplied: the decoding slots' alone) and
+#: ``state_slots_idle``, the slot-steps of recurrent state the tick
+#: spent on slots in which no request decodes);
 #: the spans of one request share ``uid``. No name equals a span of the
 #: benchmark's own. Inside the programs, ``jax.named_scope`` names ride
 #: in the device operations' ``op_name``: ``moe.*``, ``mla.absorb``, and

@@ -73,6 +73,67 @@ def test_dropless_ffn_is_the_expert_loop(case, kernel, monkeypatch):
     np.testing.assert_allclose(np.asarray(out), _expert_loop(x, experts, weights, gate, up, down), atol=1e-5)
 
 
+def _row_mask(masked, t):
+    """``[t]`` bool: the tokens that count."""
+    if masked == "none":
+        return np.ones(t, bool)
+    if masked == "all":
+        return np.zeros(t, bool)
+    return np.arange(t) % 5 == 1  # "some": one in five counts, as a dozen of a tick's 64 slots decode
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["ragged_dot", "pallas_interpreted"])
+@pytest.mark.parametrize("masked", ["none", "some", "all"])
+def test_row_valid_keeps_the_rows_that_count_and_sends_the_others_to_no_expert(masked, kernel, monkeypatch):
+    """A decode tick's shape: 40 tokens of which those that do not count are copies of one row (a free
+    slot's token 0 at position 0) and route alike. The rows that count are the unmasked call's bit for bit,
+    the others exactly zero, no group counts a pair of theirs, and the products visit no tile for them."""
+    from accelerate_tpu.ops.moe import expert_load
+
+    monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", kernel)
+    gate, up, down, key = _experts(2)
+    t, k = 40, 2
+    valid = _row_mask(masked, t)
+    x = jnp.where(valid[:, None], jax.random.normal(key, (t, 16)), jnp.full((16,), 0.7))
+    logits = x @ jax.random.normal(jax.random.key(5), (16, 8))
+    experts, weights = sigmoid_topk_routing(logits, None, k)
+    full, full_sizes = dropless_moe_ffn(x, experts, weights, gate, up, down)
+    out, sizes = dropless_moe_ffn(x, experts, weights, gate, up, down, jnp.asarray(valid))
+    out = np.asarray(out)
+    assert np.array_equal(out[valid], np.asarray(full)[valid])
+    assert np.isfinite(out).all() and not out[~valid].any()
+    assert int(sizes.sum()) == k * int(valid.sum()) and int(full_sizes.sum()) == k * t
+    live = np.asarray(experts)[valid].reshape(-1)
+    assert np.array_equal(np.asarray(sizes), np.bincount(live, minlength=8))
+    touched, _, visits, pairs = np.asarray(expert_load(sizes, t * k)).tolist()
+    assert pairs == k * int(valid.sum()) and touched == len(set(live.tolist()))
+    assert (visits == 0) == (masked == "all") and visits <= np.asarray(expert_load(full_sizes, t * k))[2]
+
+
+@pytest.mark.parametrize("sizes", [[3, 0, 5, 0, 0, 2, 0, 0], [0, 0, 0, 20, 0, 0, 1, 0], [0] * 8, [0] * 7 + [64]],
+                         ids=["ten_of_64", "21_of_64_straddling", "none", "every_row"])
+def test_visit_plan_never_reaches_rows_past_the_last_group(sizes):
+    """``group_sizes`` may sum to less than the rows (the pairs of tokens that do not count lie behind
+    every group): the plan names the tiles that hold a grouped row and no other, a sum of zero makes no
+    visit, and the kernels leave the grouped rows what ``ragged_dot`` makes them."""
+    from accelerate_tpu.ops.moe import _ragged_swiglu_ffn
+    from accelerate_tpu.ops.pallas_grouped_matmul import grouped_swiglu_ffn, visit_plan
+
+    rows, tile, grouped = 64, 16, sum(sizes)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    offsets, group_of, tile_of, n = visit_plan(group_sizes, rows, tile)
+    walked = list(zip(np.asarray(group_of)[:int(n)].tolist(), np.asarray(tile_of)[:int(n)].tolist()))
+    owner = np.repeat(np.arange(8), sizes)  # the group of each grouped row
+    assert walked == sorted({(int(g), i // tile) for i, g in enumerate(owner)})
+    assert int(offsets[-1]) == grouped and all(til * tile < grouped for _, til in walked)
+    gate, up, down, key = _experts(3)
+    xs = jax.random.normal(key, (rows, 16))
+    got = grouped_swiglu_ffn(xs, gate, up, down, group_sizes, interpret=True)
+    want = _ragged_swiglu_ffn(xs, gate, up, down, group_sizes)
+    np.testing.assert_allclose(np.asarray(got)[:grouped], np.asarray(want)[:grouped], atol=1e-5, rtol=1e-5)
+    assert not np.asarray(want)[grouped:].any(), "off the chip a row in no group is zero"
+
+
 def test_kernel_path_differentiates_as_ragged_dot_does(monkeypatch):
     """The kernel path's ``custom_vjp`` hands back the ``ragged_dot`` formulation's gradients, for the
     rows and for each of the three stacks."""
@@ -124,8 +185,9 @@ def test_expert_tile_visits_is_the_kernels_grid(case):
     assert tile == (64 if case == "one_expert_many_tiles" else 16)
     want = sorted({(int(g), i // tile) for i, g in enumerate(flat)})
     sizes = jnp.asarray(np.bincount(flat, minlength=8), jnp.int32)
-    touched, most, visits = np.asarray(expert_load(sizes, flat.size)).tolist()
+    touched, most, visits, pairs = np.asarray(expert_load(sizes, flat.size)).tolist()
     assert (touched, most, visits) == (len(set(flat.tolist())), int(np.bincount(flat).max()), len(want))
+    assert pairs == flat.size
     rows = -(-flat.size // tile) * tile
     _, group_of, tile_of, n = visit_plan(sizes, rows, tile)
     walked = list(zip(np.asarray(group_of)[:n].tolist(), np.asarray(tile_of)[:n].tolist()))
@@ -230,6 +292,15 @@ def test_warm_chunk_window_is_the_cold_prefill(model):
     np.testing.assert_allclose(np.asarray(warm), np.asarray(cold[:, 8:]), atol=2e-5)
 
 
+def _greedy_gap(model, prompt, out, n):
+    """How far the logit of each of the ``n`` served tokens lies under the plain forward's best."""
+    out = np.asarray(out)
+    ref = np.asarray(model.apply_fn(model.params, jnp.asarray(out[None])))[0, len(prompt) - 1:-1]
+    served = out[len(prompt):]
+    assert len(served) == n
+    return (ref.max(-1) - ref[np.arange(n), served]).max()
+
+
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla_gather", "pallas_interpreted"])
 def test_engine_serves_from_the_paged_latent_cache(model, kernel, monkeypatch):
     """Bucketed prefill, paste into the latent pool, decode ticks, a prompt over the largest bucket
@@ -242,11 +313,7 @@ def test_engine_serves_from_the_paged_latent_cache(model, kernel, monkeypatch):
     prompts = [np.arange(1, 6, dtype=np.int32), np.arange(3, 17, dtype=np.int32), np.arange(7, 10, dtype=np.int32),
                np.arange(2, 30, dtype=np.int32)]
     for prompt, out in zip(prompts, engine.generate_many(prompts, max_new_tokens=9)):
-        out = np.asarray(out)
-        ref = np.asarray(model.apply_fn(model.params, jnp.asarray(out[None])))[0, len(prompt) - 1:-1]
-        served = out[len(prompt):]
-        assert len(served) == 9
-        assert (ref.max(-1) - ref[np.arange(9), served]).max() < 1e-4
+        assert _greedy_gap(model, prompt, out, 9) < 1e-4
     m = engine.metrics
     layers = model.config.num_hidden_layers - 1
     assert 0 < m.expert_pairs_max <= 3 * 2 and m.experts_touched > 0
@@ -255,7 +322,7 @@ def test_engine_serves_from_the_paged_latent_cache(model, kernel, monkeypatch):
 
 
 def test_expert_load_counts_are_a_ticks(model):
-    """The counts leave the tick beside its tokens (``[steps, expert layers, 3]``), and the cache holds
+    """The counts leave the tick beside its tokens (``[steps, expert layers, 4]``), and the cache holds
     nothing but rows, tables and frontiers: no cache program has to know of them."""
     engine = ServingEngine(model, num_slots=2, prompt_buckets=(8,), max_len=32, paged_block_size=8, tick_block=2)
     names = {str(p[-1].key) for p, _ in jax.tree_util.tree_flatten_with_path(engine.slot_caches)[0]}
@@ -266,23 +333,74 @@ def test_expert_load_counts_are_a_ticks(model):
         engine.step()
         seen.append(engine._tick_expert_load)
     layers = model.config.num_hidden_layers - 1
-    # two slots (one idle) route a token each in each of 2 steps of each expert layer: 2..4 experts a layer a step
-    assert all(2 * layers * 2 <= touched <= 2 * layers * 4 and 1 <= most <= 2 for touched, most, _ in seen)
-    # 4 pairs a call lie in one 16-row tile: an expert's pairs lie in one tile each, visits == experts touched
-    assert all(visits == touched for touched, _, visits in seen)
-    assert engine.metrics.experts_touched == sum(touched for touched, _, _ in seen)
-    assert engine.metrics.expert_tile_visits == sum(visits for _, _, visits in seen)
+    # of two slots one decodes, and its token alone is routed: 2 experts in each of 2 steps of each expert layer
+    assert all((touched, most, pairs) == (2 * layers * 2, 1, 2 * layers * 2) for touched, most, _, pairs in seen)
+    # 2 pairs a call lie in one 16-row tile: an expert's pairs lie in one tile each, visits == experts touched
+    assert all(visits == touched for touched, _, visits, _ in seen)
+    assert engine.metrics.experts_touched == sum(touched for touched, _, _, _ in seen)
+    assert engine.metrics.expert_tile_visits == sum(visits for _, _, visits, _ in seen)
+    assert engine.metrics.expert_pairs == sum(pairs for _, _, _, pairs in seen)
+
+
+def test_idle_slots_reach_no_expert_and_the_decoding_slots_tokens_are_the_forwards(model):
+    """Five slots, three requests of unequal answers: slots fall idle one by one between ticks while
+    others decode on. Every served token is the plain forward's greedy token, a tick with one decoding
+    slot touches at most ``num_experts_per_tok`` experts a layer a step, and ``expert_pairs`` is the
+    decoding slots' pairs alone: what the mask kept from the experts is the rest of slots x k."""
+    engine = ServingEngine(model, num_slots=5, prompt_buckets=(8, 16), max_len=64, paged_block_size=8, tick_block=2)
+    cfg = model.config
+    k, layers = cfg.num_experts_per_tok, cfg.num_hidden_layers - cfg.first_k_dense_replace
+    prompts = [np.arange(1, 6, dtype=np.int32), np.arange(3, 17, dtype=np.int32), np.arange(7, 10, dtype=np.int32)]
+    uids = [engine.submit(p, max_new_tokens=n) for p, n in zip(prompts, (4, 9, 15))]
+    seen = []
+    while engine.queue or engine.active_count:
+        decoding = sum(ph == "decode" for ph in engine.slot_phase)  # before the tick: admissions join inside it
+        engine.step()
+        seen.append((decoding, *engine._tick_expert_load))
+    for uid, prompt, n in zip(uids, prompts, (4, 9, 15)):
+        assert _greedy_gap(model, prompt, engine.done[uid], n) < 1e-4
+    steps = engine.tick_block
+    ticks = [row for row in seen[1:] if row[0]]  # the first tick admits all three: its decoding slots were none before it
+    assert {d for d, *_ in ticks} >= {1, 2, 3}
+    for decoding, touched, most, visits, pairs in ticks:
+        assert pairs == decoding * k * layers * steps < engine.num_slots * k * layers * steps
+        assert touched <= pairs and most <= decoding and visits == touched  # 16-row tile: no group straddles
+    assert [row for row in ticks if row[0] == 1 and row[1] <= k * layers * steps]
+    assert engine.metrics.expert_pairs == sum(row[4] for row in seen)
+
+
+def test_expert_pairs_is_a_count_of_engine_tick_done(model, tmp_path):
+    """Under a profiler session the zero-length ``engine.tick.done`` carries ``expert_pairs`` beside the
+    other counts of the routed experts' load, and they sum to ``ServingMetrics``'."""
+    from chipbench import program_trace, trace
+    from chipbench.generators import open_loop_rounds
+
+    engine = ServingEngine(model, num_slots=3, prompt_buckets=(8,), max_len=32, paged_block_size=8, tick_block=2)
+    engine.generate_many([np.arange(1, 6, dtype=np.int32)], max_new_tokens=3)  # compiled before the session opens
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        was = engine.metrics.expert_pairs
+        engine.generate_many([np.arange(2, 8, dtype=np.int32), np.arange(4, 7, dtype=np.int32)], max_new_tokens=5)
+    finally:
+        jax.profiler.stop_trace()
+    phases = program_trace.reduce(program_trace.load(trace.newest_xplane(str(tmp_path)), ("window",) + open_loop_rounds.SPANS))
+    done = [phases["spans"][i]["stats"] for i in program_trace.named(phases, "engine.tick.done")]
+    assert done and all({"experts_touched", "expert_pairs_max", "expert_tile_visits", "expert_pairs"} <= set(d) for d in done)
+    assert sum(d["expert_pairs"] for d in done) == engine.metrics.expert_pairs - was > 0
+    assert all(d["experts_touched"] <= d["expert_pairs"] for d in done)
 
 
 def test_expert_load_is_collected_only_inside_its_context(model):
     """Through ``nn.remat`` too (the toy model rematerialises its layers): the counts are sown, not leaked."""
     from accelerate_tpu.ops.moe import expert_load, expert_load_counts
 
-    assert np.asarray(expert_load(jnp.array([4, 0, 1, 2, 0]), 7)).tolist() == [3, 4, 3]
+    assert np.asarray(expert_load(jnp.array([4, 0, 1, 2, 0]), 7)).tolist() == [3, 4, 3, 7]
     ids, pos = jnp.asarray([[5, 9, 5]]), jnp.arange(3)[None]
     with expert_load_counts() as loads:
         jax.jit(lambda p: model.apply_fn(p, ids, positions=pos, decode=True, cache=None)[0])(model.params)
-        assert len(loads) == model.config.num_hidden_layers - 1 and all(l.shape == (3,) for l in loads)
+        assert len(loads) == model.config.num_hidden_layers - 1 and all(l.shape == (4,) for l in loads)
     _, cache = model.apply_fn(model.params, ids, positions=pos, decode=True, cache=None)
     assert len(loads) == 2 and "expert_load" not in cache
 

@@ -564,22 +564,69 @@ def _scans(jaxpr):
             yield from _scans(sub)
 
 
+def _tick_jaxpr(eng):
+    """The engine's decode tick traced with the arguments the engine builds for it: ``(jaxpr, arguments)``."""
+    import contextlib
+
+    import jax
+
+    raw_tick, tick_args, contexts = eng._perf_programs["decode_tick"]
+    args = tick_args(None)
+    with contextlib.ExitStack() as stack:
+        for ctx in contexts:
+            stack.enter_context(ctx())
+        return jax.make_jaxpr(raw_tick)(*args).jaxpr, args
+
+
+@pytest.mark.parametrize("family", ["dense_unrolled", "dense_scanned", "state_space", "routed_experts"])
+def test_only_a_routed_models_tick_is_told_which_slots_decode(tiny_models, family):
+    """The tick of a dense model and of one with state-space layers is the program it was before the
+    routed experts' row mask: five arguments, no boolean enters it, and the model's ``apply_fn`` is called
+    with the keywords it always was. A model with routed experts gets one ``[slots]`` bool more, handed
+    down as ``row_valid``. The choice follows the config's ``n_routed_experts`` alone."""
+    import copy
+
+    import jax.numpy as jnp
+
+    if family == "state_space":
+        from accelerate_tpu.models.jamba import JambaConfig, create_jamba_model
+
+        model = create_jamba_model(JambaConfig.tiny(), seed=3, seq_len=16)
+    elif family == "routed_experts":
+        from accelerate_tpu.models.joyai_llm_flash import JoyAIFlashConfig, create_joyai_flash_model
+
+        model = create_joyai_flash_model(JoyAIFlashConfig.tiny(), seed=3, seq_len=16)
+    else:
+        model = tiny_models(family == "dense_scanned")
+    routed = family == "routed_experts"
+    assert (getattr(model.config, "n_routed_experts", None) is not None) == routed
+    keywords = []
+
+    def spy(*args, **kwargs):
+        keywords.append(set(kwargs))
+        return model.apply_fn(*args, **kwargs)
+
+    spied = copy.copy(model)
+    spied.apply_fn = spy
+    eng = ServingEngine(spied, num_slots=3, prompt_buckets=(8,), paged_block_size=4, tick_block=2)
+    keywords.clear()
+    jaxpr, args = _tick_jaxpr(eng)
+    bools = [v.aval.shape for v in jaxpr.invars if v.aval.dtype == jnp.bool_]
+    assert keywords == [{"positions", "decode", "cache", "row_valid"} if routed else {"positions", "decode", "cache"}]
+    assert len(args) == (6 if routed else 5) and len(eng._decoding_arg()) == int(routed)
+    assert bools == ([(3,)] if routed else [])
+    if routed:
+        assert args[-1].dtype == jnp.bool_ and not args[-1].any(), "no slot decodes in a fresh engine"
+
+
 @pytest.mark.parametrize("scan_layers", [True, False], ids=["scan", "unrolled"])
 def test_pools_are_loop_state_in_the_tick_never_scanned_over(tiny_models, scan_layers):
     """On the tick's jaxpr: whatever has the shape of a layer's pool or of
     the stack enters and leaves every loop as carry, never as a scanned
     input (a slice per iteration) or a stacked output (a second stack)."""
-    import contextlib
-
-    import jax
-
     model = tiny_models(scan_layers)
     eng = ServingEngine(model, num_slots=2, prompt_buckets=(8,), paged_block_size=4, tick_block=2)
-    raw_tick, tick_args, contexts = eng._perf_programs["decode_tick"]
-    with contextlib.ExitStack() as stack:
-        for ctx in contexts:
-            stack.enter_context(ctx())
-        jaxpr = jax.make_jaxpr(raw_tick)(*tick_args(None)).jaxpr
+    jaxpr, _ = _tick_jaxpr(eng)
     cfg, pcfg = model.config, eng._pcfg
     block = (pcfg.block_size, cfg.num_key_value_heads, cfg.hidden_size // cfg.num_attention_heads)
 
