@@ -46,3 +46,8 @@ from .serving import ServingEngine  # noqa: E402
 from .serving_fleet import FleetConfig, FleetRouter, RadixPrefixCache  # noqa: E402
 from .speculative import speculative_generate  # noqa: E402
 from .launchers import debug_launcher, notebook_launcher  # noqa: E402
+
+# the phase log hears jax's compile events from here on (telemetry/trace.py)
+from .telemetry.trace import phase_log as _phase_log  # noqa: E402
+
+_phase_log().listen()

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import contextlib
 import os
-import time
 from typing import Any, Callable, Optional
 
 import numpy as np
@@ -1822,7 +1821,7 @@ class Accelerator:
             self.gradient_state._set_sync_gradients(do_sync)
             from .utils.random import key_for_step
 
-            with phase("train.step", step=self.step, do_sync=int(do_sync), mono_ns=time.monotonic_ns()):
+            with phase("train.step", step=self.step, do_sync=int(do_sync)):  # a root: phase() adds its ``mono_ns``
                 with phase("train.step.args"):
                     sync_arg = bool(do_sync) if (offload_push is not None or zero_layout is not None) else jnp.bool_(do_sync)
                     rng = key_for_step(self.step)

@@ -33,6 +33,7 @@ import os
 import time
 from typing import Callable, Optional
 
+from ..telemetry.trace import phase, phase_log
 from .cache import (
     CorruptEntryError,
     ExecutableStore,
@@ -115,19 +116,30 @@ class ProgramCache:
             jit_kwargs["donate_argnums"] = tuple(donate_argnums)
         if static_argnums:
             jit_kwargs["static_argnums"] = tuple(static_argnums)
-        lowered = jax.jit(fn, **jit_kwargs).lower(*avals)
+        with phase("program.lower", program=name):
+            lowered = jax.jit(fn, **jit_kwargs).lower(*avals)
         return self.compile_lowered(lowered, name=name, key_salt=key_salt)
 
     def compile_lowered(self, lowered, name: str = "program", key_salt=()):
         """Memory -> disk -> compile for an already-lowered program.
         Returns the loaded executable; never returns a stale or corrupt
-        deserialization (those entries are deleted and recompiled)."""
+        deserialization (those entries are deleted and recompiled). The
+        whole of it, from the key's hash to the store's write, is one
+        ``program.load`` span of the phase log, with the ``source`` the
+        executable came from and its bytes."""
+        with phase("program.load", program=name) as span:
+            compiled, came_from = self._fetch_or_compile(lowered, name, key_salt)
+            span.counts.update(came_from)
+        return compiled
+
+    def _fetch_or_compile(self, lowered, name: str, key_salt) -> tuple:
+        """:meth:`compile_lowered`'s body: the executable, and ``source`` with the memory fields for its span."""
         key = content_key(lowered, extra=key_salt)
         cached = self._mem.get(key)
         if cached is not None:
             self.hits += 1
             self.log.event("compile_cache_hit", program=name, key=key[:16], source="memory")
-            return cached
+            return cached, {"source": "memory"}
 
         if self.store is not None:
             blob = None
@@ -157,21 +169,20 @@ class ProgramCache:
                     self.hits += 1
                     self.deserialized += 1
                     self._mem[key] = compiled
+                    came_from = {"source": "disk", **_memory_fields(compiled)}
                     self.log.event(
-                        "compile_cache_hit", program=name, key=key[:16], source="disk",
-                        deserialize_ms=round(ms, 3), **_memory_fields(compiled),
+                        "compile_cache_hit", program=name, key=key[:16], deserialize_ms=round(ms, 3), **came_from
                     )
                     self.log.counter("compile_cache.deserialize_ms", round(ms, 3), program=name)
-                    return compiled
+                    return compiled, came_from
 
         t0 = time.perf_counter()
         compiled = self._compile_fresh(lowered)
         ms = (time.perf_counter() - t0) * 1000.0
         self.misses += 1
         self._mem[key] = compiled
-        self.log.event(
-            "compile_cache_miss", program=name, key=key[:16], compile_ms=round(ms, 3), **_memory_fields(compiled)
-        )
+        memory = _memory_fields(compiled)
+        self.log.event("compile_cache_miss", program=name, key=key[:16], compile_ms=round(ms, 3), **memory)
         self.log.counter("compile_cache.compile_ms", round(ms, 3), program=name)
         if self.store is not None and not self._serialize_broken:
             try:
@@ -185,7 +196,8 @@ class ProgramCache:
                     "compile_cache_store_failed", severity="warning", program=name,
                     reason=type(e).__name__, detail=str(e)[:200],
                 )
-        return compiled
+        # jax's persistent cache may have had it: then the span says ``disk``, as for a hit in the store
+        return compiled, {"source": phase_log().compile_source(), **memory}
 
     def _compile_fresh(self, lowered):
         """``lowered.compile()``, asking jax to leave its persistent XLA
@@ -248,7 +260,8 @@ class ProgramCache:
             sig = (treedef, tuple(leaf_sig(l) for l in leaves), stat)
             compiled = table.get(sig)
             if compiled is None:
-                lowered = jitted.lower(*args, **kwargs)
+                with phase("program.lower", program=name):
+                    lowered = jitted.lower(*args, **kwargs)
                 compiled = self.compile_lowered(lowered, name=name)
                 table[sig] = compiled
             return compiled(*dyn, **kwargs)

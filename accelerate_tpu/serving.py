@@ -72,6 +72,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import logging
 import time
 from typing import Optional
 
@@ -79,7 +80,9 @@ import numpy as np
 
 from .ft.crashpoints import crash_point
 from .scheduling import Scheduler, SchedulerConfig, ShedError
-from .telemetry.trace import phase
+from .telemetry.trace import phase, phased
+
+logger = logging.getLogger(__name__)
 
 
 def _jax():
@@ -216,6 +219,7 @@ class ServingEngine:
     let admission control queue requests when the pool is full).
     """
 
+    @phased("engine.init")
     def __init__(
         self,
         model,
@@ -1370,10 +1374,27 @@ class ServingEngine:
         self._tick += 1
         with phase(
             "engine.tick", tick=self._tick, queue_len=len(self.queue), decoding=n_dec,
-            prefilling=len(self._prefill_order), mono_ns=time.monotonic_ns(),
-        ):
+            prefilling=len(self._prefill_order),
+        ) as tick:  # a root: phase() adds its ``mono_ns``
             self._tick_phases(n_dec)
+        self.metrics.on_tick(tick.record.wall_ns / 1e6, tick.record.slow)
+        if tick.record.slow:
+            self._report_slow_tick(tick.record)
         return self.active_count
+
+    def _report_slow_tick(self, record) -> None:
+        """A tick that closed far beyond the median of the ticks before it
+        (``telemetry.trace.SLOW_ROOT_OVER_NS``): one ``tick_slow`` event with
+        the tick's whole record, which the flight recorder's tap keeps, and
+        one warning line, which a run with no event log still prints."""
+        fields = record.fields()
+        self._log.event("tick_slow", severity="warning", **fields)
+        logger.warning(
+            "tick_slow: tick %s took %.1f ms (cpu %.1f ms, gap before it %.1f ms), longest child %s; "
+            "children_ms %s; counts %s; done %s",
+            fields["counts"].get("tick"), fields["wall_ms"], fields["cpu_ms"], fields["gap_ms"],
+            fields["longest_child"], fields["children_ms"], fields["counts"], fields["done"],
+        )
 
     def _tick_phases(self, n_dec: int) -> None:
         """The body of one tick, cut into the non-overlapping phases

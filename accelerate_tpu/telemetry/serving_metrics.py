@@ -141,6 +141,13 @@ class ServingMetrics:
         # request decodes (the tick steps every slot's state)
         self.state_bytes_per_slot = 0
         self.state_slots_idle = 0
+        # the host loop itself, from the phase log's tick records
+        # (telemetry.trace.PhaseLog): ticks made, the longest one's wall
+        # time, and how many closed far beyond the median of the ticks
+        # before them (each is one ``tick_slow`` event)
+        self.ticks = 0
+        self.tick_ms_max = 0.0
+        self.slow_ticks = 0
         # cross-request prefix reuse (serving_fleet.RadixPrefixCache):
         # a hit means the request skipped re-prefilling that many shared
         # preamble tokens — the fleet's dominant p95-TTFT lever
@@ -199,6 +206,11 @@ class ServingMetrics:
     def on_tokens(self, n: int = 1):
         self.tokens_generated += n
         self._token_marks.append((self._clock(), self.tokens_generated))
+
+    def on_tick(self, wall_ms: float, slow: bool = False):
+        self.ticks += 1
+        self.tick_ms_max = max(self.tick_ms_max, wall_ms)
+        self.slow_ticks += bool(slow)
 
     def on_state_step(self, slots_idle: int):
         self.state_slots_idle += slots_idle
@@ -387,6 +399,9 @@ class ServingMetrics:
             "replica_errors": self.replica_errors,
             "replica_timeouts": self.replica_timeouts,
             "replica_state": self.replica_state,
+            "ticks": self.ticks,
+            "tick_ms_max": self.tick_ms_max,
+            "slow_ticks": self.slow_ticks,
         }
         if self.replica is not None:
             snap["replica"] = self.replica
@@ -400,7 +415,7 @@ class ServingMetrics:
         "prefix_hits", "prefix_misses", "prefix_evictions",
         "prefix_registrations", "prefix_tokens_reused",
         "failovers_in", "failovers_out", "failovers_lost",
-        "replica_errors", "replica_timeouts",
+        "replica_errors", "replica_timeouts", "ticks", "slow_ticks",
     )
     _WINDOWS = ("ttft_ms", "e2e_ms", "itl_ms", "queue_wait_ms")
 
@@ -417,6 +432,7 @@ class ServingMetrics:
         out._sources = metrics
         for name in cls._COUNTERS:
             setattr(out, name, sum(getattr(m, name) for m in metrics))
+        out.tick_ms_max = max((m.tick_ms_max for m in metrics), default=0.0)
         for name in cls._WINDOWS:
             pooled = collections.deque(
                 (v for m in metrics for v in getattr(m, name)),
@@ -459,6 +475,8 @@ class ServingMetrics:
         ("failovers_lost_total", "In-flight requests unrecoverable at failover", "failovers_lost"),
         ("replica_errors_total", "Engine exceptions classified by the fleet router", "replica_errors"),
         ("replica_timeouts_total", "Tick wall-time SLO violations", "replica_timeouts"),
+        ("ticks_total", "Engine ticks (one step() each)", "ticks"),
+        ("slow_ticks_total", "Ticks that closed far beyond the median of the ticks before them (tick_slow events)", "slow_ticks"),
     )
     _PROM_SUMMARIES = (
         ("ttft_ms", "Time to first token (ms)", "ttft_ms"),
@@ -472,6 +490,7 @@ class ServingMetrics:
         ("kv_block_utilization", "Fraction of the paged KV pool in use", "kv_block_utilization"),
         ("tokens_per_sec", "Decode throughput over the trailing window", "tokens_per_sec"),
         ("replica_state", "Replica health (0 healthy, 1 degraded, 2 quarantined, 3 dead)", "replica_state"),
+        ("tick_ms_max", "Wall time of the longest engine tick so far (ms)", "tick_ms_max"),
     )
 
     def _label_str(self, extra: Optional[dict] = None) -> str:
