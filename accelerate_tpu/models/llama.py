@@ -704,12 +704,17 @@ class MambaMixer(nn.Module):
     is padded to a tile): one row a sequence whatever its length, no pages.
     ``new_span`` ``(lo, hi)`` names the window's new tokens; the others (a
     bucket's right pad, a chunk window's overlapped head) leave the state
-    as it was. Without a cache the same scan runs from a zero state."""
+    as it was. Without a cache the same scan runs from a zero state.
+    ``row_valid`` (bool ``[B, 1]``, a paged decode tick's slots in which a
+    request decodes) reaches the step kernel alone: the other slots'
+    ``ssm_state`` is neither read nor written and their ``y`` is zeros. The
+    projections and the convolution run on every row (their bytes are
+    weights), and the plain scan takes no mask."""
 
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, hidden, decode: bool = False, new_span=None):
+    def __call__(self, hidden, decode: bool = False, new_span=None, row_valid=None):
         from ..ops import paged_kv
         from ..ops.selective_scan import causal_conv1d, selective_scan
 
@@ -750,16 +755,18 @@ class MambaMixer(nn.Module):
                 jnp.matmul(step, w_dt.astype(dt), preferred_element_type=f32) + dt_bias.astype(f32)
             )
             a = -jnp.exp(a_log.astype(f32))
-        on_tpu = jax.default_backend() == "tpu"
-        if decode and t == 1 and paged_kv.active_paged_config() is not None and (
-            on_tpu or paged_kv.FORCE_KERNEL_INTERPRET
-        ) and _one_device():
-            # the serving tick's step: one pass over the state, in place (a vmapped dense tick and
-            # generate() take the plain step below: no kernel under vmap)
+        if decode and t == 1 and paged_kv.active_paged_config() is not None and state_step_kernel():
+            # the serving tick's step: one pass over the state of the slots that decode (``row_valid``; every
+            # slot without it), in place (a vmapped dense tick and generate() take the plain step below: no
+            # kernel under vmap, and no mask either)
             from ..ops.pallas_selective_scan import ssm_state_step
 
             with jax.named_scope("ssm.step"):
-                y, h = ssm_state_step(h0, u[:, 0], delta[:, 0], b_t[:, 0], c_t[:, 0], a, d_skip, interpret=not on_tpu)
+                y, h = ssm_state_step(
+                    h0, u[:, 0], delta[:, 0], b_t[:, 0], c_t[:, 0], a, d_skip,
+                    None if row_valid is None else row_valid.reshape(bsz),
+                    interpret=jax.default_backend() != "tpu",
+                )
                 y = y[:, None]
         else:
             with jax.named_scope("ssm.step" if t == 1 else "ssm.scan"):
@@ -830,6 +837,15 @@ def _one_device() -> bool:
     return mesh is None or mesh.size == 1
 
 
+def state_step_kernel() -> bool:
+    """Whether a paged decode step traced here steps a state-space layer's state through
+    :func:`~accelerate_tpu.ops.pallas_selective_scan.ssm_state_step`, which visits the slots ``row_valid``
+    names alone; the plain step (off the chip, across a mesh) steps every slot."""
+    from ..ops import paged_kv
+
+    return (jax.default_backend() == "tpu" or paged_kv.FORCE_KERNEL_INTERPRET) and _one_device()
+
+
 class LlamaLayer(nn.Module):
     config: LlamaConfig
     routed: bool = False  # the FFN is RoutedFFN (a layer past ``first_k_dense_replace`` of a config with experts)
@@ -842,7 +858,7 @@ class LlamaLayer(nn.Module):
 
         def attn(x):
             if self.mixer == "mamba":
-                return MambaMixer(cfg, name="mamba")(x, decode, new_span)
+                return MambaMixer(cfg, name="mamba")(x, decode, new_span, row_valid)
             if self.mixer == "conv":
                 return ShortConvMixer(cfg, name="conv")(x, decode, new_span)
             if cfg.stateful:
@@ -1016,9 +1032,10 @@ def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> 
         window's overlapped head are not); only a model whose layers keep
         a recurrent state reads it, and None means every token.
         ``row_valid`` (bool, the shape of ``input_ids``): the tokens that
-        count, a decode tick's slots in which a request decodes; only the
-        routed experts read it (the others' rows reach none of them), and
-        None means every token."""
+        count, a decode tick's slots in which a request decodes; the routed
+        experts read it (the others' rows reach none of them) and a
+        state-space layer's step kernel (the others' states are neither
+        read nor written); None means every token."""
         if decode:
             variables = {"params": p, **(state or {})}
             if cache is not None:

@@ -356,10 +356,22 @@ class ServingEngine:
         # block pool + per-slot block tables (ops/paged_kv.py) — same
         # decode roofline, pool capacity decoupled from slots x max_len.
         self.paged = paged_block_size is not None
-        # the paged decode tick of a model with routed experts is told which slots decode (one ``[slots]`` bool
-        # argument more: ``_decoding_arg``): the stale token of every other slot reaches no expert. No other
-        # program takes it (the dense tick is a ``vmap`` of one slot's step, which cannot run routed experts)
-        self._mask_idle_rows = self.paged and getattr(model.config, "n_routed_experts", None) is not None
+        # the paged decode tick of a model with routed experts, or with a recurrent state that a kernel steps
+        # (``ssm_state``), is told which slots decode (one ``[slots]`` bool argument more: ``_decoding_arg``):
+        # the stale token of every other slot reaches no expert, and its state is neither read nor written.
+        # No other program takes it (the dense tick is a ``vmap`` of one slot's step: no routed experts, no kernel)
+        from .ops.kv_cache import leaf_names
+
+        masks_state = self.paged and "ssm_state" in leaf_names(self._row_template)
+        self._mask_idle_rows = masks_state or (self.paged and getattr(model.config, "n_routed_experts", None) is not None)
+        # whether the tick steps the state of every slot (a convolution's carried inputs; a state-space layer's
+        # through the plain step) or of the decoding slots alone (through the kernel): what ``state_slots_idle`` counts
+        self._steps_idle_state = self._has_state
+        if masks_state:
+            from .models.llama import state_step_kernel
+
+            with self._trace_ctx():
+                self._steps_idle_state = not state_step_kernel()
         if self.paged:
             from .ops.paged_kv import BlockAllocator, PagedConfig, paged_mode
 
@@ -1773,7 +1785,10 @@ class ServingEngine:
         """ONE jitted K-step tick for every decode-phase slot, then the
         host walk that streams tokens/logprobs out. Prefilling slots
         compute garbage rows by construction (static shapes) — their
-        caches are fully replaced at prefill paste/insert."""
+        caches are fully replaced at prefill paste/insert. A tick that
+        takes :meth:`_decoding_arg` spends no expert and no state-space
+        step on them: a slot in which nobody decodes keeps its
+        ``ssm_state`` bit for bit until the next paste replaces it."""
         crash_point("mid_decode", replica=self.metrics.replica)
         jnp = _jax().numpy
         decoding = self._decoding_slots()
@@ -1786,8 +1801,9 @@ class ServingEngine:
                 self.model.params, self.slot_caches,
                 jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos), self._slot_keys, *self._decoding_arg(decoding)
             )
-        if self._has_state:
-            # the tick steps every slot's recurrent state; this many slot-steps of it decode nothing
+        if self._steps_idle_state:
+            # the tick steps every slot's recurrent state (a state-space layer's step kernel is told which slots
+            # decode and visits no other: then nothing is counted); this many slot-steps of it decode nothing
             self._tick_state_idle = (self.num_slots - n_decoding) * self.tick_block
             self.metrics.on_state_step(self._tick_state_idle)
         with phase("engine.decode.sync"):
@@ -1829,6 +1845,7 @@ class ServingEngine:
     def _decoding_arg(self, decoding: Optional[np.ndarray] = None) -> tuple:
         """The decode tick's last argument: ``([slots] bool,)``, :meth:`_decoding_slots` on the device, for
         the paged tick of a model with routed experts (its ``RoutedFFN`` layers multiply those slots' rows
+        alone) or with state-space layers (the step kernel reads and writes those slots' ``ssm_state``
         alone); ``()`` for every other tick, which takes no such argument."""
         if not self._mask_idle_rows:
             return ()

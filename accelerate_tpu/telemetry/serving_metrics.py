@@ -138,7 +138,9 @@ class ServingMetrics:
         # recurrent state (models with state-space layers; 0 otherwise): the
         # bytes of ssm_state + conv_state one slot holds over all layers, and
         # the slot-steps of it the decode ticks spent on slots in which no
-        # request decodes (the tick steps every slot's state)
+        # request decodes (a convolution's carried inputs and the plain
+        # state step move in every slot; the step kernel of a state-space
+        # layer is told which slots decode and visits no other: 0 then)
         self.state_bytes_per_slot = 0
         self.state_slots_idle = 0
         # the host loop itself, from the phase log's tick records

@@ -91,7 +91,8 @@ SEGMENTS = (
 #: ``expert_tile_visits`` and ``expert_pairs``, the token-expert pairs
 #: the grouped products multiplied: the decoding slots' alone) and
 #: ``state_slots_idle``, the slot-steps of recurrent state the tick
-#: spent on slots in which no request decodes);
+#: spent on slots in which no request decodes: 0 where a state-space
+#: layer's step kernel is told which slots decode and visits no other);
 #: the spans of one request share ``uid``. No name equals a span of the
 #: benchmark's own. Inside the programs, ``jax.named_scope`` names ride
 #: in the device operations' ``op_name``: ``moe.*``, ``mla.absorb``, and

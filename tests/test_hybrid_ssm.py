@@ -107,6 +107,75 @@ def test_step_kernel_is_the_plain_step(scan_inputs, blocks):
     assert "name=ssm_state_step" in text and "input_output_aliases=((0, 1),)" in text
 
 
+def _step_inputs(slots, n, d, seed=1):
+    k = jax.random.split(jax.random.key(seed), 7)
+    return (jax.random.normal(k[0], (slots, n, d)), jax.random.normal(k[1], (slots, d)),
+            jax.nn.softplus(jax.random.normal(k[2], (slots, d)) - 3), jax.random.normal(k[3], (slots, n)),
+            jax.random.normal(k[4], (slots, n)), -jnp.exp(jax.random.normal(k[5], (n, d))), jax.random.normal(k[6], (d,)))
+
+
+def _mask(kind, slots):
+    if kind == "random_70":
+        return np.isin(np.arange(slots), np.random.default_rng(5).choice(slots, 70, replace=False))
+    return {"all_live": np.ones(slots, bool), "none_live": np.zeros(slots, bool), "one_live": np.arange(slots) == 37 % slots,
+            "every_block_mixed": (np.arange(slots) * 5 // 3) % 2 == 0}[kind]
+
+
+@pytest.mark.parametrize(
+    "kind,slots,d,lane_block",
+    [("all_live", 128, 256, 128), ("none_live", 128, 256, 128), ("one_live", 128, 256, 128), ("random_70", 128, 256, 128),
+     ("every_block_mixed", 128, 256, 128), ("every_block_mixed", 12, 256, 128), ("one_live", 5, 256, 2560),
+     ("every_block_mixed", 16, 384, 2560), ("every_block_mixed", 16, 384, 256)],
+    ids=["all_live", "none_live", "one_live", "random_70_of_128", "every_block_mixed", "slots_no_tile_divides",
+         "five_slots", "d_inner_under_the_lane_block", "d_inner_no_multiple_of_the_lane_block"],
+)
+def test_step_kernel_visits_the_live_slots_alone(kind, slots, d, lane_block):
+    """With ``row_valid`` the slots it names get the plain step's ``(y, h')`` to the unmasked kernel's
+    tolerance; every other slot's ``h'`` is bitwise its ``h`` (never fetched, never written) and its ``y``
+    is zeros, whichever blocks the live slots fall in and whatever blocks the sizes allow."""
+    args = _step_inputs(slots, 16, d)
+    live = _mask(kind, slots)
+    if kind == "every_block_mixed" and slots % 8 == 0:
+        assert all(0 < live[i : i + 8].sum() < 8 for i in range(0, slots, 8))
+    want_y, want_h = map(np.asarray, state_step(*args))
+    y, h = map(np.asarray, ssm_state_step(*args, jnp.asarray(live), lane_block=lane_block, interpret=True))
+    np.testing.assert_allclose(y[live], want_y[live], atol=1e-5)
+    np.testing.assert_allclose(h[live], want_h[live], atol=1e-6)
+    np.testing.assert_array_equal(h[~live], np.asarray(args[0])[~live])
+    assert not y[~live].any()
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def test_step_kernel_without_a_mask_is_the_program_it_was():
+    """``row_valid=None`` and an all-true mask give the same numbers. The ``None`` call keeps its grid and
+    its blocks at the benchmark's size (``h`` comes 8 slots x 2560 lanes a grid step through Pallas's own
+    pipeline, aliased to ``h'``); the masked call walks the slot blocks with every lane in one step,
+    ``h`` whole in HBM behind one scalar-prefetched mask, under the same device name."""
+    args = _step_inputs(16, 16, 256)
+    plain, masked = ssm_state_step(*args, interpret=True), ssm_state_step(*args, jnp.ones(16, bool), interpret=True)
+    for a, b in zip(plain, masked):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    real = [jax.ShapeDtypeStruct(s, jnp.float32) for s in ((128, 16, 5120), (128, 5120), (128, 5120), (128, 16), (128, 16), (16, 5120), (5120,))]
+    seen = {}
+    for name, extra in (("plain", ()), ("masked", (jax.ShapeDtypeStruct((128,), jnp.bool_),))):
+        (call,) = _pallas_calls(jax.make_jaxpr(ssm_state_step)(*real, *extra).jaxpr)
+        gm = call.params["grid_mapping"]
+        blocks = [tuple(getattr(b, "block_size", b) for b in bm.block_shape) for bm in gm.block_mappings]
+        assert "name=ssm_state_step" in str(call)
+        seen[name] = (gm.grid, blocks, call.params["input_output_aliases"], gm.num_index_operands)
+    rows = [(8, 2560), (8, 2560), (8, 16, 1), (8, 16, 1), (16, 2560), (1, 2560), (8, 2560)]
+    assert seen["plain"] == ((2, 16), [(8, 16, 2560), *rows, (8, 16, 2560)], ((0, 1),), 0)
+    whole = [tuple(5120 if v == 2560 else v for v in r) for r in rows]  # a slot's state is one contiguous copy
+    assert seen["masked"] == ((1, 16), [(128, 16, 5120), *whole, (128, 16, 5120)], ((1, 1),), 1)
+
+
 def test_step_kernel_blocks_divide_the_sizes():
     assert (_block(128, 8, 8), _block(5120, 2560, 128), _block(5120, 3000, 128)) == (8, 2560, 2560)
     assert (_block(5, 8, 8), _block(12, 8, 8), _block(256, 2560, 128)) == (5, 12, 256)  # no tile divides: whole
@@ -244,7 +313,10 @@ def test_engine_serves_the_hybrid_cache(model, layout, monkeypatch):
         assert len(out) == len(prompt) + 9 and _greedy_gap(model, prompt, out) < 1e-4
     m = engine.metrics
     assert m.state_bytes_per_slot == 2 * (8 * 128 * 4 + 3 * 128 * 4)  # two state-space layers, float32 toy
-    assert 0 < m.state_slots_idle <= engine._tick * engine.tick_block * 3
+    if layout == "paged_kernel_interpreted":
+        assert m.state_slots_idle == 0, "the kernel is told which slots decode and steps no other"
+    else:
+        assert 0 < m.state_slots_idle <= engine._tick * engine.tick_block * 3
 
 
 def test_generate_equals_the_engine(model):
@@ -288,10 +360,14 @@ def test_preempted_request_resumes_token_exact(model):
     assert _greedy_gap(model, urgent_prompt, engine.poll(urgent)) < 1e-4
 
 
-def test_idle_slot_between_two_live_ones_is_finite_and_never_read(model):
-    """Slot 1 finishes early and idles between slots 0 and 2: the tick goes on stepping its state (zeroed
-    by ``clear_slot``, then token 0 from there), which stays finite; the neighbours' outputs are exact; and
-    a later request pasted into the slot is exact too, whatever the idle steps left there."""
+@pytest.mark.parametrize("step", ["xla_step", "kernel_interpreted"])
+def test_idle_slot_between_two_live_ones_is_finite_and_never_read(model, step, monkeypatch):
+    """Slot 1 finishes early and idles between slots 0 and 2. The plain step goes on stepping its state
+    (zeroed by ``clear_slot``, then token 0 from there), which stays finite; the kernel is told that nobody
+    decodes there and leaves its ``ssm_state`` bit for bit what ``clear_slot`` made it, tick after tick,
+    while the convolution's carried inputs (no kernel) move on. Either way the neighbours' outputs are exact,
+    and a later request admitted to the slot starts from its pasted state, whatever lay there."""
+    monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", step == "kernel_interpreted")
     engine = ServingEngine(model, num_slots=3, prompt_buckets=(8, 16), max_len=64, tick_block=4, paged_block_size=8)
     prompts = [_ids(6), _ids(4, start=9), _ids(12, start=20)]
     uids = [engine.submit(p, max_new_tokens=n) for p, n in zip(prompts, (24, 2, 24))]
@@ -300,12 +376,50 @@ def test_idle_slot_between_two_live_ones_is_finite_and_never_read(model):
     assert engine.slot_req[1] is None and engine.slot_req[0] is not None and engine.slot_req[2] is not None
     state = _state(engine.slot_caches)
     assert all(np.isfinite(v).all() for v in state.values())
-    assert any(np.abs(v[1]).max() > 0 for v in state.values()), "the idle slot's state is stepped on"
+    engine.step()
+    after = _state(engine.slot_caches)
+    for name, v in after.items():
+        assert np.abs(v[0] - state[name][0]).max() > 0, "a decoding slot's state moves"
+        if step == "kernel_interpreted" and "ssm_state" in name:
+            assert not v[1].any(), "the idle slot's state is what clear_slot left, never written"
+        else:
+            assert np.abs(v[1]).max() > 0, "the idle slot's state is stepped on"
+    stale = jax.tree_util.tree_map_with_path(
+        lambda p, l: l.at[1].set(7.0) if str(p[-1].key) == "ssm_state" else l, engine.slot_caches)
+    engine.slot_caches = stale  # whatever an earlier tenant left: the next paste replaces it whole
     late = engine.submit(_ids(7, start=50), max_new_tokens=8)
+    engine.step()
+    assert engine.slot_req[1] is not None and engine.slot_req[1].uid == late
     engine.run()
     for uid, p in zip(uids + [late], prompts + [_ids(7, start=50)]):
         assert _greedy_gap(model, p, engine.poll(uid)) < 1e-4
     assert all(np.isfinite(v).all() for v in _state(engine.slot_caches).values())
+
+
+def test_masked_tick_emits_the_unmasked_ticks_tokens_and_logprobs(model, monkeypatch):
+    """The same requests on the same seed through the tick that takes the ``[slots]`` bool and through the
+    parent's (five arguments, the kernel steps every slot): request for request the same tokens and the
+    same logprobs, with slots that idle from the start, idle after a retirement and are admitted again."""
+    monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", True)
+    prompts = [_ids(6), _ids(4, start=9), _ids(12, start=20), _ids(9, start=33), _ids(5, start=41)]
+    news = (17, 3, 11, 6, 9)
+
+    def serve(masked):
+        engine = ServingEngine(model, num_slots=4, prompt_buckets=(8, 16), max_len=64, tick_block=4, paged_block_size=8,
+                               temperature=0.8, top_k=20, seed=11)
+        assert engine._mask_idle_rows and not engine._steps_idle_state
+        engine._mask_idle_rows = masked  # read when the tick's arguments are built: False is the parent's call
+        uids = [engine.submit(p, max_new_tokens=n) for p, n in zip(prompts[:3], news)]
+        for _ in range(3):
+            engine.step()
+        uids += [engine.submit(p, max_new_tokens=n) for p, n in zip(prompts[3:], news[3:])]
+        engine.run()
+        assert len(engine._perf_programs["decode_tick"][1](None)) == (6 if masked else 5)
+        return [(np.asarray(engine.poll(u)), engine.logprobs(u)) for u in uids]
+
+    for (toks, lps), (want_toks, want_lps) in zip(serve(True), serve(False)):
+        np.testing.assert_array_equal(toks, want_toks)
+        np.testing.assert_allclose(lps, want_lps, atol=1e-6)
 
 
 def test_clear_slot_zeroes_the_state_and_paste_blocks_passes_it(model):
@@ -324,21 +438,31 @@ def test_clear_slot_zeroes_the_state_and_paste_blocks_passes_it(model):
         assert (pasted[name][0] == 1).all()
 
 
-def test_tick_done_carries_the_idle_state_steps(model):
-    """``state_slots_idle``: slots the tick stepped in which no request decodes, summed over its steps; 0
-    for a model without recurrent state (tests/test_serving.py's models never set it)."""
+@pytest.mark.parametrize("kind", ["state_space_xla_step", "state_space_kernel", "convolution_kernel", "no_state"])
+def test_tick_done_carries_the_idle_state_steps(model, kind, monkeypatch):
+    """``state_slots_idle``: slot-steps of recurrent state the tick stepped for slots in which no request
+    decodes. The plain step steps every slot's state: (slots - decoding) x steps. The kernel is told which
+    slots decode: 0. A convolution's carried inputs have no kernel and move in every slot, mask or none: as
+    before. 0 for a model without recurrent state (tests/test_serving.py's models never set it)."""
+    monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", kind.endswith("kernel"))
+    if kind == "convolution_kernel":
+        from accelerate_tpu.models.lfm2_moe import Lfm2MoeConfig, create_lfm2_moe_model
+
+        model = create_lfm2_moe_model(Lfm2MoeConfig.tiny(), seed=3, seq_len=16)
+    elif kind == "no_state":
+        from accelerate_tpu.models.llama import LlamaConfig, create_llama_model
+
+        model = create_llama_model(LlamaConfig.tiny(), seed=0, seq_len=8)
     engine = ServingEngine(model, num_slots=4, prompt_buckets=(8,), max_len=32, paged_block_size=8, tick_block=2)
     engine.submit(_ids(5), max_new_tokens=6)
     seen = []
     while engine.queue or engine.active_count:
         engine.step()
         seen.append(engine._tick_state_idle)
-    assert set(seen) == {3 * 2} and engine.metrics.state_slots_idle == sum(seen)
-    from accelerate_tpu.models.llama import LlamaConfig, create_llama_model
-
-    plain = ServingEngine(create_llama_model(LlamaConfig.tiny(), seed=0, seq_len=8), num_slots=2, prompt_buckets=(8,), max_len=32)
-    plain.generate_many([_ids(5)], max_new_tokens=3)
-    assert plain.metrics.state_slots_idle == 0 and plain.metrics.state_bytes_per_slot == 0 and not plain._has_state
+    want = {"state_space_xla_step": 3 * 2, "state_space_kernel": 0, "convolution_kernel": 3 * 2, "no_state": 0}[kind]
+    assert set(seen) == {want} and engine.metrics.state_slots_idle == sum(seen)
+    assert engine._has_state == (kind != "no_state") and (engine.metrics.state_bytes_per_slot > 0) == engine._has_state
+    assert engine._mask_idle_rows == (kind != "no_state") and len(engine._decoding_arg()) == int(kind != "no_state")
 
 
 def test_hand_off_and_export_refuse_a_recurrent_state_by_name(model):

@@ -579,11 +579,12 @@ def _tick_jaxpr(eng):
 
 
 @pytest.mark.parametrize("family", ["dense_unrolled", "dense_scanned", "state_space", "routed_experts"])
-def test_only_a_routed_models_tick_is_told_which_slots_decode(tiny_models, family):
-    """The tick of a dense model and of one with state-space layers is the program it was before the
-    routed experts' row mask: five arguments, no boolean enters it, and the model's ``apply_fn`` is called
-    with the keywords it always was. A model with routed experts gets one ``[slots]`` bool more, handed
-    down as ``row_valid``. The choice follows the config's ``n_routed_experts`` alone."""
+def test_only_a_tick_with_experts_or_a_stepped_state_is_told_which_slots_decode(tiny_models, family):
+    """The tick of a dense model is the program it was before the row mask: five arguments, no boolean
+    enters it, and the model's ``apply_fn`` is called with the keywords it always was. A model with routed
+    experts, or with state-space layers (an ``ssm_state`` leaf in its row cache, which the step kernel
+    visits by slot), gets one ``[slots]`` bool more, handed down as ``row_valid``. The choice follows the
+    config's ``n_routed_experts`` and the cache template's leaves alone."""
     import copy
 
     import jax.numpy as jnp
@@ -598,8 +599,8 @@ def test_only_a_routed_models_tick_is_told_which_slots_decode(tiny_models, famil
         model = create_joyai_flash_model(JoyAIFlashConfig.tiny(), seed=3, seq_len=16)
     else:
         model = tiny_models(family == "dense_scanned")
-    routed = family == "routed_experts"
-    assert (getattr(model.config, "n_routed_experts", None) is not None) == routed
+    routed = family in ("routed_experts", "state_space")  # told which slots decode
+    assert (getattr(model.config, "n_routed_experts", None) is not None) == (family == "routed_experts")
     keywords = []
 
     def spy(*args, **kwargs):
