@@ -156,12 +156,40 @@ class LlamaConfig:
     # whatever the length. Layers are then unrolled (``scan_layers=False``).
     conv_L_cache: int = 3
     conv_bias: bool = False
+    # Mamba-2 layers beside attention (Granite-4.0-H-style checkpoints publish these keys and name the
+    # layers "mamba" / "attention" in ``layer_types``): with ``mamba_n_heads`` set, a "mamba" layer's mixer
+    # is a :class:`Mamba2Mixer` of ``mamba_n_heads`` heads of ``mamba_d_head`` channels (``mamba_expand *
+    # hidden_size`` together), ``mamba_n_groups`` groups of ``B`` / ``C`` (one is built), a state of
+    # ``[mamba_d_state, mamba_n_heads * mamba_d_head]`` a sequence, chunks of ``mamba_chunk_size`` tokens
+    # in a prefill. Layers are then unrolled (``scan_layers=False``).
+    mamba_n_heads: Optional[int] = None
+    mamba_d_head: Optional[int] = None
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+    # Granite's four multipliers (None: the model has none, and its program is what it was): on the
+    # embeddings, on both branches of every layer before the residual add, the attention score scale in
+    # place of ``head_dim ** -0.5``, and a divisor of the logits
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    attention_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
+    # a shared expert of its own width (None: ``moe_intermediate_size * n_shared_experts``)
+    shared_intermediate_size: Optional[int] = None
+    # Expert parallelism, one chip's share: the routed layers hold share ``expert_share`` of
+    # ``expert_shares`` equal shares of the ``n_routed_experts`` experts (experts ``share * E / shares`` and
+    # up), the router keeps all ``n_routed_experts`` columns, and a layer returns its held experts' part
+    # of the routed sum (plus the shared expert, which every share computes alike). No exchange is built.
+    expert_shares: int = 1
+    expert_share: int = 0
 
     def mixer_kind(self, i: int) -> str:
-        """The mixer of layer ``i``: ``"conv"`` where ``layer_types`` says so, ``"mamba"`` off the
-        attention period of a config with one, else ``"attention"`` (latent where ``kv_lora_rank`` is set)."""
+        """The mixer of layer ``i``: ``"conv"`` or ``"mamba"`` where ``layer_types`` says so (a ``"mamba"``
+        layer is ``"mamba2"`` in a config with ``mamba_n_heads``), ``"mamba"`` off the attention period of
+        a config with one, else ``"attention"`` (latent where ``kv_lora_rank`` is set)."""
         if self.layer_types is not None and self.layer_types[i] == "conv":
             return "conv"
+        if self.layer_types is not None and self.layer_types[i] == "mamba":
+            return "mamba" if self.mamba_n_heads is None else "mamba2"
         if self.attn_layer_period is not None and i % self.attn_layer_period != self.attn_layer_offset:
             return "mamba"
         return "attention"
@@ -169,7 +197,17 @@ class LlamaConfig:
     @property
     def stateful(self) -> bool:
         """Some layer's mixer keeps a recurrent state a sequence (:data:`ops.paged_kv.STATE_LEAVES`)."""
-        return self.attn_layer_period is not None or (self.layer_types is not None and "conv" in self.layer_types)
+        return self.attn_layer_period is not None or (
+            self.layer_types is not None and any(kind in ("conv", "mamba") for kind in self.layer_types))
+
+    @property
+    def held_experts(self) -> tuple:
+        """``(first, count)``: the routed experts this share holds, among the router's ``n_routed_experts``."""
+        if self.n_routed_experts % self.expert_shares or not 0 <= self.expert_share < self.expert_shares:
+            raise ValueError(
+                f"share {self.expert_share} of {self.expert_shares} equal shares of {self.n_routed_experts} routed experts")
+        count = self.n_routed_experts // self.expert_shares
+        return self.expert_share * count, count
 
     @classmethod
     def llama2_7b(cls, **kw) -> "LlamaConfig":
@@ -530,6 +568,8 @@ class LlamaAttention(nn.Module):
         scale = None  # attention default: head_dim**-0.5
         if cfg.query_pre_attn_scalar is not None:
             scale = float(cfg.query_pre_attn_scalar) ** -0.5  # Gemma2
+        if cfg.attention_multiplier is not None:
+            scale = float(cfg.attention_multiplier)  # Granite: the scale itself, not a power of the head size
         if decode:
             out = self._cached_attention(q, k, v, scale, new_span)
         else:
@@ -640,48 +680,65 @@ class LatentAttention(nn.Module):
 
 
 class RoutedFFN(nn.Module):
-    """Routed SwiGLU experts with shared experts (DeepSeek-V3-style
-    ``noaux_tc`` routing with one group): scores in float32, a selection
-    bias that chooses and does not weigh, normalised top-k weights times
-    ``routed_scaling_factor``, no capacity (:mod:`accelerate_tpu.ops.moe`
-    ``dropless_moe_ffn``). It sows the experts' load of each call into the
+    """Routed SwiGLU experts with shared experts, no capacity
+    (:mod:`accelerate_tpu.ops.moe` ``dropless_moe_ffn``). The scores follow
+    ``scoring_func``: ``"sigmoid"`` is DeepSeek-V3-style ``noaux_tc``
+    routing with one group (scores in float32, a selection bias that
+    chooses and does not weigh, normalised top-k weights times
+    ``routed_scaling_factor``); ``"softmax_topk"`` is Granite's (the
+    largest raw logits, a softmax over those alone, no bias and no
+    scaling). It sows the experts' load of each call into the
     ``expert_load`` collection, for whoever makes that mutable.
     ``row_valid`` (bool, ``hidden``'s leading axes) names the tokens that
     count: the others reach no routed expert and get the shared expert
-    alone; None means every token."""
+    alone; None means every token.
+
+    Under ``expert_shares`` > 1 the layer holds its share of the experts
+    (``LlamaConfig.held_experts``; the stacked matrices are ``[held, ..]``)
+    and routes over all of them: it returns the held experts' part of the
+    routed sum plus the shared expert, and the four ``expert_load`` counts
+    are of held experts alone."""
 
     config: LlamaConfig
 
     @nn.compact
     def __call__(self, hidden, row_valid=None):
-        from ..ops.moe import EXPERT_LOAD, dropless_moe_ffn, expert_load, sigmoid_topk_routing
+        from ..ops.moe import EXPERT_LOAD, dropless_moe_ffn, expert_load, sigmoid_topk_routing, softmax_topk_routing
 
         cfg = self.config
-        if cfg.scoring_func != "sigmoid":
-            raise NotImplementedError(f"routed experts score by sigmoid only, got scoring_func={cfg.scoring_func!r}")
+        if cfg.scoring_func not in ("sigmoid", "softmax_topk"):
+            raise NotImplementedError(
+                f"routed experts score by sigmoid or by a softmax over the top k, got scoring_func={cfg.scoring_func!r}")
         d, e, ff = cfg.hidden_size, cfg.n_routed_experts, cfg.moe_intermediate_size
+        first, held = cfg.held_experts
         init = nn.initializers.lecun_normal()
         router = self.param("router/kernel", init, (d, e))
-        bias = self.param("router/e_score_correction_bias", nn.initializers.zeros, (e,))
-        gate = self.param("experts/gate_proj", init, (e, d, ff))
-        up = self.param("experts/up_proj", init, (e, d, ff))
-        down = self.param("experts/down_proj", init, (e, ff, d))
+        if cfg.scoring_func == "sigmoid":
+            bias = self.param("router/e_score_correction_bias", nn.initializers.zeros, (e,))
+        gate = self.param("experts/gate_proj", init, (held, d, ff))
+        up = self.param("experts/up_proj", init, (held, d, ff))
+        down = self.param("experts/down_proj", init, (held, ff, d))
         flat = hidden.reshape(-1, d)
         with jax.named_scope("moe.route"):
             # float32 as published; ``highest`` because a TPU's default float32 product is one bfloat16 pass
             logits = jnp.matmul(flat.astype(jnp.float32), router.astype(jnp.float32), precision="highest")
-            experts, weights = sigmoid_topk_routing(
-                logits, bias, cfg.num_experts_per_tok, cfg.norm_topk_prob, cfg.routed_scaling_factor
-            )
+            if cfg.scoring_func == "sigmoid":
+                experts, weights = sigmoid_topk_routing(
+                    logits, bias, cfg.num_experts_per_tok, cfg.norm_topk_prob, cfg.routed_scaling_factor
+                )
+            else:
+                experts, weights = softmax_topk_routing(logits, cfg.num_experts_per_tok)
         out, group_sizes = dropless_moe_ffn(
-            flat, experts, weights, gate, up, down, None if row_valid is None else row_valid.reshape(-1)
+            flat, experts, weights, gate, up, down, None if row_valid is None else row_valid.reshape(-1),
+            first_expert=None if cfg.expert_shares == 1 else first,
         )
         if self.is_mutable_collection(EXPERT_LOAD):
             self.sow(EXPERT_LOAD, "counts", expert_load(group_sizes, experts.size))
         out = out.reshape(hidden.shape)
-        if cfg.n_shared_experts:
+        shared_width = cfg.shared_intermediate_size or ff * cfg.n_shared_experts
+        if shared_width:
             with jax.named_scope("moe.shared"):
-                shared = dataclasses.replace(cfg, intermediate_size=ff * cfg.n_shared_experts)
+                shared = dataclasses.replace(cfg, intermediate_size=shared_width)
                 out = out + LlamaMLP(shared, name="shared_experts")(hidden)
         return out
 
@@ -777,6 +834,101 @@ class MambaMixer(nn.Module):
             return _dense(cfg, cfg.hidden_size, "out_proj", dt, cfg.mamba_proj_bias)(y.astype(dt) * nn.silu(z))
 
 
+class Mamba2Mixer(nn.Module):
+    """State-space duality mixer (Mamba-2, arXiv:2405.21060) as the
+    ``granitemoehybrid`` checkpoints run it, one group: ``[z | xBC | dt] =
+    in_proj(x)`` (``d_inner``, ``d_inner + 2 N`` and ``heads`` wide, no
+    bias); ``xBC = silu(conv1d(xBC))`` (depthwise, causal, ``mamba_d_conv``
+    taps, with a bias); ``[x' | B | C] = xBC``, ``x'`` as ``[heads, d_head]``;
+    ``delta = softplus(dt + dt_bias)`` a head (no clamp); ``A = -exp(A_log)``
+    a head; the recurrence of :mod:`accelerate_tpu.ops.ssd_scan`;
+    ``RMSNorm_w(y * silu(z))`` over all of ``d_inner`` (the gate before the
+    norm, one group); ``out_proj``. ``exp``, ``softplus``, the recurrence
+    and the state are float32; weights and activations keep the stream's type.
+
+    With ``decode=True`` the layer keeps, in the ``cache`` collection and in
+    the dense and the paged serving layout alike, ``ssm_state`` ``[B,
+    d_state, d_inner]`` float32 (the heads' channels side by side along
+    the lanes: 4 MB a sequence at 128 x 8192) and ``conv_state`` ``[B,
+    (d_conv - 1) * (d_inner + 2 N)]``: the leaves :class:`MambaMixer` keeps,
+    under the same names (:data:`ops.paged_kv.STATE_LEAVES`), so paste,
+    clearing, the hand-off refusals and the row mask need no second list.
+    ``new_span`` and ``row_valid`` as :class:`MambaMixer` takes them: a
+    prefill window runs the chunked scan
+    (:func:`~accelerate_tpu.ops.ssd_scan.ssd_scan`), the paged tick's step
+    the kernel :func:`~accelerate_tpu.ops.pallas_ssd_step.ssd_state_step`
+    over the slots that decode, any other step the plain one."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, hidden, decode: bool = False, new_span=None, row_valid=None):
+        from ..ops import paged_kv
+        from ..ops.selective_scan import causal_conv1d
+        from ..ops.ssd_scan import ssd_scan, ssd_state_step_plain
+
+        cfg = self.config
+        dt, f32 = hidden.dtype, jnp.float32
+        bsz, t, _ = hidden.shape
+        heads, p, n, k = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.mamba_d_conv
+        d_in = heads * p
+        if cfg.mamba_n_groups != 1 or d_in != cfg.mamba_expand * cfg.hidden_size:
+            raise NotImplementedError(
+                f"Mamba-2 layers are built with one group of B / C and heads x d_head = expand x hidden, got "
+                f"mamba_n_groups={cfg.mamba_n_groups}, {heads} x {p} against {cfg.mamba_expand} x {cfg.hidden_size}")
+        conv_dim = d_in + 2 * n
+        conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(), (k, conv_dim))
+        conv_b = self.param("conv_bias", nn.initializers.zeros, (conv_dim,)) if cfg.mamba_conv_bias else None
+        # Mamba-2's own initialiser: softplus(dt_bias) log-uniform in [0.001, 0.1], A uniform in 1 .. 16, D = 1
+        dt_bias = self.param("dt_bias", _mamba_dt_bias_init, (heads,))
+        a_log = self.param("A_log", lambda key, shape: jnp.log(jax.random.uniform(key, shape, minval=1.0, maxval=16.0)), (heads,))
+        d_skip = self.param("D", nn.initializers.ones, (heads,))
+
+        if decode:
+            ssm = self.variable("cache", "ssm_state", jnp.zeros, (bsz, n, d_in), f32)
+            conv = self.variable("cache", "conv_state", jnp.zeros, (bsz, (k - 1) * conv_dim), dt)
+            h0, carried = ssm.value, conv.value
+        else:
+            h0, carried = jnp.zeros((bsz, n, d_in), f32), jnp.zeros((bsz, (k - 1) * conv_dim), dt)
+        lo, hi = (0, t) if new_span is None else new_span
+
+        with jax.named_scope("ssd.proj"):
+            zxd = _dense(cfg, 2 * d_in + 2 * n + heads, "in_proj", dt, cfg.mamba_proj_bias)(hidden)
+            z, xbc, step = zxd[..., :d_in], zxd[..., d_in : d_in + conv_dim], zxd[..., d_in + conv_dim :]
+            delta = jax.nn.softplus(step.astype(f32) + dt_bias.astype(f32))  # [B, T, heads]
+            a = -jnp.exp(a_log.astype(f32))
+        with jax.named_scope("ssd.conv"):
+            xbc, carried = causal_conv1d(xbc, conv_w, conv_b, carried, lo, hi)
+            xbc = nn.silu(xbc)
+            x = xbc[..., :d_in].reshape(bsz, t, heads, p)
+            b_t, c_t = xbc[..., d_in : d_in + n], xbc[..., d_in + n :]
+        if decode and t == 1 and paged_kv.active_paged_config() is not None and state_step_kernel():
+            # the serving tick's step: one pass over the state of the slots that decode (``row_valid``; every
+            # slot without it), in place (a vmapped dense tick and generate() take the plain step below)
+            from ..ops.pallas_ssd_step import ssd_state_step
+
+            with jax.named_scope("ssd.step"):
+                y, h = ssd_state_step(
+                    h0, x[:, 0], delta[:, 0], a, b_t[:, 0], c_t[:, 0], d_skip,
+                    None if row_valid is None else row_valid.reshape(bsz),
+                    interpret=jax.default_backend() != "tpu",
+                )
+                y = y[:, None]
+        elif t == 1 and new_span is None:
+            with jax.named_scope("ssd.step"):
+                y, h = ssd_state_step_plain(h0, x[:, 0], delta[:, 0], a, b_t[:, 0], c_t[:, 0], d_skip)
+                y = y[:, None]
+        else:
+            with jax.named_scope("ssd.scan"):
+                y, h = ssd_scan(x, delta, a, b_t, c_t, d_skip, h0, lo, hi, chunk=cfg.mamba_chunk_size)
+        if decode:
+            ssm.value, conv.value = h, carried
+        with jax.named_scope("ssd.proj"):
+            gated = y.reshape(bsz, t, d_in) * nn.silu(z.astype(f32))  # float32: the gate before the norm
+            normed = RMSNorm(cfg.rms_norm_eps, name="norm")(gated).astype(dt)
+            return _dense(cfg, cfg.hidden_size, "out_proj", dt, cfg.mamba_proj_bias)(normed)
+
+
 class ShortConvMixer(nn.Module):
     """Gated short convolution (LFM2's ``conv`` operator): ``[B, C, x] =
     split3(in_proj(u))``, in that order; ``y = C * conv1d(B * x)``, a causal
@@ -838,8 +990,9 @@ def _one_device() -> bool:
 
 
 def state_step_kernel() -> bool:
-    """Whether a paged decode step traced here steps a state-space layer's state through
-    :func:`~accelerate_tpu.ops.pallas_selective_scan.ssm_state_step`, which visits the slots ``row_valid``
+    """Whether a paged decode step traced here steps a state-space layer's state through its kernel
+    (:func:`~accelerate_tpu.ops.pallas_selective_scan.ssm_state_step`, or a Mamba-2 layer's
+    :func:`~accelerate_tpu.ops.pallas_ssd_step.ssd_state_step`), which visits the slots ``row_valid``
     names alone; the plain step (off the chip, across a mesh) steps every slot."""
     from ..ops import paged_kv
 
@@ -849,7 +1002,7 @@ def state_step_kernel() -> bool:
 class LlamaLayer(nn.Module):
     config: LlamaConfig
     routed: bool = False  # the FFN is RoutedFFN (a layer past ``first_k_dense_replace`` of a config with experts)
-    mixer: str = "attention"  # ``LlamaConfig.mixer_kind`` of this layer: "attention" | "mamba" | "conv"
+    mixer: str = "attention"  # ``LlamaConfig.mixer_kind`` of this layer: "attention" | "mamba" | "mamba2" | "conv"
 
     @nn.compact
     def __call__(self, hidden, positions, decode: bool = False, new_span=None, row_valid=None):
@@ -859,6 +1012,8 @@ class LlamaLayer(nn.Module):
         def attn(x):
             if self.mixer == "mamba":
                 return MambaMixer(cfg, name="mamba")(x, decode, new_span, row_valid)
+            if self.mixer == "mamba2":
+                return Mamba2Mixer(cfg, name="mamba")(x, decode, new_span, row_valid)
             if self.mixer == "conv":
                 return ShortConvMixer(cfg, name="conv")(x, decode, new_span)
             if cfg.stateful:
@@ -886,6 +1041,10 @@ class LlamaLayer(nn.Module):
             # Gemma2 convention: pre- AND post-norm around each sublayer
             hidden = hidden + norm("post_attn_norm")(attn(norm("input_norm")(hidden)))
             return hidden + norm("post_ffn_norm")(mlp(norm("pre_ffn_norm")(hidden)))
+        if cfg.residual_multiplier is not None:  # Granite: both branches scaled before the residual add
+            by = jnp.asarray(cfg.residual_multiplier, hidden.dtype)
+            hidden = hidden + attn(norm("input_norm")(hidden)) * by
+            return hidden + mlp(norm("post_attn_norm")(hidden)) * by
         hidden = hidden + attn(norm("input_norm")(hidden))
         return hidden + mlp(norm("post_attn_norm")(hidden))
 
@@ -922,6 +1081,8 @@ class LlamaModel(nn.Module):
             # cast to the stream dtype FIRST (HF casts to bf16 there, and
             # matching the rounding keeps fp32 parity tests exact)
             hidden = hidden * jnp.asarray(cfg.hidden_size**0.5, hidden.dtype)
+        if cfg.embedding_multiplier is not None:
+            hidden = hidden * jnp.asarray(cfg.embedding_multiplier, hidden.dtype)  # Granite
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(input_ids.shape[-1]), input_ids.shape)
         # constrain activations onto the mesh (seq axis = Megatron-SP)
@@ -931,7 +1092,7 @@ class LlamaModel(nn.Module):
 
         if cfg.scan_layers and cfg.stateful:
             raise NotImplementedError(
-                "layers with a recurrent state (Mamba, gated short convolution) are built with "
+                "layers with a recurrent state (Mamba, Mamba-2, gated short convolution) are built with "
                 "scan_layers=False: a scanned block shares one mixer across layers, and the carried pool "
                 "stack holds K/V pools only, no ssm_state or conv_state"
             )
@@ -1015,6 +1176,8 @@ class LlamaModel(nn.Module):
             logits = hidden.astype(jnp.float32) @ embed.embedding.astype(jnp.float32).T
         else:
             logits = nn.Dense(cfg.vocab_size, use_bias=False, name="lm_head", dtype=jnp.float32)(hidden)
+        if cfg.logits_scaling is not None:
+            logits = logits / cfg.logits_scaling  # Granite
         if cfg.final_logit_softcap is not None:
             from ..ops.attention import softcap
 

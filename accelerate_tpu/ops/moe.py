@@ -182,6 +182,17 @@ def sigmoid_topk_routing(
     return experts.astype(jnp.int32), weights * scaling_factor
 
 
+def softmax_topk_routing(router_logits: jax.Array, num_selected: int):
+    """Dropless top-k routing as Granite's hybrid checkpoints publish it
+    (``granitemoehybrid``'s ``TopKGating``): a token's ``num_selected``
+    experts are its largest raw logits, and their weights a softmax over
+    THOSE logits alone (not a softmax over every expert cut to its top),
+    in float32. No bias, no scaling factor. Returns what
+    :func:`sigmoid_topk_routing` does."""
+    top, experts = jax.lax.top_k(router_logits.astype(jnp.float32), num_selected)
+    return experts.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
 def _ragged_swiglu_ffn(xs, wi_gate, wi_up, wo, group_sizes):
     """The experts' three grouped products as ``jax.lax.ragged_dot`` calls: rows ``xs [m, d]`` sorted by group."""
     h = nn.silu(jax.lax.ragged_dot(xs, wi_gate.astype(xs.dtype), group_sizes))
@@ -218,6 +229,7 @@ def dropless_moe_ffn(
     wi_up: jax.Array,  # [E, d, ff]
     wo: jax.Array,  # [E, ff, d]
     row_valid: Optional[jax.Array] = None,  # [T] bool: the tokens that count; None = every one
+    first_expert: Optional[int] = None,  # the id, among the router's, of the first expert held; None = all are held
 ):
     """Routed SwiGLU experts with no capacity and no ``[T, E, C]`` mask:
     the ``T * k`` token-expert pairs are sorted by expert and the three
@@ -241,17 +253,32 @@ def dropless_moe_ffn(
     their rows of ``out`` are exactly zero. The rows that count are what
     the call without the mask gives, bit for bit.
 
+    ``first_expert`` says that the stacked matrices are a *share* of the
+    experts the router chose among: the ``E`` experts ``first_expert ..
+    first_expert + E - 1`` of its columns (one chip's share under expert
+    parallelism). A pair whose expert lives elsewhere takes the same road
+    as a token that does not count: behind the last group, visited by no
+    product, zero in the sum. ``out`` is then the held experts' part of
+    the routed sum, under the weights the router gave over all its
+    experts; the shares of every chip add up to the whole. Nothing here
+    stands in for the other chips or the exchange with them.
+
     Returns ``(out [T, d], group_sizes [E])``; ``group_sizes`` (pairs per
-    expert, summing to the pairs of the tokens that count) is what
-    :func:`expert_load` reads."""
+    held expert, summing to the pairs of the tokens that count which
+    reached one) is what :func:`expert_load` reads."""
     from . import paged_kv
     from .attention import active_mesh
 
     t, k = experts.shape
     e = wi_gate.shape[0]
     flat = experts.reshape(t * k)
-    if row_valid is not None:
-        flat = jnp.where(jnp.repeat(row_valid, k), flat, e)  # behind the last group, and in none
+    counts = None if row_valid is None else jnp.repeat(row_valid, k)  # [T * k] bool: the pairs a product visits
+    if first_expert is not None:
+        flat = flat - first_expert
+        held = (flat >= 0) & (flat < e)
+        counts = held if counts is None else counts & held
+    if counts is not None:
+        flat = jnp.where(counts, flat, e)  # behind the last group, and in none
     order = jnp.argsort(flat, stable=True)  # pairs grouped by expert
     group_sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
     on_tpu = jax.default_backend() == "tpu"
@@ -265,9 +292,9 @@ def dropless_moe_ffn(
         # back to token order by a gather (the inverse permutation), then the weighted sum
         back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k, dtype=order.dtype))
         ys = ys[back].reshape(t, k, -1)
-        if row_valid is not None:
+        if counts is not None:
             # a row no product visited is whatever the kernel's output buffer held: zero before the sum (0 x NaN is NaN)
-            ys = jnp.where(row_valid[:, None, None], ys, 0)
+            ys = jnp.where(row_valid[:, None, None] if first_expert is None else counts.reshape(t, k, 1), ys, 0)
         out = jnp.einsum("tkd,tk->td", ys.astype(jnp.float32), weights.astype(jnp.float32)).astype(x.dtype)
     return out, group_sizes
 

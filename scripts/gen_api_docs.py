@@ -62,6 +62,8 @@ MODULES = [
     "accelerate_tpu.ops.pallas_latent_attention",
     "accelerate_tpu.ops.selective_scan",
     "accelerate_tpu.ops.pallas_selective_scan",
+    "accelerate_tpu.ops.ssd_scan",
+    "accelerate_tpu.ops.pallas_ssd_step",
     "accelerate_tpu.ops.pallas_grouped_matmul",
     "accelerate_tpu.ops.moe",
     "accelerate_tpu.ops.fp8",

@@ -399,6 +399,100 @@ def test_lfm2_moe_prefill_bucket_compiles_for_v5e(v5e, monkeypatch):
     assert cache["layer_2"]["attn"]["key"].shape == (1, 2304, 8, 64)
 
 
+def test_ssd_state_step_compiles_for_v5e_and_steps_the_state_in_place(v5e):
+    """The Mamba-2 state-step kernel at granite-4.0-h-small's widths and the cell's 64 slots: ``h`` ``[64, 128,
+    8192]`` float32 (4 MB a slot) left in HBM and aliased to the output, four VMEM buffers of one slot's state
+    (16 MB: the kernel asks for its own limit), the ``[slots]`` mask scalar-prefetched. Mosaic accepts the
+    layout (the state axis along the sublanes, the heads' channels along the lanes) and XLA copies no state."""
+    import re
+
+    from accelerate_tpu.ops.pallas_ssd_step import ssd_state_step
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    slots, n, heads, p = 64, 128, 128, 64
+    f32 = jnp.float32
+    fn = functools.partial(ssd_state_step, interpret=False)
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        _on(chip, (slots, n, heads * p), f32), _on(chip, (slots, heads, p)), _on(chip, (slots, heads), f32), _on(chip, (heads,), f32),
+        _on(chip, (slots, n)), _on(chip, (slots, n)), _on(chip, (heads,), f32), _on(chip, (slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l and "custom-call(" in l]
+    assert len(calls) == 1 and "ssd_state_step" in calls[0]
+    moved = [l.strip()[:160] for l in text.splitlines() if re.search(rf"= f32\[{slots},{n},{heads * p}\]\S* (copy|transpose)\(", l)]
+    assert not moved, "the call copies or re-lays the state:\n" + "\n".join(moved)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= slots * n * heads * p * 4 and m.temp_size_in_bytes < 32 * 2**20
+
+
+def test_granite_hybrid_decode_tick_fits_one_v5e_chip_and_copies_no_state(v5e, monkeypatch):
+    """The 64-slot decode tick of the ``granite-4.0-h-small-serve-longanswer`` cell at its real size: 4.76 B
+    parameters (36 of 72 experts a layer held), nine states of ``[64, 128, 8192]`` float32 (2.42 GB), nine
+    convolution states and one K/V pool as arguments, all aliased to the output; nine ``ssd_state_step``
+    kernels, one ``paged_decode_attention`` and 10 x 2 grouped expert kernels over ``[36, 4096, 768]`` a step,
+    and no operation that copies or re-lays a state leaf or the pool whole. A compile is not a chip run."""
+    import contextlib
+    import re
+
+    s, engine = _cell_engine("granite-4.0-h-small-l10")
+    assert engine.metrics.state_bytes_per_slot == 9 * (128 * 8192 * 4 + 3 * 8448 * 2) and engine._mask_idle_rows
+    chip = SingleDeviceSharding(v5e.devices[0])
+    raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernels
+    with contextlib.ExitStack() as stack:
+        for ctx in contexts:
+            stack.enter_context(ctx())
+        compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    slots, blocks, bs = s["num_slots"], s["pool_blocks"], s["paged_block_size"]
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l and "custom-call(" in l]
+    assert sum("ssd_state_step" in l for l in calls) == 9 and sum("paged_decode_attention" in l for l in calls) == 1
+    products = _expert_products(text)  # ten expert layers of two grouped kernels; XLA's own 512-row lowering of none
+    assert len(products) == 20 and all("tpu_custom_call" in p for p in products), [p[:160] for p in products]
+    assert "bf16[36,4096,768]" in text and "bf16[72,4096,768]" not in text, "the layer holds its share of the experts"
+    leaf = rf"(f32\[{slots},128,8192\]|bf16\[{slots},25344\]|bf16\[{blocks},{bs},8,128\])"
+    moved = [l.strip()[:160] for l in text.splitlines() if re.search(rf"= {leaf}\S* (copy|transpose)\(", l)]
+    assert not moved, "the tick copies or re-lays a state leaf or the pool:\n" + "\n".join(moved)
+    m = compiled.memory_analysis()
+    state_bytes = 9 * slots * (128 * 8192 * 4 + 25344 * 2) + 2 * blocks * bs * 8 * 128 * 2
+    assert m.alias_size_in_bytes >= state_bytes and m.temp_size_in_bytes < 0.25 * 2**30
+    assert m.argument_size_in_bytes > 9.51e9 + state_bytes, "weights, states and the pool are arguments at their real size"
+    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert total <= 11.9 * 2**30, f"the 64-slot tick needs {total / 2**30:.2f} GiB"
+
+
+def test_granite_hybrid_prefill_bucket_and_paste_compile_for_v5e(v5e, monkeypatch):
+    """The 1024-token prefill of the same cell: nine chunked scans (four chunks of 256: a chunk's ``[256, 256,
+    128]`` decays are 33 MB, the state is touched once a chunk), 10 x 2 grouped kernels, temporaries under
+    half a GiB, and a row cache whose state leaves are one row; and ``paste_row`` of that row cache, 2.42 GB of
+    state donated, writes a slot's 4 MB a layer in place."""
+    import re
+
+    from accelerate_tpu.ops.paged_kv import paste_row
+
+    s, engine = _cell_engine("granite-4.0-h-small-l10")
+    chip = SingleDeviceSharding(v5e.devices[0])
+    on = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)  # noqa: E731
+    prefill, prefill_args, _ = engine._perf_programs["prefill"]
+    args = on(prefill_args(1024))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(prefill).lower(*args).compile()
+    assert len(_expert_products(compiled.as_text())) == 20
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 0.5 * 2**30, f"the prefill's temporaries are {m.temp_size_in_bytes / 2**30:.2f} GiB"
+    cache = jax.eval_shape(prefill, *args)[2]
+    assert cache["layer_0"]["mamba"]["ssm_state"].shape == (1, 128, 8192) and cache["layer_0"]["mamba"]["ssm_state"].dtype == jnp.float32
+    assert cache["layer_0"]["mamba"]["conv_state"].shape == (1, 3 * 8448) and cache["layer_5"]["attn"]["key"].shape == (1, 2304, 8, 128)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)  # noqa: E731
+    pasted = jax.jit(paste_row, donate_argnums=(0,)).lower(
+        on(engine.slot_caches), on(engine._row_template), i32(engine._mb), i32(engine._mb), i32(), i32()).compile()
+    leaf = rf"(f32\[{s['num_slots']},128,8192\]|bf16\[{s['pool_blocks']},[^\]]*\])"
+    moved = [l.strip()[:160] for l in pasted.as_text().splitlines() if re.search(rf"= {leaf}\S* (copy|transpose)\(", l)]
+    assert not moved, "paste_row copies or re-lays a state leaf or the pool:\n" + "\n".join(moved)
+    pm = pasted.memory_analysis()
+    assert pm.temp_size_in_bytes < 64 * 2**20 and pm.alias_size_in_bytes > 3.0e9
+
+
 @pytest.mark.parametrize(
     "n_in,n_out,group",
     [(4096, 14336, 128), (14336, 4096, 128), (4096, 4096, 64)],
