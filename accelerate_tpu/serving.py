@@ -428,6 +428,11 @@ class ServingEngine:
         self.slot_phase: list[Optional[str]] = [None] * num_slots
         self._prefill_state: list[Optional[dict]] = [None] * num_slots
         self._prefill_order: list[int] = []  # prefilling slots, admission order
+        # slot -> (first token, its logprob, the request, the fused prefill's ``dispatched`` stamp or None): fresh
+        # admissions of the running tick whose first token is still on the device. The decode pass feeds each into its ``toks`` there and
+        # the host reads them only once that pass is dispatched; none outlives ``step()``
+        self._first_pending: dict[int, tuple] = {}
+        self._tick_first_deferred = 0  # of this tick's admissions, those read after the decode dispatch
         # pending requests, kept sorted by the scheduler's order key
         # (priority class, then submission order)
         self.queue: list[_Request] = []
@@ -571,6 +576,13 @@ class ServingEngine:
             )
 
         self._insert = ctx_jit(insert)
+
+        def feed_first_token(toks, slot, tok):
+            """``toks`` with a pending admission's first token in its slot: the token goes from the prefill
+            to the decode tick without a visit to the host. One shape, called once a pending admission."""
+            return toks.at[slot].set(tok.astype(toks.dtype))
+
+        self._feed_first_token = ctx_jit(feed_first_token)
 
         # Decode K steps per host round-trip: one sync per TOKEN pays the
         # dispatch and fetch latency on every token; the block scan
@@ -1141,7 +1153,9 @@ class ServingEngine:
         Safe at every labeled serving crash point by construction: the
         crash hooks fire BEFORE the jitted tick calls, so the host
         bookkeeping (out_tokens, slot_pos, slot keys, unconsumed
-        handoffs) is always consistent when a failover export runs."""
+        handoffs) is always consistent when a failover export runs, and a
+        tick that one of them cuts short reads the first tokens it had
+        left on the device before it raises (:meth:`_tick_phases`)."""
         jax = _jax()
         kv_ok = include_kv and not self.paged
         if kv_ok:
@@ -1374,6 +1388,18 @@ class ServingEngine:
         ONE decode tick for every decoding slot. Returns the number of
         occupied slots after the tick.
 
+        The tick's programs are queued on the device back to back: every
+        admission's prefill and paste, then the decode tick, with no host
+        wait between them. A fresh admission's first token stays on the
+        device, is fed to the decode tick there, and the host reads it
+        (``engine.prefill.sync``, the TTFT instant) only once the decode
+        tick is dispatched; then it waits for the tick's tokens and walks
+        them. So the host's admission and dispatch work runs under the
+        prefill before it. No first token is pending when ``step()``
+        returns (nor when it raises): ``partial``, ``poll``, ``cancel``
+        and ``export_inflight`` between steps find every token a prefill
+        sampled.
+
         With the default config (unlimited budget) every admitted prefill
         completes in its admission tick — the pre-scheduler behavior.
         With a budget, a long prompt streams one chunk window per tick
@@ -1414,7 +1440,7 @@ class ServingEngine:
         profile shows inside ``engine.tick``."""
         m = self.metrics
         admitted, tokens_was, completed_was = 0, m.tokens_generated, m.requests_completed
-        self._tick_prefill_tokens = 0
+        self._tick_prefill_tokens = self._tick_first_deferred = 0
         self._tick_expert_load = (0, 0, 0, 0)
         self._tick_state_idle = 0
         with phase("engine.schedule"):
@@ -1431,41 +1457,48 @@ class ServingEngine:
         # in at most windows-many ticks under sustained arrivals. Decodes
         # tick every step regardless — the per-tick prefill stall is
         # bounded by budget + two forced windows, never a whole prompt.
-        force = True
-        while self.queue:
-            if budget <= 0 and not force:
-                break
-            slot = next((s for s in range(self.num_slots) if self.slot_req[s] is None), None)
-            if slot is None:
-                # priority inversion: a strictly more important request
-                # waits while a lower class decodes — evict the youngest
-                # such decode (policy-gated; None without preemption)
-                with phase("engine.schedule"):
-                    slot = self._sched.pick_victim(self.queue[0].priority, self._decoding_info())
-                    if slot is not None:
-                        self._preempt(slot)
-                if slot is None:
+        try:
+            force = True
+            while self.queue:
+                if budget <= 0 and not force:
                     break
-            head = self.queue[0]
-            with phase(
-                "engine.admit", uid=head.uid, slot=slot, prompt_tokens=len(head.prompt),
-                queue_wait_ms=(time.monotonic() - head.submit_ts) * 1000.0,
-            ):
-                if not self._admit(slot):
-                    break  # pool blocked: the whole queue waits on its head
-            admitted += 1
-            budget = self._advance_prefill(slot, budget, force=force)
-            force = False
-        force = True
-        for slot in list(self._prefill_order):
-            budget = self._advance_prefill(slot, budget, force=force)
-            force = False
-        if any(ph == "decode" for ph in self.slot_phase):
-            self._decode_pass()
+                slot = next((s for s in range(self.num_slots) if self.slot_req[s] is None), None)
+                if slot is None:
+                    # priority inversion: a strictly more important request
+                    # waits while a lower class decodes — evict the youngest
+                    # such decode (policy-gated; None without preemption)
+                    with phase("engine.schedule"):
+                        slot = self._sched.pick_victim(self.queue[0].priority, self._decoding_info())
+                        if slot is not None:
+                            self._preempt(slot)
+                    if slot is None:
+                        break
+                head = self.queue[0]
+                with phase(
+                    "engine.admit", uid=head.uid, slot=slot, prompt_tokens=len(head.prompt),
+                    queue_wait_ms=(time.monotonic() - head.submit_ts) * 1000.0,
+                ):
+                    if not self._admit(slot):
+                        break  # pool blocked: the whole queue waits on its head
+                admitted += 1
+                budget = self._advance_prefill(slot, budget, force=force)
+                force = False
+            force = True
+            for slot in list(self._prefill_order):
+                budget = self._advance_prefill(slot, budget, force=force)
+                force = False
+            if any(ph == "decode" for ph in self.slot_phase):
+                self._decode_pass()
+        finally:
+            # no pending first token outlives step(): the decode pass has read them all behind its dispatch,
+            # and a tick that a crash point cut short reads here what it had admitted, so that whoever
+            # exports this engine's requests finds every first token a prefill sampled
+            self._read_first_tokens()
         with phase("engine.expire"):
             self._expire_window_blocks()
         with phase(
-            "engine.tick.done", admitted=admitted, prefill_tokens=self._tick_prefill_tokens,
+            "engine.tick.done", admitted=admitted, first_tokens_deferred=self._tick_first_deferred,
+            prefill_tokens=self._tick_prefill_tokens,
             emitted=m.tokens_generated - tokens_was, retired=m.requests_completed - completed_was,
             pool_blocked=int(self._pool_blocked), free_blocks=self._alloc.free_count if self.paged else -1,
             queue_len=len(self.queue), experts_touched=self._tick_expert_load[0],
@@ -1711,10 +1744,14 @@ class ServingEngine:
         return budget
 
     def _finalize_prefill(self, slot: int, row_cache, total: int, next_tok, lp, key) -> None:
-        """Prefill complete: paste/insert the row cache, move the slot to
-        the decode phase, and either emit the sampled first token (TTFT)
-        or — resume — re-feed the carried last token at the recomputed
-        frontier without sampling anything."""
+        """Prefill complete: paste/insert the row cache and move the slot
+        to the decode phase. A resume re-feeds the carried last token at
+        the recomputed frontier without sampling anything; a hand-off's
+        first token came on the host. A fresh admission's is still on the
+        device: it is noted as pending, nothing here waits for it, and the
+        tick's decode pass feeds it on the device and reads it behind its
+        own dispatch (:meth:`_read_first_token`: TTFT). Only a request that
+        can take one token, which no decode pass will advance, is read here."""
         jnp = _jax().numpy
         st = self._prefill_state[slot]
         req = st["req"]
@@ -1730,11 +1767,11 @@ class ServingEngine:
         self._prefill_state[slot] = None
         self._prefill_order.remove(slot)
         self.slot_phase[slot] = "decode"
+        self.slot_pos[slot] = total
         if st["resume"]:
             # token- and logprob-exact by construction: nothing is
             # re-sampled; already-streamed tokens/logprobs are untouched
             self.slot_tok[slot] = int(req.out_tokens[-1])
-            self.slot_pos[slot] = total
             self.metrics.on_resume(req.uid)
             self._log.event(
                 "resume", uid=req.uid, priority=req.priority,
@@ -1743,10 +1780,21 @@ class ServingEngine:
             if self.tracer is not None:
                 self.tracer.seg(req.trace, "resume", recomputed_tokens=int(total))
             return
+        self._first_pending[slot] = (next_tok, lp, req, st.get("dispatched"))  # not ``st``: it holds a chunk path's logits
+        if not isinstance(next_tok, _jax().Array) or req.max_new_tokens == 1:
+            self._read_first_token(slot)
+
+    def _read_first_token(self, slot: int) -> None:
+        """Bring a pending admission's first token to the host (the sync:
+        TTFT) and record it; a request that it ends is retired, and
+        whatever a dispatched decode pass computes for its slot reaches
+        nobody (its blocks are freed behind that pass: the device runs its
+        programs in order)."""
+        next_tok, lp, req, dispatched = self._first_pending.pop(slot)
         with phase("engine.prefill.sync", uid=req.uid):
             tok, lp = int(next_tok), float(lp)  # the first token exists on the host from here
-        if self.tracer is not None and "dispatched" in st:
-            t0, b = st["dispatched"]
+        if self.tracer is not None and dispatched is not None:
+            t0, b = dispatched
             self.tracer.seg(
                 req.trace, "prefill", tokens=b, compute_ms=round((time.perf_counter() - t0) * 1000.0, 3)
             )
@@ -1760,13 +1808,23 @@ class ServingEngine:
             self._retire(slot)
             return
         self.slot_tok[slot] = tok
-        self.slot_pos[slot] = total
+
+    def _read_first_tokens(self) -> None:
+        """Every pending first token, in admission order."""
+        for slot in list(self._first_pending):
+            self._read_first_token(slot)
 
     def _preempt(self, slot: int) -> None:
         """Evict a decoding slot: requeue its request with the
         generated-so-far tokens and its sampling chain, free the slot and
         its KV blocks now. The resume admission rebuilds the cache by
-        chunked recomputation — see :meth:`_finalize_prefill`."""
+        chunked recomputation — see :meth:`_finalize_prefill`. A slot
+        admitted in this very tick has its first token read first: the
+        request carries it away, or ends with it and needs no eviction."""
+        if slot in self._first_pending:
+            self._read_first_token(slot)
+            if self.slot_req[slot] is None:
+                return
         req = self.slot_req[slot]
         req.resume_key = self._slot_keys[slot]
         req.preempted = True
@@ -1783,7 +1841,10 @@ class ServingEngine:
 
     def _decode_pass(self) -> None:
         """ONE jitted K-step tick for every decode-phase slot, then the
-        host walk that streams tokens/logprobs out. Prefilling slots
+        host walk that streams tokens/logprobs out. A slot admitted in
+        this tick takes its first token from the prefill on the device
+        (``feed_first_token``), and the host reads that token between the
+        tick's dispatch and its sync, while the device works. Prefilling slots
         compute garbage rows by construction (static shapes) — their
         caches are fully replaced at prefill paste/insert. A tick that
         takes :meth:`_decoding_arg` spends no expert and no state-space
@@ -1797,10 +1858,18 @@ class ServingEngine:
             "engine.decode.dispatch", decoding=n_decoding, tick_block=self.tick_block,
             live_tokens=int(self.slot_pos[decoding].sum()),
         ):
+            toks = jnp.asarray(self.slot_tok)
+            for slot, pending in self._first_pending.items():
+                toks = self._feed_first_token(toks, jnp.int32(slot), pending[0])
             self.slot_caches, toks_k, lps_k, self._slot_keys, load_k = self._decode_tick(
                 self.model.params, self.slot_caches,
-                jnp.asarray(self.slot_tok), jnp.asarray(self.slot_pos), self._slot_keys, *self._decoding_arg(decoding)
+                toks, jnp.asarray(self.slot_pos), self._slot_keys, *self._decoding_arg(decoding)
             )
+        # the tick's programs are queued back to back; only now does the host wait for the admissions' first
+        # tokens (a request that its first token ends is retired here, and the walk below finds its slot empty)
+        self._tick_first_deferred = len(self._first_pending)
+        self.metrics.on_first_tokens_deferred(self._tick_first_deferred)
+        self._read_first_tokens()
         if self._steps_idle_state:
             # the tick steps every slot's recurrent state (a state-space layer's step kernel is told which slots
             # decode and visits no other: then nothing is counted); this many slot-steps of it decode nothing

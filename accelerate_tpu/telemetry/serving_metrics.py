@@ -143,6 +143,11 @@ class ServingMetrics:
         # layer is told which slots decode and visits no other: 0 then)
         self.state_bytes_per_slot = 0
         self.state_slots_idle = 0
+        # admissions whose first token the host read behind the decode
+        # dispatch of their tick (``first_tokens_deferred`` of
+        # ``engine.tick.done``); ``prefills`` less it took the road with a
+        # wait inside the admission: a hand-off, a request of one token
+        self.first_tokens_deferred = 0
         # the host loop itself, from the phase log's tick records
         # (telemetry.trace.PhaseLog): ticks made, the longest one's wall
         # time, and how many closed far beyond the median of the ticks
@@ -216,6 +221,9 @@ class ServingMetrics:
 
     def on_state_step(self, slots_idle: int):
         self.state_slots_idle += slots_idle
+
+    def on_first_tokens_deferred(self, n: int):
+        self.first_tokens_deferred += n
 
     def on_expert_load(self, touched: int, pairs_max: int, tile_visits: int, pairs: int):
         self.experts_touched += touched

@@ -84,9 +84,18 @@ SEGMENTS = (
     "drain",
 )
 
-#: program phase -> the PERF.md layer it belongs to. One ``engine.tick``
-#: is tiled by its children (``engine.tick.done`` is a zero-length
-#: marker that carries the tick's counts: admissions, tokens, the pool,
+#: program phase -> the PERF.md layer it belongs to, in the order a tick
+#: runs them: every admission's ``engine.admit``,
+#: ``engine.prefill.dispatch`` and ``engine.prefill.paste``, then
+#: ``engine.decode.dispatch``, and only then one ``engine.prefill.sync``
+#: an admission whose first token was still on the device (the host
+#: waits for nothing between the programs of one tick; a hand-off's
+#: token and that of a request of one token are read inside the
+#: admission, before the next), ``engine.decode.sync`` and the walk. One
+#: ``engine.tick`` is tiled by its children (``engine.tick.done`` is a
+#: zero-length marker that carries the tick's counts: admissions and, of
+#: them, ``first_tokens_deferred``, those whose first token the host
+#: read behind the decode dispatch; tokens, the pool,
 #: the routed experts' load (``experts_touched``, ``expert_pairs_max``,
 #: ``expert_tile_visits`` and ``expert_pairs``, the token-expert pairs
 #: the grouped products multiplied: the decoding slots' alone) and
@@ -105,8 +114,8 @@ PHASES = {
     "engine.admit": "scheduler",
     "engine.prefill.dispatch": "jitted programs",
     "engine.prefill.paste": "jitted programs",
-    "engine.prefill.sync": "jitted programs",
     "engine.decode.dispatch": "jitted programs",
+    "engine.prefill.sync": "jitted programs",
     "engine.decode.sync": "jitted programs",
     "engine.decode.walk": "engine host loop",
     "engine.expire": "engine host loop",

@@ -97,6 +97,47 @@ def test_children_tile_each_tick_and_the_done_counts_land_on_it_once(tiny_llama,
     assert init["t0_ns"] + init["wall_ns"] <= submits[0]["t0_ns"]
 
 
+def test_a_tick_reads_its_first_tokens_behind_the_decode_dispatch(tiny_llama, fresh_log, monkeypatch):
+    """No profiler: the annotation a session would get records the spans' order instead. In a tick that
+    admits and decodes the programs are queued back to back (prefill, paste, prefill, paste, decode tick)
+    and only then does the host wait for each admission's first token, in admission order."""
+    seen = []
+
+    class Recorded:
+        def __init__(self, name, **counts):
+            self.name, self.counts = name, counts
+
+        def __enter__(self):
+            seen.append(("open", self.name, self.counts.get("uid")))
+
+        def __exit__(self, *exc):
+            seen.append(("close", self.name, self.counts.get("uid")))
+
+    monkeypatch.setattr(trace, "_annotation", Recorded)
+    engine = toy_engine(tiny_llama)
+    uids = offer(engine, PROMPTS[:3])  # two slots: two admissions in the first tick, the third later
+    engine.step()
+    (tick,) = fresh_log.roots("engine.tick")
+    assert tick.done["admitted"] == tick.done["first_tokens_deferred"] == 2
+    names = [(what, name) for what, name, _ in seen]
+    dispatched = names.index(("close", "engine.decode.dispatch"))
+    assert dispatched < names.index(("open", "engine.prefill.sync")) < names.index(("open", "engine.decode.sync"))
+    assert max(i for i, n in enumerate(names) if n == ("close", "engine.prefill.paste")) < names.index(("open", "engine.decode.dispatch"))
+    assert [uid for what, name, uid in seen if (what, name) == ("open", "engine.prefill.sync")] == uids[:2]
+    order = list(tick.children)  # a record keeps its children in the order they first closed
+    assert order.index("engine.prefill.paste") < order.index("engine.decode.dispatch") < order.index("engine.prefill.sync") \
+        < order.index("engine.decode.sync") < order.index("engine.decode.walk")
+    assert tick.children["engine.prefill.sync"][0] == 2 and tick.children["engine.decode.dispatch"][0] == 1
+    for uid in uids[:2]:  # a request's spans keep their order
+        assert [name for what, name, u in seen if what == "open" and u == uid] == [
+            "engine.submit", "engine.admit", "engine.prefill.dispatch", "engine.prefill.paste", "engine.prefill.sync"]
+    engine.run()
+    ticks = fresh_log.roots("engine.tick")
+    assert sum(r.done["first_tokens_deferred"] for r in ticks) == engine.metrics.first_tokens_deferred == 3
+    assert sum(r.children.get("engine.prefill.sync", [0])[0] for r in ticks) == 3
+    assert list(trace.PHASES).index("engine.decode.dispatch") < list(trace.PHASES).index("engine.prefill.sync")
+
+
 def test_a_train_step_is_a_root_too(fresh_log):
     with phase("train.step", step=4, do_sync=1):
         with phase("train.step.args"):

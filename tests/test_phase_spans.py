@@ -120,6 +120,27 @@ def test_uid_links_submit_admit_and_first_token(served):
         assert names == ["engine.submit", "engine.admit", "engine.prefill.dispatch", "engine.prefill.paste", "engine.prefill.sync"]
 
 
+def test_traced_ticks_show_the_decode_dispatch_ahead_of_the_first_token_syncs(served):
+    _, profiled, phases, _ = served
+    spans = phases["spans"]
+    deferred = 0
+    for i in program_trace.named(phases, "engine.tick"):
+        children = [spans[j] for j in spans[i]["children"]]
+        done = children[-1]["stats"]
+        syncs = [c for c in children if c["name"] == "engine.prefill.sync"]
+        dispatch = [c for c in children if c["name"] == "engine.decode.dispatch"]
+        assert done["first_tokens_deferred"] == done["admitted"] == len(syncs), "every admission here is a fresh one"
+        if syncs:
+            assert len(dispatch) == 1 and dispatch[0]["end"] <= syncs[0]["start"] + 1e-9
+            after = [c["name"] for c in children if c["start"] >= syncs[-1]["end"] - 1e-9]
+            assert after[:2] == ["engine.decode.sync", "engine.decode.walk"]
+        deferred += done["first_tokens_deferred"]
+    assert deferred == len(PROMPTS)
+    # the benchmark's readers still pair each dispatch with the sync behind it
+    assert program_trace.decode_step_ms(phases) > 0 and program_trace.prefill_ms_per_ktok(phases) > 0
+    assert program_trace.first_token_hold_ms(phases) >= 0
+
+
 def test_mono_ns_places_a_tracer_window_inside_the_ticks_that_decoded_it(served):
     _, _, phases, tracer = served
     spans = phases["spans"]

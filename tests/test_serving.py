@@ -475,3 +475,161 @@ def test_serving_metrics_mirror_to_event_log(tiny_llama, tmp_path):
     report = summarize(events)
     assert report["serving"]["requests_completed"] == 1
     assert "tokens_generated" in render_text(report)
+
+
+# -- a tick's programs queued back to back: first tokens read behind the decode dispatch
+
+LAYOUTS = pytest.mark.parametrize("paged", [None, 8], ids=["dense", "paged"])
+
+# what the engine gave before first tokens were deferred (commit 513a685, CPU): five sampled requests
+# (temperature 0.8, seed 5, tick_block 3; prompts of 8, 6, 5, 12, 20 tokens from ``default_rng(0)``), the same
+# in both layouts. Three of them are admitted in one tick beside a request that decodes
+RECORDED_TOKENS = [
+    [130, 230, 241, 136, 107, 246, 90, 241, 90], [109, 45, 79, 46, 240, 74, 15], [36, 131, 94, 65, 234, 109, 246],
+    [63, 255, 9, 225, 236, 62, 91], [11, 79, 28, 121, 21, 222, 6],
+]
+RECORDED_LOGPROBS = [
+    [-4.5971, -4.2934, -4.5173, -4.0374, -6.0532, -6.8456, -4.6754, -4.8493, -5.6424],
+    [-4.4046, -6.4540, -3.8084, -5.8701, -2.9244, -6.4326, -5.1898],
+    [-4.9667, -3.5736, -5.4003, -5.0630, -3.3387, -3.2828, -3.6970],
+    [-4.5048, -4.5741, -4.5004, -4.5689, -4.2270, -4.7444, -5.5905],
+    [-4.3890, -6.3168, -5.5113, -4.3119, -5.5242, -4.3124, -5.4492],
+]
+
+
+@pytest.fixture(scope="module")
+def tiny_llama64():
+    return create_llama_model(LlamaConfig.tiny(), seq_len=64)
+
+
+def _last_tick():
+    from accelerate_tpu.telemetry.trace import phase_log
+
+    return phase_log().roots("engine.tick", 1)[0]
+
+
+def _step_whole(eng):
+    """One tick, then what holds between any two: no first token is pending, and every request that has
+    been admitted and prefilled shows its first token to ``partial`` and to ``export_inflight``."""
+    eng.step()
+    assert not eng._first_pending
+    exported = {snap["uid"]: snap for snap in eng.export_inflight(include_kv=False)}
+    for slot, req in enumerate(eng.slot_req):
+        if req is not None and eng.slot_phase[slot] == "decode":
+            assert len(eng.partial(req.uid)) >= 1
+            assert exported[req.uid]["out_tokens"] == eng.partial(req.uid).tolist()
+    return _last_tick().done
+
+
+@LAYOUTS
+def test_admissions_of_one_tick_give_the_recorded_tokens_and_logprobs(tiny_llama64, paged):
+    eng = ServingEngine(tiny_llama64, num_slots=4, prompt_buckets=(8, 16), paged_block_size=paged, max_len=64,
+                        temperature=0.8, seed=5, tick_block=3)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(5, 250, size=n).astype(np.int32) for n in (8, 6, 5, 12, 20)]
+    uids = [eng.submit(prompts[0], 9)]
+    assert _step_whole(eng)["first_tokens_deferred"] == 1
+    _step_whole(eng)
+    uids += [eng.submit(p, 7) for p in prompts[1:]]
+    done = _step_whole(eng)  # three free slots: three admissions, queued behind one another, and one decode pass
+    assert done["admitted"] == done["first_tokens_deferred"] == 3
+    assert [len(eng.partial(u)) for u in uids] == [9, 1 + 3, 1 + 3, 1 + 3, 0]
+    while eng.queue or eng.active_count:
+        _step_whole(eng)
+    for u, prompt, tokens, lps in zip(uids, prompts, RECORDED_TOKENS, RECORDED_LOGPROBS):
+        assert eng.done[u][len(prompt):].tolist() == tokens
+        np.testing.assert_allclose(eng.logprobs(u), lps, atol=2e-4)
+    assert eng.metrics.first_tokens_deferred == eng.metrics.prefills == 5
+
+
+@LAYOUTS
+def test_a_request_of_one_token_never_joins_a_decode_pass(tiny_llama64, paged):
+    eng = ServingEngine(tiny_llama64, num_slots=2, prompt_buckets=(8,), paged_block_size=paged, max_len=64, tick_block=2)
+    one, more = np.arange(1, 7, dtype=np.int32), np.arange(20, 25, dtype=np.int32)
+    a, b = eng.submit(one, 1), eng.submit(more, 4)
+    done = _step_whole(eng)
+    assert done["admitted"] == 2 and done["first_tokens_deferred"] == 1 and done["retired"] == 1
+    np.testing.assert_array_equal(eng.poll(a), _reference(tiny_llama64, one, 1))
+    assert len(eng.partial(b)) == 3  # its first token and the pass's two
+    eng.run()
+    np.testing.assert_array_equal(eng.poll(b), _reference(tiny_llama64, more, 4))
+    # alone in its tick: no decode pass follows, nothing is deferred, the token is there when step() returns
+    c = eng.submit(one, 1)
+    done = _step_whole(eng)
+    assert done["first_tokens_deferred"] == 0 and "engine.decode.dispatch" not in _last_tick().children
+    np.testing.assert_array_equal(eng.poll(c), eng.poll(a))
+
+
+@LAYOUTS
+def test_a_hand_off_and_a_resumed_admission_beside_a_fresh_one(tiny_llama64, paged):
+    kwargs = dict(prompt_buckets=(8,), max_len=64, tick_block=2)
+    prompts = [np.arange(1, 7, dtype=np.int32), np.arange(20, 25, dtype=np.int32), np.arange(40, 48, dtype=np.int32)]
+    want = [_reference(tiny_llama64, p, 6) for p in prompts]
+    handoff = ServingEngine(tiny_llama64, num_slots=1, **kwargs).prefill_detached(prompts[0], 6)
+    eng = ServingEngine(tiny_llama64, num_slots=3, paged_block_size=paged, **kwargs)
+    resumed = eng.submit(prompts[1], 6)
+    _step_whole(eng)
+    eng._preempt(0)  # between ticks, as a scheduler's eviction leaves it: queued, three tokens carried
+    assert len(eng.partial(resumed)) == 3 and eng.active_count == 0
+    handed, fresh = eng.submit_prefilled(handoff), eng.submit(prompts[2], 6)
+    done = _step_whole(eng)
+    # the hand-off's token came on the host and the resumed request samples none: one token was on the device
+    assert done["admitted"] == 3 and done["first_tokens_deferred"] == 1
+    assert [len(eng.partial(u)) for u in (handed, resumed, fresh)] == [3, 5, 3]
+    eng.run()
+    for uid, ref in zip((handed, resumed, fresh), want):
+        np.testing.assert_array_equal(eng.poll(uid), ref)
+    assert eng.metrics.first_tokens_deferred == 2 and eng.metrics.prefills == 3 and eng.metrics.resumes == 1
+
+
+@LAYOUTS
+def test_preempting_a_slot_admitted_in_the_same_tick_reads_its_token_first(tiny_llama64, paged):
+    """The stock policy evicts only a request less important than the queue's head, which the queue's order
+    keeps behind that head; a policy of the caller's own may name any decoding slot, one this tick admitted too."""
+    from accelerate_tpu.scheduling import Scheduler, SchedulerConfig
+
+    class EvictOnce(Scheduler):
+        evicted = False
+
+        def pick_victim(self, incoming_priority, decoding):
+            if self.evicted or not decoding:
+                return None
+            self.evicted = True
+            return decoding[-1][0]
+
+    eng = ServingEngine(tiny_llama64, num_slots=1, prompt_buckets=(8,), paged_block_size=paged, max_len=64, tick_block=2,
+                        scheduler=EvictOnce(SchedulerConfig(enable_preemption=True)))
+    prompts = [np.arange(1, 7, dtype=np.int32), np.arange(20, 25, dtype=np.int32)]
+    first, second = eng.submit(prompts[0], 5), eng.submit(prompts[1], 5)
+    # admits ``first``; evicts it for ``second`` with its token read and not lost; the queue's order puts it
+    # back ahead of ``second``, so it resumes in the same tick with that token carried, and decodes
+    done = _step_whole(eng)
+    assert done["admitted"] == 2 and done["first_tokens_deferred"] == 0
+    assert eng.metrics.decode_preemptions == eng.metrics.resumes == eng.metrics.prefills == 1
+    assert eng.partial(first).tolist() == _reference(tiny_llama64, prompts[0], 3)[-3:].tolist()
+    eng.run()
+    for uid, prompt in zip((first, second), prompts):
+        np.testing.assert_array_equal(eng.poll(uid), _reference(tiny_llama64, prompt, 5))
+
+
+@LAYOUTS
+def test_a_tick_cut_short_at_a_crash_point_leaves_no_first_token_on_the_device(tiny_llama64, paged):
+    from accelerate_tpu.ft.crashpoints import set_crash_hook
+
+    eng = ServingEngine(tiny_llama64, num_slots=2, prompt_buckets=(8,), paged_block_size=paged, max_len=64, tick_block=2)
+    prompt = np.arange(1, 7, dtype=np.int32)
+    uid = eng.submit(prompt, 5)
+
+    def crash(label, **_):
+        if label == "mid_decode":
+            raise RuntimeError("chaos")
+
+    set_crash_hook(crash)
+    try:
+        with pytest.raises(RuntimeError, match="chaos"):
+            eng.step()
+    finally:
+        set_crash_hook(None)
+    assert not eng._first_pending
+    (snap,) = eng.export_inflight(include_kv=False)
+    assert snap["out_tokens"] == eng.partial(uid).tolist() == _reference(tiny_llama64, prompt, 1)[-1:].tolist()

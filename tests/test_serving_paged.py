@@ -764,3 +764,49 @@ def test_setup_log_shows_the_tick_aliasing_its_pool(tiny_llama, tmp_path):
     for program in ("paged_decode_tick", "paste_row", "clear_slot"):
         assert compiled[program]["alias_bytes"] >= pool_bytes, program
         assert compiled[program]["temp_bytes"] >= 0
+
+
+# -- first tokens read behind the decode dispatch: what a deferred token may end, and chunk windows
+
+LAYOUTS = pytest.mark.parametrize("paged", [None, 4], ids=["dense", "paged"])
+
+
+@LAYOUTS
+def test_a_first_token_that_is_eos_retires_its_request_in_that_tick(tiny_llama, paged):
+    """The request joins the tick's decode pass, since the host has not yet seen its token when the pass is
+    dispatched; the rows that pass computes for it reach no caller, and its blocks are free after the tick."""
+    ends, goes_on = np.ones((4,), np.int32), np.arange(20, 25, dtype=np.int32)
+    eos = int(_reference(tiny_llama, ends, 1)[-1])
+    want = _reference(tiny_llama, goes_on, 6)
+    assert eos not in want[len(goes_on):], "the other request has to outlive the tick"
+    eng = ServingEngine(tiny_llama, num_slots=2, prompt_buckets=(4, 8), paged_block_size=paged, tick_block=2, eos_token_id=eos)
+    free0 = eng.pool_free_blocks
+    a, b = eng.submit(ends, 6), eng.submit(goes_on, 6)
+    eng.step()
+    assert not eng._first_pending and eng.active_count == 1
+    assert eng.poll(a).tolist() == ends.tolist() + [eos] and eng.partial(a).tolist() == [eos]
+    assert eng.slot_req[0] is None and eng.slot_pos[0] == 0 and len(eng.partial(b)) == 3
+    if paged:
+        assert eng.pool_free_blocks == free0 - len(eng._slot_blocks[1]) and not eng._slot_blocks[0]
+    # the freed slot and blocks serve the next request, whose rows the thrown-away pass never touched
+    c = eng.submit(goes_on[:3], 4)
+    eng.run()
+    np.testing.assert_array_equal(eng.poll(b), want)
+    np.testing.assert_array_equal(eng.poll(c), _reference(tiny_llama, goes_on[:3], 4))
+    assert eng.metrics.first_tokens_deferred == 3 and eng.pool_free_blocks == free0
+
+
+@LAYOUTS
+def test_a_chunk_window_admission_defers_its_first_token_too(paged):
+    """A prompt longer than the largest bucket runs as chunk windows and samples with ``sample_at``: that
+    token is fed to the decode pass on the device like a fused prefill's, beside one in the same tick."""
+    model = create_llama_model(LlamaConfig.tiny(), seq_len=64)
+    long, short = (np.arange(30) * 7 % 250).astype(np.int32), np.arange(20, 25, dtype=np.int32)
+    eng = ServingEngine(model, num_slots=2, prompt_buckets=(8,), paged_block_size=paged, max_len=64, tick_block=2)
+    a, b = eng.submit(long, 5), eng.submit(short, 5)
+    eng.step()
+    assert not eng._first_pending and eng.metrics.first_tokens_deferred == 2
+    assert len(eng.partial(a)) == len(eng.partial(b)) == 3
+    eng.run()
+    np.testing.assert_array_equal(eng.poll(a), _reference(model, long, 5))
+    np.testing.assert_array_equal(eng.poll(b), _reference(model, short, 5))
