@@ -181,6 +181,17 @@ class LlamaConfig:
     # of the routed sum (plus the shared expert, which every share computes alike). No exchange is built.
     expert_shares: int = 1
     expert_share: int = 0
+    # EVA, chunked linearised attention (EvaByte-style checkpoints publish ``attention_class: "eva"``,
+    # ``window_size`` and ``chunk_size``): with ``attention_class == "eva"`` every attention layer reads the
+    # exact rows of its own aligned window of ``eva_window_size`` positions and one pooled key and value for
+    # every chunk of ``eva_chunk_size`` positions of the windows before it (:mod:`..ops.eva_attention`), pooled
+    # under two learned vectors a head (``adaptive_mu_k``, ``adaptive_phi``). Layers are then unrolled
+    # (``scan_layers=False``). None: the model has no such layer, and its program is what it was.
+    attention_class: Optional[str] = None
+    eva_window_size: int = 2048
+    eva_chunk_size: int = 16
+    # residual sums taken in float32 and rounded once to the stream's type (EvaByte's ``fp32_skip_add``)
+    fp32_skip_add: bool = False
 
     def mixer_kind(self, i: int) -> str:
         """The mixer of layer ``i``: ``"conv"`` or ``"mamba"`` where ``layer_types`` says so (a ``"mamba"``
@@ -570,7 +581,9 @@ class LlamaAttention(nn.Module):
             scale = float(cfg.query_pre_attn_scalar) ** -0.5  # Gemma2
         if cfg.attention_multiplier is not None:
             scale = float(cfg.attention_multiplier)  # Granite: the scale itself, not a power of the head size
-        if decode:
+        if cfg.attention_class is not None:
+            out = self._eva_attention(q, k, v, scale, decode)
+        elif decode:
             out = self._cached_attention(q, k, v, scale, new_span)
         else:
             out = _dispatch_attention(
@@ -592,6 +605,28 @@ class LlamaAttention(nn.Module):
             logit_softcap=self.config.attn_logit_softcap,
             keep_rows_before=None if new_span is None else new_span[0],
         )
+
+
+    def _eva_attention(self, q, k, v, scale, decode: bool):
+        """EVA (:mod:`accelerate_tpu.ops.eva_attention`): the window's exact rows and the pooled chunks of the
+        windows before it, under one softmax; with a cache when ``decode``."""
+        from ..ops.eva_attention import eva_cached_attention, eva_prefill_attention
+
+        cfg = self.config
+        if cfg.attention_class != "eva":
+            raise ValueError(f"attention_class must be None or 'eva', got {cfg.attention_class!r}")
+        if cfg.num_key_value_heads != cfg.num_attention_heads or cfg.sliding_window is not None:
+            raise NotImplementedError(
+                "EVA attention pools a chunk a head under vectors of its own: as many key/value heads as query "
+                "heads, and no sliding band beside the aligned window")
+        heads = nn.initializers.normal(0.02)
+        mu = self.param("adaptive_mu_k", heads, k.shape[-2:])
+        phi = self.param("adaptive_phi", heads, k.shape[-2:])
+        sizes = {"window": cfg.eva_window_size, "chunk": cfg.eva_chunk_size}
+        if decode:
+            return eva_cached_attention(self, q, k, v, mu, phi, cfg.max_position_embeddings, scale=scale, **sizes)
+        scale = k.shape[-1] ** -0.5 if scale is None else scale
+        return eva_prefill_attention(q, k, v, mu, phi, scale=scale, **sizes)[0]
 
 
 class LlamaMLP(nn.Module):
@@ -1045,6 +1080,12 @@ class LlamaLayer(nn.Module):
             by = jnp.asarray(cfg.residual_multiplier, hidden.dtype)
             hidden = hidden + attn(norm("input_norm")(hidden)) * by
             return hidden + mlp(norm("post_attn_norm")(hidden)) * by
+        if cfg.fp32_skip_add:  # EvaByte: each residual sum in float32, rounded once to the stream's type
+            def add(x, branch):
+                return (x.astype(jnp.float32) + branch.astype(jnp.float32)).astype(x.dtype)
+
+            hidden = add(hidden, attn(norm("input_norm")(hidden)))
+            return add(hidden, mlp(norm("post_attn_norm")(hidden)))
         hidden = hidden + attn(norm("input_norm")(hidden))
         return hidden + mlp(norm("post_attn_norm")(hidden))
 
@@ -1111,6 +1152,11 @@ class LlamaModel(nn.Module):
             raise NotImplementedError(
                 "latent attention and routed experts are built with scan_layers=False: a leading dense "
                 "layer differs from the expert layers, and the carried pool stack holds K/V pools only"
+            )
+        if cfg.attention_class is not None and (cfg.scan_layers or cfg.kv_lora_rank is not None or cfg.stateful):
+            raise NotImplementedError(
+                "EVA attention (attention_class) is built with scan_layers=False, beside neither latent attention "
+                "nor a stateful mixer: the carried pool stack has no summary table"
             )
         if cfg.stateful and cfg.kv_lora_rank is not None:
             raise NotImplementedError(

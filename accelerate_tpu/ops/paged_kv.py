@@ -137,6 +137,9 @@ _LAYER_VIEW: Optional[_LayerView] = None
 
 # a pool leaf -> the dense row-cache leaf it holds the rows of
 POOL_ROWS = {"key_pool": "key", "value_pool": "value", "latent_pool": "latent"}
+# a pool leaf -> the dense row-cache leaf of chunk summaries that it also holds, a page of them under
+# ``summary_table`` (EVA, :mod:`.eva_attention`: a summary is a row of the pool's own shape)
+SUMMARY_ROWS = {"key_pool": "summary_key", "value_pool": "summary_value"}
 
 # Leaves with a slot axis and no row axis: the recurrent state of a state-space layer (``ssm_state``
 # ``[B, d_state, d_inner]``, ``conv_state`` ``[B, (d_conv - 1) * d_inner]``) and of a gated short
@@ -473,12 +476,14 @@ def _path_names(path):
     return tuple(p.key if hasattr(p, "key") else str(p) for p in path)
 
 
-def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None):
+def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None, summary_row=None):
     """Blockify a dense per-row cache and scatter it into the pools at
     ``write_row``'s block ids; apply ``table_updates(name, leaf)`` to the
-    ``block_table``/``index`` leaves (or leave them untouched if it
+    ``block_table``/``summary_table``/``index`` leaves (or leave them untouched if it
     returns None); with a ``slot``, write the row cache's state leaves
-    (:data:`STATE_LEAVES`) over that slot's."""
+    (:data:`STATE_LEAVES`) over that slot's; with a ``summary_row``, blockify
+    the row cache's chunk summaries (:data:`SUMMARY_ROWS`) too and scatter them
+    into the same pools at its block ids."""
     dense = {_path_names(p): leaf for p, leaf in jax.tree_util.tree_flatten_with_path(row_cache)[0]}
 
     def rows_of(prefix, name):
@@ -511,17 +516,28 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None):
             blocks = row.reshape(*leaf.shape[:lead], mb, bs_, *token)
             if latent:
                 blocks = blocks.swapaxes(-1, -2)
-            sel = (slice(None),) * lead + (write_row,)
-            if not latent and 1 < leaf.shape[-2] < SUBLANES:
-                # A head axis of 2..7 is under a sublane tile: around a scatter of whole ``[bs, H, W]`` blocks the
-                # TPU compiler re-lays the pool with ``bs`` innermost and back, two copies of the whole pool a
-                # paste (read in the compile for a described v5e; 15 ms a paste at 2.4 GB of pools on the chip).
-                # Through the flat view ``[NB, bs * H, W]``, which is a bitcast, it scatters in place.
-                flat = (*leaf.shape[: lead + 1], bs_ * leaf.shape[-2], leaf.shape[-1])
-                blocks = blocks.reshape(*blocks.shape[: lead + 1], *flat[-2:])
-                return leaf.reshape(flat).at[sel].set(blocks.astype(leaf.dtype)).reshape(leaf.shape)
-            return leaf.at[sel].set(blocks.astype(leaf.dtype))
-        if name in ("block_table", "index"):
+            def scatter(leaf, ids, blocks):
+                """``blocks`` ``[.., n, bs, *token]`` (or latent pages) written at the pool's block ``ids`` ``[n]``."""
+                sel = (slice(None),) * lead + (ids,)
+                if not latent and 1 < leaf.shape[-2] < SUBLANES:
+                    # A head axis of 2..7 is under a sublane tile: around a scatter of whole ``[bs, H, W]`` blocks the
+                    # TPU compiler re-lays the pool with ``bs`` innermost and back, two copies of the whole pool a
+                    # paste (read in the compile for a described v5e; 15 ms a paste at 2.4 GB of pools on the chip).
+                    # Through the flat view ``[NB, bs * H, W]``, which is a bitcast, it scatters in place.
+                    flat = (*leaf.shape[: lead + 1], bs_ * leaf.shape[-2], leaf.shape[-1])
+                    blocks = blocks.reshape(*blocks.shape[: lead + 1], *flat[-2:])
+                    return leaf.reshape(flat).at[sel].set(blocks.astype(leaf.dtype)).reshape(leaf.shape)
+                return leaf.at[sel].set(blocks.astype(leaf.dtype))
+
+            leaf = scatter(leaf, write_row, blocks)
+            if summary_row is not None:
+                # the summaries of the prompt's chunks, rows of the pool's own shape: pages of the same pool
+                pooled = rows_of(prefix, SUMMARY_ROWS[name])
+                pages = summary_row.shape[0]
+                pooled = jnp.pad(pooled, [(0, 0)] * (lead + 1) + [(0, pages * bs_ - pooled.shape[lead + 1])] + [(0, 0)] * tail)
+                leaf = scatter(leaf, summary_row, pooled.reshape(*leaf.shape[:lead], pages, bs_, *token))
+            return leaf
+        if name in ("block_table", "summary_table", "index"):
             out = table_updates(name, leaf)
             return leaf if out is None else out
         if name in STATE_LEAVES:
@@ -533,7 +549,7 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None):
     return jax.tree_util.tree_map_with_path(write, paged_cache)
 
 
-def paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index):
+def paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index, summary_row=None):
     """Install a dense prefill row cache into the pool for ``slot``.
 
     ``row_cache`` is the ordinary dense per-row cache a prefill program
@@ -547,17 +563,24 @@ def paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index):
     other slots decoding against it and waste the write traffic), while
     ``table_row`` keeps the real ids for reads. A state-space layer's
     state (:data:`STATE_LEAVES`) replaces ``slot``'s whole: whatever an
-    idle slot stepped into it in the meantime is never read. Pure — jit once.
+    idle slot stepped into it in the meantime is never read. With a
+    ``summary_row`` (a cache with a ``summary_table``: EVA) the row cache's
+    chunk summaries go to that row's pages of the same pools, and the row is
+    ``slot``'s summary table: an entry the request will never read through
+    is the trash sink, in the table and for the write alike. Pure — jit once.
     """
 
     def tables(name, leaf):
         if name == "block_table":
             sel = (slice(None),) * (leaf.ndim - 2) + (slot,)
             return leaf.at[sel].set(table_row.astype(leaf.dtype))
+        if name == "summary_table":
+            sel = (slice(None),) * (leaf.ndim - 2) + (slot,)
+            return leaf.at[sel].set(summary_row.astype(leaf.dtype))
         sel = (slice(None),) * (leaf.ndim - 1) + (slot,)
         return leaf.at[sel].set(jnp.asarray(new_index, leaf.dtype))
 
-    return _scatter_pools(paged_cache, row_cache, write_row, tables, slot=slot)
+    return _scatter_pools(paged_cache, row_cache, write_row, tables, slot=slot, summary_row=summary_row)
 
 
 def paste_blocks(paged_cache, row_cache, write_row):
@@ -594,7 +617,7 @@ def clear_slot(paged_cache, slot):
 
     def write(path, leaf):
         name = _path_names(path)[-1]
-        if name == "block_table":
+        if name in ("block_table", "summary_table"):
             sel = (slice(None),) * (leaf.ndim - 2) + (slot,)
             return leaf.at[sel].set(jnp.zeros((leaf.shape[-1],), leaf.dtype))
         if name == "index":

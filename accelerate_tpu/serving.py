@@ -73,6 +73,7 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import logging
+import math
 import time
 from typing import Optional
 
@@ -113,11 +114,27 @@ def check_handoff_layout(row_cache) -> None:
     from .ops.kv_cache import leaf_names
 
     check_no_state_leaf(row_cache, "KV hand-off (kv_handoff_dims, prefill_detached, HandoffCodec)")
+    check_no_summary_leaf(row_cache, "KV hand-off (kv_handoff_dims, prefill_detached, HandoffCodec)")
     if "latent" in leaf_names(row_cache):
         raise NotImplementedError(
             "KV hand-off (kv_handoff_dims, prefill_detached, HandoffCodec) cannot carry a latent "
             "cache yet: its rows are [kv_lora_rank + qk_rope_head_dim] latents shared by all heads, "
             "not per-head keys and values; serve latent-attention models without disaggregated prefill"
+        )
+
+
+def check_no_summary_leaf(row_cache, what: str) -> None:
+    """``what`` ships K/V rows trimmed to a frontier; a cache that also holds
+    chunk summaries (``summary_key`` / ``summary_value``: EVA's pooled keys and
+    values, which the paged layout keeps in pages of their own under a second
+    table) is refused by name."""
+    from .ops.kv_cache import leaf_names
+
+    if "summary_key" in leaf_names(row_cache):
+        raise NotImplementedError(
+            f"{what} cannot carry summary pages yet: an EVA cache holds the open window's rows and one pooled "
+            "key and value for every chunk of the closed windows under a second table, not rows a token to "
+            "trim and pad; serve EVA models without it"
         )
 
 
@@ -356,6 +373,10 @@ class ServingEngine:
         # block pool + per-slot block tables (ops/paged_kv.py) — same
         # decode roofline, pool capacity decoupled from slots x max_len.
         self.paged = paged_block_size is not None
+        # ``(window, chunk)`` for a model whose attention reads an ALIGNED window and pooled chunks before it (EVA,
+        # ``attention_class == "eva"``), under the paged layout; None for every other engine
+        self._aligned: Optional[tuple] = None
+        self._tick_windows = (0, 0, 0, 0)  # rows attended, context rows, chunks pooled, windows closed: this tick's
         # the paged decode tick of a model with routed experts, or with a recurrent state that a kernel steps
         # (``ssm_state``), is told which slots decode (one ``[slots]`` bool argument more: ``_decoding_arg``):
         # the stale token of every other slot reaches no expert, and its state is neither read nor written.
@@ -404,6 +425,8 @@ class ServingEngine:
             layer_types = getattr(model.config, "layer_types", None)
             if layer_types is not None and any(t != "sliding_attention" for t in layer_types):
                 self._window = None
+            if getattr(model.config, "attention_class", None) == "eva":
+                self._init_aligned(model.config, bs_)
             with paged_mode(self._pcfg):
                 _, pcache = jax.eval_shape(
                     lambda p, i, pos: apply_fn(p, i, positions=pos, decode=True, cache=None),
@@ -433,6 +456,7 @@ class ServingEngine:
         # the host reads them only once that pass is dispatched; none outlives ``step()``
         self._first_pending: dict[int, tuple] = {}
         self._tick_first_deferred = 0  # of this tick's admissions, those read after the decode dispatch
+        self._row_cap = None  # :meth:`_row_cache_cap`, read from the device at the first admission
         # pending requests, kept sorted by the scheduler's order key
         # (priority class, then submission order)
         self.queue: list[_Request] = []
@@ -801,6 +825,8 @@ class ServingEngine:
         with state-space layers the stored row cache also holds the
         recurrent state at the prefix's end: a request's suffix windows
         start from a copy of it (blocks are aliased, state is not)."""
+        if self._aligned is not None:
+            raise NotImplementedError(self._aligned_refusal("prefix reuse (register_prefix)"))
         toks = np.asarray(prefix_ids, np.int32).ravel()
         if len(toks) == 0:
             raise ValueError("empty prefix")
@@ -913,6 +939,9 @@ class ServingEngine:
                 f"prefix ({plen}) + prompt ({len(prompt)}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds the slot cache ({self.max_len})"
             )
+        if self._aligned is not None and self._bucket_for(len(prompt)) is None:
+            raise NotImplementedError(self._aligned_refusal(
+                f"chunk windows (a prompt of {len(prompt)} tokens, past the largest prefill bucket {self._chunk})"))
         if self.paged:
             need = self._new_blocks_for(plen, len(prompt), max_new_tokens)
             if need > self._pcfg.num_blocks - 1:
@@ -1160,6 +1189,7 @@ class ServingEngine:
         kv_ok = include_kv and not self.paged
         if kv_ok:
             check_no_state_leaf(self._row_template, "export_inflight(include_kv=True)")
+            check_no_summary_leaf(self._row_template, "export_inflight(include_kv=True)")
         snaps = []
 
         def handoff_snap(req, h):
@@ -1229,6 +1259,8 @@ class ServingEngine:
             raise ValueError(f"snapshot logprobs ({len(lps)}) misaligned with tokens ({len(out)})")
         if len(out) > max_new:
             raise ValueError(f"snapshot carries {len(out)} tokens > max_new_tokens {max_new}")
+        if self._aligned is not None and out:
+            raise NotImplementedError(self._aligned_refusal("import_inflight of a request that has decoded (it resumes through chunk windows)"))
         if len(prompt) + max_new > self.max_len:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_new_tokens ({max_new}) "
@@ -1443,6 +1475,7 @@ class ServingEngine:
         self._tick_prefill_tokens = self._tick_first_deferred = 0
         self._tick_expert_load = (0, 0, 0, 0)
         self._tick_state_idle = 0
+        self._tick_windows = (0, 0, 0, 0)
         with phase("engine.schedule"):
             now = time.monotonic()
             self._pool_blocked = False
@@ -1496,6 +1529,11 @@ class ServingEngine:
             self._read_first_tokens()
         with phase("engine.expire"):
             self._expire_window_blocks()
+        pages = (0, 0)
+        if self._aligned is not None:
+            self._close_windows()
+            pages = (sum(map(len, self._slot_blocks)), sum(map(len, self._slot_summary)))
+            m.on_pages_held(*pages)
         with phase(
             "engine.tick.done", admitted=admitted, first_tokens_deferred=self._tick_first_deferred,
             prefill_tokens=self._tick_prefill_tokens,
@@ -1504,6 +1542,8 @@ class ServingEngine:
             queue_len=len(self.queue), experts_touched=self._tick_expert_load[0],
             expert_pairs_max=self._tick_expert_load[1], expert_tile_visits=self._tick_expert_load[2],
             expert_pairs=self._tick_expert_load[3], state_slots_idle=self._tick_state_idle,
+            attn_rows_read=self._tick_windows[0], context_rows=self._tick_windows[1], chunks_pooled=self._tick_windows[2],
+            windows_closed=self._tick_windows[3], exact_pages=pages[0], summary_pages=pages[1],
         ):
             pass
 
@@ -1565,6 +1605,8 @@ class ServingEngine:
         ``(owned, shared_entries, table, write_row)`` or None when the
         pool cannot satisfy it."""
         plen, prompt_len, max_new = self._request_block_dims(req)
+        if self._aligned is not None:
+            return self._reserve_aligned(plen + prompt_len, max_new)
         lo, hi, alias_hi = self._plan_blocks(plen, prompt_len, max_new)
         shared_entries: dict[int, int] = {}
         if req.prefix_id is not None:
@@ -1621,6 +1663,8 @@ class ServingEngine:
             self._slot_blocks[slot], self._slot_shared[slot] = owned, shared_entries
             self._slot_table[slot] = table
             st["table"], st["write_row"] = table, write_row
+            if self._aligned is not None:
+                self._slot_summary[slot], st["summary_row"], self._slot_last[slot] = self._reserved_summary
         # the per-request sampling chain: fold the uid at first admission,
         # carry the evicted chain across a preemption — the resumed stream
         # continues the SAME chain, so sampled outputs stay request-exact
@@ -1709,6 +1753,11 @@ class ServingEngine:
             b = st["bucket"]
             if budget < b and not force:
                 return budget
+            if len(self._first_pending) >= self._row_cache_cap():
+                # as many row caches as memory has room for already wait on the device for their pastes (each is
+                # freed only when its paste has run): let the device finish them before another is asked for
+                with phase("engine.prefill.room.sync", uid=req.uid, waiting=len(self._first_pending)):
+                    _jax().block_until_ready(_jax().tree_util.tree_leaves(self.slot_caches)[0])
             with phase("engine.prefill.dispatch", uid=req.uid, tokens=b, prompt_tokens=len(req.prompt)):
                 padded = np.zeros((1, b), np.int32)
                 padded[0, : len(req.prompt)] = req.prompt
@@ -1743,6 +1792,21 @@ class ServingEngine:
         self._finalize_prefill(slot, cache, t, next_tok, lp, key)
         return budget
 
+    def _row_cache_cap(self):
+        """How many admissions of one tick may have their prefill's row cache on the device at once. The tick's
+        programs are queued back to back and a row cache is freed only when its paste has run, so a tick that
+        admits many holds as many row caches as it has queued: half of the device memory that is free once the
+        engine stands (weights and cache resident) over a row cache's bytes, at least one. Most models' row caches
+        are small beside it (tens of MB) and the cap is never met; an EVA model's is 84 KB a position a layer.
+        Unbounded where the backend reports no memory (a CPU)."""
+        if self._row_cap is None:
+            jax = _jax()
+            stats = jax.local_devices()[0].memory_stats() or {}
+            row = sum(math.prod(l.shape) * np.dtype(l.dtype).itemsize for l in jax.tree_util.tree_leaves(self._row_template))
+            free = stats.get("bytes_limit", 0) - stats.get("bytes_in_use", 0)
+            self._row_cap = max(1, free // 2 // max(row, 1)) if stats.get("bytes_limit") else math.inf
+        return self._row_cap
+
     def _finalize_prefill(self, slot: int, row_cache, total: int, next_tok, lp, key) -> None:
         """Prefill complete: paste/insert the row cache and move the slot
         to the decode phase. A resume re-feeds the carried last token at
@@ -1758,9 +1822,10 @@ class ServingEngine:
         with phase("engine.prefill.paste", uid=req.uid):
             self._slot_keys = self._slot_keys.at[slot].set(key)
             if self.paged:
+                summaries = () if self._aligned is None else (jnp.asarray(st["summary_row"]),)
                 self.slot_caches = self._paste(
                     self.slot_caches, row_cache, jnp.asarray(st["write_row"]),
-                    jnp.asarray(st["table"]), jnp.int32(slot), jnp.int32(total),
+                    jnp.asarray(st["table"]), jnp.int32(slot), jnp.int32(total), *summaries,
                 )
             else:
                 self.slot_caches = self._insert(self.slot_caches, row_cache, jnp.int32(slot))
@@ -1884,10 +1949,14 @@ class ServingEngine:
                 self._tick_expert_load = (int(touched.sum()), int(most.max()), int(visits.sum()), int(pairs.sum()))
                 self.metrics.on_expert_load(*self._tick_expert_load)
         with phase("engine.decode.walk"):
+            # (first position, tokens kept) a decoding slot: what an aligned window's counts are made of
+            kept = None if self._aligned is None else []
             for slot, req in enumerate(self.slot_req):
                 if req is None or self.slot_phase[slot] != "decode":
                     continue
                 n_new, retired = 0, False
+                if kept is not None:
+                    kept.append([int(self.slot_pos[slot]), 0])
                 for k in range(self.tick_block):
                     tok = int(toks_k[k, slot])
                     req.out_tokens.append(tok)
@@ -1903,8 +1972,12 @@ class ServingEngine:
                     self.metrics.on_tick_tokens(req.uid, n_new)
                     if self.tracer is not None:
                         self.tracer.window(req.trace, "decode", tokens=n_new)
+                if kept is not None:
+                    kept[-1][1] = n_new
                 if retired:
                     self._retire(slot)
+            if kept:
+                self._count_window_attention(np.asarray(kept))
 
     def _decoding_slots(self) -> np.ndarray:
         """``[slots]`` bool: the slots in which a request decodes. (A numpy array: ``jnp.asarray`` of a
@@ -1919,6 +1992,110 @@ class ServingEngine:
         if not self._mask_idle_rows:
             return ()
         return (_jax().numpy.asarray(self._decoding_slots() if decoding is None else decoding),)
+
+    # ---- aligned windows (EVA): pages by kind, the close, the counts ----------------------------------------------
+
+    def _init_aligned(self, config, block: int) -> None:
+        """A model whose attention reads an aligned window and pooled chunks before it (EVA). A slot holds the
+        exact pages of ONE window (``window // block``: its ``_slot_blocks``) and a page of summaries for every
+        ``block`` chunks of the windows before it (``_slot_summary``, the cache's ``summary_table``), all from
+        the one allocator. What is not built over the second table is refused by name, here or at its call."""
+        from .ops.eva_attention import check_paged_sizes, summary_pages
+
+        window, chunk = config.eva_window_size, config.eva_chunk_size
+        check_paged_sizes(block, window, chunk)
+        self._aligned = (window, chunk)
+        if self._sched.config.enable_preemption:
+            raise NotImplementedError(self._aligned_refusal("preemption with resume (SchedulerConfig.enable_preemption)"))
+        if self.bucketer is not None:
+            raise NotImplementedError(self._aligned_refusal("auto_bucketing (a learned bucket past the seeds runs chunk windows)"))
+        self._summary_entries = summary_pages(config.max_position_embeddings, block, chunk)
+        self._slot_summary: list[dict] = [{} for _ in range(self.num_slots)]  # summary_table entry -> pool block id
+        self._slot_last = [0] * self.num_slots  # the last position whose row a slot keeps (total + max_new - 2)
+        self._reserved_summary = ({}, None, 0)
+
+    @staticmethod
+    def _aligned_refusal(what: str) -> str:
+        return (f"{what} is not built over summary pages: an EVA cache keeps the open window's rows and the pooled "
+                "chunks of the closed windows under two tables; serve it with bucketed prefill and no preemption")
+
+    def _aligned_pages(self, total: int, max_new: int) -> tuple:
+        """``(exact, summary)`` pages a request of ``total`` prompt tokens and ``max_new`` new ones reserves: the
+        pages of the fullest window it will hold, ``min(total + max_new - 1, window) / block`` at most (a closed
+        window's blocks are the next window's), and whole pages of summaries for the windows it will CLOSE AND
+        READ PAST, ``(window / chunk / block) * ((total + max_new - 2) // window)``: the summaries of the last
+        window it reaches are read by nobody and go to the trash sink. The first decode step writes position
+        ``total``, so the first window held is ``total // window``: a prompt that ends on a window's edge
+        pastes summaries alone."""
+        window, chunk = self._aligned
+        bs_ = self._pcfg.block_size
+        last = total + max_new - 2  # the last position whose row is kept (>= total - 1)
+        exact = min(window // bs_, last // bs_ + 1 - (window // bs_) * (total // window))
+        return max(exact, 0), (window // chunk // bs_) * (last // window)
+
+    def _reserve_aligned(self, total: int, max_new: int):
+        """:meth:`_reserve_blocks` for an aligned window. The slot's table is written ONCE: entry ``i`` of every
+        window the request will reach names block ``i % (window / block)`` of the ``exact`` it owns, so a close
+        changes nothing on the device and the rows of window ``w + 1`` overwrite those of ``w``, which nothing
+        reads any more (its last chunk was pooled by the step that wrote its last row)."""
+        window, _ = self._aligned
+        bs_ = self._pcfg.block_size
+        per_w = window // bs_
+        n_exact, n_summary = self._aligned_pages(total, max_new)
+        ids = self._alloc.alloc(n_exact + n_summary)
+        if ids is None:
+            return None
+        exact, first = ids[:n_exact], per_w * (total // window)
+        table = np.zeros((self._mb,), np.int32)  # pad -> trash sink
+        for i in range(first, min(self._mb, (total + max_new - 2) // bs_ + 1)):
+            table[i] = exact[i % per_w]
+        write_row = np.zeros((self._mb,), np.int32)
+        write_row[first : first + n_exact] = exact  # the paste writes the open window's pages alone
+        summary_row = np.zeros((self._summary_entries,), np.int32)
+        summary_row[:n_summary] = ids[n_exact:]
+        self._reserved_summary = (dict(enumerate(ids[n_exact:])), summary_row, total + max_new - 2)
+        return {first + j: bid for j, bid in enumerate(exact)}, {}, table, write_row
+
+    def _close_windows(self) -> None:
+        """After the tick in which a slot's position crossed into a new window: the closed window's blocks are
+        the new window's (the table said so from admission), and those the rest of the request will not fill go
+        back to the allocator. Host bookkeeping alone: no program runs and the device's table stays as admission
+        wrote it (the closed window's entries are never read or written again: a step gathers from the open
+        window's first entry on and stores at its frontier's; ``clear_slot`` zeroes the row when the slot is
+        released). A phase of its own (``engine.window.close``), entered only when a window closed, so that a slow
+        tick says so."""
+        window, _ = self._aligned
+        bs_ = self._pcfg.block_size
+        per_w = window // bs_
+        crossed = []  # (slot, the blocks of the closed window under the entries of the window entered, how many of them it keeps)
+        for slot, req in enumerate(self.slot_req):
+            owned = self._slot_blocks[slot]
+            if req is None or self.slot_phase[slot] != "decode" or not owned:
+                continue
+            w_now = int(self.slot_pos[slot]) // window
+            if w_now > min(owned) // per_w:
+                keep = max(0, min(per_w, self._slot_last[slot] // bs_ + 1 - per_w * w_now))
+                crossed.append((slot, [(per_w * w_now + j, owned[i]) for j, i in enumerate(sorted(owned))], keep))
+        if not crossed:
+            return
+        with phase("engine.window.close", slots=len(crossed), blocks_freed=sum(len(blocks) - keep for _, blocks, keep in crossed)):
+            for slot, blocks, keep in crossed:
+                self._slot_blocks[slot] = dict(blocks[:keep])
+                self._alloc.free([bid for _, bid in blocks[keep:]])
+
+    def _count_window_attention(self, kept: np.ndarray) -> None:
+        """The tick's counts for an aligned window, from its kept steps alone (``kept``: a row ``(first position,
+        steps kept)`` a decoding slot): rows of keys attended (``frontier + 1``: a summary a chunk of the closed
+        windows, the open window's rows), rows of context (``t + 1``), chunks pooled, windows closed."""
+        window, chunk = self._aligned
+        k = np.arange(self.tick_block)[None, :]
+        t, valid = kept[:, :1] + k, k < kept[:, 1:]
+        rows = (window // chunk) * (t // window) + t % window + 1
+        self._tick_windows = (
+            int(rows[valid].sum()), int((t + 1)[valid].sum()), int((valid & (t % chunk == chunk - 1)).sum()),
+            int((valid & (t % window == window - 1)).sum()),
+        )
+        self.metrics.on_window_attention(*self._tick_windows)
 
     def _expire_window_blocks(self) -> None:
         """Sliding-window models: expire blocks the band can no longer
@@ -2113,6 +2290,8 @@ class ServingEngine:
         the capacity arithmetic lives (submit's feasibility check, the
         admission allocation, and run()'s unsatisfiable-head diagnostic
         must agree or admission deadlocks/overcommits)."""
+        if self._aligned is not None:
+            return sum(self._aligned_pages(plen + prompt_len, max_new))
         lo, hi, alias_hi = self._plan_blocks(plen, prompt_len, max_new)
         return (hi - lo) - max(0, alias_hi - lo)
 
@@ -2184,6 +2363,9 @@ class ServingEngine:
             jnp = _jax().numpy
             self._alloc.free(list(self._slot_blocks[slot].values()))
             self._slot_blocks[slot] = {}
+            if self._aligned is not None:
+                self._alloc.free(list(self._slot_summary[slot].values()))
+                self._slot_summary[slot] = {}
             for bid in self._slot_shared[slot].values():
                 self._shared_refs[bid] -= 1
             self._slot_shared[slot] = {}

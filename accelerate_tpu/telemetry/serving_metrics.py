@@ -143,6 +143,18 @@ class ServingMetrics:
         # layer is told which slots decode and visits no other: 0 then)
         self.state_bytes_per_slot = 0
         self.state_slots_idle = 0
+        # aligned windows (EVA attention; 0 otherwise), from the decode
+        # ticks' kept steps: the rows of keys the steps attended to (a
+        # summary for every chunk of the closed windows, the open window's
+        # rows) beside the rows of their contexts, the chunks pooled and
+        # the windows closed; and the pool's pages held by kind when the
+        # last tick ended (a closed window leaves a sixteenth of its pages)
+        self.attn_rows_read = 0
+        self.context_rows = 0
+        self.chunks_pooled = 0
+        self.windows_closed = 0
+        self.exact_pages_held = 0
+        self.summary_pages_held = 0
         # admissions whose first token the host read behind the decode
         # dispatch of their tick (``first_tokens_deferred`` of
         # ``engine.tick.done``); ``prefills`` less it took the road with a
@@ -221,6 +233,15 @@ class ServingMetrics:
 
     def on_state_step(self, slots_idle: int):
         self.state_slots_idle += slots_idle
+
+    def on_window_attention(self, rows_read: int, context_rows: int, chunks_pooled: int, windows_closed: int):
+        self.attn_rows_read += rows_read
+        self.context_rows += context_rows
+        self.chunks_pooled += chunks_pooled
+        self.windows_closed += windows_closed
+
+    def on_pages_held(self, exact: int, summary: int):
+        self.exact_pages_held, self.summary_pages_held = exact, summary
 
     def on_first_tokens_deferred(self, n: int):
         self.first_tokens_deferred += n

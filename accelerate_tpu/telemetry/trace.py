@@ -127,6 +127,15 @@ PHASES = {
 }
 
 
+#: phases only some engines enter, and only in some ticks -> layer: an admission that waits for room for another
+#: prefill's row cache (a model whose row cache is large: ``ServingEngine._row_cache_cap``), and the tick in which
+#: an aligned window (EVA attention) closed. Children of ``engine.tick`` like those of :data:`PHASES`, kept apart
+#: because a trace of another model's engine holds none of them.
+RARE_PHASES = {
+    "engine.prefill.room.sync": "jitted programs",
+    "engine.window.close": "scheduler",
+}
+
 #: phases that close into a :class:`PhaseRecord` of their own; a root's
 #: zero-length ``<root>.done`` marker hands it the counts known at its end.
 ROOT_PHASES = ("engine.tick", "train.step")

@@ -63,6 +63,7 @@ MODULES = [
     "accelerate_tpu.ops.selective_scan",
     "accelerate_tpu.ops.pallas_selective_scan",
     "accelerate_tpu.ops.ssd_scan",
+    "accelerate_tpu.ops.eva_attention",
     "accelerate_tpu.ops.pallas_ssd_step",
     "accelerate_tpu.ops.pallas_grouped_matmul",
     "accelerate_tpu.ops.moe",

@@ -493,6 +493,89 @@ def test_granite_hybrid_prefill_bucket_and_paste_compile_for_v5e(v5e, monkeypatc
     assert pm.temp_size_in_bytes < 64 * 2**20 and pm.alias_size_in_bytes > 3.0e9
 
 
+def test_paged_decode_attention_compiles_at_pages_of_32_key_value_heads(v5e):
+    """EvaByte's page, ``[16, 32, 128]`` (131,072 B for K): 512 rows of the flat view a page, eight pages (128 tokens) a
+    chunk of 4 MiB of VMEM, every query head scoring all 512 rows of a page and keeping 16; the table is the 16 + 128
+    entries a decode step gathers (``ops/eva_attention.py``). One Mosaic call."""
+    from accelerate_tpu.ops.pallas_paged_attention import _pages_per_chunk, paged_decode_attention
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    assert _pages_per_chunk(16, 32, 128, BF16) == 8
+    pool = _on(chip, (4737, 16, 32, 128))
+    text = _compile(
+        functools.partial(paged_decode_attention, interpret=False), _on(chip, (32, 32, 128)), pool, pool,
+        _on(chip, (32, 144), jnp.int32), _on(chip, (32,), jnp.int32))
+    assert len(_mosaic_programs(text, "paged_decode_attention")) == 1
+
+
+def test_evabyte_decode_tick_fits_one_v5e_chip_and_copies_no_pool(v5e, monkeypatch):
+    """The 32-slot decode tick of the ``evabyte-serve-longchat`` cell at its real size: 1.62 B parameters and eight
+    pools of ``[4737, 16, 32, 128]`` for K and for V (9.93 GB) as arguments, all aliased to the output; eight
+    ``paged_decode_attention`` kernels a step over the gathered table, the chunk's pooling (a page read back and
+    a summary row written, in place), and no operation that copies or re-lays a pool whole. Inside 15.75 GiB with
+    the 1 GB the issue asks to leave free. A compile is not a chip run."""
+    import contextlib
+    import re
+
+    s, engine = _cell_engine("evabyte-6.5b-l8")
+    assert engine._aligned == (2048, 16) and engine._summary_entries == 20 and engine._mb == 320
+    chip = SingleDeviceSharding(v5e.devices[0])
+    raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernel
+    with contextlib.ExitStack() as stack:
+        for ctx in contexts:
+            stack.enter_context(ctx())
+        compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l and "custom-call(" in l]
+    assert sum("paged_decode_attention" in l for l in calls) == 8 and "s32[32,144]" in text, "the gathered table: 16 + 128 entries a slot"
+    pool = f"{s['pool_blocks']},{s['paged_block_size']},32,128"
+    moved = [l.strip()[:160] for l in text.splitlines() if re.search(rf"= bf16\[{pool}\]\S* (copy|transpose)\(", l)]
+    assert not moved, "the tick copies or re-lays a pool:\n" + "\n".join(moved)
+    m = compiled.memory_analysis()
+    pool_bytes = 8 * 2 * s["pool_blocks"] * 16 * 32 * 128 * 2
+    assert pool_bytes == 4737 * 2_097_152 and m.alias_size_in_bytes >= pool_bytes and m.temp_size_in_bytes < 1.0 * 2**30
+    assert m.argument_size_in_bytes > 3.24e9 + pool_bytes, "weights and the pools are arguments at their real size"
+    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert total <= 14.75 * 2**30, f"the 32-slot tick needs {total / 2**30:.2f} GiB"
+
+
+def test_evabyte_prefill_bucket_and_paste_compile_for_v5e(v5e, monkeypatch):
+    """The 4096-byte prefill of the same cell, two windows: the second attends ``[128 summaries | its 2048 rows]``
+    through the flash kernel (a causal mask aligned bottom-right; 2,176 keys padded to 2,560), one Mosaic call a window a
+    layer and no float32 score matrix, so the temporaries stay under 0.6 GiB beside a row cache of 0.66 GiB (5,120 rows
+    of K and V a layer and their 320 summaries); and ``paste_row`` of that row cache, 9.93 GB of pools donated,
+    scatters the open window's pages and the summaries' pages in place."""
+    import re
+
+    from accelerate_tpu.ops.paged_kv import paste_row
+
+    s, engine = _cell_engine("evabyte-6.5b-l8")
+    chip = SingleDeviceSharding(v5e.devices[0])
+    on = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)  # noqa: E731
+    prefill, prefill_args, _ = engine._perf_programs["prefill"]
+    args = on(prefill_args(4096))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(prefill).lower(*args).compile()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 0.6 * 2**30, f"the prefill's temporaries are {m.temp_size_in_bytes / 2**30:.2f} GiB"
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 16, "a flash call a window a layer"
+    assert not re.search(r"f32\[(1,)?32,512,2176\]", text), "a window's float32 scores are formed"
+    cache = jax.eval_shape(prefill, *args)[2]
+    assert cache["layer_0"]["attn"]["key"].shape == (1, 5120, 32, 128) and cache["layer_7"]["attn"]["summary_value"].shape == (1, 320, 32, 128)
+    assert 0.6 * 2**30 < m.output_size_in_bytes < 0.7 * 2**30
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)  # noqa: E731
+    pasted = jax.jit(paste_row, donate_argnums=(0,)).lower(
+        on(engine.slot_caches), on(engine._row_template), i32(engine._mb), i32(engine._mb), i32(), i32(), i32(engine._summary_entries)).compile()
+    leaf = rf"bf16\[{s['pool_blocks']},[^\]]*\]"
+    moved = [l.strip()[:160] for l in pasted.as_text().splitlines() if re.search(rf"= {leaf}\S* (copy|transpose)\(", l)]
+    assert not moved, "paste_row copies or re-lays a pool:\n" + "\n".join(moved)
+    pm = pasted.memory_analysis()
+    assert pm.temp_size_in_bytes < 64 * 2**20 and pm.alias_size_in_bytes > 9.9e9
+
+
 @pytest.mark.parametrize(
     "n_in,n_out,group",
     [(4096, 14336, 128), (14336, 4096, 128), (4096, 4096, 64)],
