@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import os
 import time
+import weakref
+from operator import is_ as _is
 from typing import Callable, Optional
 
 from ..telemetry.trace import phase, phase_log
@@ -67,6 +69,47 @@ def _memory_fields(compiled) -> dict:
         return {}
 
 
+def _deref(ref):
+    return ref()
+
+
+class _ArgSignature:
+    """What one top-level argument of a :meth:`ProgramCache.wrap_jit` call
+    contributes to the executable table's key: its treedef and, a leaf,
+    the aval (shape, dtype, weak type: a jax array carries it ready made)
+    and the sharding, or shape and dtype of a numpy leaf, or a Python
+    scalar's type and value. The hash is computed once: a signature kept
+    from the call before is the very object in the table's key.
+    ``by_identity``: every leaf is a jax array, which nothing can change
+    in place, so the same leaves are the same signature."""
+
+    __slots__ = ("key", "by_identity", "_hash")
+
+    def __init__(self, treedef, leaves):
+        sig = [treedef]
+        self.by_identity = True
+        for x in leaves:
+            aval = getattr(x, "aval", None)
+            if aval is not None:
+                sig.append(aval)
+                sig.append(getattr(x, "sharding", None))
+                continue
+            self.by_identity = False
+            shape, dtype = getattr(x, "shape", None), getattr(x, "dtype", None)
+            if shape is None or dtype is None:
+                sig.append(("py", type(x).__name__, x if isinstance(x, (bool, int, float, str)) else None))
+            else:
+                sig.append((tuple(shape), dtype))
+        self.key = tuple(sig)
+        self._hash = hash(self.key)
+
+    def __hash__(self):
+        return self._hash
+
+    def __eq__(self, other):
+        return self is other or (isinstance(other, _ArgSignature) and self.key == other.key)
+
+
 class ProgramCache:
     """Compile-or-fetch for jitted programs, with an optional persistent
     executable store and full telemetry.
@@ -85,6 +128,8 @@ class ProgramCache:
         self.misses = 0
         self.deserialized = 0
         self.rejected = 0
+        self.leaves_signed = 0  # leaves whose signature a wrap_jit call computed
+        self.signatures_kept = 0  # top-level arguments of wrap_jit calls whose signature was the last call's
         self._serialize_broken = False  # backend can't serialize; warn once
 
     @classmethod
@@ -229,35 +274,56 @@ class ProgramCache:
     def wrap_jit(self, jitted, name: str = "step", static_argnums=()):
         """Shadow a ``jax.jit`` function's dispatch with this cache.
 
-        The wrapper keys on the concrete input signature (treedef +
-        per-leaf shape/dtype/sharding + the static arg values) and keeps
-        one executable per signature: a first-seen signature lowers and
-        goes through :meth:`compile_lowered` (so a restarted process
-        deserializes instead of compiling), later calls dispatch straight
-        to the executable. Exposes ``_cache_size`` so the PR-3 recompile
-        watchdog's jit-cache probe keeps working through the wrapper."""
+        The wrapper keys on the concrete input signature (per argument:
+        treedef + per-leaf aval and sharding; plus the static arg values)
+        and keeps one executable per signature: a first-seen signature
+        lowers and goes through :meth:`compile_lowered` (so a restarted
+        process deserializes instead of compiling), later calls dispatch
+        straight to the executable.
+
+        A call costs the host by what changed: the signature of a
+        top-level argument whose leaves are, one for one, the objects
+        they were in the previous call (the parameters of an engine's
+        life) is kept and not computed again; only its flattening and an
+        identity check a leaf remain, so a tree mutated in place is
+        signed anew (and an argument with a leaf that is no jax array is
+        signed every call). The last call's leaves are held weakly:
+        nothing is kept alive for it. ``leaves_signed`` and
+        ``signatures_kept`` count both roads on the cache. Exposes
+        ``_cache_size`` so the PR-3 recompile watchdog's jit-cache probe
+        keeps working through the wrapper."""
         jax = _jax()
+        flatten = jax.tree_util.tree_flatten
         statics = tuple(static_argnums)
         table: dict = {}
+        last: dict = {}  # argument position or keyword -> (treedef, weak refs to its leaves, its _ArgSignature)
 
-        def leaf_sig(x):
-            shape = getattr(x, "shape", None)
-            dtype = getattr(x, "dtype", None)
-            if shape is None or dtype is None:
-                return ("py", type(x).__name__, x if isinstance(x, (bool, int, float, str)) else None)
-            sharding = getattr(x, "sharding", None)
-            weak = getattr(x, "weak_type", False)
-            return (tuple(shape), str(dtype), sharding, bool(weak))
+        def sign(where, arg):
+            leaves, treedef = flatten(arg)
+            seen = last.get(where)
+            if seen is not None and seen[0] == treedef and all(map(_is, map(_deref, seen[1]), leaves)):
+                self.signatures_kept += 1
+                return seen[2]
+            self.leaves_signed += len(leaves)
+            signed = _ArgSignature(treedef, leaves)
+            if signed.by_identity:
+                last[where] = (treedef, [weakref.ref(leaf) for leaf in leaves], signed)
+            else:  # a numpy array's shape or a Python scalar's value can change under the same object: signed every call
+                last.pop(where, None)
+            return signed
 
         def dispatch(*args, **kwargs):
             if kwargs and statics:
                 # keyword args + positional statics don't compose in the
                 # AOT call convention; fall back to plain jit dispatch
                 return jitted(*args, **kwargs)
-            dyn = tuple(a for i, a in enumerate(args) if i not in statics)
+            dyn = tuple(a for i, a in enumerate(args) if i not in statics) if statics else args
             stat = tuple(args[i] for i in statics)
-            leaves, treedef = jax.tree_util.tree_flatten((dyn, kwargs))
-            sig = (treedef, tuple(leaf_sig(l) for l in leaves), stat)
+            sig = (
+                tuple(sign(i, a) for i, a in enumerate(dyn)),
+                tuple((k, sign(k, kwargs[k])) for k in sorted(kwargs)) if kwargs else (),
+                stat,
+            )
             compiled = table.get(sig)
             if compiled is None:
                 with phase("program.lower", program=name):
@@ -300,6 +366,8 @@ class ProgramCache:
             "deserialized": self.deserialized,
             "rejected": self.rejected,
             "in_memory": len(self._mem),
+            "leaves_signed": self.leaves_signed,
+            "signatures_kept": self.signatures_kept,
         }
         if self.store is not None:
             out["store_dir"] = self.store.path

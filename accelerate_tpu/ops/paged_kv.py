@@ -43,7 +43,7 @@ shapes) allocates cache in fixed-size *blocks* from one shared pool:
 
 The pool is ONE device buffer for the engine's life. Every program that
 takes the paged cache (the decode tick, :func:`paste_row`,
-:func:`paste_blocks`, :func:`clear_slot`, :func:`set_table_row`) is jitted
+:func:`paste_blocks`, :func:`clear_slots`, :func:`set_table_row`) is jitted
 with the cache donated, writes into it in place and hands the same buffer
 back; inside the tick a scanned layer stack carries the pools of all its
 layers through the layer loop as one ``[L, NB, bs, H_kv, D]`` stack per K
@@ -606,10 +606,26 @@ def set_table_row(paged_cache, slot, table_row):
     return jax.tree_util.tree_map_with_path(write, paged_cache)
 
 
+# the leaves a retirement writes (:func:`clear_slot`): a slot's table rows, its frontier, its recurrent state
+CLEARED_LEAVES = ("block_table", "summary_table", "index", *STATE_LEAVES)
+
+
+def _cleared(name: str, leaf, slot):
+    """A leaf named in :data:`CLEARED_LEAVES` as a retirement of ``slot`` leaves it."""
+    if name in ("block_table", "summary_table"):
+        sel = (slice(None),) * (leaf.ndim - 2) + (slot,)
+        return leaf.at[sel].set(jnp.zeros((leaf.shape[-1],), leaf.dtype))
+    if name == "index":
+        sel = (slice(None),) * (leaf.ndim - 1) + (slot,)
+        return leaf.at[sel].set(jnp.zeros((), leaf.dtype))
+    return jax.lax.dynamic_update_index_in_dim(leaf, jnp.zeros(leaf.shape[1:], leaf.dtype), slot, 0)  # STATE_LEAVES
+
+
 def clear_slot(paged_cache, slot):
     """Re-point ``slot``'s table row at the trash sink and zero its
-    frontier. MUST run when a slot retires: the static decode tick keeps
-    computing (and writing) for every slot, and a stale table would
+    frontier. MUST reach the device once a slot has retired, before the
+    next paste into it and before the next decode tick: the static tick
+    keeps computing (and writing) for every slot, and a stale table would
     corrupt blocks after they are freed and reallocated. A state-space
     layer's state is zeroed too: the tick goes on stepping the free slot
     (token 0 at position 0 from there: finite, and never read) until the
@@ -617,17 +633,29 @@ def clear_slot(paged_cache, slot):
 
     def write(path, leaf):
         name = _path_names(path)[-1]
-        if name in ("block_table", "summary_table"):
-            sel = (slice(None),) * (leaf.ndim - 2) + (slot,)
-            return leaf.at[sel].set(jnp.zeros((leaf.shape[-1],), leaf.dtype))
-        if name == "index":
-            sel = (slice(None),) * (leaf.ndim - 1) + (slot,)
-            return leaf.at[sel].set(jnp.zeros((), leaf.dtype))
-        if name in STATE_LEAVES:
-            return jax.lax.dynamic_update_index_in_dim(leaf, jnp.zeros(leaf.shape[1:], leaf.dtype), slot, 0)
-        return leaf
+        return _cleared(name, leaf, slot) if name in CLEARED_LEAVES else leaf
 
     return jax.tree_util.tree_map_with_path(write, paged_cache)
+
+
+def clear_slots(paged_cache, slots, n):
+    """:func:`clear_slot` for ``slots[:n]`` in ONE program: what the engine
+    runs for a tick's retirements (``slots`` is ``[num_slots]`` int32,
+    whatever stands past ``n`` is not read). A loop of ``n`` trips over the
+    leaves a retirement touches (tables, frontiers, recurrent state), each
+    written in place one slot at a time, so the program moves a retired
+    slot's bytes and no other's; the pools pass it by. Pure — jit it."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(paged_cache)
+    names = [_path_names(path)[-1] for path, _ in flat]
+    leaves = [leaf for _, leaf in flat]
+    touched = [i for i, name in enumerate(names) if name in CLEARED_LEAVES]
+
+    def clear_one(k, written):
+        return [_cleared(names[i], leaf, slots[k]) for i, leaf in zip(touched, written)]
+
+    for i, leaf in zip(touched, jax.lax.fori_loop(0, n, clear_one, [leaves[i] for i in touched])):
+        leaves[i] = leaf
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 class BlockAllocator:

@@ -156,6 +156,9 @@ def check_no_state_leaf(row_cache, what: str) -> None:
         )
 
 
+_NO_FOLD = np.int32(-1)  # :meth:`ServingEngine._request_key`: the key is the chain as it stands
+
+
 class _LazyBuckets:
     """dict-like ``bucket -> compiled program`` that compiles on FIRST
     use instead of eagerly at engine construction: startup pays only for
@@ -456,6 +459,12 @@ class ServingEngine:
         # the host reads them only once that pass is dispatched; none outlives ``step()``
         self._first_pending: dict[int, tuple] = {}
         self._tick_first_deferred = 0  # of this tick's admissions, those read after the decode dispatch
+        # paged: retired slots whose ``clear_slot`` has not reached the device yet. A retirement is found in the
+        # walk, when the device has nothing to do, and nothing needs its clear before the next paste into the
+        # slot or the next decode tick: it goes out behind the next tick's first prefill (or with its decode
+        # dispatch), all of a tick's in one program (:meth:`_flush_clears`)
+        self._clear_pending: list[int] = []
+        self._tick_clears_deferred = 0  # slots whose clear this tick sent behind one of its programs
         self._row_cap = None  # :meth:`_row_cache_cap`, read from the device at the first admission
         # pending requests, kept sorted by the scheduler's order key
         # (priority class, then submission order)
@@ -497,14 +506,20 @@ class ServingEngine:
             call.__name__ = call.__qualname__ = name
             return call
 
-        def prefill(params, ids, true_len, key):
+        def request_key(key, fold):
+            """The sampling chain a request starts from: ``fold_in(key, fold)`` for a fresh request (``key`` the
+            engine's, ``fold`` the uid: computed here, inside the program that consumes it, and not by eager
+            programs ahead of its dispatch), ``key`` as it is where ``fold`` is negative (a chain carried in)."""
+            return jnp.where(fold >= 0, jax.random.fold_in(key, fold), key)
+
+        def prefill(params, ids, true_len, key, fold):
             """[1, B] padded prompt -> (first next-token, its logprob,
             per-row cache with write index reset to true_len, advanced
-            key)."""
+            key). ``key``, ``fold``: :func:`request_key`."""
             b_len = ids.shape[1]
             positions = jnp.broadcast_to(jnp.arange(b_len), (1, b_len))
             logits, cache = apply_fn(params, ids, positions=positions, decode=True, cache=None, **span(0, true_len))
-            key, sub = jax.random.split(key)
+            key, sub = jax.random.split(request_key(key, fold))
             row = logits[0, true_len - 1]
             next_tok = sampler(row[None], sub)[0]
             from .ops.kv_cache import reset_cache_index
@@ -512,14 +527,17 @@ class ServingEngine:
             cache = reset_cache_index(cache, true_len)
             return next_tok, pick_lp(row, next_tok), cache, key
 
-        key_aval = jax.eval_shape(lambda: jax.random.key(0))
+        # every fresh request's chain is ``fold_in`` of this key with its uid (:meth:`_request_key`)
+        self._base_key = jax.random.key(seed)
+        key_aval = jax.eval_shape(lambda: self._base_key)
+        fold_aval = jax.ShapeDtypeStruct((), jnp.int32)
 
         def _build_prefill(b):
             t0 = time.perf_counter()
             with self._trace_ctx():
                 prog = self._pc.compile(
                     named(prefill, f"prefill_b{b}"), params, jax.ShapeDtypeStruct((1, b), jnp.int32),
-                    jax.ShapeDtypeStruct((), jnp.int32), key_aval,
+                    jax.ShapeDtypeStruct((), jnp.int32), key_aval, fold_aval,
                     name=f"prefill_b{b}",
                 )
             self._note_bucket_compile("prefill", b, (time.perf_counter() - t0) * 1000.0)
@@ -533,6 +551,7 @@ class ServingEngine:
                 jax.ShapeDtypeStruct((1, b), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32),
                 key_aval,
+                fold_aval,
             ),
             (self._trace_ctx,),
         )
@@ -555,8 +574,8 @@ class ServingEngine:
         self._chunk_cold = ctx_jit(chunk_cold)
         self._chunk_warm = ctx_jit(chunk_warm)
 
-        def sample_at(logits, offset, key):
-            key, sub = jax.random.split(key)
+        def sample_at(logits, offset, key, fold):
+            key, sub = jax.random.split(request_key(key, fold))
             row = logits[0, offset]
             tok = sampler(row[None], sub)[0]
             return tok, pick_lp(row, tok), key
@@ -592,12 +611,15 @@ class ServingEngine:
         self._prefixes: dict[int, dict] = {}
         self._prefix_uid = 0
 
-        def insert(slot_caches, row_cache, slot):
-            return jax.tree.map(
+        # an admission's paste / insert also starts the slot's sampling chain (``keys`` is ``_slot_keys``): one
+        # program, and the host hands it its numpy arguments as they are
+        def insert(slot_caches, keys, row_cache, key, slot):
+            caches = jax.tree.map(
                 lambda big, row: jax.lax.dynamic_update_index_in_dim(big, row.astype(big.dtype), slot, 0),
                 slot_caches,
                 row_cache,
             )
+            return caches, keys.at[slot].set(key)
 
         self._insert = ctx_jit(insert)
 
@@ -668,7 +690,7 @@ class ServingEngine:
                 lps = jax.vmap(pick_lp)(logits[:, -1], nxt)
                 return cache, nxt, lps, keys, jnp.stack(loads) if loads else None
 
-            from .ops.paged_kv import clear_slot, paged_mode, paste_blocks, paste_row, set_table_row
+            from .ops.paged_kv import clear_slots, paged_mode, paste_blocks, paste_row, set_table_row
 
             # Lazy dispatch wrapped in BOTH trace contexts (paged layout +
             # model mesh), re-entered every call: contexts only matter at
@@ -702,9 +724,13 @@ class ServingEngine:
                 ),
                 (lambda: paged_mode(pcfg), self._trace_ctx),
             )
-            self._paste = ctx_jit(paste_row, donate_argnums=(0,))
+
+            def paste(paged_cache, keys, row_cache, key, write_row, table_row, slot, new_index, *summary_row):
+                return paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index, *summary_row), keys.at[slot].set(key)
+
+            self._paste = ctx_jit(named(paste, "paste_row"), donate_argnums=(0,))
             self._paste_blocks = ctx_jit(paste_blocks, donate_argnums=(0,))
-            self._clear_slot = ctx_jit(clear_slot, donate_argnums=(0,))
+            self._clear_slots = ctx_jit(clear_slots, donate_argnums=(0,))
             self._set_table = ctx_jit(set_table_row, donate_argnums=(0,))
         else:
             def one_step(params, cache_row, tok, pos, key):
@@ -752,8 +778,8 @@ class ServingEngine:
         head's K/V rows as the cache has them (the head's hidden states
         came through layers that did not advance). Returns
         ``(next_tok | None, cache, key)`` with the cache write index reset
-        to ``len(full_tokens)``; sampling happens only when ``key`` is given
-        (prefix registration skips it).
+        to ``len(full_tokens)``; sampling happens only when ``key`` (a pair of
+        :meth:`_request_key`) is given (prefix registration skips it).
 
         The continuous-batching scheduler does NOT call this loop — it
         advances the same :meth:`_run_window` steps one budget-claimed
@@ -767,7 +793,7 @@ class ServingEngine:
         row_cache = self._reset_idx(row_cache, jnp.int32(t))
         next_tok = lp = None
         if key is not None:
-            next_tok, lp, key = self._sample_at(logits, jnp.int32(t - 1 - s_last), key)
+            next_tok, lp, key = self._sample_at(logits, jnp.int32(t - 1 - s_last), *key)
         return next_tok, lp, row_cache, key
 
     def _next_window(self, t: int, s: int):
@@ -1078,10 +1104,9 @@ class ServingEngine:
                 f"prompt ({len(prompt)}) + max_new_tokens ({max_new_tokens}) "
                 f"exceeds the slot cache ({self.max_len})"
             )
-        key = jax.random.fold_in(jax.random.key(self._seed), int(uid_key))
         next_tok, lp, cache, key = self._chunked_prefill(
-            prompt, row_cache=None if pre is None else pre["cache"], done_upto=plen, key=key,
-            trace=trace,
+            prompt, row_cache=None if pre is None else pre["cache"], done_upto=plen,
+            key=self._request_key(int(uid_key)), trace=trace,
         )
         total = len(prompt)
         trimmed = self._trim_row_cache(cache, total)
@@ -1171,9 +1196,10 @@ class ServingEngine:
         """Snapshot EVERY in-flight request (queued + active) for
         migration to another replica — the failover half of
         :mod:`accelerate_tpu.serving_fleet`. Non-mutating: the engine is
-        left exactly as found (the router decides what to do with the
-        husk). Each snapshot carries the request plus its sampling-chain
-        ``key_data``, so :meth:`import_inflight` on a survivor continues
+        left exactly as found, but for retirements' clears that had not
+        reached the device yet, which are sent first (the router decides
+        what to do with the husk). Each snapshot carries the request plus
+        its sampling-chain ``key_data``, so :meth:`import_inflight` on a survivor continues
         token- and logprob-exactly; decoding slots additionally export
         their trimmed KV rows (``cache`` + ``rows``) when ``include_kv``
         and the layout allows (dense — paged slots fail over by prefix
@@ -1186,6 +1212,7 @@ class ServingEngine:
         tick that one of them cuts short reads the first tokens it had
         left on the device before it raises (:meth:`_tick_phases`)."""
         jax = _jax()
+        self._flush_clears()
         kv_ok = include_kv and not self.paged
         if kv_ok:
             check_no_state_leaf(self._row_template, "export_inflight(include_kv=True)")
@@ -1222,9 +1249,7 @@ class ServingEngine:
                 snaps.append(handoff_snap(req, st["handoff"]))
                 continue
             snap = self._snapshot_request(req)
-            key = st["key"] if st is not None else jax.random.fold_in(
-                jax.random.key(self._seed), req.uid
-            )
+            key = self._chain_key(*(st["key"] if st is not None else self._request_key(req.uid)))
             snap["key_data"] = np.asarray(jax.random.key_data(key))
             snaps.append(snap)
         for req in self.queue:
@@ -1232,9 +1257,7 @@ class ServingEngine:
                 snaps.append(handoff_snap(req, req.handoff))
                 continue
             snap = self._snapshot_request(req)
-            key = req.resume_key if req.resume_key is not None else jax.random.fold_in(
-                jax.random.key(self._seed), req.uid
-            )
+            key = self._chain_key(*self._request_key(req.uid, req.resume_key))
             snap["key_data"] = np.asarray(jax.random.key_data(key))
             snaps.append(snap)
         return snaps
@@ -1472,7 +1495,8 @@ class ServingEngine:
         profile shows inside ``engine.tick``."""
         m = self.metrics
         admitted, tokens_was, completed_was = 0, m.tokens_generated, m.requests_completed
-        self._tick_prefill_tokens = self._tick_first_deferred = 0
+        self._tick_prefill_tokens = self._tick_first_deferred = self._tick_clears_deferred = 0
+        leaves_signed_was = self._pc.leaves_signed
         self._tick_expert_load = (0, 0, 0, 0)
         self._tick_state_idle = 0
         self._tick_windows = (0, 0, 0, 0)
@@ -1529,6 +1553,10 @@ class ServingEngine:
             self._read_first_tokens()
         with phase("engine.expire"):
             self._expire_window_blocks()
+            if self.active_count == 0:
+                self._flush_clears()  # nothing is left to send them behind: an idle engine holds no stale row
+        leaves_signed = self._pc.leaves_signed - leaves_signed_was
+        m.on_host_work(self._tick_clears_deferred, leaves_signed)
         pages = (0, 0)
         if self._aligned is not None:
             self._close_windows()
@@ -1536,6 +1564,7 @@ class ServingEngine:
             m.on_pages_held(*pages)
         with phase(
             "engine.tick.done", admitted=admitted, first_tokens_deferred=self._tick_first_deferred,
+            clears_deferred=self._tick_clears_deferred, leaves_signed=leaves_signed,
             prefill_tokens=self._tick_prefill_tokens,
             emitted=m.tokens_generated - tokens_was, retired=m.requests_completed - completed_was,
             pool_blocked=int(self._pool_blocked), free_blocks=self._alloc.free_count if self.paged else -1,
@@ -1635,6 +1664,22 @@ class ServingEngine:
             write_row[i] = 0
         return owned, shared_entries, table, write_row
 
+    def _request_key(self, uid: int, carried=None) -> tuple:
+        """``(key, fold)`` as the programs that sample a request's first token take them (``request_key`` in
+        ``__init__``): the engine's key and the uid for a fresh request, whose chain ``fold_in(key(seed), uid)``
+        the program then derives itself, so that an admission runs no eager program ahead of its prefill; a
+        chain ``carried`` in (a resume, a hand-off, a migrated request) with nothing to fold."""
+        if carried is not None:
+            return carried, _NO_FOLD
+        if 0 <= uid <= np.iinfo(np.int32).max:
+            return self._base_key, np.int32(uid)
+        return _jax().random.fold_in(self._base_key, uid), _NO_FOLD  # a uid the program's int32 does not hold
+
+    @staticmethod
+    def _chain_key(key, fold):
+        """The chain a :meth:`_request_key` pair stands for, computed here: for whoever needs the key itself."""
+        return key if fold < 0 else _jax().random.fold_in(key, int(fold))
+
     def _admit(self, slot: int) -> bool:
         """Move the queue head into ``slot`` in the prefill phase,
         reserving its pool blocks first (paged). Under pool exhaustion,
@@ -1665,13 +1710,10 @@ class ServingEngine:
             st["table"], st["write_row"] = table, write_row
             if self._aligned is not None:
                 self._slot_summary[slot], st["summary_row"], self._slot_last[slot] = self._reserved_summary
-        # the per-request sampling chain: fold the uid at first admission,
-        # carry the evicted chain across a preemption — the resumed stream
-        # continues the SAME chain, so sampled outputs stay request-exact
-        if req.resume_key is not None:
-            st["key"] = req.resume_key
-        else:
-            st["key"] = jax.random.fold_in(jax.random.key(self._seed), req.uid)
+        # the per-request sampling chain: the uid folded into the engine's key at first admission (by the
+        # program that samples the first token: no eager program runs here), the evicted chain carried across a
+        # preemption — the resumed stream continues the SAME chain, so sampled outputs stay request-exact
+        st["key"] = self._request_key(req.uid, req.resume_key)
         if req.handoff is not None:
             # disaggregated admission: the KV rows, first token, and the
             # advanced sampling chain all arrived with the handoff — no
@@ -1682,7 +1724,9 @@ class ServingEngine:
             # request's exact KV frontier, and the resume finalize re-feeds
             # its carried last token instead of emitting h["next_tok"].
             st["handoff"] = req.handoff
-            st["key"] = jax.random.wrap_key_data(jax.numpy.asarray(req.handoff["key_data"]))
+            st["key"] = self._request_key(
+                req.uid, jax.random.wrap_key_data(jax.numpy.asarray(req.handoff["key_data"]))
+            )
             req.handoff = None
         elif not resume and req.prefix_id is None and (b := self._bucket_for(len(req.prompt))) is not None:
             # short prompt, no prefix: the one-shot fused program
@@ -1740,6 +1784,7 @@ class ServingEngine:
             # pad the trimmed rows back onto the template and paste —
             # zero tokens of this tick's budget are spent
             h = st.pop("handoff")
+            self._flush_clears()  # no program of this admission stands ahead of its paste: today's order
             with phase("engine.prefill.dispatch", uid=req.uid, tokens=0, prompt_tokens=len(req.prompt)):
                 cache = self._untrim_row_cache(h["cache"], h["total"])
             if self.tracer is not None:
@@ -1747,7 +1792,7 @@ class ServingEngine:
                 # priced wire move); no moved_bytes here, so critpath
                 # skips this span's byte check by design
                 self.tracer.seg(req.trace, "kv_handoff", phase="paste", rows=int(h["total"]))
-            self._finalize_prefill(slot, cache, h["total"], h["next_tok"], h["lp"], st["key"])
+            self._finalize_prefill(slot, cache, h["total"], h["next_tok"], h["lp"], st["key"][0])
             return budget
         if st["bucket"] is not None:
             b = st["bucket"]
@@ -1764,8 +1809,9 @@ class ServingEngine:
                 # the request's ``prefill`` span closes at the first-token
                 # sync in _finalize_prefill: dispatch to there is compute
                 st["dispatched"] = (time.perf_counter(), int(b))
+                # the ids and the length go to the executable as they are: it puts them on the device itself
                 next_tok, lp, row_cache, key = self._prefill[b](
-                    self.model.params, jnp.asarray(padded), jnp.int32(len(req.prompt)), st["key"]
+                    self.model.params, padded, np.int32(len(req.prompt)), *st["key"]
                 )
             self._tick_prefill_tokens += b
             self._finalize_prefill(slot, row_cache, len(req.prompt), next_tok, lp, key)
@@ -1784,11 +1830,11 @@ class ServingEngine:
             budget -= w
             force = False
         next_tok = lp = None
-        key = st["key"]
+        key = st["key"][0]  # a resume's is the chain it carried
         with phase("engine.prefill.dispatch", uid=req.uid, tokens=0, prompt_tokens=len(req.prompt)):
             cache = self._reset_idx(st["cache"], jnp.int32(t))
             if not st["resume"]:
-                next_tok, lp, key = self._sample_at(st["logits"], jnp.int32(t - 1 - st["s_last"]), key)
+                next_tok, lp, key = self._sample_at(st["logits"], jnp.int32(t - 1 - st["s_last"]), *st["key"])
         self._finalize_prefill(slot, cache, t, next_tok, lp, key)
         return budget
 
@@ -1816,19 +1862,21 @@ class ServingEngine:
         tick's decode pass feeds it on the device and reads it behind its
         own dispatch (:meth:`_read_first_token`: TTFT). Only a request that
         can take one token, which no decode pass will advance, is read here."""
-        jnp = _jax().numpy
         st = self._prefill_state[slot]
         req = st["req"]
         with phase("engine.prefill.paste", uid=req.uid):
-            self._slot_keys = self._slot_keys.at[slot].set(key)
+            # the retirements' clears, behind the prefill that was just dispatched and ahead of the paste
+            self._flush_clears(deferred=True)
             if self.paged:
-                summaries = () if self._aligned is None else (jnp.asarray(st["summary_row"]),)
-                self.slot_caches = self._paste(
-                    self.slot_caches, row_cache, jnp.asarray(st["write_row"]),
-                    jnp.asarray(st["table"]), jnp.int32(slot), jnp.int32(total), *summaries,
+                summaries = () if self._aligned is None else (st["summary_row"],)
+                self.slot_caches, self._slot_keys = self._paste(
+                    self.slot_caches, self._slot_keys, row_cache, key, st["write_row"], st["table"],
+                    np.int32(slot), np.int32(total), *summaries,
                 )
             else:
-                self.slot_caches = self._insert(self.slot_caches, row_cache, jnp.int32(slot))
+                self.slot_caches, self._slot_keys = self._insert(
+                    self.slot_caches, self._slot_keys, row_cache, key, np.int32(slot)
+                )
         self._prefill_state[slot] = None
         self._prefill_order.remove(slot)
         self.slot_phase[slot] = "decode"
@@ -1923,6 +1971,7 @@ class ServingEngine:
             "engine.decode.dispatch", decoding=n_decoding, tick_block=self.tick_block,
             live_tokens=int(self.slot_pos[decoding].sum()),
         ):
+            self._flush_clears(deferred=True)  # a tick that admitted nothing: its clears ride ahead of the decode program
             toks = jnp.asarray(self.slot_tok)
             for slot, pending in self._first_pending.items():
                 toks = self._feed_first_token(toks, jnp.int32(slot), pending[0])
@@ -1951,33 +2000,44 @@ class ServingEngine:
         with phase("engine.decode.walk"):
             # (first position, tokens kept) a decoding slot: what an aligned window's counts are made of
             kept = None if self._aligned is None else []
+            toks_by_slot, lps_by_slot = toks_k.T.tolist(), lps_k.T.tolist()  # Python ints and floats, once for all slots
             for slot, req in enumerate(self.slot_req):
                 if req is None or self.slot_phase[slot] != "decode":
                     continue
-                n_new, retired = 0, False
+                first = int(self.slot_pos[slot])
+                n_new, retired = self._take_tokens(req, toks_by_slot[slot], lps_by_slot[slot])
+                self.slot_pos[slot] += n_new
+                self.slot_tok[slot] = req.out_tokens[-1]
+                self.metrics.on_tokens(n_new)
+                self.metrics.on_tick_tokens(req.uid, n_new)
+                if self.tracer is not None:
+                    self.tracer.window(req.trace, "decode", tokens=n_new)
                 if kept is not None:
-                    kept.append([int(self.slot_pos[slot]), 0])
-                for k in range(self.tick_block):
-                    tok = int(toks_k[k, slot])
-                    req.out_tokens.append(tok)
-                    req.out_lps.append(float(lps_k[k, slot]))
-                    self.metrics.on_tokens(1)
-                    n_new += 1
-                    self.slot_pos[slot] += 1
-                    self.slot_tok[slot] = tok
-                    if self._finished(req, tok):
-                        retired = True
-                        break  # remaining block tokens are overshoot — discarded
-                if n_new:
-                    self.metrics.on_tick_tokens(req.uid, n_new)
-                    if self.tracer is not None:
-                        self.tracer.window(req.trace, "decode", tokens=n_new)
-                if kept is not None:
-                    kept[-1][1] = n_new
+                    kept.append((first, n_new))
                 if retired:
-                    self._retire(slot)
+                    self._retire(slot)  # the host's books now; the slot's clear goes behind the next tick's first program
             if kept:
                 self._count_window_attention(np.asarray(kept))
+
+    def _take_tokens(self, req: _Request, toks: list, lps: list) -> tuple:
+        """Give ``req`` the tokens it keeps of its column of a tick's block (``toks``, ``lps``: lists), in one
+        pass: up to its budget and through the first eos (what is left of the block is overshoot, discarded).
+        ``(tokens kept, whether the last of them ends the request)``. Stop sequences are matched against the
+        stream as it grows, token by token."""
+        out = req.out_tokens
+        if req.stop_sequences:
+            for n, tok in enumerate(toks, 1):
+                out.append(tok)
+                if ended := self._finished(req, tok):
+                    break
+            req.out_lps.extend(lps[:n])
+            return n, ended
+        col = toks[: max(1, req.max_new_tokens - len(out))]
+        if self.eos_token_id is not None and self.eos_token_id in col:
+            del col[col.index(self.eos_token_id) + 1 :]
+        out.extend(col)
+        req.out_lps.extend(lps[: len(col)])
+        return len(col), self._finished(req, col[-1])
 
     def _decoding_slots(self) -> np.ndarray:
         """``[slots]`` bool: the slots in which a request decodes. (A numpy array: ``jnp.asarray`` of a
@@ -2324,13 +2384,13 @@ class ServingEngine:
         self.done[req.uid] = np.concatenate(parts)
         self._done_new[req.uid] = np.asarray(req.out_tokens, np.int32)
         self._done_lps[req.uid] = np.asarray(req.out_lps, np.float32)
-        self._release(slot)
+        self._release(slot, defer_clear=True)
         self._index[req.uid] = ("done", None)
         self.metrics.on_complete(req.uid)
         if self.tracer is not None:
             self.tracer.finish(req.trace, status="ok", tokens=len(req.out_tokens))
 
-    def _release(self, slot: int):
+    def _release(self, slot: int, defer_clear: bool = False):
         """Free a slot's resources without publishing a result (shared by
         retirement, cancellation, and decode preemption). The static tick
         goes on computing for the free slot: in the paged layout
@@ -2338,7 +2398,11 @@ class ServingEngine:
         recurrent state (a state-space layer then steps token 0 from zero:
         finite, never read); in the dense layout rows and state alike keep
         accumulating garbage. Either way the next prefill's paste / insert
-        replaces the slot whole, state leaves included."""
+        replaces the slot whole, state leaves included. The host's books
+        are settled here; a retirement leaves the device's half pending
+        (``defer_clear``: :meth:`_flush_clears` sends it before any paste
+        into the slot and before the next decode tick), cancellation and
+        preemption send it now with whatever was pending."""
         self.slot_phase[slot] = None
         self._prefill_state[slot] = None
         if slot in self._prefill_order:
@@ -2360,7 +2424,6 @@ class ServingEngine:
             # trash sink — the static tick keeps computing for every slot,
             # and a stale table would corrupt blocks once they're
             # reallocated to another request
-            jnp = _jax().numpy
             self._alloc.free(list(self._slot_blocks[slot].values()))
             self._slot_blocks[slot] = {}
             if self._aligned is not None:
@@ -2370,4 +2433,19 @@ class ServingEngine:
                 self._shared_refs[bid] -= 1
             self._slot_shared[slot] = {}
             self._slot_table[slot][:] = 0
-            self.slot_caches = self._clear_slot(self.slot_caches, jnp.int32(slot))
+            self._clear_pending.append(slot)
+            if not defer_clear:
+                self._flush_clears()
+
+    def _flush_clears(self, deferred: bool = False) -> None:
+        """Send the pending clears to the device, ONE program for all of them (``clear_slots`` over a padded
+        list of slots). ``deferred`` says the call stands behind a program of the running tick (its first
+        prefill) or with its decode dispatch: those slots count as ``clears_deferred`` of ``engine.tick.done``."""
+        if not self._clear_pending:
+            return
+        slots = np.zeros((self.num_slots,), np.int32)
+        slots[: len(self._clear_pending)] = self._clear_pending
+        self.slot_caches = self._clear_slots(self.slot_caches, slots, np.int32(len(self._clear_pending)))
+        if deferred:
+            self._tick_clears_deferred += len(self._clear_pending)
+        self._clear_pending.clear()

@@ -160,6 +160,13 @@ class ServingMetrics:
         # ``engine.tick.done``); ``prefills`` less it took the road with a
         # wait inside the admission: a hand-off, a request of one token
         self.first_tokens_deferred = 0
+        # the host's work around the programs (``engine.tick.done``):
+        # retired slots whose clear went out behind a program of a later
+        # tick and not inside the walk that found them, and leaves whose
+        # signature the compile cache's dispatch computed for the ticks'
+        # calls (the parameters' are kept from call to call)
+        self.clears_deferred = 0
+        self.leaves_signed = 0
         # the host loop itself, from the phase log's tick records
         # (telemetry.trace.PhaseLog): ticks made, the longest one's wall
         # time, and how many closed far beyond the median of the ticks
@@ -245,6 +252,10 @@ class ServingMetrics:
 
     def on_first_tokens_deferred(self, n: int):
         self.first_tokens_deferred += n
+
+    def on_host_work(self, clears_deferred: int, leaves_signed: int):
+        self.clears_deferred += clears_deferred
+        self.leaves_signed += leaves_signed
 
     def on_expert_load(self, touched: int, pairs_max: int, tile_visits: int, pairs: int):
         self.experts_touched += touched

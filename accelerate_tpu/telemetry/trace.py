@@ -95,7 +95,10 @@ SEGMENTS = (
 #: ``engine.tick`` is tiled by its children (``engine.tick.done`` is a
 #: zero-length marker that carries the tick's counts: admissions and, of
 #: them, ``first_tokens_deferred``, those whose first token the host
-#: read behind the decode dispatch; tokens, the pool,
+#: read behind the decode dispatch; ``clears_deferred``, retired slots
+#: whose clear the tick sent behind its first prefill or with its decode
+#: dispatch, and ``leaves_signed``, the leaves the compile cache's
+#: dispatch signed for the tick's calls; tokens, the pool,
 #: the routed experts' load (``experts_touched``, ``expert_pairs_max``,
 #: ``expert_tile_visits`` and ``expert_pairs``, the token-expert pairs
 #: the grouped products multiplied: the decoding slots' alone) and

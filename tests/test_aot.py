@@ -171,6 +171,121 @@ def test_wrap_jit_dispatch_and_cache_size(tmp_path):
     assert w._cache_size() == 2 and pc.misses == 2
 
 
+def _tree(dtype=jnp.float32, shape=(16, 16)):
+    return {"w": jnp.ones(shape, dtype), "more": [jnp.zeros((3,), jnp.float32), jnp.ones((2, 2), jnp.bfloat16)]}
+
+
+def _tree_fn(p, x):
+    return (x @ p["w"].astype(jnp.float32)[: x.shape[1]]).sum() + p["more"][0].sum()
+
+
+def test_wrap_jit_keeps_the_signature_of_a_tree_that_comes_back():
+    """The same tree twice: one executable, and the second call signs the other argument alone (the tree's
+    three leaves are looked at by identity, not signed); ``stats()`` carries both counts."""
+    pc = ProgramCache()
+    w = pc.wrap_jit(jax.jit(_tree_fn), name="w")
+    tree = _tree()
+    a = float(w(tree, jnp.ones((8, 16))))
+    assert (pc.leaves_signed, pc.signatures_kept) == (4, 0) and w._cache_size() == 1
+    b = float(w(tree, jnp.ones((8, 16))))  # a new array of the old signature beside the old tree
+    assert (pc.leaves_signed, pc.signatures_kept) == (5, 1) and w._cache_size() == 1 and pc.misses == 1 and a == b
+    x = jnp.ones((8, 16))
+    w(tree, x), w(tree, x)
+    assert (pc.leaves_signed, pc.signatures_kept) == (6, 4), "both arguments came back the second time"
+    assert pc.stats()["leaves_signed"] == 6 and pc.stats()["signatures_kept"] == 4
+    w(_tree(), x)  # an equal tree of other arrays: signed anew, the same executable
+    assert (pc.leaves_signed, pc.signatures_kept) == (9, 5) and w._cache_size() == 1 and pc.misses == 1
+
+
+def _on_another_device(tree):
+    return {**tree, "w": jax.device_put(tree["w"], jax.devices()[1])}
+
+
+@pytest.mark.parametrize(
+    "other",
+    [lambda t: {**t, "w": t["w"].astype(jnp.bfloat16)}, lambda t: {**t, "w": jnp.ones((32, 16), jnp.float32)}, _on_another_device],
+    ids=["dtype", "shape", "sharding"],
+)
+def test_wrap_jit_new_tree_with_one_leaf_changed_is_a_second_executable(other):
+    """One leaf of another dtype, shape or sharding among leaves that are the very arrays of the call before."""
+    pc = ProgramCache()
+    w = pc.wrap_jit(jax.jit(lambda p: jax.tree.map(lambda l: l * 2, p)), name="w")
+    tree = _tree()
+    w(tree), w(tree)
+    assert w._cache_size() == 1 and pc.signatures_kept == 1
+    changed = other(tree)
+    out = w(changed)
+    assert w._cache_size() == 2 and pc.misses == 2
+    assert out["w"].dtype == changed["w"].dtype and out["w"].shape == changed["w"].shape
+    assert out["w"].sharding == changed["w"].sharding
+    w(tree)  # and back: the first executable, no third
+    assert w._cache_size() == 2 and pc.misses == 2
+
+
+@pytest.mark.parametrize("how", ["leaf_replaced", "key_added", "list_grown"])
+def test_wrap_jit_tree_mutated_in_place_is_not_served_the_stale_executable(how):
+    """The dict that came back is the same object, its contents are not: the kept signature is checked against
+    the leaves' identities and the treedef, so the call lowers for what the tree now is."""
+    pc = ProgramCache()
+    w = pc.wrap_jit(jax.jit(lambda p: jax.tree.map(lambda l: l * 2, p)), name="w")
+    tree = _tree()
+    w(tree), w(tree)
+    kept, size = pc.signatures_kept, w._cache_size()
+    if how == "leaf_replaced":
+        tree["w"] = jnp.ones((16, 16), jnp.bfloat16)
+    elif how == "key_added":
+        tree["bias"] = jnp.zeros((4,), jnp.float32)
+    else:
+        tree["more"].append(jnp.zeros((5,), jnp.int32))
+    out = w(tree)
+    assert pc.signatures_kept == kept and w._cache_size() == size + 1
+    assert jax.tree.structure(out) == jax.tree.structure(tree)
+    assert [l.dtype for l in jax.tree.leaves(out)] == [l.dtype for l in jax.tree.leaves(tree)]
+    w(tree)
+    assert pc.signatures_kept == kept + 1 and w._cache_size() == size + 1
+
+
+def test_wrap_jit_signs_numpy_and_python_leaves_every_call_and_holds_nothing_alive():
+    """An argument with a leaf that is no jax array can change under the same object (a numpy array's shape, a
+    Python scalar's value): signed every call. And the last call's arrays are held weakly: dropping the
+    caller's reference frees them."""
+    import gc
+    import weakref
+
+    pc = ProgramCache()
+    w = pc.wrap_jit(jax.jit(lambda p, n: p["x"] * n), name="w")
+    batch = {"x": np.ones((4,), np.float32)}
+    w(batch, 2.0), w(batch, 2.0)
+    assert pc.signatures_kept == 0 and pc.leaves_signed == 4 and w._cache_size() == 1
+    batch["x"].resize((8,), refcheck=False)  # the same numpy object, another shape
+    assert w(batch, 2.0).shape == (8,) and w._cache_size() == 2
+    big = {"x": jnp.ones((1024,), jnp.float32)}
+    w(big, 2.0)
+    ref = weakref.ref(big["x"])
+    del big
+    gc.collect()
+    assert ref() is None, "the dispatch kept the last call's array alive"
+
+
+def test_wrap_jit_watchdog_probe_and_export_see_what_they_saw(tmp_path):
+    """The recompile watchdog's probe (``_cache_size`` through ``StepTelemetry.wrap``) stays silent over calls
+    that keep their signatures and fires on the one that does not; ``aot_export`` ships one entry a program."""
+    from accelerate_tpu.telemetry import StepTelemetry
+
+    pc = ProgramCache(store=ExecutableStore(str(tmp_path / "store")))
+    dispatch = pc.wrap_jit(jax.jit(_tree_fn), name="probe")
+    telem = StepTelemetry(warmup_steps=1)
+    step = telem.wrap(dispatch)
+    tree, x = _tree(), jnp.ones((8, 16))
+    for _ in range(4):
+        step(tree, x)
+    assert telem.recompiles == 0 and dispatch._cache_size() == 1 and pc.signatures_kept == 6
+    tree["w"] = jnp.ones((16, 16), jnp.bfloat16)
+    step(tree, x)
+    assert telem.recompiles == 1 and dispatch._cache_size() == 2
+    assert pc.aot_export(str(tmp_path / "bundle.tar.gz")) == 2 == pc.misses
+
+
 def test_aot_export_import_roundtrip(tmp_path):
     src = ProgramCache(store=ExecutableStore(str(tmp_path / "src")))
     src.compile(_fn, *_avals(), name="t")
@@ -419,6 +534,30 @@ def test_compile_kwargs_activates_program_cache(tmp_path, reset_singletons):
     losses2 = [float(step2(batch2)) for _ in range(3)]
     assert losses2 == losses
     assert acc2.program_cache.misses == 0 and acc2.program_cache.deserialized >= 1
+
+
+def test_train_step_through_the_program_cache_gives_the_plain_steps_losses(tmp_path, monkeypatch, reset_singletons):
+    """``build_train_step`` dispatched by ``wrap_jit`` (a compile cache directory is set) and by plain ``jax.jit``
+    (none is): the same losses step for step, and the cache signed every step's leaves (parameters and
+    optimizer state are new arrays each step: nothing to keep)."""
+    import optax
+
+    from accelerate_tpu import Accelerator
+    from accelerate_tpu.state import AcceleratorState, GradientState, PartialState
+
+    acc, step, batch = _make_accelerator(str(tmp_path))
+    cached = [float(step(batch)) for _ in range(4)]
+    assert acc.program_cache.leaves_signed > 0 and step._jitted._cache_size() >= 1
+    AcceleratorState._reset_state(), GradientState._reset_state(), PartialState._reset_state()
+    monkeypatch.delenv("ACCELERATE_COMPILE_CACHE_DIR", raising=False)
+    plain_acc = Accelerator()
+    assert plain_acc.program_cache is None
+    params = {"w": np.ones((4, 4), np.float32)}
+    apply_fn = lambda p, x: x @ p["w"]  # noqa: E731
+    plain_acc.prepare_model((apply_fn, params))
+    plain_acc.prepare_optimizer(optax.sgd(0.1))
+    plain = plain_acc.build_train_step(lambda p, b: ((apply_fn(p, b["x"]) - b["y"]) ** 2).mean())
+    assert [float(plain(batch)) for _ in range(4)] == cached and cached[-1] < cached[0]
 
 
 def test_bare_accelerator_has_no_program_cache(monkeypatch, reset_singletons):

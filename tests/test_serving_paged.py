@@ -532,7 +532,7 @@ def test_every_cache_program_donates_the_pool_and_updates_it_in_place(tiny_model
 
     born = [p.unsafe_buffer_pointer() for p in pools(eng.slot_caches)]
     seen = set()
-    for name in ("_paste", "_paste_blocks", "_clear_slot", "_set_table", "_decode_tick"):
+    for name in ("_paste", "_paste_blocks", "_clear_slots", "_set_table", "_decode_tick"):
         program = getattr(eng, name)
 
         def checked(*args, _program=program, _name=name):
@@ -549,7 +549,7 @@ def test_every_cache_program_donates_the_pool_and_updates_it_in_place(tiny_model
     eng.step()
     assert all(leaf.is_deleted() for leaf in jax.tree.leaves(before))
     eng.run()
-    assert seen == {"_paste", "_paste_blocks", "_clear_slot", "_set_table", "_decode_tick"}
+    assert seen == {"_paste", "_paste_blocks", "_clear_slots", "_set_table", "_decode_tick"}
     assert [p.unsafe_buffer_pointer() for p in pools(eng.slot_caches)] == born
 
 
@@ -761,7 +761,7 @@ def test_setup_log_shows_the_tick_aliasing_its_pool(tiny_llama, tmp_path):
     compiled = {e["program"]: e for e in read_events(log_path) if e.get("name") == "compile_cache_miss"}
     if "alias_bytes" not in compiled["paged_decode_tick"]:
         pytest.skip("this backend gives no memory analysis")
-    for program in ("paged_decode_tick", "paste_row", "clear_slot"):
+    for program in ("paged_decode_tick", "paste_row", "clear_slots"):
         assert compiled[program]["alias_bytes"] >= pool_bytes, program
         assert compiled[program]["temp_bytes"] >= 0
 
