@@ -21,6 +21,7 @@ from ..ops.fp8 import policy_dot_general as _pdg
 from jax.sharding import PartitionSpec as P
 
 from ..modeling import Model
+from .llama import rows_at
 
 
 @dataclasses.dataclass
@@ -122,7 +123,7 @@ class GPT2Model(nn.Module):
     config: GPT2Config
 
     @nn.compact
-    def __call__(self, input_ids, positions=None, deterministic: bool = True, decode: bool = False):
+    def __call__(self, input_ids, positions=None, deterministic: bool = True, decode: bool = False, logits_at=None):
         cfg = self.config
         wte = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="wte")
         hidden = wte(input_ids)
@@ -138,6 +139,8 @@ class GPT2Model(nn.Module):
         block = nn.remat(GPT2Block, prevent_cse=False, static_argnums=(2,)) if cfg.remat else GPT2Block
         for i in range(cfg.num_hidden_layers):
             hidden = block(cfg, name=f"layer_{i}")(hidden, decode)
+        if logits_at is not None:  # the caller reads these positions' logits alone, as ``LlamaModel``'s
+            hidden = rows_at(hidden, logits_at)
         hidden = nn.LayerNorm(epsilon=cfg.layer_norm_eps, name="ln_f", dtype=hidden.dtype)(hidden)
         if cfg.tie_word_embeddings:
             return hidden.astype(jnp.float32) @ wte.embedding.T.astype(jnp.float32)
@@ -150,18 +153,20 @@ def create_gpt2_model(config: Optional[GPT2Config] = None, seed: int = 0, seq_le
     dummy = jnp.zeros((2, seq_len), jnp.int32)
     params = module.init(jax.random.key(seed), dummy)["params"]
 
-    def apply_fn(p, input_ids, positions=None, decode=False, cache=None):
+    def apply_fn(p, input_ids, positions=None, decode=False, cache=None, logits_at=None):
         """decode=True threads the KV cache: pass ``cache`` (or None to
-        initialise) and receive ``(logits, new_cache)``."""
+        initialise) and receive ``(logits, new_cache)``. ``logits_at``
+        (int32 positions, ``[n]`` or a scalar): these positions' logits
+        alone, ``[batch, n, vocab]``; None, every position."""
         if decode:
             variables = {"params": p}
             if cache is not None:
                 variables["cache"] = cache
             logits, mutated = module.apply(
-                variables, input_ids, positions, decode=True, mutable=["cache"]
+                variables, input_ids, positions, decode=True, logits_at=logits_at, mutable=["cache"]
             )
             return logits, mutated["cache"]
-        return module.apply({"params": p}, input_ids, positions)
+        return module.apply({"params": p}, input_ids, positions, logits_at=logits_at)
 
     model = Model(apply_fn, params, sharding_rules=GPT2_SHARDING_RULES, name="gpt2")
     model.config = config

@@ -30,7 +30,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..modeling import Model
 from ..ops.fp8 import policy_dot_general as _pdg
-from .llama import rope
+from .llama import rope, rows_at
 
 
 @dataclasses.dataclass
@@ -155,7 +155,7 @@ class GPTNeoXModel(nn.Module):
     config: GPTNeoXConfig
 
     @nn.compact
-    def __call__(self, input_ids, positions=None, decode: bool = False):
+    def __call__(self, input_ids, positions=None, decode: bool = False, logits_at=None):
         cfg = self.config
         hidden = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_in")(input_ids)
         if positions is None:
@@ -167,6 +167,8 @@ class GPTNeoXModel(nn.Module):
         block = nn.remat(GPTNeoXBlock, prevent_cse=False, static_argnums=(3,)) if cfg.remat else GPTNeoXBlock
         for i in range(cfg.num_hidden_layers):
             hidden = block(cfg, name=f"layer_{i}")(hidden, positions, decode)
+        if logits_at is not None:  # the caller reads these positions' logits alone, as ``LlamaModel``'s
+            hidden = rows_at(hidden, logits_at)
         hidden = nn.LayerNorm(epsilon=cfg.layer_norm_eps, name="final_norm", dtype=hidden.dtype)(hidden)
         return nn.Dense(cfg.vocab_size, use_bias=False, name="embed_out", dtype=jnp.float32)(hidden)
 
@@ -177,16 +179,20 @@ def create_gptneox_model(config: Optional[GPTNeoXConfig] = None, seed: int = 0, 
     dummy = jnp.zeros((2, seq_len), jnp.int32)
     params = module.init(jax.random.key(seed), dummy)["params"]
 
-    def apply_fn(p, input_ids, positions=None, decode=False, cache=None):
+    def apply_fn(p, input_ids, positions=None, decode=False, cache=None, logits_at=None):
         """decode=True threads the KV cache: pass ``cache`` (or None to
-        initialise) and receive ``(logits, new_cache)``."""
+        initialise) and receive ``(logits, new_cache)``. ``logits_at``
+        (int32 positions, ``[n]`` or a scalar): these positions' logits
+        alone, ``[batch, n, vocab]``; None, every position."""
         if decode:
             variables = {"params": p}
             if cache is not None:
                 variables["cache"] = cache
-            logits, mutated = module.apply(variables, input_ids, positions, decode=True, mutable=["cache"])
+            logits, mutated = module.apply(
+                variables, input_ids, positions, decode=True, logits_at=logits_at, mutable=["cache"]
+            )
             return logits, mutated["cache"]
-        return module.apply({"params": p}, input_ids, positions)
+        return module.apply({"params": p}, input_ids, positions, logits_at=logits_at)
 
     model = Model(apply_fn, params, sharding_rules=GPTNEOX_SHARDING_RULES, name="gptneox")
     model.config = config

@@ -1109,11 +1109,33 @@ class _ScanLayer(nn.Module):
         return (hidden, view.key_pool, view.value_pool), None
 
 
+def rows_at(hidden, at):
+    """``hidden[:, at]`` as ``[batch, n, width]`` for int32 positions ``at`` along the sequence (``[n]`` or a
+    scalar, clipped to it): a masked sum over the sequence, not a gather. Each sum has one row in it, so it is exact,
+    and a row nobody asked for may hold anything (it is left out, not multiplied by 0). A gather or a dynamic slice
+    as the last reader of the residual stream has the TPU compiler lay a prefill's whole program out again (EvaByte's
+    4096 bucket: 0.48 -> 0.73 GiB of temporaries); a reduction reads it in the layout the head's product did
+    (PERF.md 6, PR 45)."""
+    at = jnp.clip(jnp.atleast_1d(at), 0, hidden.shape[1] - 1)
+    here = jnp.arange(hidden.shape[1])[None, :, None] == at[:, None, None]  # [n, sequence, 1]
+    return jnp.where(here, hidden[:, None], 0).sum(2)
+
+
 class LlamaModel(nn.Module):
+    """The zoo's decoder: embedding, the layers ``config`` describes, the final
+    norm and the float32 output head; logits ``[batch, sequence, vocab]``.
+    ``logits_at`` (int32 positions along the sequence, ``[n]`` or a scalar):
+    the caller reads these positions' logits alone and gets ``[batch, n,
+    vocab]``; the norm and the head run on those rows of the last layer's
+    output, every layer (and a cache the call writes) on the whole sequence.
+    None, the default: every position."""
+
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, input_ids, positions=None, decode: bool = False, new_span=None, row_valid=None):
+    def __call__(
+        self, input_ids, positions=None, decode: bool = False, new_span=None, row_valid=None, logits_at=None
+    ):
         cfg = self.config
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="embed_tokens")
         hidden = embed(input_ids)
@@ -1214,6 +1236,11 @@ class LlamaModel(nn.Module):
                 hidden = layer_cls(lcfg, routed and i >= n_lead, cfg.mixer_kind(i), name=f"layer_{i}")(
                     hidden, positions, decode, new_span, row_valid
                 )
+        if logits_at is not None:
+            # the caller reads these positions' logits alone (a bucket's prefill keeps one row): the norm and the
+            # head run on their rows, [batch, n, hidden]. Every layer above saw the whole sequence, so the cache
+            # is that of the whole call
+            hidden = rows_at(hidden, logits_at)
         hidden = RMSNorm(cfg.rms_norm_eps, cfg.norm_plus_one, name="final_norm")(hidden)
         if cfg.tie_word_embeddings:
             # true weight tying: reuse the embedding table (no lm_head
@@ -1232,7 +1259,10 @@ class LlamaModel(nn.Module):
 
 
 def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> Model:
-    def apply_fn(p, input_ids, positions=None, decode=False, cache=None, state=None, new_span=None, row_valid=None):
+    def apply_fn(
+        p, input_ids, positions=None, decode=False, cache=None, state=None, new_span=None, row_valid=None,
+        logits_at=None,
+    ):
         """decode=True threads the KV cache: pass ``cache`` (or None to
         initialise) and receive ``(logits, new_cache)``. ``state`` threads
         non-param collections (the fp8 amax histories): returns
@@ -1244,7 +1274,12 @@ def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> 
         count, a decode tick's slots in which a request decodes; the routed
         experts read it (the others' rows reach none of them) and a
         state-space layer's step kernel (the others' states are neither
-        read nor written); None means every token."""
+        read nor written); None means every token. ``logits_at`` (int32
+        positions along the sequence, ``[n]`` or a scalar): the caller
+        reads the logits of these positions alone and gets
+        ``[batch, n, vocab]``, the final norm and the head having run on
+        those rows of the last layer's output; the cache is the whole
+        call's. None means every position."""
         if decode:
             variables = {"params": p, **(state or {})}
             if cache is not None:
@@ -1258,15 +1293,19 @@ def _wrap_llama(module: LlamaModel, params, config: LlamaConfig, state=None) -> 
             loads = requested_expert_load()
             if loads is not None:
                 mutable.append(EXPERT_LOAD)
-            logits, mutated = module.apply(variables, input_ids, positions, True, new_span, row_valid, mutable=mutable)
+            logits, mutated = module.apply(
+                variables, input_ids, positions, True, new_span, row_valid, logits_at, mutable=mutable
+            )
             if loads is not None:
                 loads.extend(jax.tree_util.tree_leaves(mutated.get(EXPERT_LOAD, {})))
             return logits, mutated["cache"]
         if state:
             variables = {"params": p, **state}
-            logits, new_state = module.apply(variables, input_ids, positions, mutable=list(state.keys()))
+            logits, new_state = module.apply(
+                variables, input_ids, positions, logits_at=logits_at, mutable=list(state.keys())
+            )
             return logits, dict(new_state)
-        return module.apply({"params": p}, input_ids, positions)
+        return module.apply({"params": p}, input_ids, positions, logits_at=logits_at)
 
     model = Model(apply_fn, params, sharding_rules=LLAMA_SHARDING_RULES, name="llama")
     model.config = config

@@ -354,12 +354,32 @@ class ServingEngine:
         # the dense per-row cache template (a 1-token dummy prefill outside
         # paged_mode): a dense slot's rows, what chunk windows run against
         # in both layouts, and what a KV handoff ships (trimmed to true_len
-        # rows) and the receiving replica pads back before its paste/insert
-        _, self._row_template = jax.eval_shape(
-            lambda p, i: apply_fn(p, i, positions=jnp.zeros((1, 1), jnp.int32), decode=True, cache=None),
-            params,
-            jnp.zeros((1, 1), jnp.int32),
-        )
+        # rows) and the receiving replica pads back before its paste/insert.
+        # The same abstract call asks for the logits of one position (``logits_at``): a model that takes the
+        # argument runs its output head on that row alone, and a bucket's prefill, which keeps one row, asks it
+        # to (``prefill`` below). Every family of ``accelerate_tpu.models`` that decodes takes it. An ``apply_fn``
+        # from elsewhere (``Model`` around a flax module of the user's) may not: the engine finds out by asking once,
+        # here, where nothing is computed, and only the refusal of that keyword means no; it then keeps the program
+        # that heads every position of the bucket and says so. Any other error of the traced call is the caller's
+        def row_shapes(**head):
+            return jax.eval_shape(
+                lambda p, i: apply_fn(p, i, positions=jnp.zeros((1, 1), jnp.int32), decode=True, cache=None, **head),
+                params,
+                jnp.zeros((1, 1), jnp.int32),
+            )
+
+        try:
+            _, self._row_template = row_shapes(logits_at=jnp.int32(0))
+            self._head_at_row = True
+        except TypeError as refused:
+            if "unexpected keyword argument 'logits_at'" not in str(refused):
+                raise
+            logger.warning(
+                "%s takes no logits_at: every prefill runs the output head on its whole bucket and keeps one row",
+                getattr(model, "name", None) or "the model's apply_fn",
+            )
+            _, self._row_template = row_shapes()
+            self._head_at_row = False
 
         # a model whose layers keep a recurrent state (STATE_LEAVES) beside its K/V rows: its windows are told
         # which of their tokens are new (``new_span``); no other model's programs take the argument
@@ -481,6 +501,7 @@ class ServingEngine:
         self._uid = 0
         self._tick = 0  # ordinal of the running tick (the ``engine.tick`` span's count)
         self._tick_prefill_tokens = 0  # prompt tokens dispatched by this tick's prefills
+        self._tick_head_rows = 0  # rows of logits those prefills computed: one a bucket whose model heads one row
         self._pool_blocked = False  # last admit pass hit pool exhaustion
         self.bucket_compile_ms: dict = {}  # (kind, bucket) -> build wall ms
         # raw (pre-jit) program + sample-args builder + trace contexts per
@@ -518,9 +539,13 @@ class ServingEngine:
             key). ``key``, ``fold``: :func:`request_key`."""
             b_len = ids.shape[1]
             positions = jnp.broadcast_to(jnp.arange(b_len), (1, b_len))
-            logits, cache = apply_fn(params, ids, positions=positions, decode=True, cache=None, **span(0, true_len))
+            last = true_len - 1
+            head = {"logits_at": last} if self._head_at_row else {}
+            logits, cache = apply_fn(
+                params, ids, positions=positions, decode=True, cache=None, **span(0, true_len), **head
+            )
             key, sub = jax.random.split(request_key(key, fold))
-            row = logits[0, true_len - 1]
+            row = logits[0, 0 if self._head_at_row else last]
             next_tok = sampler(row[None], sub)[0]
             from .ops.kv_cache import reset_cache_index
 
@@ -1495,7 +1520,7 @@ class ServingEngine:
         profile shows inside ``engine.tick``."""
         m = self.metrics
         admitted, tokens_was, completed_was = 0, m.tokens_generated, m.requests_completed
-        self._tick_prefill_tokens = self._tick_first_deferred = self._tick_clears_deferred = 0
+        self._tick_prefill_tokens = self._tick_head_rows = self._tick_first_deferred = self._tick_clears_deferred = 0
         leaves_signed_was = self._pc.leaves_signed
         self._tick_expert_load = (0, 0, 0, 0)
         self._tick_state_idle = 0
@@ -1556,7 +1581,7 @@ class ServingEngine:
             if self.active_count == 0:
                 self._flush_clears()  # nothing is left to send them behind: an idle engine holds no stale row
         leaves_signed = self._pc.leaves_signed - leaves_signed_was
-        m.on_host_work(self._tick_clears_deferred, leaves_signed)
+        m.on_host_work(self._tick_clears_deferred, leaves_signed, self._tick_head_rows)
         pages = (0, 0)
         if self._aligned is not None:
             self._close_windows()
@@ -1565,7 +1590,7 @@ class ServingEngine:
         with phase(
             "engine.tick.done", admitted=admitted, first_tokens_deferred=self._tick_first_deferred,
             clears_deferred=self._tick_clears_deferred, leaves_signed=leaves_signed,
-            prefill_tokens=self._tick_prefill_tokens,
+            prefill_tokens=self._tick_prefill_tokens, head_rows=self._tick_head_rows,
             emitted=m.tokens_generated - tokens_was, retired=m.requests_completed - completed_was,
             pool_blocked=int(self._pool_blocked), free_blocks=self._alloc.free_count if self.paged else -1,
             queue_len=len(self.queue), experts_touched=self._tick_expert_load[0],
@@ -1814,6 +1839,7 @@ class ServingEngine:
                     self.model.params, padded, np.int32(len(req.prompt)), *st["key"]
                 )
             self._tick_prefill_tokens += b
+            self._tick_head_rows += 1 if self._head_at_row else b
             self._finalize_prefill(slot, row_cache, len(req.prompt), next_tok, lp, key)
             return budget - b
         full = st["full"]
@@ -1827,6 +1853,7 @@ class ServingEngine:
                     full, st["done"], st["cache"], trace=req.trace
                 )
             self._tick_prefill_tokens += w
+            self._tick_head_rows += w  # a chunk window's logits come back whole (``_sample_at`` picks one)
             budget -= w
             force = False
         next_tok = lp = None

@@ -167,6 +167,11 @@ class ServingMetrics:
         # calls (the parameters' are kept from call to call)
         self.clears_deferred = 0
         self.leaves_signed = 0
+        # rows of logits the prefill programs computed (``head_rows`` of
+        # ``engine.tick.done``): one a bucket's prefill where the model
+        # runs its output head on the row it is asked for, the bucket's
+        # rows where it cannot be asked, a chunk window's rows
+        self.head_rows = 0
         # the host loop itself, from the phase log's tick records
         # (telemetry.trace.PhaseLog): ticks made, the longest one's wall
         # time, and how many closed far beyond the median of the ticks
@@ -253,9 +258,10 @@ class ServingMetrics:
     def on_first_tokens_deferred(self, n: int):
         self.first_tokens_deferred += n
 
-    def on_host_work(self, clears_deferred: int, leaves_signed: int):
+    def on_host_work(self, clears_deferred: int, leaves_signed: int, head_rows: int = 0):
         self.clears_deferred += clears_deferred
         self.leaves_signed += leaves_signed
+        self.head_rows += head_rows
 
     def on_expert_load(self, touched: int, pairs_max: int, tile_visits: int, pairs: int):
         self.experts_touched += touched

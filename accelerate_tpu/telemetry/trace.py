@@ -98,7 +98,9 @@ SEGMENTS = (
 #: read behind the decode dispatch; ``clears_deferred``, retired slots
 #: whose clear the tick sent behind its first prefill or with its decode
 #: dispatch, and ``leaves_signed``, the leaves the compile cache's
-#: dispatch signed for the tick's calls; tokens, the pool,
+#: dispatch signed for the tick's calls; ``head_rows``, the rows of
+#: logits the tick's prefill programs computed: 1 a bucket's prefill of
+#: a model that heads the row it is asked for; tokens, the pool,
 #: the routed experts' load (``experts_touched``, ``expert_pairs_max``,
 #: ``expert_tile_visits`` and ``expert_pairs``, the token-expert pairs
 #: the grouped products multiplied: the decoding slots' alone) and

@@ -271,6 +271,26 @@ def test_latent_moe_decode_tick_fits_one_v5e_chip_and_moves_no_pool(v5e, monkeyp
     assert total <= 15.75 * 2**30, f"the 64-slot tick needs {total / 2**30:.2f} GiB"
 
 
+def test_latent_moe_prefill_bucket_heads_one_row_on_one_v5e_chip(v5e, monkeypatch):
+    """The 4096-token prefill of the same cell keeps one row of logits and computes one: no
+    ``f32[4096,129280]`` (2.12 GB, which was 2.01 GiB of temporaries and the program's largest operation), the
+    head a product of one normed row with the ``[2048, 129280]`` kernel. What is left, 0.32 GiB, is the layers'
+    own: the routed experts' ``[4096 x 8, 2048]`` rows and their float32 sums."""
+    import re
+
+    s, engine = _cell_engine("joyai-llm-flash-l5")
+    chip = SingleDeviceSharding(v5e.devices[0])
+    prefill, prefill_args, _ = engine._perf_programs["prefill"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), prefill_args(4096))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(prefill).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(_expert_products(text)) == 8 and "lm_head" in text
+    assert not re.search(r"f32\[(1,)?4096,129280\]", text), "the head runs on every position of the bucket"
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 0.4 * 2**30, f"the prefill's temporaries are {m.temp_size_in_bytes / 2**30:.2f} GiB"
+
+
 def test_hybrid_ssm_decode_tick_steps_the_state_in_place_on_one_v5e_chip(v5e, monkeypatch):
     """The 128-slot decode tick of the ``jamba2-3b-serve-longanswer`` cell at its real size: 3.03 B
     parameters, 26 states of ``[128, 16, 5120]`` float32 and two K/V pools as arguments, all aliased to
