@@ -159,32 +159,6 @@ def check_no_state_leaf(row_cache, what: str) -> None:
 _NO_FOLD = np.int32(-1)  # :meth:`ServingEngine._request_key`: the key is the chain as it stands
 
 
-class _LazyBuckets:
-    """dict-like ``bucket -> compiled program`` that compiles on FIRST
-    use instead of eagerly at engine construction: startup pays only for
-    the buckets traffic actually hits, and each build is attributed by a
-    per-bucket ``serving_bucket_compile`` telemetry event."""
-
-    def __init__(self, build):
-        self._build = build
-        self._programs: dict = {}
-
-    def __getitem__(self, bucket: int):
-        prog = self._programs.get(bucket)
-        if prog is None:
-            prog = self._programs[bucket] = self._build(bucket)
-        return prog
-
-    def __contains__(self, bucket) -> bool:
-        return bucket in self._programs
-
-    def __len__(self) -> int:
-        return len(self._programs)
-
-    def compiled_buckets(self) -> tuple:
-        return tuple(sorted(self._programs))
-
-
 @dataclasses.dataclass
 class _Request:
     uid: int
@@ -222,7 +196,13 @@ class _Request:
 class ServingEngine:
     """Continuous-batching decode engine for a zoo model with the decode
     contract (``apply_fn(params, ids, positions=..., decode=True,
-    cache=...) -> (logits, cache)``; llama / gpt2 / gptneox).
+    cache=..., logits_at=...) -> (logits, cache)``; llama / gpt2 /
+    gptneox). ``logits_at``: an int32 scalar or ``[n]`` of positions whose
+    logits the caller keeps (the final norm and the output head run on
+    those rows alone), ``None`` = every position. An ``apply_fn`` that
+    cannot be called so is refused at construction, with a ``TypeError``
+    that names the contract. The device programs the engine runs are
+    built by :mod:`accelerate_tpu.serving_programs`, once, from here.
 
     ``prompt_buckets``: ascending prefill sizes; each distinct bucket
     compiles one prefill program. ``max_len``: cache capacity per slot
@@ -255,7 +235,6 @@ class ServingEngine:
         pool_blocks: Optional[int] = None,
         telemetry_log=None,
         program_cache=None,
-        auto_bucketing: bool = False,
         scheduler=None,
         tracer=None,
     ):
@@ -270,13 +249,8 @@ class ServingEngine:
         self.model = model
         self.num_slots = num_slots
         self.prompt_buckets = tuple(sorted(prompt_buckets))
+        self._chunk = max(self.prompt_buckets)  # a chunk window's width: the largest bucket
         self.max_len = max_len or model.config.max_position_embeddings
-        # Compile management (docs/usage_guides/compilation.md): EVERY
-        # engine program goes through one ProgramCache — construction
-        # compiles nothing (buckets are lazy, ticks jit on first call),
-        # and with a persistent store (``program_cache=`` or
-        # ``ACCELERATE_COMPILE_CACHE_DIR``) a new replica deserializes
-        # the programs a previous process compiled instead of re-JITting.
         from .telemetry.eventlog import EventLog
 
         self._log = telemetry_log if telemetry_log is not None else EventLog(None)
@@ -285,21 +259,14 @@ class ServingEngine:
         # prefill windows, decode ticks, preemption/resume, and retire.
         # None disables tracing with zero overhead beyond these guards.
         self.tracer = tracer
+        # Compile management (docs/usage_guides/compilation.md): EVERY engine program goes through one
+        # ProgramCache (serving_programs.ctx_jit): construction compiles nothing, and a persistent store
+        # (``program_cache=`` or ``ACCELERATE_COMPILE_CACHE_DIR``) spares a new replica the compiles
         if program_cache is None:
             from .aot import ProgramCache
 
             program_cache = ProgramCache.from_env(log=self._log, name="serving")
         self._pc = program_cache
-        # Auto-bucketing: the static prompt_buckets seed a learned set —
-        # prompt lengths beyond the seed grow new (power-of-two) buckets
-        # on demand instead of falling to the chunked path, refined online
-        # from the observed length histogram; compile count stays
-        # O(len(buckets)) by construction.
-        self.bucketer = None
-        if auto_bucketing:
-            from .aot import ShapeBucketer
-
-            self.bucketer = ShapeBucketer(self.prompt_buckets, max_size=self.max_len)
         # Scheduling policy (accelerate_tpu.scheduling): accepts a
         # SchedulerConfig, a Scheduler, or anything with
         # ``to_scheduler_config()`` (utils.ServingSchedulerKwargs). The
@@ -315,107 +282,29 @@ class ServingEngine:
                 f"max_len {self.max_len} exceeds the model cache "
                 f"(max_position_embeddings={model.config.max_position_embeddings})"
             )
-        if max(self.prompt_buckets) > self.max_len:
+        if self._chunk > self.max_len:
             raise ValueError(
-                f"prompt bucket {max(self.prompt_buckets)} exceeds the slot cache "
+                f"prompt bucket {self._chunk} exceeds the slot cache "
                 f"(max_len={self.max_len})"
             )
+        if tick_block < 1:
+            raise ValueError(f"tick_block must be >= 1, got {tick_block}")
+        if pool_blocks is not None and paged_block_size is None:
+            raise ValueError("pool_blocks requires paged_block_size (paged mode)")
         self.eos_token_id = eos_token_id
+        self.tick_block = tick_block
         self._seed = seed
-
-        from .generation import _make_sampler
-
-        sampler = _make_sampler(temperature, top_k)
-
-        def ctx_jit(fn, name=None, donate_argnums=()):
-            """jit + re-enter the model's mesh context around every call:
-            a shard_model'ed model pins ITS mesh for the cache sharding
-            constraints and the paged kernel's shard_map (constraints
-            bake in at the first trace; later calls hit the jit cache).
-
-            Dispatch goes through the engine's ProgramCache (lowering at
-            CALL time with the real input shardings, so GSPMD-propagated
-            layouts are honoured exactly like lazy jit): with a
-            persistent store attached, a restarted replica deserializes
-            these programs instead of recompiling them."""
-            jitted = self._pc.wrap_jit(
-                jax.jit(fn, donate_argnums=donate_argnums), name=name or getattr(fn, "__name__", "program")
-            )
-
-            def call(*args):
-                with self._trace_ctx():
-                    return jitted(*args)
-
-            return call
-
-        params = model.params
-        apply_fn = model.apply_fn
-
-        # the dense per-row cache template (a 1-token dummy prefill outside
-        # paged_mode): a dense slot's rows, what chunk windows run against
-        # in both layouts, and what a KV handoff ships (trimmed to true_len
-        # rows) and the receiving replica pads back before its paste/insert.
-        # The same abstract call asks for the logits of one position (``logits_at``): a model that takes the
-        # argument runs its output head on that row alone, and a bucket's prefill, which keeps one row, asks it
-        # to (``prefill`` below). Every family of ``accelerate_tpu.models`` that decodes takes it. An ``apply_fn``
-        # from elsewhere (``Model`` around a flax module of the user's) may not: the engine finds out by asking once,
-        # here, where nothing is computed, and only the refusal of that keyword means no; it then keeps the program
-        # that heads every position of the bucket and says so. Any other error of the traced call is the caller's
-        def row_shapes(**head):
-            return jax.eval_shape(
-                lambda p, i: apply_fn(p, i, positions=jnp.zeros((1, 1), jnp.int32), decode=True, cache=None, **head),
-                params,
-                jnp.zeros((1, 1), jnp.int32),
-            )
-
-        try:
-            _, self._row_template = row_shapes(logits_at=jnp.int32(0))
-            self._head_at_row = True
-        except TypeError as refused:
-            if "unexpected keyword argument 'logits_at'" not in str(refused):
-                raise
-            logger.warning(
-                "%s takes no logits_at: every prefill runs the output head on its whole bucket and keeps one row",
-                getattr(model, "name", None) or "the model's apply_fn",
-            )
-            _, self._row_template = row_shapes()
-            self._head_at_row = False
-
-        # a model whose layers keep a recurrent state (STATE_LEAVES) beside its K/V rows: its windows are told
-        # which of their tokens are new (``new_span``); no other model's programs take the argument
-        from .ops.paged_kv import state_bytes
-
-        self.metrics.state_bytes_per_slot = state_bytes(self._row_template)
-        self._has_state = self.metrics.state_bytes_per_slot > 0
-
-        def span(lo, hi):
-            return {"new_span": (lo, hi)} if self._has_state else {}
 
         # Cache layout: dense = leading slot axis over the per-row cache
         # pytree (each slot reserves max_len rows); paged = one shared
         # block pool + per-slot block tables (ops/paged_kv.py) — same
         # decode roofline, pool capacity decoupled from slots x max_len.
         self.paged = paged_block_size is not None
+        self._pcfg = None
         # ``(window, chunk)`` for a model whose attention reads an ALIGNED window and pooled chunks before it (EVA,
         # ``attention_class == "eva"``), under the paged layout; None for every other engine
         self._aligned: Optional[tuple] = None
         self._tick_windows = (0, 0, 0, 0)  # rows attended, context rows, chunks pooled, windows closed: this tick's
-        # the paged decode tick of a model with routed experts, or with a recurrent state that a kernel steps
-        # (``ssm_state``), is told which slots decode (one ``[slots]`` bool argument more: ``_decoding_arg``):
-        # the stale token of every other slot reaches no expert, and its state is neither read nor written.
-        # No other program takes it (the dense tick is a ``vmap`` of one slot's step: no routed experts, no kernel)
-        from .ops.kv_cache import leaf_names
-
-        masks_state = self.paged and "ssm_state" in leaf_names(self._row_template)
-        self._mask_idle_rows = masks_state or (self.paged and getattr(model.config, "n_routed_experts", None) is not None)
-        # whether the tick steps the state of every slot (a convolution's carried inputs; a state-space layer's
-        # through the plain step) or of the decoding slots alone (through the kernel): what ``state_slots_idle`` counts
-        self._steps_idle_state = self._has_state
-        if masks_state:
-            from .models.llama import state_step_kernel
-
-            with self._trace_ctx():
-                self._steps_idle_state = not state_step_kernel()
         if self.paged:
             from .ops.paged_kv import BlockAllocator, PagedConfig, paged_mode
 
@@ -452,18 +341,39 @@ class ServingEngine:
                 self._init_aligned(model.config, bs_)
             with paged_mode(self._pcfg):
                 _, pcache = jax.eval_shape(
-                    lambda p, i, pos: apply_fn(p, i, positions=pos, decode=True, cache=None),
-                    params,
+                    lambda p, i, pos: model.apply_fn(p, i, positions=pos, decode=True, cache=None),
+                    model.params,
                     jnp.zeros((num_slots, 1), jnp.int32),
                     jnp.zeros((num_slots, 1), jnp.int32),
                 )
             self.slot_caches = jax.tree.map(lambda l: jnp.zeros(l.shape, l.dtype), pcache)
-        elif pool_blocks is not None:
-            raise ValueError("pool_blocks requires paged_block_size (paged mode)")
-        else:
-            self.slot_caches = jax.tree.map(
-                lambda l: jnp.zeros((num_slots, *l.shape), l.dtype), self._row_template
-            )
+
+        # ---- the programs (compiled once each, on first use): accelerate_tpu/serving_programs.py ----
+        from .serving_programs import EnginePrograms
+
+        programs = EnginePrograms(
+            model, temperature=temperature, top_k=top_k, tick_block=tick_block, prompt_buckets=self.prompt_buckets,
+            paged_config=self._pcfg, program_cache=self._pc, trace_ctx=self._trace_ctx, tick_args=self._tick_args,
+            on_bucket_build=self._note_bucket_compile,
+        )
+        self._prefill, self._chunk_cold, self._chunk_warm = programs.prefill, programs.chunk_cold, programs.chunk_warm
+        self._sample_at, self._reset_idx = programs.sample_at, programs.reset_idx
+        self._feed_first_token, self._decode_tick = programs.feed_first_token, programs.decode_tick
+        self._insert, self._paste, self._paste_blocks = programs.insert, programs.paste_row, programs.paste_blocks
+        self._clear_slots, self._set_table = programs.clear_slots, programs.set_table_row
+        # name -> serving_programs.Program (raw function, sample arguments, trace contexts): perf_check() and
+        # numerics_check() roofline the real prefill / decode jaxprs from it without compiling anything
+        self._perf_programs = programs.described
+        self._row_template = programs.row_template  # serving_programs.row_template: one sequence's dense cache
+        # what this model's programs take beyond the decode contract (serving_programs.extra_arguments)
+        self._has_state = programs.extra.new_span
+        self._mask_idle_rows = programs.extra.decoding
+        self._steps_idle_state = programs.extra.steps_idle_state
+        from .ops.paged_kv import state_bytes
+
+        self.metrics.state_bytes_per_slot = state_bytes(self._row_template)
+        if not self.paged:
+            self.slot_caches = jax.tree.map(lambda l: jnp.zeros((num_slots, *l.shape), l.dtype), self._row_template)
 
         # host-side slot state
         self.slot_req: list[Optional[_Request]] = [None] * num_slots
@@ -501,282 +411,19 @@ class ServingEngine:
         self._uid = 0
         self._tick = 0  # ordinal of the running tick (the ``engine.tick`` span's count)
         self._tick_prefill_tokens = 0  # prompt tokens dispatched by this tick's prefills
-        self._tick_head_rows = 0  # rows of logits those prefills computed: one a bucket whose model heads one row
+        self._tick_head_rows = 0  # rows of logits those prefills computed: one a bucket prefill, a window's width
+        self._tick_expert_load = (0, 0, 0, 0)
+        self._tick_state_idle = 0
         self._pool_blocked = False  # last admit pass hit pool exhaustion
         self.bucket_compile_ms: dict = {}  # (kind, bucket) -> build wall ms
-        # raw (pre-jit) program + sample-args builder + trace contexts per
-        # engine program, so perf_check() can roofline the real prefill /
-        # decode jaxprs without compiling anything
-        self._perf_programs: dict = {}
-
-        # ---- jitted programs (compiled once each) ----
-        def pick_lp(row, tok):
-            """log P(tok) under the model's FULL distribution at this step
-            (f32 log-softmax) — the standard serving logprob surface, even
-            when sampling is temperature/top-k shaped."""
-            return jax.nn.log_softmax(row.astype(jnp.float32))[tok]
-
-        def named(fn, name):
-            """``fn`` under ``name``: jit names the module after the
-            function, and the device line of a profile shows the module,
-            so a program is jitted under the name ProgramCache logs."""
-
-            def call(*args):
-                return fn(*args)
-
-            call.__name__ = call.__qualname__ = name
-            return call
-
-        def request_key(key, fold):
-            """The sampling chain a request starts from: ``fold_in(key, fold)`` for a fresh request (``key`` the
-            engine's, ``fold`` the uid: computed here, inside the program that consumes it, and not by eager
-            programs ahead of its dispatch), ``key`` as it is where ``fold`` is negative (a chain carried in)."""
-            return jnp.where(fold >= 0, jax.random.fold_in(key, fold), key)
-
-        def prefill(params, ids, true_len, key, fold):
-            """[1, B] padded prompt -> (first next-token, its logprob,
-            per-row cache with write index reset to true_len, advanced
-            key). ``key``, ``fold``: :func:`request_key`."""
-            b_len = ids.shape[1]
-            positions = jnp.broadcast_to(jnp.arange(b_len), (1, b_len))
-            last = true_len - 1
-            head = {"logits_at": last} if self._head_at_row else {}
-            logits, cache = apply_fn(
-                params, ids, positions=positions, decode=True, cache=None, **span(0, true_len), **head
-            )
-            key, sub = jax.random.split(request_key(key, fold))
-            row = logits[0, 0 if self._head_at_row else last]
-            next_tok = sampler(row[None], sub)[0]
-            from .ops.kv_cache import reset_cache_index
-
-            cache = reset_cache_index(cache, true_len)
-            return next_tok, pick_lp(row, next_tok), cache, key
-
-        # every fresh request's chain is ``fold_in`` of this key with its uid (:meth:`_request_key`)
-        self._base_key = jax.random.key(seed)
-        key_aval = jax.eval_shape(lambda: self._base_key)
-        fold_aval = jax.ShapeDtypeStruct((), jnp.int32)
-
-        def _build_prefill(b):
-            t0 = time.perf_counter()
-            with self._trace_ctx():
-                prog = self._pc.compile(
-                    named(prefill, f"prefill_b{b}"), params, jax.ShapeDtypeStruct((1, b), jnp.int32),
-                    jax.ShapeDtypeStruct((), jnp.int32), key_aval, fold_aval,
-                    name=f"prefill_b{b}",
-                )
-            self._note_bucket_compile("prefill", b, (time.perf_counter() - t0) * 1000.0)
-            return prog
-
-        self._prefill = _LazyBuckets(_build_prefill)
-        self._perf_programs["prefill"] = (
-            prefill,
-            lambda b: (
-                params,
-                jax.ShapeDtypeStruct((1, b), jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.int32),
-                key_aval,
-                fold_aval,
-            ),
-            (self._trace_ctx,),
-        )
-
-        # ---- chunked-prefill programs (long prompts / prefix suffixes) ----
-        # one chunk size (the largest bucket) x {cold, warm}: compile count
-        # stays O(buckets), prompt length is bounded only by max_len
-        chunk = max(self.prompt_buckets)
-        self._chunk = chunk
-
-        # ``[lo, hi)``: the window's new tokens, from its own first (_run_window)
-        def chunk_cold(params, ids, lo, hi):
-            positions = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
-            return apply_fn(params, ids, positions=positions, decode=True, cache=None, **span(lo, hi))
-
-        def chunk_warm(params, ids, pos0, cache, lo, hi):
-            positions = pos0 + jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
-            return apply_fn(params, ids, positions=positions, decode=True, cache=cache, **span(lo, hi))
-
-        self._chunk_cold = ctx_jit(chunk_cold)
-        self._chunk_warm = ctx_jit(chunk_warm)
-
-        def sample_at(logits, offset, key, fold):
-            key, sub = jax.random.split(request_key(key, fold))
-            row = logits[0, offset]
-            tok = sampler(row[None], sub)[0]
-            return tok, pick_lp(row, tok), key
-
-        self._sample_at = ctx_jit(sample_at)
-
-        def reset_idx(cache, n):
-            from .ops.kv_cache import reset_cache_index
-
-            return reset_cache_index(cache, n)
-
-        self._reset_idx = ctx_jit(reset_idx)
-
-        # The resume-recompute program (preempt -> requeue -> resume
-        # rebuilds the evicted KV by warm chunk windows) registered for
-        # perf_check()/numerics_check(): the analysis stack must cover
-        # every program the scheduler can launch, and this one is the
-        # only engine program that reads AND extends a warm row cache.
-        self._perf_programs["resume_recompute"] = (
-            chunk_warm,
-            lambda b: (
-                params,
-                jax.ShapeDtypeStruct((1, self._chunk), jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.int32),
-                self._row_template,
-                jax.ShapeDtypeStruct((), jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.int32),
-            ),
-            (self._trace_ctx,),
-        )
-
         # registered shared prefixes: id -> {"len", "cache", "tokens"}
         self._prefixes: dict[int, dict] = {}
         self._prefix_uid = 0
-
-        # an admission's paste / insert also starts the slot's sampling chain (``keys`` is ``_slot_keys``): one
-        # program, and the host hands it its numpy arguments as they are
-        def insert(slot_caches, keys, row_cache, key, slot):
-            caches = jax.tree.map(
-                lambda big, row: jax.lax.dynamic_update_index_in_dim(big, row.astype(big.dtype), slot, 0),
-                slot_caches,
-                row_cache,
-            )
-            return caches, keys.at[slot].set(key)
-
-        self._insert = ctx_jit(insert)
-
-        def feed_first_token(toks, slot, tok):
-            """``toks`` with a pending admission's first token in its slot: the token goes from the prefill
-            to the decode tick without a visit to the host. One shape, called once a pending admission."""
-            return toks.at[slot].set(tok.astype(toks.dtype))
-
-        self._feed_first_token = ctx_jit(feed_first_token)
-
-        # Decode K steps per host round-trip: one sync per TOKEN pays the
-        # dispatch and fetch latency on every token; the block scan
-        # amortises it K-fold. A slot that finishes (eos / budget)
-        # mid-block keeps computing until the block ends — those overshoot
-        # tokens are discarded host-side and the slot's cache is fully
-        # replaced at the next prefill-insert, so outputs stay token-exact.
-        if tick_block < 1:
-            raise ValueError(f"tick_block must be >= 1, got {tick_block}")
-        self.tick_block = tick_block
-
+        # every fresh request's chain is ``fold_in`` of this key with its uid (:meth:`_request_key`)
+        self._base_key = jax.random.key(seed)
         # independent sampling chain per slot (re-folded with the request
         # uid at each admit, so retries/new requests don't replay a chain)
-        self._slot_keys = jax.vmap(jax.random.fold_in, (None, 0))(
-            jax.random.key(seed), jnp.arange(num_slots)
-        )
-
-        self._tick_expert_load = (0, 0, 0, 0)
-        self._tick_state_idle = 0
-
-        def make_tick(step_body):
-            """K-step tick scaffold shared by both cache layouts:
-            ``step_body(params, caches, toks, poss, keys, *decoding) ->
-            (caches, next_toks, logprobs, keys, load)`` advances every slot
-            one token; ``load`` is None, or the routed experts' counts of the
-            step (``[expert layers, 4]``, ops/moe.py ``expert_load_counts``).
-            ``decoding`` (:meth:`_decoding_arg`) is the same in every step."""
-
-            def decode_tick(params, slot_caches, toks, poss, keys, *decoding):
-                def block_step(carry, _):
-                    caches, toks, poss, keys = carry
-                    caches, nxt, lps, keys, load = step_body(params, caches, toks, poss, keys, *decoding)
-                    return (caches, nxt, poss + 1, keys), (nxt, lps, load)
-
-                (slot_caches, _, _, keys), (toks_k, lps_k, load_k) = jax.lax.scan(
-                    block_step, (slot_caches, toks, poss, keys), None, length=tick_block
-                )
-                return slot_caches, toks_k, lps_k, keys, load_k  # each [K, slots]; load_k [K, layers, 4] or None
-
-            return decode_tick
-
-        if self.paged:
-            # Per-row frontiers are native to the paged layout (index is
-            # [B], not a scalar), so the tick is ONE batched program — no
-            # per-row vmap. Same key-split order as the dense one_step,
-            # so outputs stay token-exact across layouts.
-            from .ops.moe import expert_load_counts
-
-            def paged_step(params, cache, toks, poss, keys, decoding=None):
-                rows = {} if decoding is None else {"row_valid": decoding[:, None]}
-                # one program sees the whole batch, so routed experts can count their step's load
-                with expert_load_counts() as loads:
-                    logits, cache = apply_fn(
-                        params, toks[:, None], positions=poss[:, None], decode=True, cache=cache, **rows
-                    )
-                split = jax.vmap(jax.random.split)(keys)
-                keys, subs = split[:, 0], split[:, 1]
-                nxt = jax.vmap(lambda lg, s: sampler(lg[None], s)[0])(logits[:, -1], subs)
-                lps = jax.vmap(pick_lp)(logits[:, -1], nxt)
-                return cache, nxt, lps, keys, jnp.stack(loads) if loads else None
-
-            from .ops.paged_kv import clear_slots, paged_mode, paste_blocks, paste_row, set_table_row
-
-            # Lazy dispatch wrapped in BOTH trace contexts (paged layout +
-            # model mesh), re-entered every call: contexts only matter at
-            # trace time, and call-time lowering (ProgramCache.wrap_jit
-            # lowers with the REAL concrete inputs) lets the program adapt
-            # to whatever input shardings GSPMD propagates onto the pool
-            # between pastes — an eagerly .lower()ed program would pin the
-            # shardings it saw at construction and reject the real ones.
-            #
-            # The pool is ONE buffer for the engine's life: every program
-            # that takes the paged cache donates it, writes in place and
-            # hands the same buffer back (the callers all rebind
-            # ``self.slot_caches``); the array passed in is deleted by the
-            # call, so nothing may keep a reference to it across one.
-            raw_tick = make_tick(paged_step)
-            tick = self._pc.wrap_jit(
-                jax.jit(named(raw_tick, "paged_decode_tick"), donate_argnums=(1,)), name="paged_decode_tick"
-            )
-            pcfg = self._pcfg
-
-            def decode_tick(*args):
-                with paged_mode(pcfg), self._trace_ctx():
-                    return tick(*args)
-
-            decode_tick.__wrapped__ = tick.__wrapped__  # the jit object, donation and all
-            self._decode_tick = decode_tick
-            self._perf_programs["decode_tick"] = (
-                raw_tick,
-                lambda b: (
-                    params, self.slot_caches, self.slot_tok, self.slot_pos, self._slot_keys, *self._decoding_arg()
-                ),
-                (lambda: paged_mode(pcfg), self._trace_ctx),
-            )
-
-            def paste(paged_cache, keys, row_cache, key, write_row, table_row, slot, new_index, *summary_row):
-                return paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index, *summary_row), keys.at[slot].set(key)
-
-            self._paste = ctx_jit(named(paste, "paste_row"), donate_argnums=(0,))
-            self._paste_blocks = ctx_jit(paste_blocks, donate_argnums=(0,))
-            self._clear_slots = ctx_jit(clear_slots, donate_argnums=(0,))
-            self._set_table = ctx_jit(set_table_row, donate_argnums=(0,))
-        else:
-            def one_step(params, cache_row, tok, pos, key):
-                logits, cache_row = apply_fn(
-                    params, tok.reshape(1, 1), positions=pos.reshape(1, 1), decode=True, cache=cache_row
-                )
-                key, sub = jax.random.split(key)
-                row = logits[0, -1]
-                nxt = sampler(row[None], sub)[0]
-                return cache_row, nxt, pick_lp(row, nxt), key
-
-            def dense_step(params, caches, toks, poss, keys):
-                return *jax.vmap(one_step, in_axes=(None, 0, 0, 0, 0))(params, caches, toks, poss, keys), None
-
-            raw_dense_tick = make_tick(dense_step)
-            self._decode_tick = ctx_jit(raw_dense_tick)
-            self._perf_programs["decode_tick"] = (
-                raw_dense_tick,
-                lambda b: (params, self.slot_caches, self.slot_tok, self.slot_pos, self._slot_keys),
-                (self._trace_ctx,),
-            )
+        self._slot_keys = jax.vmap(jax.random.fold_in, (None, 0))(self._base_key, jnp.arange(num_slots))
 
     # ---- chunked prefill (host driver) ----------------------------------
 
@@ -826,15 +473,9 @@ class ServingEngine:
         ``(w, s_adj, e)`` — width = smallest bucket covering the remainder
         (a short suffix after a long prefix runs a suffix-sized program,
         not a full chunk), else the largest chunk; jit specializes per
-        width, so the compile count stays O(buckets). Auto-bucketing
-        consults the CURRENT learned set without growing it (lookup, not
-        bucket) — long-remainder chunks must not mint unbounded buckets.
-        The width is also the window's token-budget claim."""
-        c = self._chunk
-        if self.bucketer is not None:
-            w = self.bucketer.lookup(t - s) or c
-        else:
-            w = next((b for b in self.prompt_buckets if b >= t - s), c)
+        width, so the compile count stays O(buckets). The width is also
+        the window's token-budget claim."""
+        w = self._bucket_for(t - s) or self._chunk
         e = min(s + w, t)
         return w, max(0, e - w), e  # end-aligned window [s_adj, s_adj + w)
 
@@ -1690,10 +1331,10 @@ class ServingEngine:
         return owned, shared_entries, table, write_row
 
     def _request_key(self, uid: int, carried=None) -> tuple:
-        """``(key, fold)`` as the programs that sample a request's first token take them (``request_key`` in
-        ``__init__``): the engine's key and the uid for a fresh request, whose chain ``fold_in(key(seed), uid)``
-        the program then derives itself, so that an admission runs no eager program ahead of its prefill; a
-        chain ``carried`` in (a resume, a hand-off, a migrated request) with nothing to fold."""
+        """``(key, fold)`` as the programs that sample a request's first token take them
+        (``serving_programs.request_key``): the engine's key and the uid for a fresh request, whose chain
+        ``fold_in(key(seed), uid)`` the program then derives itself, so that an admission runs no eager program
+        ahead of its prefill; a chain ``carried`` in (a resume, a hand-off, a migrated request) with nothing to fold."""
         if carried is not None:
             return carried, _NO_FOLD
         if 0 <= uid <= np.iinfo(np.int32).max:
@@ -1755,8 +1396,6 @@ class ServingEngine:
             req.handoff = None
         elif not resume and req.prefix_id is None and (b := self._bucket_for(len(req.prompt))) is not None:
             # short prompt, no prefix: the one-shot fused program
-            # (auto-bucketing: the bucketer can mint a new covering
-            # bucket here, so "short" stretches to any prompt <= max_len)
             st["bucket"] = b
         else:
             # prefix-seeded, long, or resumed prompt: chunk windows. The
@@ -1839,7 +1478,7 @@ class ServingEngine:
                     self.model.params, padded, np.int32(len(req.prompt)), *st["key"]
                 )
             self._tick_prefill_tokens += b
-            self._tick_head_rows += 1 if self._head_at_row else b
+            self._tick_head_rows += 1  # the bucket's program heads the one row it keeps
             self._finalize_prefill(slot, row_cache, len(req.prompt), next_tok, lp, key)
             return budget - b
         full = st["full"]
@@ -2094,8 +1733,6 @@ class ServingEngine:
         self._aligned = (window, chunk)
         if self._sched.config.enable_preemption:
             raise NotImplementedError(self._aligned_refusal("preemption with resume (SchedulerConfig.enable_preemption)"))
-        if self.bucketer is not None:
-            raise NotImplementedError(self._aligned_refusal("auto_bucketing (a learned bucket past the seeds runs chunk windows)"))
         self._summary_entries = summary_pages(config.max_position_embeddings, block, chunk)
         self._slot_summary: list[dict] = [{} for _ in range(self.num_slots)]  # summary_table entry -> pool block id
         self._slot_last = [0] * self.num_slots  # the last position whose row a slot keeps (total + max_new - 2)
@@ -2256,6 +1893,27 @@ class ServingEngine:
 
         return _trace_ctx(getattr(self.model, "mesh", None))
 
+    def _tick_args(self) -> tuple:
+        """The decode tick's arguments as they stand now: what its ``serving_programs.Program`` is lowered from."""
+        return self.model.params, self.slot_caches, self.slot_tok, self.slot_pos, self._slot_keys, *self._decoding_arg()
+
+    def _check_programs(self, check, mesh, bucket, **options) -> dict:
+        """``check(raw program, *its sample arguments, mesh=, **options)`` for every program of
+        ``_perf_programs``, each traced under its own contexts: ``{name: report}``. Nothing compiles or executes.
+        ``mesh`` defaults to the sharded model's mesh, else a single-device mesh; ``bucket`` to the smallest."""
+        if mesh is None:
+            mesh = getattr(self.model, "mesh", None)
+        if mesh is None:
+            from .parallel.mesh import MeshConfig
+
+            mesh = MeshConfig(data=1).build(_jax().devices()[:1])
+        b = int(bucket) if bucket is not None else min(self.prompt_buckets)
+        reports = {}
+        for name, program in self._perf_programs.items():
+            with program.traced():
+                reports[name] = check(program.fn, *program.args(b), mesh=mesh, **options)
+        return reports
+
     def perf_check(self, mesh=None, generation=None, bucket=None, dcn=None) -> dict:
         """Static roofline of the engine's real serving programs — the
         prefill at ``bucket`` (default: the smallest prompt bucket) and
@@ -2266,29 +1924,10 @@ class ServingEngine:
         bytes-on-wire, predicted step time, MFU upper bound, TPU5xx
         findings). Returns ``{"prefill": PerfReport, "decode_tick":
         PerfReport}`` (whichever programs this engine configuration
-        has). ``mesh`` defaults to the sharded model's mesh, else a
-        single-device mesh."""
-        jax = _jax()
-        import contextlib
+        has)."""
+        from .analysis.perfmodel import perf_check
 
-        from .analysis.perfmodel import perf_check as _perf_check
-
-        if mesh is None:
-            mesh = getattr(self.model, "mesh", None)
-        if mesh is None:
-            from .parallel.mesh import MeshConfig
-
-            mesh = MeshConfig(data=1).build(jax.devices()[:1])
-        b = int(bucket) if bucket is not None else min(self.prompt_buckets)
-        reports = {}
-        for name, (fn, args_fn, ctx_factories) in self._perf_programs.items():
-            with contextlib.ExitStack() as stack:
-                for factory in ctx_factories:
-                    stack.enter_context(factory())
-                reports[name] = _perf_check(
-                    fn, *args_fn(b), mesh=mesh, generation=generation, dcn=dcn
-                )
-        return reports
+        return self._check_programs(perf_check, mesh, bucket, generation=generation, dcn=dcn)
 
     def numerics_check(self, mesh=None, bucket=None, assume=None) -> dict:
         """Static numerics analysis of the engine's real serving programs
@@ -2299,33 +1938,14 @@ class ServingEngine:
         low precision and unguarded normalisations are exactly the
         decode-path hazards this catches before a compile. Returns
         ``{"prefill": NumericsReport, "decode_tick": NumericsReport}``."""
-        jax = _jax()
-        import contextlib
+        from .analysis.numerics import numerics_check
 
-        from .analysis.numerics import numerics_check as _numerics_check
-
-        if mesh is None:
-            mesh = getattr(self.model, "mesh", None)
-        if mesh is None:
-            from .parallel.mesh import MeshConfig
-
-            mesh = MeshConfig(data=1).build(jax.devices()[:1])
-        b = int(bucket) if bucket is not None else min(self.prompt_buckets)
-        reports = {}
-        for name, (fn, args_fn, ctx_factories) in self._perf_programs.items():
-            with contextlib.ExitStack() as stack:
-                for factory in ctx_factories:
-                    stack.enter_context(factory())
-                reports[name] = _numerics_check(fn, *args_fn(b), mesh=mesh, assume=assume)
-        return reports
+        return self._check_programs(numerics_check, mesh, bucket, assume=assume)
 
     def _bucket_for(self, n: int) -> Optional[int]:
-        """Covering prefill bucket for an ``n``-token prompt: the minimal
-        static bucket, or (auto-bucketing) the learned bucketer's choice —
-        which records the observation and may mint a new bucket. ``None``
-        routes the prompt to the chunked-prefill path."""
-        if self.bucketer is not None:
-            return self.bucketer.bucket(n)
+        """Covering prefill bucket for an ``n``-token prompt: the smallest
+        that holds it. ``None`` routes the prompt to the chunked-prefill
+        path."""
         return next((b for b in self.prompt_buckets if b >= n), None)
 
     def _note_bucket_compile(self, kind: str, bucket: int, ms: float):

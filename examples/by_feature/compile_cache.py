@@ -8,15 +8,11 @@ executable. Phase 2 simulates a restarted process (a new Accelerator
 over the same cache dir — a preemption-resumed trainer or a new serving
 replica): the SAME programs deserialize from the store with **zero** XLA
 compiles, the loss trajectory is bit-exact, and the recompile watchdog
-stays silent. Phase 3 shows auto-bucketing: ragged prompt lengths
-through a ServingEngine compile one program per learned bucket, not one
-per length.
+stays silent.
 """
 
 import tempfile
 import time
-
-import numpy as np
 
 from accelerate_tpu import Accelerator, CompileKwargs
 from accelerate_tpu.state import AcceleratorState, GradientState, PartialState
@@ -57,19 +53,6 @@ def main():
         assert warm_losses == cold_losses, "warm trajectory must be bit-exact"
         print(f"speedup  : {cold_s / warm_s:.2f}x, trajectory bit-exact")
 
-        # auto-bucketing: ragged prompt lengths -> one compile per learned
-        # bucket (still inside the cache-dir scope: jax's persistent cache
-        # was pointed here for the rest of the process)
-        from accelerate_tpu.models import LlamaConfig, create_llama_model
-        from accelerate_tpu.serving import ServingEngine
-
-        model = create_llama_model(LlamaConfig.tiny(), seq_len=16)
-        engine = ServingEngine(model, num_slots=2, prompt_buckets=(4,), auto_bucketing=True)
-        prompts = [np.arange(1, 1 + n, dtype=np.int32) for n in (3, 5, 7, 9, 2, 6)]
-        engine.generate_many(prompts, max_new_tokens=3)
-        print(f"serving  : {len(prompts)} ragged prompts -> buckets {engine.bucketer.buckets}, "
-              f"{len(engine._prefill)} prefill compile(s)")
-        assert len(engine._prefill) <= len(engine.bucketer.buckets)
     print("compile_cache example: ALL OK")
 
 

@@ -33,6 +33,7 @@ MODULES = [
     "accelerate_tpu.generation",
     "accelerate_tpu.diffusion",
     "accelerate_tpu.serving",
+    "accelerate_tpu.serving_programs",
     "accelerate_tpu.serving_fleet",
     "accelerate_tpu.serving_proc",
     "accelerate_tpu.serving_transport",

@@ -577,7 +577,7 @@ def test_env_var_activates_program_cache(tmp_path, monkeypatch, reset_singletons
 
 
 # --------------------------------------------------------------------- #
-# serving: lazy buckets + per-bucket compile_ms + auto-bucketing
+# serving: lazy buckets + per-bucket compile_ms
 # --------------------------------------------------------------------- #
 
 
@@ -602,23 +602,6 @@ def test_serving_buckets_compile_lazily(tiny_llama, tmp_path):
     events = [e for e in read_events(log_path) if e.get("name") == "serving_bucket_compile"]
     assert [(e["program"], e["bucket"]) for e in events] == [("prefill", 8)]
     assert events[0]["compile_ms"] > 0
-
-
-def test_serving_auto_bucketing_token_exact(tiny_llama):
-    """Auto-bucketing mints covering buckets on demand and outputs stay
-    token-exact vs generate(); compile count stays O(buckets)."""
-    from accelerate_tpu.generation import generate
-    from accelerate_tpu.serving import ServingEngine
-
-    eng = ServingEngine(tiny_llama, num_slots=2, prompt_buckets=(4,), auto_bucketing=True)
-    prompts = [np.arange(1, 1 + n, dtype=np.int32) for n in (3, 5, 6, 9, 2)]
-    outs = eng.generate_many(prompts, max_new_tokens=4)
-    for prompt, got in zip(prompts, outs):
-        ref = np.asarray(generate(tiny_llama, prompt[None], max_new_tokens=4))[0]
-        np.testing.assert_array_equal(got, ref)
-    # lengths 3,5,6,9,2 -> buckets {4, 8, 16}: three prefill compiles, not five
-    assert eng.bucketer.buckets == (4, 8, 16)
-    assert eng._prefill.compiled_buckets() == (4, 8, 16)
 
 
 _CHILD_SERVE = """

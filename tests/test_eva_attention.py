@@ -183,7 +183,7 @@ def test_a_close_gives_blocks_back_and_the_counts_say_what_was_read(model):
 
 def test_every_refusal_over_summary_pages_is_by_name(model):
     """What is not built over the second table says so: prefix reuse, chunk windows (a prompt past the largest
-    bucket), preemption with resume, learned buckets, KV hand-off and export with KV; and a page that is no chunk."""
+    bucket), preemption with resume, KV hand-off and export with KV; and a page that is no chunk."""
     engine = _engine(model)
     with pytest.raises(NotImplementedError, match="prefix reuse .*summary pages"):
         engine.register_prefix(np.arange(8))
@@ -191,8 +191,6 @@ def test_every_refusal_over_summary_pages_is_by_name(model):
         engine.submit(np.arange(70, dtype=np.int32), max_new_tokens=4)
     with pytest.raises(NotImplementedError, match="preemption with resume"):
         _engine(model, scheduler=SchedulerConfig(enable_preemption=True))
-    with pytest.raises(NotImplementedError, match="auto_bucketing"):
-        _engine(model, auto_bucketing=True)
     with pytest.raises(NotImplementedError, match="KV hand-off .*summary pages"):
         engine.kv_handoff_dims()
     with pytest.raises(NotImplementedError, match="KV hand-off .*summary pages"):

@@ -102,7 +102,6 @@ def test_bucket_prefill_heads_one_row_and_serves_the_whole_programs_token(served
     """A prompt in each bucket: first token, its logprob and the row cache are those of the program that ran the
     head on every position; ``head_rows`` is 1 a bucket prefill; the lowered program holds no ``[bucket, vocab]``."""
     model, engine = served
-    assert engine._head_at_row
     rows_was = engine.metrics.head_rows
     for bucket, n in zip(BUCKETS, (11, 27)):
         prompt = _ids(n, start=2 * n)
@@ -123,9 +122,7 @@ def test_bucket_prefill_heads_one_row_and_serves_the_whole_programs_token(served
         engine.run()
         assert int(engine.partial(uid)[0]) == tok
         np.testing.assert_allclose(float(engine.logprobs(uid)[0]), lp, rtol=0, atol=ATOL)
-        fn, args, ctxs = engine._perf_programs["prefill"]
-        with ctxs[0]():
-            lowered = jax.jit(fn).lower(*args(bucket)).as_text()
+        lowered = engine._perf_programs["prefill"].lower(bucket=bucket).as_text()
         assert f"x{VOCAB}xf32>" in lowered and f"x{bucket}x{VOCAB}xf32>" not in lowered
     assert engine.metrics.head_rows - rows_was == len(BUCKETS)
 
@@ -150,15 +147,6 @@ def _created(name):
     return {"gpt2": zoo.create_gpt2_model, "gptneox": zoo.create_gptneox_model}[name](config, seq_len=16)
 
 
-def _foreign(apply_fn, inner, name):
-    """A ``Model`` of the user's own around ``inner``'s parameters."""
-    from accelerate_tpu.modeling import Model
-
-    model = Model(apply_fn, inner.params, name=name)
-    model.config = inner.config
-    return model
-
-
 @pytest.mark.parametrize("name", ["gpt2", "gptneox"])
 def test_the_layer_norm_families_head_the_rows_asked_for_too(name):
     """``gpt2`` and ``gptneox`` end in a LayerNorm and a float32 head: the same rows picked ahead of the norm."""
@@ -172,7 +160,6 @@ def test_the_layer_norm_families_head_the_rows_asked_for_too(name):
     at7 = jax.jit(lambda p, i: model.apply_fn(p, i, logits_at=jnp.int32(7)))(model.params, ids)
     np.testing.assert_allclose(np.asarray(at7)[0, 0], np.asarray(whole)[0, 7], rtol=0, atol=ATOL)
     engine = ServingEngine(model, num_slots=2, prompt_buckets=(16,), max_len=48, tick_block=2)
-    assert engine._head_at_row
     prompt = _ids(9)
     uid = engine.submit(prompt, max_new_tokens=3)
     engine.run()
@@ -193,38 +180,3 @@ def test_rows_asked_for_leave_out_what_the_other_rows_hold():
     np.testing.assert_array_equal(
         np.asarray(jax.jit(rows_at)(hidden, jnp.int32(4)), np.float32), np.asarray(hidden[:, 4:5], np.float32)
     )
-
-
-def test_an_apply_fn_that_cannot_be_asked_keeps_the_whole_program(caplog):
-    """An ``apply_fn`` from outside the zoo may take no ``logits_at``: the engine finds that out by asking once at
-    build (nothing is computed there), says so, keeps the program that heads every position, and counts the
-    bucket's rows."""
-    inner = _created("gpt2")
-    model = _foreign(
-        lambda p, ids, positions=None, decode=False, cache=None: inner.apply_fn(p, ids, positions, decode, cache),
-        inner, "foreign",
-    )
-    with caplog.at_level("WARNING", logger="accelerate_tpu.serving"):
-        engine = ServingEngine(model, num_slots=2, prompt_buckets=(16,), max_len=48, tick_block=2)
-    assert not engine._head_at_row and "foreign takes no logits_at" in caplog.text
-    prompt = _ids(9)
-    uid = engine.submit(prompt, max_new_tokens=3)
-    engine.step()
-    assert phase_log().roots("engine.tick")[-1].done["head_rows"] == 16
-    engine.run()
-    logits = np.asarray(model.apply_fn(model.params, jnp.asarray(prompt[None])))[0, -1]
-    assert int(engine.partial(uid)[0]) == int(logits.argmax()) and engine.metrics.head_rows == 16
-
-
-def test_a_fault_in_a_models_logits_at_path_is_not_taken_for_a_refusal():
-    """Tracing errors are ``TypeError``s too: only the refusal of the keyword itself means the model cannot be
-    asked; anything else the traced call raises reaches the caller of ``ServingEngine``."""
-    inner = _created("gpt2")
-
-    def faulty(p, ids, positions=None, decode=False, cache=None, logits_at=None):
-        if logits_at is not None:
-            raise TypeError("take requires ndarray or scalar arguments")
-        return inner.apply_fn(p, ids, positions, decode, cache)
-
-    with pytest.raises(TypeError, match="take requires"):
-        ServingEngine(_foreign(faulty, inner, "faulty"), num_slots=2, prompt_buckets=(16,), max_len=48)

@@ -566,16 +566,12 @@ def _scans(jaxpr):
 
 def _tick_jaxpr(eng):
     """The engine's decode tick traced with the arguments the engine builds for it: ``(jaxpr, arguments)``."""
-    import contextlib
-
     import jax
 
-    raw_tick, tick_args, contexts = eng._perf_programs["decode_tick"]
-    args = tick_args(None)
-    with contextlib.ExitStack() as stack:
-        for ctx in contexts:
-            stack.enter_context(ctx())
-        return jax.make_jaxpr(raw_tick)(*args).jaxpr, args
+    tick = eng._perf_programs["decode_tick"]
+    args = tick.args(None)
+    with tick.traced():
+        return jax.make_jaxpr(tick.fn)(*args).jaxpr, args
 
 
 @pytest.mark.parametrize("family", ["dense_unrolled", "dense_scanned", "state_space", "routed_experts"])
@@ -653,7 +649,6 @@ def test_tick_compiled_for_a_v5e_keeps_the_pool_in_one_buffer(monkeypatch):
     program copies, slices out or writes back an array the size of a
     layer's pool, the pool's bytes are aliased to the output, and the
     temporaries stay under one layer's pool."""
-    import contextlib
     import os
     import re
 
@@ -682,8 +677,8 @@ def test_tick_compiled_for_a_v5e_keeps_the_pool_in_one_buffer(monkeypatch):
         _wrap_llama(module, shapes, cfg), num_slots=slots, prompt_buckets=(64,), max_len=4096,
         paged_block_size=block_size, pool_blocks=blocks,
     )
-    _, tick_args, contexts = eng._perf_programs["decode_tick"]
-    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    tick = eng._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick.args(None))
     # the program asks the default backend whether to lower the Pallas kernel or interpret it
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     # a program compiled for a described chip cannot be read back from the persistent cache
@@ -691,9 +686,7 @@ def test_tick_compiled_for_a_v5e_keeps_the_pool_in_one_buffer(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        with contextlib.ExitStack() as stack:
-            for ctx in contexts:
-                stack.enter_context(ctx())
+        with tick.traced():
             compiled = eng._decode_tick.__wrapped__.lower(*args).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)

@@ -244,18 +244,14 @@ def test_latent_moe_decode_tick_fits_one_v5e_chip_and_moves_no_pool(v5e, monkeyp
     parameters and a 1.89 GB latent pool as arguments, the pool aliased to the output, and no
     operation that copies or re-lays a whole layer's pool (the compiler did both around a
     ``[.., 128, 576]`` pool: ops/paged_kv.py). A compile is not a chip run."""
-    import contextlib
     import re
 
     s, engine = _cell_engine("joyai-llm-flash-l5")
     chip = SingleDeviceSharding(v5e.devices[0])
-    raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
-    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    tick = engine._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick.args(None))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernel
-    with contextlib.ExitStack() as stack:
-        for ctx in contexts:
-            stack.enter_context(ctx())
-        compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
+    compiled = tick.lower(*args, donate_argnums=(1,)).compile()
     text = compiled.as_text()
     assert text.count("latent_paged_decode") >= 5 and "ragged-dot" in text
     products = _expert_products(text)  # four expert layers of two grouped kernels; XLA's own 512-row lowering of none
@@ -280,10 +276,10 @@ def test_latent_moe_prefill_bucket_heads_one_row_on_one_v5e_chip(v5e, monkeypatc
 
     s, engine = _cell_engine("joyai-llm-flash-l5")
     chip = SingleDeviceSharding(v5e.devices[0])
-    prefill, prefill_args, _ = engine._perf_programs["prefill"]
-    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), prefill_args(4096))
+    prefill = engine._perf_programs["prefill"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), prefill.args(4096))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = jax.jit(prefill).lower(*args).compile()
+    compiled = prefill.lower(*args).compile()
     text = compiled.as_text()
     assert len(_expert_products(text)) == 8 and "lm_head" in text
     assert not re.search(r"f32\[(1,)?4096,129280\]", text), "the head runs on every position of the bucket"
@@ -296,18 +292,14 @@ def test_hybrid_ssm_decode_tick_steps_the_state_in_place_on_one_v5e_chip(v5e, mo
     parameters, 26 states of ``[128, 16, 5120]`` float32 and two K/V pools as arguments, all aliased to
     the output; 26 ``ssm_state_step`` kernels and two ``paged_decode_attention`` a step, and no operation
     that copies or re-lays a state leaf or a pool. A compile is not a chip run."""
-    import contextlib
     import re
 
     s, engine = _cell_engine("ai21-jamba2-3b")
     chip = SingleDeviceSharding(v5e.devices[0])
-    raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
-    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    tick = engine._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick.args(None))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernels
-    with contextlib.ExitStack() as stack:
-        for ctx in contexts:
-            stack.enter_context(ctx())
-        compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
+    compiled = tick.lower(*args, donate_argnums=(1,)).compile()
     text = compiled.as_text()
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l and "custom-call(" in l]
     assert sum("ssm_state_step" in l for l in calls) == 26 and sum("paged_decode_attention" in l for l in calls) == 2
@@ -328,12 +320,12 @@ def test_hybrid_ssm_prefill_bucket_compiles_for_v5e(v5e):
     window's ``[1024, 16, 5120]`` float32 (335 MB a layer), and a row cache whose state leaves are one row."""
     _, engine = _cell_engine("ai21-jamba2-3b")
     chip = SingleDeviceSharding(v5e.devices[0])
-    prefill, prefill_args, _ = engine._perf_programs["prefill"]
-    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), prefill_args(1024))
-    compiled = jax.jit(prefill).lower(*args).compile()
+    prefill = engine._perf_programs["prefill"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), prefill.args(1024))
+    compiled = prefill.lower(*args).compile()
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes < 1.5 * 2**30, f"the prefill's temporaries are {m.temp_size_in_bytes / 2**30:.2f} GiB"
-    cache = jax.eval_shape(prefill, *args)[2]
+    cache = jax.eval_shape(prefill.fn, *args)[2]
     assert cache["layer_0"]["mamba"]["ssm_state"].shape == (1, 16, 5120)
     assert cache["layer_7"]["attn"]["key"].shape == (1, 2304, 1, 128)
 
@@ -345,20 +337,16 @@ def test_lfm2_moe_decode_tick_fits_one_v5e_chip_and_copies_no_pool_or_state(v5e,
     states of ``[128, 4096]`` as arguments, all aliased to the output; four ``paged_decode_attention`` and
     14 x 2 grouped expert kernels a step at ``[2048, 1792]`` with row tile 64, and no operation that
     copies or re-lays a pool or a state leaf whole. A compile is not a chip run."""
-    import contextlib
     import re
 
     from accelerate_tpu.ops.pallas_grouped_matmul import row_tile
 
     s, engine = _cell_engine("lfm2-8b-a1b-l16")
     chip = SingleDeviceSharding(v5e.devices[0])
-    raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
-    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    tick = engine._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick.args(None))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernels
-    with contextlib.ExitStack() as stack:
-        for ctx in contexts:
-            stack.enter_context(ctx())
-        compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
+    compiled = tick.lower(*args, donate_argnums=(1,)).compile()
     text = compiled.as_text()
     slots, blocks, bs = s["num_slots"], s["pool_blocks"], s["paged_block_size"]
     assert row_tile(slots * 4, 32) == 64
@@ -407,14 +395,14 @@ def test_lfm2_moe_prefill_bucket_compiles_for_v5e(v5e, monkeypatch):
     under a GiB, and a row cache whose state leaves are one row of two gated inputs a convolution layer."""
     _, engine = _cell_engine("lfm2-8b-a1b-l16")
     chip = SingleDeviceSharding(v5e.devices[0])
-    prefill, prefill_args, _ = engine._perf_programs["prefill"]
-    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), prefill_args(1024))
+    prefill = engine._perf_programs["prefill"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), prefill.args(1024))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = jax.jit(prefill).lower(*args).compile()
+    compiled = prefill.lower(*args).compile()
     assert len(_expert_products(compiled.as_text())) == 28
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes < 0.75 * 2**30, f"the prefill's temporaries are {m.temp_size_in_bytes / 2**30:.2f} GiB"
-    cache = jax.eval_shape(prefill, *args)[2]
+    cache = jax.eval_shape(prefill.fn, *args)[2]
     assert cache["layer_0"]["conv"]["conv_state"].shape == (1, 4096)
     assert cache["layer_2"]["attn"]["key"].shape == (1, 2304, 8, 64)
 
@@ -450,19 +438,15 @@ def test_granite_hybrid_decode_tick_fits_one_v5e_chip_and_copies_no_state(v5e, m
     convolution states and one K/V pool as arguments, all aliased to the output; nine ``ssd_state_step``
     kernels, one ``paged_decode_attention`` and 10 x 2 grouped expert kernels over ``[36, 4096, 768]`` a step,
     and no operation that copies or re-lays a state leaf or the pool whole. A compile is not a chip run."""
-    import contextlib
     import re
 
     s, engine = _cell_engine("granite-4.0-h-small-l10")
     assert engine.metrics.state_bytes_per_slot == 9 * (128 * 8192 * 4 + 3 * 8448 * 2) and engine._mask_idle_rows
     chip = SingleDeviceSharding(v5e.devices[0])
-    raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
-    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    tick = engine._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick.args(None))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernels
-    with contextlib.ExitStack() as stack:
-        for ctx in contexts:
-            stack.enter_context(ctx())
-        compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
+    compiled = tick.lower(*args, donate_argnums=(1,)).compile()
     text = compiled.as_text()
     slots, blocks, bs = s["num_slots"], s["pool_blocks"], s["paged_block_size"]
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l and "custom-call(" in l]
@@ -493,14 +477,14 @@ def test_granite_hybrid_prefill_bucket_and_paste_compile_for_v5e(v5e, monkeypatc
     s, engine = _cell_engine("granite-4.0-h-small-l10")
     chip = SingleDeviceSharding(v5e.devices[0])
     on = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)  # noqa: E731
-    prefill, prefill_args, _ = engine._perf_programs["prefill"]
-    args = on(prefill_args(1024))
+    prefill = engine._perf_programs["prefill"]
+    args = on(prefill.args(1024))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = jax.jit(prefill).lower(*args).compile()
+    compiled = prefill.lower(*args).compile()
     assert len(_expert_products(compiled.as_text())) == 20
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes < 0.5 * 2**30, f"the prefill's temporaries are {m.temp_size_in_bytes / 2**30:.2f} GiB"
-    cache = jax.eval_shape(prefill, *args)[2]
+    cache = jax.eval_shape(prefill.fn, *args)[2]
     assert cache["layer_0"]["mamba"]["ssm_state"].shape == (1, 128, 8192) and cache["layer_0"]["mamba"]["ssm_state"].dtype == jnp.float32
     assert cache["layer_0"]["mamba"]["conv_state"].shape == (1, 3 * 8448) and cache["layer_5"]["attn"]["key"].shape == (1, 2304, 8, 128)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)  # noqa: E731
@@ -534,19 +518,15 @@ def test_evabyte_decode_tick_fits_one_v5e_chip_and_copies_no_pool(v5e, monkeypat
     ``paged_decode_attention`` kernels a step over the gathered table, the chunk's pooling (a page read back and
     a summary row written, in place), and no operation that copies or re-lays a pool whole. Inside 15.75 GiB with
     the 1 GB the issue asks to leave free. A compile is not a chip run."""
-    import contextlib
     import re
 
     s, engine = _cell_engine("evabyte-6.5b-l8")
     assert engine._aligned == (2048, 16) and engine._summary_entries == 20 and engine._mb == 320
     chip = SingleDeviceSharding(v5e.devices[0])
-    raw_tick, tick_args, contexts = engine._perf_programs["decode_tick"]
-    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick_args(None))
+    tick = engine._perf_programs["decode_tick"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tick.args(None))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernel
-    with contextlib.ExitStack() as stack:
-        for ctx in contexts:
-            stack.enter_context(ctx())
-        compiled = jax.jit(raw_tick, donate_argnums=(1,)).lower(*args).compile()
+    compiled = tick.lower(*args, donate_argnums=(1,)).compile()
     text = compiled.as_text()
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l and "custom-call(" in l]
     assert sum("paged_decode_attention" in l for l in calls) == 8 and "s32[32,144]" in text, "the gathered table: 16 + 128 entries a slot"
@@ -574,16 +554,16 @@ def test_evabyte_prefill_bucket_and_paste_compile_for_v5e(v5e, monkeypatch):
     s, engine = _cell_engine("evabyte-6.5b-l8")
     chip = SingleDeviceSharding(v5e.devices[0])
     on = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)  # noqa: E731
-    prefill, prefill_args, _ = engine._perf_programs["prefill"]
-    args = on(prefill_args(4096))
+    prefill = engine._perf_programs["prefill"]
+    args = on(prefill.args(4096))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = jax.jit(prefill).lower(*args).compile()
+    compiled = prefill.lower(*args).compile()
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes < 0.6 * 2**30, f"the prefill's temporaries are {m.temp_size_in_bytes / 2**30:.2f} GiB"
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 16, "a flash call a window a layer"
     assert not re.search(r"f32\[(1,)?32,512,2176\]", text), "a window's float32 scores are formed"
-    cache = jax.eval_shape(prefill, *args)[2]
+    cache = jax.eval_shape(prefill.fn, *args)[2]
     assert cache["layer_0"]["attn"]["key"].shape == (1, 5120, 32, 128) and cache["layer_7"]["attn"]["summary_value"].shape == (1, 320, 32, 128)
     assert 0.6 * 2**30 < m.output_size_in_bytes < 0.7 * 2**30
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)  # noqa: E731

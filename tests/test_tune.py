@@ -410,7 +410,7 @@ def _bucket_factory(hidden=512, true_batch=96):
     return factory
 
 
-@pytest.mark.parametrize(
+TRUST_WORKLOADS = pytest.mark.parametrize(
     "factory_builder,space_kwargs",
     [
         (_bucket_factory, dict(bucket_sets=("128", "256", "512", "1024"))),
@@ -418,23 +418,43 @@ def _bucket_factory(hidden=512, true_batch=96):
     ],
     ids=["bucket-padding", "token-budget"],
 )
-def test_perfmodel_ranking_trust(mesh8, factory_builder, space_kwargs):
-    """The tuner's oracle contract: static predicted-step-time ordering
-    matches the StepTelemetry-measured ordering — top-1 agreement and
-    Spearman >= 0.8 — on CPU, where the knobs change real compute."""
+
+
+def _confirmed(mesh8, factory_builder, space_kwargs):
     from accelerate_tpu.analysis.tuner import tune
 
-    report = tune(
+    return tune(
         factory_builder(), SearchSpace(**space_kwargs),
         base_mesh=mesh8, generation="cpu",
         top_k=4, confirm=True, confirm_steps=6,
     )
+
+
+@TRUST_WORKLOADS
+def test_perfmodel_ranking_trust(mesh8, factory_builder, space_kwargs):
+    """What of the tuner's oracle contract does not depend on the machine's load: four arms are ranked and
+    confirmed, no arm recompiles while it is measured, and the static predicted step time rises with the padded
+    size (the batch's bucket, the token budget). That the measured ordering agrees is
+    ``test_perfmodel_ranking_matches_wall_time``."""
+    report = _confirmed(mesh8, factory_builder, space_kwargs)
     assert len(report.ranked) == 4
-    ra = report.confirm["rank_agreement"]
-    assert ra["n"] == 4, report.confirm
+    assert report.confirm["rank_agreement"]["n"] == 4, report.confirm
+    assert report.confirm["recompiles"] == 0
+    padded = [c.point.buckets[0] if c.point.buckets else c.point.token_budget for c in report.ranked]
+    assert padded == [128, 256, 512, 1024]
+    predicted = [c.predicted_step_us for c in report.ranked]
+    assert predicted == sorted(predicted) and predicted[0] < predicted[-1]
+
+
+@pytest.mark.slow  # XLA:CPU wall time: run alone (``-m slow``), not beside five loaded xdist workers
+@TRUST_WORKLOADS
+def test_perfmodel_ranking_matches_wall_time(mesh8, factory_builder, space_kwargs):
+    """The tuner's oracle contract: static predicted-step-time ordering
+    matches the StepTelemetry-measured ordering — top-1 agreement and
+    Spearman >= 0.8 — on CPU, where the knobs change real compute."""
+    ra = _confirmed(mesh8, factory_builder, space_kwargs).confirm["rank_agreement"]
     assert ra["top1"] is True
     assert ra["spearman"] >= 0.8
-    assert report.confirm["recompiles"] == 0
 
 
 # --------------------------------------------------------------------- #
