@@ -1,5 +1,5 @@
 """Model zoo: TPU-first flax implementations with mesh sharding rules
-(bert/gpt2/gptneox/t5/llama/mistral/joyai_llm_flash/jamba/lfm2_moe/granitemoehybrid/evabyte/qwen2/qwen3/olmo2/gemma/gemma2/gemma3/phi3/mixtral/qwen3moe/resnet/vit/whisper/clip/unet/vae)
+(bert/gpt2/gptneox/t5/llama/mistral/joyai_llm_flash/jamba/lfm2_moe/granitemoehybrid/evabyte/laguna/qwen2/qwen3/olmo2/gemma/gemma2/gemma3/phi3/mixtral/qwen3moe/resnet/vit/whisper/clip/unet/vae)
 + HF safetensors weight import. The reference delegates models to
 transformers; here they ship in-tree (SURVEY hard-part #3: torch-free
 model story)."""
@@ -65,6 +65,12 @@ from .evabyte import (
     EvaByteConfig,
     EvaByteModel,
     create_evabyte_model,
+)
+from .laguna import (
+    LAGUNA_SHARDING_RULES,
+    LagunaConfig,
+    LagunaModel,
+    create_laguna_model,
 )
 from .gemma import (
     GEMMA_SHARDING_RULES,

@@ -15,6 +15,7 @@ fine-tunes Llama-2-7B — BASELINE.json configs). TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
 from typing import Optional
 
@@ -192,6 +193,25 @@ class LlamaConfig:
     eva_chunk_size: int = 16
     # residual sums taken in float32 and rounded once to the stream's type (EvaByte's ``fp32_skip_add``)
     fp32_skip_add: bool = False
+    # Layers of two kinds of attention that differ in more than the band (Laguna-style checkpoints publish these
+    # keys beside ``layer_types``; unrolled layers): query heads by layer (``num_attention_heads_per_layer``, on
+    # the same ``num_key_value_heads``; ``head_dim`` is then stated), a rotary rule by layer TYPE
+    # (``rope_parameters[layer_types[i]]``: ``rope_theta``, ``partial_rotary_factor`` and, with a ``rope_type``
+    # other than ``default``, the ``rope_scaling`` dict itself), a gate a head on the attention output
+    # (``attn_gate``: ``softplus(g_proj(x))``, one scalar a head, from the layer's normed input), and the
+    # feed-forward by layer (``mlp_layer_types``: ``"dense"`` | ``"sparse"``, in place of
+    # ``first_k_dense_replace``). None / False: the model has none of it, and its program is what it was.
+    num_attention_heads_per_layer: Optional[tuple] = None
+    rope_parameters: Optional[dict] = None
+    partial_rotary_factor: float = 1.0  # the share of a head's values that rotary turns, its first ones
+    attn_gate: bool = False
+    mlp_layer_types: Optional[tuple] = None
+    # A prefill that STARTS a cache (``decode=True``, no cache yet, outside the paged layout) attends over its
+    # own tokens through ``_dispatch_attention`` (the flash kernel, banded under a window, at flash lengths)
+    # and stores its rows, where ``cached_attention`` takes a masked product against all ``max_len`` rows
+    # of the new cache: ``[heads, bucket, max_len]`` float32 scores, 5.4 GB at 64 heads, 4096 and 5120. Every
+    # model whose configuration does not ask keeps the masked product, and its prefill programs.
+    cold_prefill: bool = False
 
     def mixer_kind(self, i: int) -> str:
         """The mixer of layer ``i``: ``"conv"`` or ``"mamba"`` where ``layer_types`` says so (a ``"mamba"``
@@ -204,6 +224,19 @@ class LlamaConfig:
         if self.attn_layer_period is not None and i % self.attn_layer_period != self.attn_layer_offset:
             return "mamba"
         return "attention"
+
+    def layer_overrides(self, i: int) -> dict:
+        """What layer ``i`` overrides of this configuration by its place: its query heads
+        (``num_attention_heads_per_layer``) and its type's rotary rule (``rope_parameters``)."""
+        out = {}
+        if self.num_attention_heads_per_layer is not None:
+            out["num_attention_heads"] = self.num_attention_heads_per_layer[i]
+        rule = (self.rope_parameters or {}).get(self.layer_types[i])
+        if rule is not None:
+            out["rope_theta"] = float(rule["rope_theta"])
+            out["partial_rotary_factor"] = float(rule.get("partial_rotary_factor", 1.0))
+            out["rope_scaling"] = None if rule.get("rope_type", "default") == "default" else dict(rule)
+        return out
 
     @property
     def stateful(self) -> bool:
@@ -570,12 +603,15 @@ class LlamaAttention(nn.Module):
         # decode sees S=1, so the cache capacity stands in for it
         rope_len = cfg.max_position_embeddings if decode else hidden.shape[1]
         if cfg.rope_theta is not None:
-            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling,
-                     max_pos=cfg.max_position_embeddings, seq_len=rope_len,
-                     orig_max=cfg.original_max_position_embeddings)
-            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling,
-                     max_pos=cfg.max_position_embeddings, seq_len=rope_len,
-                     orig_max=cfg.original_max_position_embeddings)
+            turn = functools.partial(
+                rope, positions=positions, theta=cfg.rope_theta, scaling=cfg.rope_scaling,
+                max_pos=cfg.max_position_embeddings, seq_len=rope_len, orig_max=cfg.original_max_position_embeddings)
+            turned = int(head_dim * cfg.partial_rotary_factor)
+            if turned == head_dim:
+                q, k = turn(q), turn(k)
+            else:  # rotary turns the first ``partial_rotary_factor`` of a head's values, under frequencies of that width
+                q = jnp.concatenate([turn(q[..., :turned]), q[..., turned:]], axis=-1)
+                k = jnp.concatenate([turn(k[..., :turned]), k[..., turned:]], axis=-1)
         scale = None  # attention default: head_dim**-0.5
         if cfg.query_pre_attn_scalar is not None:
             scale = float(cfg.query_pre_attn_scalar) ** -0.5  # Gemma2
@@ -583,6 +619,10 @@ class LlamaAttention(nn.Module):
             scale = float(cfg.attention_multiplier)  # Granite: the scale itself, not a power of the head size
         if cfg.attention_class is not None:
             out = self._eva_attention(q, k, v, scale, decode)
+        elif decode and cfg.cold_prefill:
+            # a device operation's name carries the kind of layer it belongs to
+            with jax.named_scope("attn.full" if cfg.sliding_window is None else "attn.window"):
+                out = self._cold_or_cached_attention(q, k, v, scale, new_span)
         elif decode:
             out = self._cached_attention(q, k, v, scale, new_span)
         else:
@@ -590,8 +630,25 @@ class LlamaAttention(nn.Module):
                 q, k, v, cfg.attention_impl, cfg.sliding_window,
                 scale=scale, logit_softcap=cfg.attn_logit_softcap,
             )
+        if cfg.attn_gate:
+            with jax.named_scope("attn.gate"):
+                gate = _dense(cfg, cfg.num_attention_heads, "g_proj", hidden.dtype)(hidden)  # [.., H]: a scalar a head
+                out = out * jax.nn.softplus(gate.astype(jnp.float32)).astype(out.dtype)[..., None]
         out = out.reshape(*out.shape[:-2], cfg.num_attention_heads * head_dim)
         return _dense(cfg, cfg.hidden_size, "o_proj", hidden.dtype)(out)
+
+    def _cold_or_cached_attention(self, q, k, v, scale, new_span):
+        """``cold_prefill``: the call that starts a dense cache stores its rows and attends over them alone,
+        through :func:`_dispatch_attention`; every later call (a decode step, a warm chunk window, the paged
+        layout) is :meth:`_cached_attention`'s."""
+        from ..ops import kv_cache, paged_kv
+
+        cfg = self.config
+        if paged_kv.active_paged_config() is not None or self.has_variable("cache", "key"):
+            return self._cached_attention(q, k, v, scale, new_span)
+        kv_cache.start_cache(self, k, v, cfg.max_position_embeddings)
+        return _dispatch_attention(
+            q, k, v, cfg.attention_impl, cfg.sliding_window, scale=scale, logit_softcap=cfg.attn_logit_softcap)
 
     def _cached_attention(self, q, k, v, scale=None, new_span=None):
         """KV-cache incremental attention (generation path; shared cache
@@ -1232,8 +1289,10 @@ class LlamaModel(nn.Module):
                     if windowed and cfg.rope_local_theta is not None:
                         overrides["rope_theta"] = cfg.rope_local_theta
                         overrides["rope_scaling"] = None
+                    overrides.update(cfg.layer_overrides(i))
                     lcfg = dataclasses.replace(cfg, **overrides)
-                hidden = layer_cls(lcfg, routed and i >= n_lead, cfg.mixer_kind(i), name=f"layer_{i}")(
+                sparse = i >= n_lead if cfg.mlp_layer_types is None else cfg.mlp_layer_types[i] == "sparse"
+                hidden = layer_cls(lcfg, routed and sparse, cfg.mixer_kind(i), name=f"layer_{i}")(
                     hidden, positions, decode, new_span, row_valid
                 )
         if logits_at is not None:

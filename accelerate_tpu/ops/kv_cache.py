@@ -135,6 +135,19 @@ def cached_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
 
 
+def start_cache(module, k, v, max_len: int) -> None:
+    """Declare ``module``'s dense cache (the leaves :func:`cached_attention` declares) holding the rows ``k`` /
+    ``v`` ``[B, S, H_kv, D]`` of a call that starts it, the frontier at ``S``: for a caller that attends over
+    those rows itself."""
+    b, s, h_kv, d = k.shape
+    ck = module.variable("cache", "key", jnp.zeros, (b, max_len, h_kv, d), k.dtype)
+    cv = module.variable("cache", "value", jnp.zeros, (b, max_len, h_kv, d), v.dtype)
+    idx = module.variable("cache", "index", lambda: jnp.zeros((), jnp.int32))
+    ck.value = _constrain(jax.lax.dynamic_update_slice(ck.value, k, (0, 0, 0, 0)))
+    cv.value = _constrain(jax.lax.dynamic_update_slice(cv.value, v, (0, 0, 0, 0)))
+    idx.value = jnp.asarray(s, jnp.int32)
+
+
 def cached_latent_attention(module, q_lat, rows, max_len: int, *, value_width: int, scale: float):
     """Incremental absorbed latent attention (MLA) against a growing dense
     cache of latent rows ``[B, max_len, W]`` (``W = kv_lora_rank +

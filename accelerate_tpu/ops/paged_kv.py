@@ -89,6 +89,22 @@ from jax.sharding import PartitionSpec as P
 class PagedConfig:
     block_size: int
     num_blocks: int  # total pool blocks INCLUDING the reserved trash block 0
+    # A pool and a table a KIND of layer (a model whose layers are some windowed and some not): with
+    # ``window_ring`` set, a layer under a sliding window keeps pools of ``window_blocks`` blocks (its own
+    # trash sink among them) and, in place of ``block_table``, a ``window_table`` ``[B, window_ring]`` that a
+    # slot uses as a ring: position ``p`` lives at entry ``(p // block_size) % window_ring``
+    # (:func:`ring_entries` of the window: no more pages than the band spans, and one to spare). A layer
+    # without a window keeps ``num_blocks`` and ``block_table``. None: every layer alike, as it was.
+    window_ring: Optional[int] = None
+    window_blocks: Optional[int] = None
+
+
+def ring_entries(window: int, block_size: int, max_len: int) -> int:
+    """Entries of a slot's ring under a band of ``window`` keys: the band of a frontier at offset ``r`` of
+    its page reaches back ``window - 1`` keys, ``ceil((window - 1) / block_size) + 1`` pages at most (512 keys in
+    pages of 16: 33, at every ``r`` but the page's last), and one entry is spare, so that what a step is about
+    to write never shares an entry with what a step still reads. Never more than the whole context's."""
+    return min(-(-(window - 1) // block_size) + 2, -(-max_len // block_size))
 
 
 _ACTIVE: Optional[PagedConfig] = None
@@ -280,6 +296,11 @@ def paged_cached_attention(
     fold = pool_lane_fold(h_kv, d)
     row = (h_kv // fold, fold * d)  # one token of the pool: [H_kv, D], or heads side by side in 128 lanes
     view = _LAYER_VIEW
+    ring = cfg.window_ring is not None and sliding_window is not None
+    if ring:
+        if view is not None:
+            raise NotImplementedError("a table a kind of layer is built for unrolled layers: the carried pool stack has one shape")
+        return _ring_cached_attention(module, q, k, v, row, scale=scale, sliding_window=sliding_window, cfg=cfg)
     if view is None:
         kp = module.variable("cache", "key_pool", jnp.zeros, (nb, bs_, *row), k.dtype)
         vp = module.variable("cache", "value_pool", jnp.zeros, (nb, bs_, *row), v.dtype)
@@ -338,12 +359,47 @@ def paged_cached_attention(
     )
 
 
-def paged_gather_attention(q, key_pool, value_pool, block_table, cur, *, scale, sliding_window=None):
+def _ring_cached_attention(module, q, k, v, row, *, scale, sliding_window, cfg: PagedConfig):
+    """:func:`paged_cached_attention` for a layer under a sliding window of a cache that keeps a pool and a table
+    a kind of layer (``cfg.window_ring``): pools of ``cfg.window_blocks`` blocks and a ``window_table`` ``[B,
+    ring]`` used as a ring. The token's row goes to entry ``(cur // bs) % ring``, whatever ``cur``: a slot that
+    finished mid-tick and overshoots goes round its own ring (or the sink's, once cleared), never into another
+    slot's block, and an entry is written again only ``ring`` pages later, when the band has left what it held."""
+    b = k.shape[0]
+    bs_, nb, entries = cfg.block_size, cfg.window_blocks, cfg.window_ring
+    kp = module.variable("cache", "key_pool", jnp.zeros, (nb, bs_, *row), k.dtype)
+    vp = module.variable("cache", "value_pool", jnp.zeros, (nb, bs_, *row), v.dtype)
+    wt = module.variable("cache", "window_table", jnp.zeros, (b, entries), jnp.int32)
+    idx = module.variable("cache", "index", jnp.zeros, (b,), jnp.int32)
+    cur, table = idx.value, wt.value
+    dest = table[jnp.arange(b), (cur // bs_) % entries]
+    key_pool = kp.value = _constrain_pool(kp.value.at[dest, cur % bs_].set(k[:, 0].reshape(b, *row)))
+    value_pool = vp.value = _constrain_pool(vp.value.at[dest, cur % bs_].set(v[:, 0].reshape(b, *row)))
+    idx.value = cur + 1
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu or FORCE_KERNEL_INTERPRET:
+        import functools
+
+        from .pallas_paged_attention import paged_decode_attention
+
+        fn = functools.partial(
+            paged_decode_attention, sliding_window=sliding_window, scale=scale, interpret=not on_tpu, ring=True
+        )
+        run = _kernel_runner(fn, q.shape[2], k.shape[2])
+        if run is not None:
+            # a row that stores into the sink is idle: one page, as in paged_cached_attention
+            return run(q[:, 0], key_pool, value_pool, table, jnp.where(dest == 0, 0, cur))[:, None]
+    return paged_gather_attention(q, key_pool, value_pool, table, cur, scale=scale, sliding_window=sliding_window, ring=True)
+
+
+def paged_gather_attention(q, key_pool, value_pool, block_table, cur, *, scale, sliding_window=None, ring=False):
     """The plain XLA paged decode step: gather each row's pages into a
     contiguous copy and attend to it. ``q`` is ``[B, 1, H, D]``, the pools
     ``[NB, bs, H_kv, D]`` (or lane-folded), ``block_table`` ``[B, MB]`` and ``cur`` the
     per-row frontier ``[B]``; returns ``[B, 1, H, D]``. What the Pallas
-    kernel is checked against, and what runs where it cannot."""
+    kernel is checked against, and what runs where it cannot. ``ring``: the
+    table is a ring (``window_table``): entry ``e`` holds the newest page
+    ``p <= cur // bs`` with ``p % MB == e``."""
     b, d = q.shape[0], q.shape[-1]
     bs_ = key_pool.shape[1]
     h_kv = key_pool.shape[2] * key_pool.shape[3] // d  # a lane-folded pool holds the same rows (pool_lane_fold)
@@ -352,9 +408,15 @@ def paged_gather_attention(q, key_pool, value_pool, block_table, cur, *, scale, 
     k_all = key_pool[block_table].reshape(b, mb * bs_, h_kv, d)
     v_all = value_pool[block_table].reshape(b, mb * bs_, h_kv, d)
     key_pos = jnp.arange(mb * bs_)
-    live = key_pos[None, :] <= cur[:, None]  # [B, L] causal frontier per row
-    if sliding_window is not None:
-        live &= key_pos[None, :] > cur[:, None] - sliding_window  # Mistral band
+    if ring:
+        last = (cur // bs_)[:, None]  # [B, 1]: the frontier's page; entry e holds page last - (last - e) % MB
+        page = last - (last - jnp.arange(mb)[None, :]) % mb  # [B, MB], negative where the ring is not yet full
+        key_pos = (page[:, :, None] * bs_ + jnp.arange(bs_)[None, None, :]).reshape(b, mb * bs_)
+        live = (key_pos >= 0) & (key_pos <= cur[:, None]) & (key_pos > cur[:, None] - sliding_window)
+    else:
+        live = key_pos[None, :] <= cur[:, None]  # [B, L] causal frontier per row
+        if sliding_window is not None:
+            live &= key_pos[None, :] > cur[:, None] - sliding_window  # Mistral band
 
     groups = q.shape[2] // h_kv
     if groups > 1:
@@ -476,15 +538,21 @@ def _path_names(path):
     return tuple(p.key if hasattr(p, "key") else str(p) for p in path)
 
 
-def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None, summary_row=None):
+def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None, summary_row=None, window_row=None,
+                   new_index=None):
     """Blockify a dense per-row cache and scatter it into the pools at
     ``write_row``'s block ids; apply ``table_updates(name, leaf)`` to the
-    ``block_table``/``summary_table``/``index`` leaves (or leave them untouched if it
+    ``block_table``/``summary_table``/``window_table``/``index`` leaves (or leave them untouched if it
     returns None); with a ``slot``, write the row cache's state leaves
     (:data:`STATE_LEAVES`) over that slot's; with a ``summary_row``, blockify
     the row cache's chunk summaries (:data:`SUMMARY_ROWS`) too and scatter them
-    into the same pools at its block ids."""
+    into the same pools at its block ids; with a ``window_row`` (a slot's ring,
+    ``[ring]`` block ids of the windowed layers' pools), a pool that stands beside
+    a ``window_table`` takes the LAST ``ring`` pages of the ``new_index`` rows
+    alone, page ``p`` at ``window_row[p % ring]``: all that the band can still read."""
     dense = {_path_names(p): leaf for p, leaf in jax.tree_util.tree_flatten_with_path(row_cache)[0]}
+    ringed = {_path_names(p)[:-1] for p, _ in jax.tree_util.tree_flatten_with_path(paged_cache)[0]
+              if _path_names(p)[-1] == "window_table"}
 
     def rows_of(prefix, name):
         """The dense leaf a pool holds the rows of: the one ``key`` /
@@ -516,6 +584,17 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None, 
             blocks = row.reshape(*leaf.shape[:lead], mb, bs_, *token)
             if latent:
                 blocks = blocks.swapaxes(-1, -2)
+            ids = write_row
+            if prefix in ringed:
+                # a windowed layer's pool: the pages the ring can hold, ``last - ring + 1 .. last`` (``last`` the page of
+                # the prompt's last row), cut out of the row cache in one slice and written each at its ring entry;
+                # a page the prompt does not reach (a short prompt's) goes to the trash sink
+                entries = window_row.shape[0]
+                last = jnp.maximum(new_index - 1, 0) // bs_
+                first = jnp.clip(last - entries + 1, 0, mb - entries)
+                pages = first + jnp.arange(entries)
+                blocks = jax.lax.dynamic_slice_in_dim(blocks, first, entries, axis=lead)
+                ids = jnp.where(pages <= last, window_row[pages % entries], 0)
             def scatter(leaf, ids, blocks):
                 """``blocks`` ``[.., n, bs, *token]`` (or latent pages) written at the pool's block ``ids`` ``[n]``."""
                 sel = (slice(None),) * lead + (ids,)
@@ -529,7 +608,7 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None, 
                     return leaf.reshape(flat).at[sel].set(blocks.astype(leaf.dtype)).reshape(leaf.shape)
                 return leaf.at[sel].set(blocks.astype(leaf.dtype))
 
-            leaf = scatter(leaf, write_row, blocks)
+            leaf = scatter(leaf, ids, blocks)
             if summary_row is not None:
                 # the summaries of the prompt's chunks, rows of the pool's own shape: pages of the same pool
                 pooled = rows_of(prefix, SUMMARY_ROWS[name])
@@ -537,7 +616,7 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None, 
                 pooled = jnp.pad(pooled, [(0, 0)] * (lead + 1) + [(0, pages * bs_ - pooled.shape[lead + 1])] + [(0, 0)] * tail)
                 leaf = scatter(leaf, summary_row, pooled.reshape(*leaf.shape[:lead], pages, bs_, *token))
             return leaf
-        if name in ("block_table", "summary_table", "index"):
+        if name in TABLES or name == "index":
             out = table_updates(name, leaf)
             return leaf if out is None else out
         if name in STATE_LEAVES:
@@ -549,7 +628,7 @@ def _scatter_pools(paged_cache, row_cache, write_row, table_updates, slot=None, 
     return jax.tree_util.tree_map_with_path(write, paged_cache)
 
 
-def paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index, summary_row=None):
+def paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index, summary_row=None, window_row=None):
     """Install a dense prefill row cache into the pool for ``slot``.
 
     ``row_cache`` is the ordinary dense per-row cache a prefill program
@@ -567,20 +646,23 @@ def paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index, sum
     ``summary_row`` (a cache with a ``summary_table``: EVA) the row cache's
     chunk summaries go to that row's pages of the same pools, and the row is
     ``slot``'s summary table: an entry the request will never read through
-    is the trash sink, in the table and for the write alike. Pure — jit once.
+    is the trash sink, in the table and for the write alike. With a
+    ``window_row`` (a cache with ``window_table`` leaves: a pool and a table a
+    kind of layer) the windowed layers take the last ``ring`` pages of the row
+    cache at that row's blocks, and the row is ``slot``'s ring; ``write_row``
+    and ``table_row`` are the other layers'. Pure — jit once.
     """
+    rows = {"block_table": table_row, "summary_table": summary_row, "window_table": window_row}
 
     def tables(name, leaf):
-        if name == "block_table":
+        if name in rows:
             sel = (slice(None),) * (leaf.ndim - 2) + (slot,)
-            return leaf.at[sel].set(table_row.astype(leaf.dtype))
-        if name == "summary_table":
-            sel = (slice(None),) * (leaf.ndim - 2) + (slot,)
-            return leaf.at[sel].set(summary_row.astype(leaf.dtype))
+            return leaf.at[sel].set(rows[name].astype(leaf.dtype))
         sel = (slice(None),) * (leaf.ndim - 1) + (slot,)
         return leaf.at[sel].set(jnp.asarray(new_index, leaf.dtype))
 
-    return _scatter_pools(paged_cache, row_cache, write_row, tables, slot=slot, summary_row=summary_row)
+    return _scatter_pools(paged_cache, row_cache, write_row, tables, slot=slot, summary_row=summary_row,
+                          window_row=window_row, new_index=new_index)
 
 
 def paste_blocks(paged_cache, row_cache, write_row):
@@ -606,13 +688,15 @@ def set_table_row(paged_cache, slot, table_row):
     return jax.tree_util.tree_map_with_path(write, paged_cache)
 
 
+# a slot's tables: the whole context's, an EVA layer's summary pages, a windowed layer's ring
+TABLES = ("block_table", "summary_table", "window_table")
 # the leaves a retirement writes (:func:`clear_slot`): a slot's table rows, its frontier, its recurrent state
-CLEARED_LEAVES = ("block_table", "summary_table", "index", *STATE_LEAVES)
+CLEARED_LEAVES = (*TABLES, "index", *STATE_LEAVES)
 
 
 def _cleared(name: str, leaf, slot):
     """A leaf named in :data:`CLEARED_LEAVES` as a retirement of ``slot`` leaves it."""
-    if name in ("block_table", "summary_table"):
+    if name in TABLES:
         sel = (slice(None),) * (leaf.ndim - 2) + (slot,)
         return leaf.at[sel].set(jnp.zeros((leaf.shape[-1],), leaf.dtype))
     if name == "index":

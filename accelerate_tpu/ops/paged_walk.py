@@ -70,7 +70,8 @@ def online_softmax(s, m_prev, l_prev):
     return m_new, alpha, p, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
 
 
-def walk_live_pages(tbl_ref, cur_ref, side_ref, *, pages, block_size, window, page_copies, zero_buffers, make_fold):
+def walk_live_pages(tbl_ref, cur_ref, side_ref, *, pages, block_size, window, page_copies, zero_buffers, make_fold,
+                    ring: bool = False):
     """Fold the live pages of this grid step's row, a chunk of ``pages`` at a time, and return the fold's
     last carry.
 
@@ -88,7 +89,9 @@ def walk_live_pages(tbl_ref, cur_ref, side_ref, *, pages, block_size, window, pa
         """First live page of ``row`` and how many follow it: the clamp keeps a frontier that overshot
         the table (a slot that finished mid-tick) on the row's own last entry."""
         cur = cur_ref[row]
-        last = jnp.minimum(jax.lax.div(cur, block_size), max_blocks - 1)
+        last = jax.lax.div(cur, block_size)
+        if not ring:
+            last = jnp.minimum(last, max_blocks - 1)
         first = 0 if window is None else jax.lax.div(jnp.maximum(cur - window + 1, 0), block_size)
         return first, jnp.maximum(last - first + 1, 0)
 
@@ -98,7 +101,8 @@ def walk_live_pages(tbl_ref, cur_ref, side_ref, *, pages, block_size, window, pa
         at = chunk * pages
 
         def one(i, _):
-            for copy in page_copies(tbl_ref[row, first + at + i], side, i):
+            page = first + at + i
+            for copy in page_copies(tbl_ref[row, jax.lax.rem(page, max_blocks) if ring else page], side, i):
                 act(copy)
 
         jax.lax.fori_loop(0, jnp.clip(count - at, 0, pages), one, None)

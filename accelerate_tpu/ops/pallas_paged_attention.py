@@ -106,6 +106,7 @@ def _kernel(
     kv_heads: int,
     window: Optional[int],
     scale: float,
+    ring: bool,
 ):
     from jax.experimental.pallas import tpu as pltpu
 
@@ -131,7 +132,7 @@ def _kernel(
             jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 0), heads // kv_heads
         )
         token = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1), kv_heads)
-        newest = newest_position(cur, max_blocks, block_size)
+        newest = cur if ring else newest_position(cur, max_blocks, block_size)
 
         def fold(j, side, carry):
             m_prev, l_prev, acc = carry
@@ -159,14 +160,14 @@ def _kernel(
 
     _, l, acc = walk_live_pages(
         tbl_ref, cur_ref, side_ref, pages=pages, block_size=block_size, window=window,
-        page_copies=page_copies, zero_buffers=zero_buffers, make_fold=make_fold,
+        page_copies=page_copies, zero_buffers=zero_buffers, make_fold=make_fold, ring=ring,
     )
     # l is 0 for a row with nothing live (a long-retired slot whose windowed frontier moved past its
     # table): its output is discarded host-side, but an unguarded 0/0 would trip jax_debug_nans
     o_ref[0] = (acc / jnp.maximum(l, 1.0)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("sliding_window", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("sliding_window", "scale", "interpret", "ring"))
 def paged_decode_attention(
     q: jax.Array,  # [B, H, D]
     key_pool: jax.Array,  # [NB, bs, Hkv, D], or lane-folded [NB, bs, Hkv / f, f * D]
@@ -177,12 +178,17 @@ def paged_decode_attention(
     sliding_window: Optional[int] = None,
     scale: Optional[float] = None,
     interpret: bool = False,
+    ring: bool = False,
 ) -> jax.Array:
     """One decode step of attention for every row against its paged KV.
 
     Returns ``[B, H, D]`` in ``q.dtype``. The caller has already written
     the step's K/V into the pool at position ``cur`` (the engine's
-    scatter), so the frontier key is included.
+    scatter), so the frontier key is included. ``ring`` (with a
+    ``sliding_window``): ``block_table`` is a ring of ``MB`` entries, page
+    ``p`` at entry ``p % MB`` (``paged_kv`` ``window_table``); the call's
+    device name then carries the window (``paged_decode_attention_w512``),
+    so that a trace tells a model's two kinds of layer apart.
     """
     from jax.experimental.pallas import tpu as pltpu
 
@@ -198,7 +204,7 @@ def paged_decode_attention(
         mine = lane[:, None] == jnp.arange(fold)[None, :]  # [H, fold]
         wide = jnp.where(mine[None, :, :, None], q[:, :, None, :], 0).reshape(b, heads, fold * d)
         out = paged_decode_attention(wide, key_pool, value_pool, block_table, cur, sliding_window=sliding_window,
-                                     scale=scale, interpret=interpret)
+                                     scale=scale, interpret=interpret, ring=ring)
         return jnp.sum(jnp.where(mine[None, :, :, None], out.reshape(b, heads, fold, d), 0), axis=2)
     b, heads, dim = q.shape
     nb, block_size, kv_heads, _ = key_pool.shape
@@ -228,7 +234,10 @@ def paged_decode_attention(
         kv_heads=kv_heads,
         window=sliding_window,
         scale=scale,
+        ring=ring,
     )
+    if ring and sliding_window is None:
+        raise ValueError("a ring table holds a sliding window's pages: ring=True needs sliding_window")
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, heads, dim), q.dtype),
@@ -236,7 +245,7 @@ def paged_decode_attention(
         # rows run in order on one core: each starts the next one's first copies
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_decode_attention",
+        name=f"paged_decode_attention_w{sliding_window}" if ring else "paged_decode_attention",
     )(
         block_table.astype(jnp.int32),
         cur.astype(jnp.int32),

@@ -217,6 +217,19 @@ class ServingEngine:
     trash sink (default ``num_slots * ceil(max_len / block_size) + 1``,
     i.e. dense-equivalent capacity — pass less to oversubscribe HBM and
     let admission control queue requests when the pool is full).
+
+    **A pool and a table a kind of layer.** A model whose ``layer_types``
+    name both ``sliding_attention`` and ``full_attention`` layers is served,
+    under the paged layout, from two pools: the full layers keep blocks for
+    the whole context under ``block_table``; the window layers keep a pool
+    of their own (``window_pool_blocks``, the sink included; default
+    ``num_slots * ring + 1``) under a ``window_table`` whose ``ring`` entries
+    (``ops.paged_kv.ring_entries``: the pages a band spans and one) a slot
+    uses as a ring, reserved at admission and freed at retirement with no
+    table traffic in between. Admission waits for the scarcer pool. What is
+    not built over the ring is refused by name: chunk windows, prefix reuse,
+    preemption with resume, ``import_inflight`` of a request that has
+    decoded, KV hand-off and export.
     """
 
     @phased("engine.init")
@@ -233,6 +246,7 @@ class ServingEngine:
         seed: int = 0,
         paged_block_size: Optional[int] = None,
         pool_blocks: Optional[int] = None,
+        window_pool_blocks: Optional[int] = None,
         telemetry_log=None,
         program_cache=None,
         scheduler=None,
@@ -289,8 +303,8 @@ class ServingEngine:
             )
         if tick_block < 1:
             raise ValueError(f"tick_block must be >= 1, got {tick_block}")
-        if pool_blocks is not None and paged_block_size is None:
-            raise ValueError("pool_blocks requires paged_block_size (paged mode)")
+        if (pool_blocks is not None or window_pool_blocks is not None) and paged_block_size is None:
+            raise ValueError("pool_blocks and window_pool_blocks require paged_block_size (paged mode)")
         self.eos_token_id = eos_token_id
         self.tick_block = tick_block
         self._seed = seed
@@ -305,6 +319,10 @@ class ServingEngine:
         # ``attention_class == "eva"``), under the paged layout; None for every other engine
         self._aligned: Optional[tuple] = None
         self._tick_windows = (0, 0, 0, 0)  # rows attended, context rows, chunks pooled, windows closed: this tick's
+        # entries of a slot's ring in the window layers' table, for a model with window AND full attention layers
+        # under the paged layout (a pool and a table a kind of layer); None for every other engine
+        self._ring: Optional[int] = None
+        self._tick_window_rows = 0  # rows the window layers' band held for this tick's kept steps: min(t + 1, window)
         if self.paged:
             from .ops.paged_kv import BlockAllocator, PagedConfig, paged_mode
 
@@ -318,7 +336,13 @@ class ServingEngine:
             # by max_len, which submit() enforces
             self._mb = -(-model.config.max_position_embeddings // bs_)
             nb = int(pool_blocks) if pool_blocks is not None else num_slots * (-(-self.max_len // bs_)) + 1
-            self._pcfg = PagedConfig(block_size=bs_, num_blocks=nb)
+            layer_types = getattr(model.config, "layer_types", None) or ()
+            by_kind = {}
+            if "sliding_attention" in layer_types and "full_attention" in layer_types:
+                by_kind = self._init_ring(model.config, bs_, window_pool_blocks)
+            elif window_pool_blocks is not None:
+                raise ValueError("window_pool_blocks is the window layers' pool of a model with window and full attention layers")
+            self._pcfg = PagedConfig(block_size=bs_, num_blocks=nb, **by_kind)
             self._alloc = BlockAllocator(nb)
             self._shared_refs: dict[int, int] = {}  # prefix block id -> refcount
             # per-slot {table entry index -> pool block id}: owned blocks
@@ -333,9 +357,10 @@ class ServingEngine:
             # Per-layer attention kinds (Gemma2 alternating local/global)
             # disable the recycling: a full_attention layer reads EVERY
             # position, so no block ever becomes dead
+            # (with a pool and a table a kind, ``_init_ring``, the window layers' ring recycles by itself and
+            # the full layers' table holds the whole context)
             self._window = getattr(model.config, "sliding_window", None)
-            layer_types = getattr(model.config, "layer_types", None)
-            if layer_types is not None and any(t != "sliding_attention" for t in layer_types):
+            if any(t != "sliding_attention" for t in layer_types):
                 self._window = None
             if getattr(model.config, "attention_class", None) == "eva":
                 self._init_aligned(model.config, bs_)
@@ -519,6 +544,8 @@ class ServingEngine:
         start from a copy of it (blocks are aliased, state is not)."""
         if self._aligned is not None:
             raise NotImplementedError(self._aligned_refusal("prefix reuse (register_prefix)"))
+        if self._ring is not None:
+            raise NotImplementedError(self._ring_refusal("prefix reuse (register_prefix)"))
         toks = np.asarray(prefix_ids, np.int32).ravel()
         if len(toks) == 0:
             raise ValueError("empty prefix")
@@ -631,8 +658,9 @@ class ServingEngine:
                 f"prefix ({plen}) + prompt ({len(prompt)}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds the slot cache ({self.max_len})"
             )
-        if self._aligned is not None and self._bucket_for(len(prompt)) is None:
-            raise NotImplementedError(self._aligned_refusal(
+        if (self._aligned is not None or self._ring is not None) and self._bucket_for(len(prompt)) is None:
+            refusal = self._aligned_refusal if self._ring is None else self._ring_refusal
+            raise NotImplementedError(refusal(
                 f"chunk windows (a prompt of {len(prompt)} tokens, past the largest prefill bucket {self._chunk})"))
         if self.paged:
             need = self._new_blocks_for(plen, len(prompt), max_new_tokens)
@@ -640,6 +668,12 @@ class ServingEngine:
                 raise ValueError(
                     f"request needs {need} pool blocks but the pool has "
                     f"{self._pcfg.num_blocks - 1}; raise pool_blocks or paged_block_size"
+                )
+            # (the window layers' pool holds no prefix: a head that fits it fits once the slots have drained)
+            if self._ring is not None and (ring := self._ring_blocks_for(len(prompt), max_new_tokens)) > self._pcfg.window_blocks - 1:
+                raise ValueError(
+                    f"request needs {ring} blocks of the window layers' pool but it has "
+                    f"{self._pcfg.window_blocks - 1}; raise window_pool_blocks"
                 )
         with phase("engine.submit", uid=self._uid, prompt_tokens=len(prompt), queue_len=len(self.queue)):
             priority = self._admission_shed_check(int(priority), trace=trace)
@@ -672,7 +706,7 @@ class ServingEngine:
         prediction and a router's post-transfer accounting
         (``handoff["wire_bytes"]``) must agree byte-for-byte."""
         jax = _jax()
-        check_handoff_layout(self._row_template)
+        self._check_handoff()
         cap = self.model.config.max_position_embeddings
         per_tok = fixed = 0
         for leaf in jax.tree_util.tree_leaves(self._row_template):
@@ -751,7 +785,7 @@ class ServingEngine:
         chunk windows (radix-cache reuse composes with disaggregation on
         the prefill side)."""
         jax = _jax()
-        check_handoff_layout(self._row_template)
+        self._check_handoff()
         prompt = np.asarray(prompt_ids, np.int32).ravel()
         if len(prompt) == 0:
             raise ValueError("empty prompt")
@@ -799,6 +833,8 @@ class ServingEngine:
         exact vs a local prefill by construction. A later preemption
         resumes by ordinary prefix recompute — the handoff payload is
         consumed at first admission."""
+        if self._ring is not None:
+            self._check_handoff()
         prompt = np.asarray(handoff["prompt"], np.int32).ravel()
         total, max_new = int(handoff["total"]), int(handoff["max_new_tokens"])
         if total != len(prompt):
@@ -878,6 +914,9 @@ class ServingEngine:
         tick that one of them cuts short reads the first tokens it had
         left on the device before it raises (:meth:`_tick_phases`)."""
         jax = _jax()
+        if self._ring is not None:
+            raise NotImplementedError(self._ring_refusal(
+                "export_inflight (a migrated request resumes through chunk windows, or by its K/V rows)"))
         self._flush_clears()
         kv_ok = include_kv and not self.paged
         if kv_ok:
@@ -950,6 +989,8 @@ class ServingEngine:
             raise ValueError(f"snapshot carries {len(out)} tokens > max_new_tokens {max_new}")
         if self._aligned is not None and out:
             raise NotImplementedError(self._aligned_refusal("import_inflight of a request that has decoded (it resumes through chunk windows)"))
+        if self._ring is not None and (out or snap.get("cache") is not None):
+            raise NotImplementedError(self._ring_refusal("import_inflight of a request that has decoded (it resumes through chunk windows, or by its K/V rows)"))
         if len(prompt) + max_new > self.max_len:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_new_tokens ({max_new}) "
@@ -1166,6 +1207,7 @@ class ServingEngine:
         self._tick_expert_load = (0, 0, 0, 0)
         self._tick_state_idle = 0
         self._tick_windows = (0, 0, 0, 0)
+        self._tick_window_rows = 0
         with phase("engine.schedule"):
             now = time.monotonic()
             self._pool_blocked = False
@@ -1199,7 +1241,7 @@ class ServingEngine:
                 head = self.queue[0]
                 with phase(
                     "engine.admit", uid=head.uid, slot=slot, prompt_tokens=len(head.prompt),
-                    queue_wait_ms=(time.monotonic() - head.submit_ts) * 1000.0,
+                    queue_wait_ms=(time.monotonic() - head.submit_ts) * 1000.0, **self._blocks_by_kind(head),
                 ):
                     if not self._admit(slot):
                         break  # pool blocked: the whole queue waits on its head
@@ -1228,17 +1270,22 @@ class ServingEngine:
             self._close_windows()
             pages = (sum(map(len, self._slot_blocks)), sum(map(len, self._slot_summary)))
             m.on_pages_held(*pages)
+        by_kind = (0, 0)
+        if self._ring is not None:
+            by_kind = (sum(map(len, self._slot_blocks)), sum(map(len, self._slot_ring)))
+            m.on_pages_by_kind(*by_kind)
         with phase(
             "engine.tick.done", admitted=admitted, first_tokens_deferred=self._tick_first_deferred,
             clears_deferred=self._tick_clears_deferred, leaves_signed=leaves_signed,
             prefill_tokens=self._tick_prefill_tokens, head_rows=self._tick_head_rows,
             emitted=m.tokens_generated - tokens_was, retired=m.requests_completed - completed_was,
-            pool_blocked=int(self._pool_blocked), free_blocks=self._alloc.free_count if self.paged else -1,
+            pool_blocked=int(self._pool_blocked), free_blocks=self.pool_free_blocks if self.paged else -1,
             queue_len=len(self.queue), experts_touched=self._tick_expert_load[0],
             expert_pairs_max=self._tick_expert_load[1], expert_tile_visits=self._tick_expert_load[2],
             expert_pairs=self._tick_expert_load[3], state_slots_idle=self._tick_state_idle,
             attn_rows_read=self._tick_windows[0], context_rows=self._tick_windows[1], chunks_pooled=self._tick_windows[2],
             windows_closed=self._tick_windows[3], exact_pages=pages[0], summary_pages=pages[1],
+            window_rows_read=self._tick_window_rows, full_pages=by_kind[0], window_pages=by_kind[1],
         ):
             pass
 
@@ -1312,6 +1359,9 @@ class ServingEngine:
         new_ids = self._alloc.alloc((hi - lo) - len(shared_entries))
         if new_ids is None:
             return None
+        if self._ring is not None and not self._reserve_ring(plen + prompt_len, max_new):
+            self._alloc.free(new_ids)  # the window layers' pool is the scarcer: nothing is held while the request waits
+            return None
         for bid in shared_entries.values():
             self._shared_refs[bid] += 1
         table = np.zeros((self._mb,), np.int32)  # pad/out-of-band -> trash sink
@@ -1376,6 +1426,8 @@ class ServingEngine:
             st["table"], st["write_row"] = table, write_row
             if self._aligned is not None:
                 self._slot_summary[slot], st["summary_row"], self._slot_last[slot] = self._reserved_summary
+            if self._ring is not None:
+                self._slot_ring[slot], st["window_row"] = self._reserved_ring
         # the per-request sampling chain: the uid folded into the engine's key at first admission (by the
         # program that samples the first token: no eager program runs here), the evicted chain carried across a
         # preemption — the resumed stream continues the SAME chain, so sampled outputs stay request-exact
@@ -1534,10 +1586,11 @@ class ServingEngine:
             # the retirements' clears, behind the prefill that was just dispatched and ahead of the paste
             self._flush_clears(deferred=True)
             if self.paged:
-                summaries = () if self._aligned is None else (st["summary_row"],)
+                # the second table's row, where the cache has one: an EVA layer's summary pages, a window layer's ring
+                second = (st["summary_row"],) if self._aligned is not None else (st["window_row"],) if self._ring is not None else ()
                 self.slot_caches, self._slot_keys = self._paste(
                     self.slot_caches, self._slot_keys, row_cache, key, st["write_row"], st["table"],
-                    np.int32(slot), np.int32(total), *summaries,
+                    np.int32(slot), np.int32(total), *second,
                 )
             else:
                 self.slot_caches, self._slot_keys = self._insert(
@@ -1665,7 +1718,7 @@ class ServingEngine:
                 self.metrics.on_expert_load(*self._tick_expert_load)
         with phase("engine.decode.walk"):
             # (first position, tokens kept) a decoding slot: what an aligned window's counts are made of
-            kept = None if self._aligned is None else []
+            kept = None if self._aligned is None and self._ring is None else []
             toks_by_slot, lps_by_slot = toks_k.T.tolist(), lps_k.T.tolist()  # Python ints and floats, once for all slots
             for slot, req in enumerate(self.slot_req):
                 if req is None or self.slot_phase[slot] != "decode":
@@ -1682,8 +1735,10 @@ class ServingEngine:
                     kept.append((first, n_new))
                 if retired:
                     self._retire(slot)  # the host's books now; the slot's clear goes behind the next tick's first program
-            if kept:
+            if kept and self._aligned is not None:
                 self._count_window_attention(np.asarray(kept))
+            elif kept:
+                self._count_ring_attention(np.asarray(kept))
 
     def _take_tokens(self, req: _Request, toks: list, lps: list) -> tuple:
         """Give ``req`` the tokens it keeps of its column of a tick's block (``toks``, ``lps``: lists), in one
@@ -1812,14 +1867,83 @@ class ServingEngine:
         steps kept)`` a decoding slot): rows of keys attended (``frontier + 1``: a summary a chunk of the closed
         windows, the open window's rows), rows of context (``t + 1``), chunks pooled, windows closed."""
         window, chunk = self._aligned
-        k = np.arange(self.tick_block)[None, :]
-        t, valid = kept[:, :1] + k, k < kept[:, 1:]
+        t, valid = self._kept_steps(kept)
         rows = (window // chunk) * (t // window) + t % window + 1
         self._tick_windows = (
             int(rows[valid].sum()), int((t + 1)[valid].sum()), int((valid & (t % chunk == chunk - 1)).sum()),
             int((valid & (t % window == window - 1)).sum()),
         )
         self.metrics.on_window_attention(*self._tick_windows)
+
+    # ---- a pool and a table a kind of layer: window layers' rings beside full layers' tables --------------------
+
+    def _init_ring(self, config, block: int, window_pool_blocks: Optional[int]) -> dict:
+        """A model with window AND full attention layers. The full layers keep what the engine has: blocks for the
+        whole context under ``block_table``, from ``_alloc``. The window layers get a pool of their own
+        (``_alloc_w``) and a ``window_table`` of ``ring`` entries a slot, used as a ring (position ``p`` at entry
+        ``(p // block) % ring``): a slot's ring is reserved at admission (``_slot_ring``: no more blocks than the
+        request will ever touch), written once by its paste, and freed at retirement; between the two no program
+        touches the table and nothing expires. Returns the two fields of the ``PagedConfig``. What is not built
+        over the ring is refused by name, here or at its call."""
+        from .ops.paged_kv import BlockAllocator, ring_entries
+
+        if self._sched.config.enable_preemption:
+            raise NotImplementedError(self._ring_refusal("preemption with resume (SchedulerConfig.enable_preemption)"))
+        self._ring = ring_entries(config.sliding_window, block, config.max_position_embeddings)
+        blocks = int(window_pool_blocks) if window_pool_blocks is not None else self.num_slots * self._ring + 1
+        self._alloc_w = BlockAllocator(blocks)
+        self._slot_ring: list[list] = [[] for _ in range(self.num_slots)]  # a slot's blocks of the window pool, by entry
+        self._reserved_ring = ([], None)
+        return {"window_ring": self._ring, "window_blocks": blocks}
+
+    @staticmethod
+    def _ring_refusal(what: str) -> str:
+        return (f"{what} is not built over a ring table: a model with window and full attention layers keeps the window "
+                "layers' rows in a pool of their own under a ring of entries a slot, which holds the last window alone; "
+                "serve it with bucketed prefill, no prefix reuse and no preemption")
+
+    def _check_handoff(self) -> None:
+        check_handoff_layout(self._row_template)
+        if self._ring is not None:
+            raise NotImplementedError(self._ring_refusal("KV hand-off (kv_handoff_dims, prefill_detached, submit_prefilled)"))
+
+    def _ring_blocks_for(self, total: int, max_new: int) -> int:
+        """Blocks of the window layers' pool a request reserves: the pages it will ever touch (through its last
+        kept write, position ``total + max_new - 2``), the ring's entries at most."""
+        return min(self._ring, (total + max_new - 2) // self._pcfg.block_size + 1)
+
+    def _reserve_ring(self, total: int, max_new: int) -> bool:
+        ids = self._alloc_w.alloc(self._ring_blocks_for(total, max_new))
+        if ids is None:
+            return False
+        window_row = np.zeros((self._ring,), np.int32)  # an entry the request never reaches -> trash sink
+        window_row[: len(ids)] = ids
+        self._reserved_ring = (ids, window_row)
+        return True
+
+    def _blocks_by_kind(self, req: _Request) -> dict:
+        """``engine.admit``'s counts of the blocks the admission reserves by kind; nothing for a model of one kind."""
+        if self._ring is None:
+            return {}
+        plen, prompt_len, max_new = self._request_block_dims(req)
+        return {"full_blocks": self._new_blocks_for(plen, prompt_len, max_new),
+                "window_blocks": self._ring_blocks_for(plen + prompt_len, max_new)}
+
+    def _kept_steps(self, kept: np.ndarray) -> tuple:
+        """``(t, valid)``, each ``[decoding slots, tick_block]``: the position of every step of the tick and whether the
+        slot kept it (``kept``: a row ``(first position, steps kept)`` a decoding slot)."""
+        k = np.arange(self.tick_block)[None, :]
+        return kept[:, :1] + k, k < kept[:, 1:]
+
+    def _count_ring_attention(self, kept: np.ndarray) -> None:
+        """The tick's counts for layers of two kinds, from its kept steps alone: rows of context (``t + 1``: what a
+        full layer reads) and rows inside the band (``min(t + 1, window)``: what a window layer reads)."""
+        t, valid = self._kept_steps(kept)
+        context = int((t + 1)[valid].sum())
+        self._tick_window_rows = int(np.minimum(t + 1, self.model.config.sliding_window)[valid].sum())
+        self._tick_windows = (0, context, 0, 0)
+        self.metrics.on_window_attention(0, context, 0, 0)
+        self.metrics.on_window_rows(self._tick_window_rows)
 
     def _expire_window_blocks(self) -> None:
         """Sliding-window models: expire blocks the band can no longer
@@ -2020,8 +2144,10 @@ class ServingEngine:
 
     @property
     def pool_free_blocks(self) -> Optional[int]:
-        """Free blocks in the paged pool (None in dense mode)."""
-        return self._alloc.free_count if self.paged else None
+        """Free blocks in the paged pool (None in dense mode); with a pool a kind of layer, the scarcer's."""
+        if not self.paged:
+            return None
+        return self._alloc.free_count if self._ring is None else min(self._alloc.free_count, self._alloc_w.free_count)
 
     def _retire(self, slot: int):
         req = self.slot_req[slot]
@@ -2076,6 +2202,9 @@ class ServingEngine:
             if self._aligned is not None:
                 self._alloc.free(list(self._slot_summary[slot].values()))
                 self._slot_summary[slot] = {}
+            if self._ring is not None:
+                self._alloc_w.free(self._slot_ring[slot])
+                self._slot_ring[slot] = []
             for bid in self._slot_shared[slot].values():
                 self._shared_refs[bid] -= 1
             self._slot_shared[slot] = {}

@@ -172,6 +172,13 @@ def paste_row(paged_cache, keys, row_cache, key, write_row, table_row, slot, new
     return pasted, keys.at[slot].set(key)
 
 
+def paste_row_ring(paged_cache, keys, row_cache, key, write_row, table_row, slot, new_index, window_row):
+    """:func:`paste_row` for a cache with a pool and a table a kind of layer: ``window_row`` is the slot's ring, and
+    the window layers take the last window of the row cache alone (``paged_kv.paste_row(window_row=)``)."""
+    pasted = paged_kv.paste_row(paged_cache, row_cache, write_row, table_row, slot, new_index, window_row=window_row)
+    return pasted, keys.at[slot].set(key)
+
+
 def feed_first_token(toks, slot, tok):
     """``toks`` with a pending admission's first token in its slot: the token goes from the prefill
     to the decode tick without a visit to the host. One shape, called once a pending admission."""
@@ -358,7 +365,9 @@ class EnginePrograms:
 
             raw_tick = make_tick(make_paged_step(apply_fn, sampler), tick_block)
             self.decode_tick = ctx_jit(program_cache, tick_ctx, raw_tick, name="paged_decode_tick", donate_argnums=(1,))
-            self.paste_row, self.paste_blocks = jit(paste_row, donate_argnums=(0,)), jit(paged_kv.paste_blocks, donate_argnums=(0,))
+            ringed = paged_config.window_ring is not None
+            self.paste_row = jit(paste_row_ring if ringed else paste_row, name="paste_row" if ringed else None, donate_argnums=(0,))
+            self.paste_blocks = jit(paged_kv.paste_blocks, donate_argnums=(0,))
             self.clear_slots = jit(paged_kv.clear_slots, donate_argnums=(0,))
             self.set_table_row = jit(paged_kv.set_table_row, donate_argnums=(0,))
         else:

@@ -155,6 +155,11 @@ class ServingMetrics:
         self.windows_closed = 0
         self.exact_pages_held = 0
         self.summary_pages_held = 0
+        # layers of two kinds (window and full attention, a pool and a table a kind): rows inside the band for the
+        # kept steps (``min(t + 1, window)``, beside ``context_rows``), and the pages held by kind at the last tick's end
+        self.window_rows_read = 0
+        self.full_pages_held = 0
+        self.window_pages_held = 0
         # admissions whose first token the host read behind the decode
         # dispatch of their tick (``first_tokens_deferred`` of
         # ``engine.tick.done``); ``prefills`` less it took the road with a
@@ -254,6 +259,12 @@ class ServingMetrics:
 
     def on_pages_held(self, exact: int, summary: int):
         self.exact_pages_held, self.summary_pages_held = exact, summary
+
+    def on_window_rows(self, rows: int):
+        self.window_rows_read += rows
+
+    def on_pages_by_kind(self, full: int, window: int):
+        self.full_pages_held, self.window_pages_held = full, window
 
     def on_first_tokens_deferred(self, n: int):
         self.first_tokens_deferred += n

@@ -14,6 +14,7 @@ the compile-cache helper must follow the one placement rule.
 
 import functools
 import os
+import re
 import subprocess
 import sys
 import time
@@ -574,6 +575,84 @@ def test_evabyte_prefill_bucket_and_paste_compile_for_v5e(v5e, monkeypatch):
     assert not moved, "paste_row copies or re-lays a pool:\n" + "\n".join(moved)
     pm = pasted.memory_analysis()
     assert pm.temp_size_in_bytes < 64 * 2**20 and pm.alias_size_in_bytes > 9.9e9
+
+
+@pytest.mark.parametrize("heads,window,table,blocks,name", [(48, None, 320, 10_241, "paged_decode_attention"),
+                                                           (64, 512, 34, 2_177, "paged_decode_attention_w512")],
+                         ids=["full_layers_groups_of_6", "window_layers_ring_of_34"])
+def test_paged_decode_attention_compiles_at_laguna_widths_under_both_tables(v5e, heads, window, table, blocks, name):
+    """The two shapes of the kernel in ``laguna-xs.2-serve-longchat``'s tick: 48 query heads on 8 key/value heads
+    (groups of 6, new to the kernel) against the whole context's table, and 64 heads under a band of 512 keys against
+    a ring of 34 entries a slot (``tbl_ref[row, page % 34]``), each under a device name of its own."""
+    from accelerate_tpu.ops.pallas_paged_attention import paged_decode_attention
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    pool = _on(chip, (blocks, 16, KV_HEADS, DIM))
+    fn = functools.partial(paged_decode_attention, sliding_window=window, interpret=False, ring=window is not None)
+    text = _compile(fn, _on(chip, (64, heads, DIM)), pool, pool, _on(chip, (64, table), jnp.int32), _on(chip, (64,), jnp.int32))
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l and "custom-call(" in l]
+    assert len(calls) == 1 and re.match(rf"\s*(ROOT )?%?{name}(\.\d+)* =", calls[0]), calls[0][:120]
+    assert not [l for l in text.splitlines() if f"[{blocks}," in l and " copy(" in l], "the pool is copied on its way to the kernel"
+
+
+def test_laguna_tick_prefill_and_paste_compile_for_v5e_and_move_no_pool(v5e, monkeypatch):
+    """The three programs of ``laguna-xs.2-serve-longchat`` at their real size, one engine: the 64-slot decode tick (3.38 B
+    parameters, four pools of ``[10241, 16, 8, 128]`` and nine of ``[2177, 16, 8, 128]`` for K and for V, 3.97 GB, all
+    aliased to the output; 4 + 9 paged kernels a step under their two names and 12 + 12 grouped expert products), the
+    4096-token prefill (13 flash calls, banded on the window layers, no float32 score matrix: under 0.6 GiB of
+    temporaries beside a 273 MB row cache) and ``paste_row`` with the slot's ring (the window layers' last 34 pages
+    cut out of the row cache and scattered in place). Inside 15.75 GiB with 1 GB to spare. A compile is not a chip run."""
+    import json
+
+    from accelerate_tpu.models.llama import _wrap_llama
+    from accelerate_tpu.serving import ServingEngine
+    from accelerate_tpu.serving_programs import paste_row_ring
+    from chipbench import run
+
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    with open(os.path.join(REPO, "chipbench", "configs", "laguna-xs.2-l13-ep4.json")) as f:
+        config = json.load(f)
+    builder = run.load(manifest, "builders", config["bench"]["builder"])
+    cfg = builder.core_config(config)
+    module, shapes = builder.abstract_params(cfg)
+    s = config["bench"]["serving"]
+    engine = ServingEngine(
+        _wrap_llama(module, jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, BF16), shapes), cfg), num_slots=s["num_slots"],
+        prompt_buckets=tuple(s["prompt_buckets"]), max_len=s["max_len"], paged_block_size=s["paged_block_size"],
+        pool_blocks=s["pool_blocks"], window_pool_blocks=s["window_pool_blocks"], tick_block=s["tick_block"])
+    assert (engine._ring, engine._mb, engine._pcfg.window_blocks, engine._pcfg.num_blocks) == (34, 320, 2_177, 10_241)
+    chip = SingleDeviceSharding(v5e.devices[0])
+    on = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)  # noqa: E731
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the programs ask whether to lower the kernels
+    tick = engine._perf_programs["decode_tick"]
+    compiled = tick.lower(*on(tick.args(None)), donate_argnums=(1,)).compile()
+    text = compiled.as_text()
+    calls = [l.strip() for l in text.splitlines() if "tpu_custom_call" in l and "custom-call(" in l]
+    named = lambda name: sum(bool(re.match(rf"(ROOT )?%?{name}(\.\d+)* =", l)) for l in calls)  # noqa: E731
+    assert (named("paged_decode_attention"), named("paged_decode_attention_w512")) == (4, 9), "two shapes of the kernel, by name"
+    assert (named("ragged-dot-swiglu"), named("ragged-dot-down")) == (12, 12)
+    pools = r"bf16\[(10241|2177),16,8,128\]"
+    moved = [l.strip()[:160] for l in text.splitlines() if re.search(rf"= {pools}\S* (copy|transpose)\(", l)]
+    assert not moved, "the tick copies or re-lays a pool:\n" + "\n".join(moved)
+    m = compiled.memory_analysis()
+    pool_bytes = (4 * 10_241 + 9 * 2_177) * 65_536
+    assert m.alias_size_in_bytes >= pool_bytes and m.argument_size_in_bytes > 6.76e9 + pool_bytes and m.temp_size_in_bytes < 0.7 * 2**30
+    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert total <= 14.75 * 2**30, f"the 64-slot tick needs {total / 2**30:.2f} GiB"
+    prefill = engine._perf_programs["prefill"]
+    compiled = prefill.lower(*on(prefill.args(4096))).compile()
+    pm = compiled.memory_analysis()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 13 + 24, "a flash call a layer beside the grouped products"
+    assert pm.temp_size_in_bytes < 0.6 * 2**30 and 0.25 * 2**30 < pm.output_size_in_bytes < 0.26 * 2**30
+    assert not re.search(r"f32\[(1,)?(48|64),4096,5120\]", compiled.as_text()), "a bucket's float32 scores against the whole cache are formed"
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)  # noqa: E731
+    keys, key = on(jax.eval_shape(lambda: jax.random.split(jax.random.key(0), 64))), on(jax.eval_shape(lambda: jax.random.key(0)))
+    pasted = jax.jit(paste_row_ring, donate_argnums=(0,)).lower(
+        on(engine.slot_caches), keys, on(engine._row_template), key, i32(320), i32(320), i32(), i32(), i32(34)).compile()
+    moved = [l.strip()[:160] for l in pasted.as_text().splitlines() if re.search(rf"= {pools}\S* (copy|transpose)\(", l)]
+    assert not moved, "paste_row copies or re-lays a pool:\n" + "\n".join(moved)
+    assert pasted.memory_analysis().temp_size_in_bytes < 64 * 2**20 and pasted.memory_analysis().alias_size_in_bytes >= pool_bytes
 
 
 @pytest.mark.parametrize(
