@@ -84,7 +84,6 @@ class LagunaConfig(LlamaConfig):
     scoring_func: str = "sigmoid"
     norm_topk_prob: bool = True
     scan_layers: bool = False  # layers of two kinds: unrolled
-    cold_prefill: bool = True  # 64 heads x a 4096 bucket x 5120 rows of float32 scores is 5.4 GB: the flash kernel
     # the published names of keys the core has under another
     num_experts: int = 256
     shared_expert_intermediate_size: int = 512

@@ -206,12 +206,6 @@ class LlamaConfig:
     partial_rotary_factor: float = 1.0  # the share of a head's values that rotary turns, its first ones
     attn_gate: bool = False
     mlp_layer_types: Optional[tuple] = None
-    # A prefill that STARTS a cache (``decode=True``, no cache yet, outside the paged layout) attends over its
-    # own tokens through ``_dispatch_attention`` (the flash kernel, banded under a window, at flash lengths)
-    # and stores its rows, where ``cached_attention`` takes a masked product against all ``max_len`` rows
-    # of the new cache: ``[heads, bucket, max_len]`` float32 scores, 5.4 GB at 64 heads, 4096 and 5120. Every
-    # model whose configuration does not ask keeps the masked product, and its prefill programs.
-    cold_prefill: bool = False
 
     def mixer_kind(self, i: int) -> str:
         """The mixer of layer ``i``: ``"conv"`` or ``"mamba"`` where ``layer_types`` says so (a ``"mamba"``
@@ -527,7 +521,8 @@ def rope(
 
 
 def _dispatch_attention(
-    q, k, v, impl: str, sliding_window: Optional[int] = None, scale=None, logit_softcap=None
+    q, k, v, impl: str, sliding_window: Optional[int] = None, scale=None, logit_softcap=None,
+    forward_only: bool = False,
 ):
     """Pick the attention path: context-parallel (ring / all-to-all) when
     the active mesh has a non-trivial ``seq`` axis, else dense/flash. This
@@ -535,7 +530,10 @@ def _dispatch_attention(
     rewrite (SURVEY §5). ``sliding_window`` adds a Mistral-style band on
     EVERY path: the XLA mask at short lengths, the banded flash kernel
     (O(S*W)) at flash lengths on TPU, and absolute-position masking
-    inside the ring / all-to-all schedules on seq-sharded meshes."""
+    inside the ring / all-to-all schedules on seq-sharded meshes.
+    ``forward_only``: the caller never differentiates this call (a
+    prefill that starts a cache), so the dense path chooses its kernel
+    by the forward pass alone (``ops.attention.prefers_flash``)."""
     if impl not in ("auto", "ring", "all_to_all", "dense"):
         raise ValueError(f"attention_impl must be auto|ring|all_to_all|dense, got {impl!r}")
     mesh = None
@@ -570,7 +568,7 @@ def _dispatch_attention(
     # set — the kernel has no tanh-cap branch)
     return dot_product_attention(
         q, k, v, causal=True, mesh=mesh, window=sliding_window, scale=scale,
-        logit_softcap=logit_softcap,
+        logit_softcap=logit_softcap, forward_only=forward_only,
     )
 
 
@@ -619,12 +617,10 @@ class LlamaAttention(nn.Module):
             scale = float(cfg.attention_multiplier)  # Granite: the scale itself, not a power of the head size
         if cfg.attention_class is not None:
             out = self._eva_attention(q, k, v, scale, decode)
-        elif decode and cfg.cold_prefill:
+        elif decode:
             # a device operation's name carries the kind of layer it belongs to
             with jax.named_scope("attn.full" if cfg.sliding_window is None else "attn.window"):
                 out = self._cold_or_cached_attention(q, k, v, scale, new_span)
-        elif decode:
-            out = self._cached_attention(q, k, v, scale, new_span)
         else:
             out = _dispatch_attention(
                 q, k, v, cfg.attention_impl, cfg.sliding_window,
@@ -638,9 +634,12 @@ class LlamaAttention(nn.Module):
         return _dense(cfg, cfg.hidden_size, "o_proj", hidden.dtype)(out)
 
     def _cold_or_cached_attention(self, q, k, v, scale, new_span):
-        """``cold_prefill``: the call that starts a dense cache stores its rows and attends over them alone,
-        through :func:`_dispatch_attention`; every later call (a decode step, a warm chunk window, the paged
-        layout) is :meth:`_cached_attention`'s."""
+        """``decode=True``: the call that starts a dense cache stores its rows and attends over them alone,
+        through :func:`_dispatch_attention` as a call nobody differentiates (``cached_attention`` would score
+        them against all ``max_len`` rows of a cache that was empty a moment ago: ``[heads, S, max_len]``
+        float32); every later call (a decode step, a warm chunk window, the paged layout) is
+        :meth:`_cached_attention`'s. ``"dense"``: a cache's rows are not sharded over ``seq``, so no call with
+        a cache takes the ring / all-to-all schedules, whatever ``attention_impl`` says of a training step."""
         from ..ops import kv_cache, paged_kv
 
         cfg = self.config
@@ -648,7 +647,7 @@ class LlamaAttention(nn.Module):
             return self._cached_attention(q, k, v, scale, new_span)
         kv_cache.start_cache(self, k, v, cfg.max_position_embeddings)
         return _dispatch_attention(
-            q, k, v, cfg.attention_impl, cfg.sliding_window, scale=scale, logit_softcap=cfg.attn_logit_softcap)
+            q, k, v, "dense", cfg.sliding_window, scale=scale, logit_softcap=cfg.attn_logit_softcap, forward_only=True)
 
     def _cached_attention(self, q, k, v, scale=None, new_span=None):
         """KV-cache incremental attention (generation path; shared cache
@@ -766,7 +765,7 @@ class LatentAttention(nn.Module):
             q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
             # one head size for the dispatched kernels: v padded with zeros to the keys' width, cut after
             v = jnp.pad(kv[..., nope:], ((0, 0),) * 3 + ((0, nope + rot - vd),))
-            out = _dispatch_attention(q_full, k, v, cfg.attention_impl, scale=scale)[..., :vd]
+            out = _dispatch_attention(q_full, k, v, cfg.attention_impl, scale=scale, forward_only=cold)[..., :vd]
         out = out.reshape(*out.shape[:-2], heads * vd)
         return _dense(cfg, cfg.hidden_size, "o_proj", dt)(out)
 

@@ -5,10 +5,15 @@ The reference framework has no attention kernels at all (it delegates to
 torch models); this module exists because the build is a *framework with a
 model zoo* and attention is the hot op. Dispatch policy:
 
-* small/medium sequence or non-TPU backend -> plain XLA einsum attention
-  (XLA fuses the softmax chain well);
-* long sequence on TPU -> Pallas flash attention
-  (:mod:`accelerate_tpu.ops.flash_attention`), O(S) memory;
+* non-TPU backend, or a mask, dropout or softcap the kernel has no branch
+  for -> plain XLA einsum attention (XLA fuses the softmax chain well);
+* on TPU the automatic choice is a pure function of the call's shapes and
+  of whether it may be differentiated (:func:`prefers_flash`): Pallas flash
+  attention (:mod:`accelerate_tpu.ops.pallas_attention`, O(S) memory, the
+  scores in a VMEM tile) from ``FLASH_MIN_SEQ`` query positions for a call
+  with a backward pass, from ``FLASH_MIN_SEQ_FORWARD`` for a forward-only
+  one (a prefill that starts a cache says so: ``forward_only=True``); XLA's
+  product below. Each constant says what it was measured on;
 * ``seq``-sharded activations -> ring attention
   (:mod:`accelerate_tpu.parallel.ring_attention`).
 """
@@ -20,11 +25,33 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-# Below this many query positions the quadratic XLA path is faster than the
-# Pallas kernel's grid overhead. Measured on v5e (fwd+bwd, batch 4 x 12
-# heads x 64 dim, value-fetch sync): seq 1024 flash is 0.86x XLA, seq 2048
-# flash is 1.82x — the crossover sits between them.
+# A call that may be differentiated: below this many query positions the
+# quadratic XLA path is faster than the Pallas kernels' grid overhead.
+# Measured on v5e, forward AND backward (three kernels), batch 4 x 12
+# heads x 64 dim (BERT-like), value-fetch sync: seq 1024 flash is 0.86x
+# XLA, seq 2048 flash is 1.82x — the crossover sits between them. No cell
+# measures a train step at 1024-2047 tokens, so it stands as it was.
 FLASH_MIN_SEQ = 2048
+# A forward-only call (a bucket's prefill: one sequence, no backward
+# kernels, XLA materialises [heads, S, S] float32 where the kernel keeps a
+# [512, 512] tile). Measured on v5e, eight dependent calls a program
+# (PERF.md 6, PR 49), kernel over XLA in ms a call: at 1024 queries 0.34 /
+# 0.53 (32 heads x 128 on 8 K/V heads, mistral and granite), 0.47 / 0.76
+# (48 x 128), 0.60 / 1.28 (64 x 128 under a band of 512), 0.33 / 0.52 (32 x
+# 64), 0.23 / 0.24 (20 x 128 on one K/V head), 0.39 / 0.59 (32 x 192); at
+# 512, 256, 128 and 64 XLA wins at every one of them (the kernel 1.2-1.9x
+# XLA: 0.15 / 0.12 at 512, 0.12 / 0.08 at 256, 0.09 / 0.06 at 64). Inside a
+# bucket's program XLA's product is dearer than alone (3.25 ms a layer at
+# 64 x 1024 x 1024 where the kernel is 0.50), so the crossover is no lower.
+FLASH_MIN_SEQ_FORWARD = 1024
+
+
+def prefers_flash(sq: int, sk: int, heads: int, head_dim: int, *, forward_only: bool = False) -> bool:
+    """The automatic choice between the flash kernel and XLA's product, as a pure function of the call's
+    shapes (on a TPU, with no mask, dropout or softcap in the way: :func:`dot_product_attention` asks those
+    first). The chip read one crossover for every head count and head size the cells run, so ``sq`` alone
+    decides today; the other shapes are the rule's to read when a cell says they matter."""
+    return sq >= (FLASH_MIN_SEQ_FORWARD if forward_only else FLASH_MIN_SEQ)
 
 
 def dot_product_attention(
@@ -40,6 +67,7 @@ def dot_product_attention(
     mesh=None,  # pin the mesh for the sharded pallas path (else read from state at trace time)
     window: Optional[int] = None,  # Mistral band: keys <= q_pos - window are masked
     logit_softcap: Optional[float] = None,  # Gemma2: tanh-bound scores (XLA path only)
+    forward_only: bool = False,  # the caller never differentiates this call: the choice reads the forward pass alone
 ) -> jax.Array:
     """Multi-head attention with optional GQA (H_kv divides H) and
     flash-kernel dispatch. Causal masking is bottom-right aligned when
@@ -60,7 +88,7 @@ def dot_product_attention(
     if use_flash is None:
         use_flash = (
             jax.default_backend() == "tpu"
-            and seq_len >= FLASH_MIN_SEQ
+            and prefers_flash(seq_len, k.shape[1], q.shape[2], head_dim, forward_only=forward_only)
             and mask is None  # kernel supports causal/banded masking only
             and dropout_rate == 0.0
             and logit_softcap is None  # the kernel has no tanh-cap branch

@@ -83,20 +83,6 @@ def test_a_layers_place_decides_its_heads_its_rotary_rule_and_its_feed_forward(m
     assert "g_proj" not in plain.params["layer_0"]["attn"] and plain.config.layer_overrides is not None
 
 
-def test_a_cold_prefill_then_cached_decode_is_the_forward_pass(model):
-    """The dense cache's two paths: the call that starts the cache attends over its own tokens (``cold_prefill``:
-    ``_dispatch_attention``, banded on a window layer) and stores its rows; a step attends against the cache."""
-    tokens = np.random.default_rng(0).integers(5, 250, size=40).astype(np.int32)
-    full = _forward(model, tokens)[:40]
-    logits, cache = model.apply_fn(model.params, jnp.asarray(tokens[None, :24]), positions=jnp.arange(24)[None], decode=True, cache=None)
-    np.testing.assert_allclose(np.asarray(logits)[0], full[:24], atol=TOLERANCE)
-    assert {p[-1].key for p, _ in jax.tree_util.tree_flatten_with_path(cache)[0]} == {"key", "value", "index"}
-    for t in range(24, 40):
-        step, cache = model.apply_fn(model.params, jnp.asarray(tokens[None, t : t + 1]), positions=jnp.array([[t]]), decode=True, cache=cache)
-        np.testing.assert_allclose(np.asarray(step)[0, 0], full[t], atol=TOLERANCE)
-    assert np.abs(full).max() > 1.0
-
-
 # -- the ring: the kernel, the paste, the engine
 
 @pytest.mark.parametrize("heads,kv_heads", [(6, 1), (8, 1), (6, 2), (16, 2)], ids=["groups_of_6", "groups_of_8", "groups_of_3", "groups_of_8_on_2"])

@@ -230,7 +230,8 @@ def _cell_engine(config_name):
     with open(os.path.join(REPO, "chipbench", "configs", config_name + ".json")) as f:
         config = json.load(f)
     builder = run.load(manifest, "builders", config["bench"]["builder"])
-    cfg = builder.core_config(config)
+    cfg = getattr(builder, "core_config", None) or builder.mistral_config  # the chat cell's builder names its family
+    cfg = cfg(config)
     module, shapes = builder.abstract_params(cfg)
     shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, BF16), shapes)
     s = config["bench"]["serving"]
@@ -238,6 +239,29 @@ def _cell_engine(config_name):
         _wrap_llama(module, shapes, cfg), num_slots=s["num_slots"], prompt_buckets=tuple(s["prompt_buckets"]),
         max_len=s["max_len"], paged_block_size=s["paged_block_size"], pool_blocks=s["pool_blocks"],
     )
+
+
+@pytest.mark.parametrize("bucket,temp_mib", [(64, 1), (256, 1), (1024, 32)])
+def test_chat_cell_prefill_bucket_attends_over_its_own_rows_on_v5e(v5e, monkeypatch, bucket, temp_mib):
+    """A bucket of ``mistral7b-serve-chat`` at its real size, sixteen layers under the layer scan: the call that starts
+    the row cache scores its own rows and not the 4,096 rows of a cache that was empty a moment ago (``f32[1,8,4,
+    bucket,4096]``, 537 MB a layer at 1024: 267 MiB of temporaries), through the kernel ``prefers_flash`` names for a
+    forward-only call of its shape (one Mosaic call in the scan's body, or none), and still hands back 4,096 rows."""
+    from accelerate_tpu.ops.attention import prefers_flash
+
+    s, engine = _cell_engine("mistral-7b-v0.1-l16")
+    chip = SingleDeviceSharding(v5e.devices[0])
+    prefill = engine._perf_programs["prefill"]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), prefill.args(bucket))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the program asks whether to lower the kernel
+    compiled = prefill.lower(*args).compile()
+    text = compiled.as_text()
+    # scores have a head axis or two ahead of ``[bucket, max_len]``; the hidden rows are ``[1, bucket, 4096]`` too
+    assert not re.search(rf"f32\[(\d+,){{2,}}{bucket},{s['max_len']}\]", text), "scores against the whole cache"
+    assert text.count('custom_call_target="tpu_custom_call"') == int(prefers_flash(bucket, bucket, HEADS, DIM, forward_only=True))
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_mib * 2**20
+    cache = jax.eval_shape(prefill.fn, *args)[2]
+    assert cache["layers"]["block"]["attn"]["key"].shape == (16, 1, s["max_len"], KV_HEADS, DIM)
 
 
 def test_latent_moe_decode_tick_fits_one_v5e_chip_and_moves_no_pool(v5e, monkeypatch):
