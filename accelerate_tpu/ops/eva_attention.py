@@ -247,6 +247,7 @@ def eva_paged_attention(module, q, k, v, mu, phi, max_len: int, *, window: int, 
 
         run = paged_kv._kernel_runner(functools.partial(paged_decode_attention, scale=scale, interpret=not on_tpu), h, h)
         if run is not None:
-            # a slot that stores into the sink is idle, or finished and overshooting: one page, as in paged_cached_attention
-            return run(q[:, 0], key_pool, value_pool, table, jnp.where(dest == 0, 0, frontier))[:, None]
+            # a slot that stores into the sink is idle, or finished and overshooting: no keys, no copy and no fold, as
+            # in paged_cached_attention
+            return run(q[:, 0], key_pool, value_pool, table, jnp.where(dest == 0, paged_kv.NO_KEYS, frontier))[:, None]
     return paged_kv.paged_gather_attention(q, key_pool, value_pool, table, frontier, scale=scale)

@@ -21,10 +21,11 @@ shapes) allocates cache in fixed-size *blocks* from one shared pool:
   :mod:`.pallas_latent_attention`, which walks a slot's live pages as the
   K/V kernel does (one grid step a slot, two pages a chunk copied into the
   lanes of one of two VMEM buffers, one product a chunk for the scores and
-  one for the values; a slot that stores into the sink is handed frontier
-  0 and costs one page); everything below (tables, the sink, prefix
-  aliasing, donation) holds for it as for K/V, the carried stack of a
-  layer scan apart: latent layers are unrolled, a pool each;
+  one for the values; a slot that stores into the sink is handed "no
+  keys", :data:`NO_KEYS`, and costs no copy and no fold); everything
+  below (tables, the sink, prefix aliasing, donation) holds for it as for
+  K/V, the carried stack of a layer scan apart: latent layers are
+  unrolled, a pool each;
 * beside the pools, for a layer with a recurrent state (Mamba, a gated
   short convolution), NO pool: its state a sequence is fixed in size, so
   the layer keeps ``ssm_state`` / ``conv_state`` leaves (a convolution layer
@@ -59,7 +60,8 @@ Pallas kernel in :mod:`.pallas_paged_attention` (a latent pool's to
 which leaves the pool in HBM and walks each row's LIVE pages only (first
 page of the band to the frontier's, read from the scalar-prefetched table
 and frontier): async copies a chunk of pages at a time into two VMEM
-buffers, one product a chunk; reserved and pad table entries are never visited
+buffers, one product a chunk; reserved and pad table entries are never visited,
+and neither is any page of a slot in which nobody decodes (:data:`NO_KEYS`)
 (under a ``shard_map`` over ``tensor`` when the pool is TP-sharded — a
 ``pallas_call`` can't be auto-partitioned). The XLA fallback (CPU, or
 head counts the tensor axis can't split) gathers ``pool[table]`` into a
@@ -113,6 +115,11 @@ _ACTIVE: Optional[PagedConfig] = None
 # mode instead of the XLA gather — CI's hook for exercising the exact
 # kernel-in-engine composition TPU serving runs, without a chip.
 FORCE_KERNEL_INTERPRET = False
+
+# The frontier the decode kernels are handed for a row that stores this step's token into the sink: below
+# zero, which the walk (:mod:`.paged_walk`) reads as "no keys". The XLA gather paths are given the row's
+# own frontier.
+NO_KEYS = -1
 
 
 def active_paged_config() -> Optional[PagedConfig]:
@@ -348,11 +355,11 @@ def paged_cached_attention(
         run = _kernel_runner(fn, q.shape[2], h_kv)
         if run is not None:  # None: TP mesh the heads can't split -> XLA path
             # A row that stores this token in the sink is idle, or finished and overshooting: nothing
-            # reads what it attends to, and its frontier has grown a step a token since clear_slot
-            # zeroed it. The kernel walks a row's pages up to the frontier it is given: one page, then.
+            # reads what it attends to. It is handed a frontier below zero, "no keys": the kernel's walk
+            # starts no copy and folds nothing for it, its output is zeros, and the row before it starts
+            # the first copies of the next row that has keys. A live row at frontier 0 (one key) is walked.
             sink = 0 if view is None else view.base
-            walk_to = jnp.where(dest == sink, 0, cur)
-            return run(q[:, 0], key_pool, value_pool, table, walk_to)[:, None]
+            return run(q[:, 0], key_pool, value_pool, table, jnp.where(dest == sink, NO_KEYS, cur))[:, None]
 
     return paged_gather_attention(
         q, key_pool, value_pool, table, cur, scale=scale, sliding_window=sliding_window
@@ -387,8 +394,10 @@ def _ring_cached_attention(module, q, k, v, row, *, scale, sliding_window, cfg: 
         )
         run = _kernel_runner(fn, q.shape[2], k.shape[2])
         if run is not None:
-            # a row that stores into the sink is idle: one page, as in paged_cached_attention
-            return run(q[:, 0], key_pool, value_pool, table, jnp.where(dest == 0, 0, cur))[:, None]
+            # a row that stores into the sink is idle: no keys, no copy and no fold, as in paged_cached_attention
+            # (said by NO_KEYS and not by a page count: a ring clamps nothing)
+            walk_to = jnp.where(dest == 0, NO_KEYS, cur)
+            return run(q[:, 0], key_pool, value_pool, table, walk_to)[:, None]
     return paged_gather_attention(q, key_pool, value_pool, table, cur, scale=scale, sliding_window=sliding_window, ring=True)
 
 
@@ -474,9 +483,9 @@ def paged_latent_attention(module, q_lat, row, max_len: int, *, value_width: int
         fn = functools.partial(latent_paged_decode, value_width=value_width, scale=scale, interpret=not on_tpu)
         run = _kernel_runner(fn, q_lat.shape[2], 1, pool_specs=(P(None, None, None),))
         if run is not None:
-            # a row that stores this token in the sink is idle, or finished and overshooting: frontier 0,
-            # one page, as in paged_cached_attention
-            return run(q_lat[:, 0], pool, table, jnp.where(dest == 0, 0, cur))[:, None]
+            # a row that stores this token in the sink is idle, or finished and overshooting: no keys, no
+            # copy and no fold, zeros out, as in paged_cached_attention
+            return run(q_lat[:, 0], pool, table, jnp.where(dest == 0, NO_KEYS, cur))[:, None]
     return paged_latent_gather_attention(q_lat, pool, table, cur, value_width=value_width, scale=scale)
 
 

@@ -15,10 +15,11 @@ one grid step a row (slot), the pool left in HBM, and inside the step a loop
 over the row's live pages ``0 .. min(cur // block_size, MB - 1)``, its trip
 count from the scalar-prefetched frontier, a chunk of pages at a time into one
 of two VMEM buffers with one async copy a page addressed through the
-scalar-prefetched table; chunk ``i + 1`` in flight while chunk ``i`` folds, row
-``b + 1``'s first chunk started before row ``b`` ends. Table entries past the
-frontier are neither visited nor fetched, and a row handed frontier 0 (an idle
-slot: ``ops/paged_kv.py``) costs one page. The fold is this kernel's own: a
+scalar-prefetched table; chunk ``i + 1`` in flight while chunk ``i`` folds, the
+first chunk of the next row that has keys started before a row ends. Table
+entries past the frontier are neither visited nor fetched, and a row handed a
+frontier below zero (a slot that stores into the sink: ``paged_kv.NO_KEYS``)
+has no keys: no copy, no fold, zeros out. The fold is this kernel's own: a
 chunk buffer is ``[W, pages * block_size]``, each page copied into its
 ``block_size`` lanes, so a chunk folds as one product for the scores
 (``[H, W] x [W, pages * bs]``) and one for the values (contracting the lanes of
@@ -104,13 +105,15 @@ def _kernel(
             )
             return m_new, l_new, acc * alpha + pv
 
-        return fold, online_softmax_init(heads, value_width)
+        return fold
 
     _, l, acc = walk_live_pages(
         tbl_ref, cur_ref, side_ref, pages=pages, block_size=block_size, window=None,
         page_copies=page_copies, zero_buffers=zero_buffers, make_fold=make_fold,
+        init=online_softmax_init(heads, value_width),
     )
-    # a live row's sum is at least 1 (its best key counts 1): the guard is for a row with nothing live
+    # a live row's sum is at least 1 (its best key counts 1): the guard is for a row with no keys (the
+    # walk's first carry: zeros out)
     o_ref[0] = (acc / jnp.maximum(l, 1.0)).astype(o_ref.dtype)
 
 
@@ -127,7 +130,8 @@ def latent_paged_decode(
 ) -> jax.Array:
     """One decode step of absorbed latent attention for every row against
     its pages: ``[B, H, value_width]`` in ``q.dtype``. The caller has
-    already stored the step's row at position ``cur``."""
+    already stored the step's row at position ``cur``; a row whose ``cur``
+    is below zero has no keys and comes back zeros."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, heads, width = q.shape
@@ -152,7 +156,7 @@ def latent_paged_decode(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, heads, value_width), q.dtype),
         grid_spec=grid_spec,
-        # rows run in order on one core: each starts the next one's first copies
+        # rows run in order on one core: each that has keys starts the first copies of the next that has
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="latent_paged_decode",

@@ -13,13 +13,17 @@ each LIVE page once and nothing else:
   that holds the band's oldest key), a *chunk* of pages at a time. Its
   trip count comes from the scalar-prefetched frontier, so table entries
   past the frontier (blocks reserved for tokens not yet decoded, pad
-  entries at the trash sink) are neither visited nor fetched, and an idle
-  row (frontier 0) costs one page;
+  entries at the trash sink) are neither visited nor fetched, and a row
+  handed a frontier below zero (a slot that stores into the sink, in
+  which nobody decodes: ``paged_kv.NO_KEYS``) has no keys: it costs no
+  copy and no fold, and its output is zeros. A row at frontier 0 has one
+  key and is walked;
 * a chunk is fetched with one async copy a page, addressed through the
   scalar-prefetched table, into one of two VMEM buffers: chunk ``i + 1``
-  is in flight while chunk ``i`` is folded, and the first chunk of row
-  ``b + 1`` is started before row ``b`` is finished, so the copies'
-  latency is paid once a call and not once a row;
+  is in flight while chunk ``i`` is folded, and before a row is finished
+  it starts the first chunk of the next row that has keys, however many
+  rows without lie between, so the copies' latency is paid once a call
+  and not once a row;
 * the fold is one MXU-shaped product a chunk with no relayout. A page
   ``[bs, H_kv, D]`` is, byte for byte, ``[bs * H_kv, D]``; ``q [H, D]``
   against a chunk of them gives scores ``[H, pages * bs * H_kv]`` in
@@ -156,14 +160,16 @@ def _kernel(
                 pv = both[:heads] + both[heads:]
             return m_new, l_new, acc * alpha + pv
 
-        return fold, online_softmax_init(heads, dim)
+        return fold
 
     _, l, acc = walk_live_pages(
         tbl_ref, cur_ref, side_ref, pages=pages, block_size=block_size, window=window,
-        page_copies=page_copies, zero_buffers=zero_buffers, make_fold=make_fold, ring=ring,
+        page_copies=page_copies, zero_buffers=zero_buffers, make_fold=make_fold, init=online_softmax_init(heads, dim),
+        ring=ring,
     )
-    # l is 0 for a row with nothing live (a long-retired slot whose windowed frontier moved past its
-    # table): its output is discarded host-side, but an unguarded 0/0 would trip jax_debug_nans
+    # l is 0 for a row with nothing live (a slot at the sink, handed no keys: the walk's first carry; a
+    # long-retired slot whose windowed frontier moved past its table): zeros out, which nothing reads, where
+    # an unguarded 0/0 would trip jax_debug_nans
     o_ref[0] = (acc / jnp.maximum(l, 1.0)).astype(o_ref.dtype)
 
 
@@ -184,7 +190,8 @@ def paged_decode_attention(
 
     Returns ``[B, H, D]`` in ``q.dtype``. The caller has already written
     the step's K/V into the pool at position ``cur`` (the engine's
-    scatter), so the frontier key is included. ``ring`` (with a
+    scatter), so the frontier key is included; a row whose ``cur`` is below
+    zero has no keys and comes back zeros. ``ring`` (with a
     ``sliding_window``): ``block_table`` is a ring of ``MB`` entries, page
     ``p`` at entry ``p % MB`` (``paged_kv`` ``window_table``); the call's
     device name then carries the window (``paged_decode_attention_w512``),
@@ -242,7 +249,7 @@ def paged_decode_attention(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, heads, dim), q.dtype),
         grid_spec=grid_spec,
-        # rows run in order on one core: each starts the next one's first copies
+        # rows run in order on one core: each that has keys starts the first copies of the next that has
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=f"paged_decode_attention_w{sliding_window}" if ring else "paged_decode_attention",

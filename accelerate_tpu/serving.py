@@ -439,6 +439,7 @@ class ServingEngine:
         self._tick_head_rows = 0  # rows of logits those prefills computed: one a bucket prefill, a window's width
         self._tick_expert_load = (0, 0, 0, 0)
         self._tick_state_idle = 0
+        self._tick_rows_skipped = 0
         self._pool_blocked = False  # last admit pass hit pool exhaustion
         self.bucket_compile_ms: dict = {}  # (kind, bucket) -> build wall ms
         # registered shared prefixes: id -> {"len", "cache", "tokens"}
@@ -1206,6 +1207,7 @@ class ServingEngine:
         leaves_signed_was = self._pc.leaves_signed
         self._tick_expert_load = (0, 0, 0, 0)
         self._tick_state_idle = 0
+        self._tick_rows_skipped = 0
         self._tick_windows = (0, 0, 0, 0)
         self._tick_window_rows = 0
         with phase("engine.schedule"):
@@ -1283,6 +1285,7 @@ class ServingEngine:
             queue_len=len(self.queue), experts_touched=self._tick_expert_load[0],
             expert_pairs_max=self._tick_expert_load[1], expert_tile_visits=self._tick_expert_load[2],
             expert_pairs=self._tick_expert_load[3], state_slots_idle=self._tick_state_idle,
+            attention_rows_skipped=self._tick_rows_skipped,
             attn_rows_read=self._tick_windows[0], context_rows=self._tick_windows[1], chunks_pooled=self._tick_windows[2],
             windows_closed=self._tick_windows[3], exact_pages=pages[0], summary_pages=pages[1],
             window_rows_read=self._tick_window_rows, full_pages=by_kind[0], window_pages=by_kind[1],
@@ -1703,6 +1706,11 @@ class ServingEngine:
         self._tick_first_deferred = len(self._first_pending)
         self.metrics.on_first_tokens_deferred(self._tick_first_deferred)
         self._read_first_tokens()
+        if self.paged:
+            # every slot that does not decode (free, or waiting on a prefill's paste) has its table row at the sink:
+            # this many rows of the tick's steps the paged decode kernels walk no page for (``paged_kv.NO_KEYS``)
+            self._tick_rows_skipped = (self.num_slots - n_decoding) * self.tick_block
+            self.metrics.on_attention_rows_skipped(self._tick_rows_skipped)
         if self._steps_idle_state:
             # the tick steps every slot's recurrent state (a state-space layer's step kernel is told which slots
             # decode and visits no other: then nothing is counted); this many slot-steps of it decode nothing

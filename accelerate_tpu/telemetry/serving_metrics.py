@@ -143,6 +143,10 @@ class ServingMetrics:
         # layer is told which slots decode and visits no other: 0 then)
         self.state_bytes_per_slot = 0
         self.state_slots_idle = 0
+        # paged attention: the rows of the decode ticks' steps that stood in slots whose table row was at
+        # the sink (nobody decodes there), which the paged decode kernels are handed "no keys" for and
+        # walk no page of (the XLA gather off the chip still reads them); 0 in the dense layout
+        self.attention_rows_skipped = 0
         # aligned windows (EVA attention; 0 otherwise), from the decode
         # ticks' kept steps: the rows of keys the steps attended to (a
         # summary for every chunk of the closed windows, the open window's
@@ -250,6 +254,9 @@ class ServingMetrics:
 
     def on_state_step(self, slots_idle: int):
         self.state_slots_idle += slots_idle
+
+    def on_attention_rows_skipped(self, rows: int):
+        self.attention_rows_skipped += rows
 
     def on_window_attention(self, rows_read: int, context_rows: int, chunks_pooled: int, windows_closed: int):
         self.attn_rows_read += rows_read

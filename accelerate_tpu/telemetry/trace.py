@@ -103,10 +103,13 @@ SEGMENTS = (
 #: a model that heads the row it is asked for; tokens, the pool,
 #: the routed experts' load (``experts_touched``, ``expert_pairs_max``,
 #: ``expert_tile_visits`` and ``expert_pairs``, the token-expert pairs
-#: the grouped products multiplied: the decoding slots' alone) and
+#: the grouped products multiplied: the decoding slots' alone),
 #: ``state_slots_idle``, the slot-steps of recurrent state the tick
 #: spent on slots in which no request decodes: 0 where a state-space
-#: layer's step kernel is told which slots decode and visits no other);
+#: layer's step kernel is told which slots decode and visits no other)
+#: and ``attention_rows_skipped``, the rows of the tick's steps in slots
+#: whose table row is at the sink, which the paged decode kernels walk
+#: no page for);
 #: the spans of one request share ``uid``. No name equals a span of the
 #: benchmark's own. Inside the programs, ``jax.named_scope`` names ride
 #: in the device operations' ``op_name``: ``moe.*``, ``mla.absorb``, and

@@ -125,6 +125,42 @@ def test_interpreted_kernel_over_the_gathered_table_is_the_gather_path():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
 
 
+@pytest.mark.parametrize("grown", [8, 70, 200], ids=["first_window", "past_two_closes", "past_the_context"])
+def test_a_slot_at_the_sink_is_handed_no_keys(monkeypatch, grown):
+    """``eva_paged_attention``: an idle slot's tables are the sink's, its row is stored there, and the kernel is handed
+    ``paged_kv.NO_KEYS`` in place of the gathered table's frontier, whatever the index has grown to: the sink is NaN but
+    for the row just stored, the slot's output zeros, the slot beside it (past two closes) what the gather path gives
+    for it over a clean sink."""
+    import flax.linen as nn
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, q, k, v, mu, phi):
+            cfg = paged_kv.PagedConfig(4, 40)
+            return eva_attention.eva_paged_attention(self, q, k, v, mu, phi, 96, window=32, chunk=4, scale=0.25, cfg=cfg)
+
+    rng = np.random.default_rng(2)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 1, 8, 16)), jnp.float32) for _ in range(3))
+    mu, phi = (jnp.asarray(rng.normal(size=(8, 16)), jnp.float32) for _ in range(2))
+    block_table = np.zeros((2, 24), np.int32)
+    block_table[0] = rng.permutation(np.arange(1, 25))
+    summary_table = np.zeros((2, eva_attention.summary_pages(96, 4, 4)), np.int32)
+    summary_table[0] = np.arange(33, 33 + summary_table.shape[1])
+
+    def cache(sink):
+        pools = [jnp.asarray(rng_.normal(size=(40, 4, 8, 16)), jnp.float32).at[0].set(sink) for rng_ in (np.random.default_rng(3), np.random.default_rng(4))]
+        return {"key_pool": pools[0], "value_pool": pools[1], "block_table": jnp.asarray(block_table), "summary_table": jnp.asarray(summary_table),
+                "index": jnp.asarray([70, grown], jnp.int32)}
+
+    monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", True)
+    out, new = Layer().apply({"cache": cache(jnp.nan)}, q, k, v, mu, phi, mutable=["cache"])
+    np.testing.assert_array_equal(np.asarray(new["cache"]["key_pool"][0, grown % 4]), np.asarray(k[1, 0]))  # stored in the sink
+    assert not np.asarray(out[1]).any(), "a slot at the sink reads nothing and returns zeros"
+    monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", False)
+    want, _ = Layer().apply({"cache": cache(0.0)}, q, k, v, mu, phi, mutable=["cache"])
+    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(want[0]), atol=2e-6)
+
+
 @pytest.mark.parametrize("total,max_new,exact,summary", [
     (5, 10, 4, 0),      # positions 5..13 kept: pages 0..3 of window 0, no window closed
     (5, 40, 8, 2),      # crosses into window 1: a whole window of pages, and window 0's summaries are read

@@ -4,6 +4,7 @@ engine's paged layout **a pool and a table a kind of layer**: the window layers'
 ``window_table``, ``ops/paged_walk.py`` ``ring``), the allocator a kind, the counts, and every refusal by its message."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +113,68 @@ def test_ring_kernel_interpreted_is_the_gather(heads, kv_heads):
     np.testing.assert_allclose(np.asarray(got)[4], by_hand, atol=2e-6)
     with pytest.raises(ValueError, match="ring=True needs sliding_window"):
         paged_decode_attention(q, key_pool, value_pool, table, cur, interpret=True, ring=True)
+
+
+@pytest.mark.parametrize("curs", [[-1, 41, 7], [41, 7, -1], [41, -1, -1, 16, 2], [-1, -1, -1], [-1, 0, -1, 41]],
+                         ids=["first", "last", "two_in_a_row_between", "every_row", "beside_a_row_of_one_key"])
+def test_ring_kernel_walks_no_row_that_has_no_keys(curs):
+    """Under a band through the ring, where nothing clamps a frontier: a row handed -1 (a slot that stores into the
+    sink, ``paged_kv.NO_KEYS``) starts no copy and returns zeros, with the sink and every block of its own NaN; the rows
+    beside it are to the bit what they are with those rows at frontier 0 and what they are alone, and are the gather's."""
+    from accelerate_tpu.ops.pallas_paged_attention import paged_decode_attention
+
+    rng = np.random.default_rng(4)
+    slots, heads, kv_heads, dim, ring = len(curs), 6, 2, 16, 4
+    blocks = 1 + slots * ring
+    table = 1 + np.arange(slots * ring).reshape(slots, ring)
+    skipped = [i for i, c in enumerate(curs) if c < 0]
+    live = [i for i, c in enumerate(curs) if c >= 0]
+    table[skipped] = 0
+    pools = [rng.normal(size=(blocks, BLOCK, kv_heads, dim)).astype(np.float32) for _ in range(2)]
+    clean = [jnp.asarray(pool) for pool in pools]
+    for pool in pools:
+        pool[0] = np.nan
+        pool[[1 + i * ring + e for i in skipped for e in range(ring)]] = np.nan
+    key_pool, value_pool = (jnp.asarray(pool) for pool in pools)
+    table, cur = jnp.asarray(table, jnp.int32), jnp.asarray(curs, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(slots, heads, dim)), jnp.float32)
+    run = functools.partial(paged_decode_attention, sliding_window=WINDOW, scale=0.25, interpret=True, ring=True)
+    got = np.asarray(run(q, key_pool, value_pool, table, cur))
+    assert not got[skipped].any() and np.isfinite(got).all()
+    if live:
+        at = jnp.asarray(live)
+        np.testing.assert_array_equal(got[live], np.asarray(run(q, *clean, table, jnp.maximum(cur, 0)))[live])
+        np.testing.assert_array_equal(got[live], np.asarray(run(q[at], key_pool, value_pool, table[at], cur[at])))
+        want = paged_kv.paged_gather_attention(q[at][:, None], *clean, table[at], cur[at], scale=0.25, sliding_window=WINDOW, ring=True)
+        np.testing.assert_allclose(got[live], np.asarray(want)[:, 0], atol=2e-6)
+
+
+@pytest.mark.parametrize("grown", [5, 41, 300], ids=["first_turn", "past_two_turns", "past_the_context"])
+def test_a_slot_at_the_sinks_ring_is_handed_no_keys(monkeypatch, grown):
+    """``_ring_cached_attention``: an idle slot's index grows a token a step and its ring is the sink's, so its row is
+    stored in the sink and the kernel is handed ``NO_KEYS`` for it, whatever the index has grown to: the sink is NaN but
+    for the row just stored, the slot's output zeros, the slot beside it the gather's."""
+    import flax.linen as nn
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, q, k, v):
+            cfg = PagedConfig(BLOCK, 9, window_ring=4, window_blocks=9)
+            return paged_kv.paged_cached_attention(self, q, k, v, 128, scale=0.25, sliding_window=WINDOW, cfg=cfg)
+
+    monkeypatch.setattr(paged_kv, "FORCE_KERNEL_INTERPRET", True)
+    rng = np.random.default_rng(5)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 1, h, 16)), jnp.float32) for h in (6, 2, 2))
+    pools = [jnp.asarray(rng.normal(size=(9, BLOCK, 2, 16)), jnp.float32).at[0].set(jnp.nan) for _ in range(2)]
+    cache = {"key_pool": pools[0], "value_pool": pools[1], "window_table": jnp.asarray([[3, 1, 4, 2], [0, 0, 0, 0]], jnp.int32),
+             "index": jnp.asarray([21, grown], jnp.int32)}
+    out, new = Layer().apply({"cache": cache}, q, k, v, mutable=["cache"])
+    new = new["cache"]
+    np.testing.assert_array_equal(np.asarray(new["key_pool"][0, grown % BLOCK]), np.asarray(k[1, 0]))  # stored in the sink
+    want = paged_kv.paged_gather_attention(q[:1], new["key_pool"].at[0].set(0), new["value_pool"].at[0].set(0), cache["window_table"][:1],
+                                           jnp.asarray([21]), scale=0.25, sliding_window=WINDOW, ring=True)
+    np.testing.assert_allclose(np.asarray(out[:1]), np.asarray(want), atol=2e-6)
+    assert not np.asarray(out[1]).any(), "a slot at the sink reads nothing and returns zeros"
 
 
 def test_ring_entries_are_the_pages_a_band_spans_and_one():

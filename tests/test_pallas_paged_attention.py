@@ -3,15 +3,22 @@ XLA gather reference, in Pallas interpret mode on CPU: MHA/GQA, ragged
 per-row frontiers, trash-sink pad entries, sliding-window bands, bf16
 inputs, and the walk itself: frontiers at page and chunk edges, rows of
 one chunk and of several, pages it must never read (reserved past the
-frontier, or before the band) holding NaN, an idle row between long ones,
+frontier, or before the band) holding NaN, a row of one key between long ones,
+rows handed "no keys" (a frontier below zero: no copy, no fold, zeros out, the
+rows beside them to the bit what they are without them), the copies the walk
+starts and waits for counted one by one,
 a flattened layer stack addressed as ``table + layer * NB``, and a pool whose
 heads are under the lane width, folded two to a row of 128 lanes (LFM2's
 head size 64: ``paged_kv.pool_lane_fold``)."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from jax.experimental import pallas as pl
 
 from accelerate_tpu.ops.pallas_paged_attention import _pages_per_chunk, paged_decode_attention
 
@@ -119,15 +126,29 @@ def test_window_excludes_old_pages_exactly():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
-def _walk(curs, *, h, hkv, d, bs, mb, window=None, reserve=0, poison=(), layers=1, layer=0, dtype=jnp.float32, fold=1):
-    """Rows with the frontiers ``curs`` (None: an idle row, frontier 0, every entry at the sink), each
+NO_KEYS = "no-keys"  # a row of ``curs`` the kernel is handed a frontier below zero for: a slot that stores into the sink
+
+
+def _walk(curs, *, h, hkv, d, bs, mb, window=None, reserve=0, poison=(), layers=1, layer=0, dtype=jnp.float32, fold=1,
+          no_keys_as=-1):
+    """Rows with the frontiers ``curs`` (None: a row of one key, frontier 0, every entry at the sink;
+    ``NO_KEYS``: a row at the sink handed the frontier ``no_keys_as``, its reference zeros), each
     holding real blocks for ``reserve`` tokens past its frontier as the engine reserves prompt + max_new.
     ``poison`` fills with NaN what the kernel must never fold: ``"reserved"`` the blocks wholly past a
-    frontier, ``"before_band"`` those wholly before the window's band. With ``layers`` > 1 the pool is a
+    frontier, ``"before_band"`` those wholly before the window's band, ``"unwalked"`` the sink and every
+    block no row holds. With ``layers`` > 1 the pool is a
     flattened stack and the rows address ``table + layer * NB``; the other layers hold NaN throughout.
     With ``fold`` > 1 the kernel is handed the pools lane-folded, ``[NB, bs, hkv / fold, fold * d]`` (the
     same bytes), as ``paged_kv.pool_lane_fold`` declares them; the reference reads them unfolded.
     Returns the kernel's output and the reference's."""
+    (q, kp, vp, tbl, cur), want = _walk_inputs(
+        curs, h=h, hkv=hkv, d=d, bs=bs, mb=mb, window=window, reserve=reserve, poison=poison, layers=layers, layer=layer,
+        dtype=dtype, fold=fold, no_keys_as=no_keys_as)
+    return paged_decode_attention(q, kp, vp, tbl, cur, sliding_window=window, interpret=True), want
+
+
+def _walk_inputs(curs, *, h, hkv, d, bs, mb, window, reserve, poison, layers, layer, dtype, fold, no_keys_as):
+    """:func:`_walk`'s arguments of the kernel ``(q, key pool, value pool, table, frontiers)`` and the reference."""
     b = len(curs)
     nb = b * mb + 1
     keys = jax.random.split(jax.random.PRNGKey(5), 3)
@@ -140,6 +161,9 @@ def _walk(curs, *, h, hkv, d, bs, mb, window=None, reserve=0, poison=(), layers=
     for i, c in enumerate(curs):
         if c is None:
             continue
+        if c is NO_KEYS:
+            cur[i] = no_keys_as
+            continue
         cur[i] = c
         live_pages = c // bs + 1
         held = min(mb, (c + reserve) // bs + 1)
@@ -149,9 +173,12 @@ def _walk(curs, *, h, hkv, d, bs, mb, window=None, reserve=0, poison=(), layers=
         if "before_band" in poison:
             dead = max(c - window + 1, 0) // bs
             kp[tbl[i, :dead]] = vp[tbl[i, :dead]] = np.nan
+    if "unwalked" in poison:
+        kp[[0] + free] = vp[[0] + free] = np.nan
     tbl, cur = jnp.asarray(tbl), jnp.asarray(cur)
     kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
-    want = _reference(q, kp, vp, tbl, cur, window=window)
+    want = _reference(q, kp, vp, tbl, jnp.maximum(cur, 0), window=window)
+    want = jnp.where(jnp.asarray([c is NO_KEYS for c in curs])[:, None, None], 0, want)
     if layers > 1:
         stack = jnp.full((layers, nb, bs, hkv, d), jnp.nan, dtype)
         kp = stack.at[layer].set(kp).reshape(layers * nb, bs, hkv, d)
@@ -159,7 +186,7 @@ def _walk(curs, *, h, hkv, d, bs, mb, window=None, reserve=0, poison=(), layers=
         tbl = tbl + layer * nb
     if fold > 1:
         kp, vp = (x.reshape(x.shape[0], bs, hkv // fold, fold * d) for x in (kp, vp))
-    return paged_decode_attention(q, kp, vp, tbl, cur, sliding_window=window, interpret=True), want
+    return (q, kp, vp, tbl, cur), want
 
 
 # float32 pages of 16 tokens x 2 heads are whole tiles: a chunk is 16 pages, 256 tokens
@@ -178,6 +205,9 @@ WALKS = [
     ),
     pytest.param([500, None, 333], CHUNKED, id="idle-row-between-two-long-rows"),
     pytest.param([None, None], CHUNKED, id="every-row-idle"),
+    pytest.param([500, NO_KEYS, 333], dict(CHUNKED, poison=("unwalked",)), id="row-with-no-keys-between-two-long-rows-the-sink-nan"),
+    pytest.param([NO_KEYS, NO_KEYS], dict(CHUNKED, poison=("unwalked",)), id="every-row-with-no-keys"),
+    pytest.param([NO_KEYS, 270, None], dict(CHUNKED, layers=2, layer=1), id="row-with-no-keys-in-the-second-layer-of-a-stack"),
     pytest.param([270, 40], dict(CHUNKED, layers=2, layer=1, reserve=30, poison=("reserved",)), id="second-layer-of-a-flattened-stack"),
     pytest.param([270, 17, None], dict(h=4, hkv=1, d=32, bs=8, mb=40), id="one-kv-head"),
     pytest.param([270, 17, None], dict(h=2, hkv=2, d=32, bs=16, mb=20), id="one-query-head-a-kv-head"),
@@ -196,6 +226,131 @@ def test_walk_follows_the_live_pages(curs, shape):
     out, want = _walk(curs, **shape)
     assert np.isfinite(np.asarray(out)).all(), "a page that is not live reached the fold"
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+SKIPS = [
+    pytest.param([NO_KEYS, 500, 333], CHUNKED, id="first-row"),
+    pytest.param([500, 333, NO_KEYS], CHUNKED, id="last-row"),
+    pytest.param([500, NO_KEYS, NO_KEYS, 333, 20], CHUNKED, id="two-in-a-row-between-long-rows"),
+    pytest.param([NO_KEYS, NO_KEYS, 270, NO_KEYS, 639, NO_KEYS], dict(CHUNKED, reserve=40), id="around-every-live-row"),
+    pytest.param([NO_KEYS, NO_KEYS, NO_KEYS], CHUNKED, id="every-row"),
+    pytest.param([400, NO_KEYS, 620, NO_KEYS, 130], dict(CHUNKED, window=200, reserve=20), id="under-a-band"),
+    pytest.param([300, NO_KEYS, NO_KEYS, 639, NO_KEYS, 16], dict(HEAD64, reserve=200), id="head64-folded"),
+    pytest.param([NO_KEYS, 0, NO_KEYS, 300, 0], CHUNKED, id="a-live-row-of-one-key-is-still-walked"),
+    pytest.param([21, NO_KEYS, 9, NO_KEYS], dict(h=2, hkv=1, d=16, bs=4, mb=8, reserve=6), id="one-page-a-chunk"),
+]
+
+
+@pytest.mark.parametrize("curs,shape", SKIPS)
+def test_a_row_with_no_keys_is_not_walked(curs, shape):
+    """A row handed a frontier below zero (a slot that stores into the sink: ``paged_kv.NO_KEYS``) starts no
+    copy and folds nothing: the sink page and every block no row holds are NaN and its output is zeros.
+    The rows beside it are, to the bit, what the same call gives with those rows at frontier 0 (what the
+    callers handed the kernel before) and what a call of the live rows alone gives."""
+    live = [i for i, c in enumerate(curs) if c is not NO_KEYS]
+    shape = dict(dict(window=None, reserve=0, layers=1, layer=0, dtype=jnp.float32, fold=1), **shape)
+    (q, kp, vp, tbl, cur), want = _walk_inputs(curs, poison=("unwalked", "reserved"), no_keys_as=-1, **shape)
+    run = functools.partial(paged_decode_attention, sliding_window=shape["window"], interpret=True)
+    out = np.asarray(run(q, kp, vp, tbl, cur))
+    assert not out[[i for i in range(len(curs)) if i not in live]].any(), "a row with no keys returns zeros"
+    assert np.isfinite(out).all(), "a page that is not live reached the fold"
+    np.testing.assert_allclose(out, np.asarray(want), atol=2e-5, rtol=2e-5)
+    if 0 in curs:
+        assert np.abs(out[curs.index(0)]).max() > 1e-3, "a live row at frontier 0 attends to its one key"
+    if live:
+        at_frontier_zero = run(q, kp.at[0].set(1.0), vp.at[0].set(1.0), tbl, jnp.maximum(cur, 0))
+        np.testing.assert_array_equal(out[live], np.asarray(at_frontier_zero)[live])
+        alone = run(q[jnp.asarray(live)], kp, vp, tbl[jnp.asarray(live)], cur[jnp.asarray(live)])
+        np.testing.assert_array_equal(out[live], np.asarray(alone))
+
+
+def _copies_of_a_walk(curs, *, pages, bs, mb, window=None, ring=False):
+    """Run :func:`paged_walk.walk_live_pages` alone over rows with the frontiers ``curs`` (their table
+    entry ``e`` names block ``1000 * row + e + 1``), with copies that do nothing but count: the blocks
+    started in order, and how many copies were started and waited for on each buffer."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from accelerate_tpu.ops.paged_walk import walk_live_pages
+
+    b, room = len(curs), 4096
+
+    class Counted:
+        def __init__(self, refs, page, side):
+            self.refs, self.page, self.side = refs, page, side
+
+        def start(self):
+            log, n, started, _ = self.refs
+            log[n[0]] = self.page
+            n[0] += 1
+            started[self.side] += 1
+
+        def wait(self):
+            self.refs[3][self.side] += 1
+
+    def kernel(tbl_ref, cur_ref, log, n, started, waited, folds, side_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            n[0] = 0
+            folds[0] = 0
+            for side in range(2):
+                started[side] = waited[side] = 0
+
+        def make_fold(cur, first):
+            def fold(j, side, carry):
+                folds[0] += 1
+                return carry + 1
+
+            return fold
+
+        walk_live_pages(
+            tbl_ref, cur_ref, side_ref, pages=pages, block_size=bs, window=window, ring=ring, init=jnp.int32(0),
+            page_copies=lambda page, side, i: (Counted((log, n, started, waited), page, side),),
+            zero_buffers=lambda: None, make_fold=make_fold,
+        )
+
+    tbl = 1000 * np.arange(b)[:, None] + np.arange(mb)[None, :] + 1
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    log, n, started, waited, folds = pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct(s, jnp.int32) for s in ((room,), (1,), (2,), (2,), (1,))],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,), in_specs=[], out_specs=[smem] * 5,
+            scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        ),
+        interpret=True,
+    )(jnp.asarray(tbl, jnp.int32), jnp.asarray(curs, jnp.int32))
+    return [int(x) for x in log[: int(n[0])]], np.asarray(started), np.asarray(waited), int(folds[0])
+
+
+COPIES = [
+    pytest.param([40, -1, -1, 7, -1], dict(pages=2, bs=4, mb=16), id="rows-without-keys-between-and-after"),
+    pytest.param([-1, -1, 3, 100], dict(pages=2, bs=4, mb=16), id="rows-without-keys-first-and-a-frontier-past-the-table"),
+    pytest.param([-1, -1, -1], dict(pages=2, bs=4, mb=16), id="every-row-without-keys"),
+    pytest.param([0, -1, 0], dict(pages=2, bs=4, mb=16), id="rows-of-one-key"),
+    pytest.param([90, -1, 33, -1, -1, 200], dict(pages=3, bs=4, mb=9, window=20, ring=True), id="a-band-through-a-ring"),
+    pytest.param([70, -1, 90], dict(pages=2, bs=4, mb=16, window=8), id="a-band-that-left-its-table-has-no-pages"),
+]
+
+
+@pytest.mark.parametrize("curs,walk", COPIES)
+def test_every_copy_the_walk_starts_is_a_live_page_and_is_waited_for(curs, walk):
+    """The walk's copies counted one by one: the blocks started are exactly the live pages of the rows that
+    have keys, row by row in order, every one waited for on the buffer it was started on, one fold a chunk;
+    a row handed a frontier below zero starts none, and a call of such rows alone starts none at all."""
+    pages, bs, mb, window, ring = walk["pages"], walk["bs"], walk["mb"], walk.get("window"), walk.get("ring", False)
+    want, chunks = [], 0
+    for row, cur in enumerate(curs):
+        if cur < 0:
+            continue
+        last = cur // bs if ring else min(cur // bs, mb - 1)
+        first = 0 if window is None else max(cur - window + 1, 0) // bs
+        held = range(first, last + 1)
+        want += [1000 * row + (p % mb if ring else p) + 1 for p in held]
+        chunks += -(-len(held) // pages)
+    log, started, waited, folds = _copies_of_a_walk(curs, **walk)
+    assert log == want
+    np.testing.assert_array_equal(started, waited)
+    assert started.sum() == len(want) and folds == chunks
 
 
 def test_cell_widths_bf16():

@@ -199,11 +199,12 @@ def test_kernel_in_engine_matches_dense(tiny_llama):
         np.testing.assert_array_equal(d, g)
 
 
-def test_idle_rows_hand_the_kernel_frontier_zero(monkeypatch):
+def test_idle_rows_hand_the_kernel_no_keys(monkeypatch):
     """The static tick computes every slot, so an idle slot's frontier grows a step a token after
-    ``clear_slot`` zeroed it, its whole row at the sink. The kernel walks a row up to the frontier it
-    is given: a row that stores its token in the sink (idle, or finished and overshooting) is given 0
-    and costs one page; a live row keeps its own frontier and its result."""
+    ``clear_slot`` zeroed it, its whole row at the sink. A row that stores its token in the sink (idle, or
+    finished and overshooting) is handed ``NO_KEYS``, a frontier below zero: the kernel walks no page of it
+    (the sink holds NaN but for the rows the step stored) and its output is zeros; a live row keeps its own
+    frontier and its result. The gather path is given every row's own frontier, as before."""
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -240,9 +241,68 @@ def test_idle_rows_hand_the_kernel_frontier_zero(monkeypatch):
 
     monkeypatch.setattr(kernel_file, "paged_decode_attention", spy)
     monkeypatch.setattr(pkv, "FORCE_KERNEL_INTERPRET", True)
-    got, _ = Attend().apply({"cache": cache}, q, k, v, mutable=["cache"])
-    np.testing.assert_array_equal(seen[0], [6, 0, 0])
+    poisoned = dict(cache, key_pool=cache["key_pool"].at[0].set(jnp.nan), value_pool=cache["value_pool"].at[0].set(jnp.nan))
+    got, _ = Attend().apply({"cache": poisoned}, q, k, v, mutable=["cache"])
+    np.testing.assert_array_equal(seen[0], [6, pkv.NO_KEYS, pkv.NO_KEYS])
+    assert pkv.NO_KEYS < 0
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got[1:]).any(), "a row at the sink reads nothing and returns zeros"
+
+
+@pytest.mark.parametrize("family", ["llama_scanned", "llama_unrolled", "routed_experts"])
+def test_idle_slots_beside_decoding_ones_change_no_token_and_are_counted(family, monkeypatch):
+    """Through ``ServingEngine`` with the kernel interpreted: one, two and three requests of different lengths in four
+    slots, so every tick has slots at the sink beside decoding ones, a slot retires mid-tick and overshoots, and a freed
+    slot is taken again. Handed ``NO_KEYS`` for the slots at the sink the engine serves, to the bit, the tokens and
+    logprobs it serves with those slots at frontier 0 (what the callers handed the kernel before), and the gather path's
+    tokens. ``attention_rows_skipped`` of ``engine.tick.done`` is, tick by tick, (slots - decoding) x ``tick_block``,
+    and ``ServingMetrics`` sums it; the dense layout counts nothing."""
+    import accelerate_tpu.ops.paged_kv as pkv
+    from accelerate_tpu.telemetry.trace import phase_log
+
+    if family == "routed_experts":
+        from accelerate_tpu.models.lfm2_moe import Lfm2MoeConfig, create_lfm2_moe_model
+
+        model = create_lfm2_moe_model(Lfm2MoeConfig.tiny(), seed=3, seq_len=16)
+    else:
+        model = create_llama_model(LlamaConfig.tiny(scan_layers=family == "llama_scanned"), seed=0, seq_len=8)
+    prompts = [np.arange(2, 2 + n, dtype=np.int32) for n in (5, 3, 7)]
+    new_tokens = (9, 3, 6)
+
+    def serve(**kwargs):
+        eng = ServingEngine(model, num_slots=4, prompt_buckets=(8,), max_len=32, tick_block=2, **kwargs)
+        uids = [eng.submit(prompts[0], max_new_tokens=new_tokens[0])]
+        ticks, passes, decode_pass = [], [], eng._decode_pass
+        # the slots that decode when a tick's decode program is dispatched (None: a tick without one)
+        eng._decode_pass = lambda: (passes.append(int(eng._decoding_slots().sum())), decode_pass())
+        while eng.queue or eng.active_count:
+            if eng._tick == 2:  # two more arrive while the first decodes
+                uids += [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts[1:], new_tokens[1:])]
+            passes.clear()
+            eng.step()
+            ticks.append((passes[0] if passes else None, phase_log().roots("engine.tick", n=1)[0].done["attention_rows_skipped"]))
+        return eng, [eng.poll(u) for u in uids], [eng.logprobs(u) for u in uids], ticks
+
+    monkeypatch.setattr(pkv, "FORCE_KERNEL_INTERPRET", True)
+    eng, tokens, lps, ticks = serve(paged_block_size=4)
+    assert all(len(t) == len(p) + n for t, p, n in zip(tokens, prompts, new_tokens))
+    for decoding, skipped in ticks:
+        assert skipped == (0 if decoding is None else (4 - decoding) * 2)
+    assert {d for d, _ in ticks} >= {1, 2, 3}, "the occupancy the test fixes: one, two and three slots decoding of four"
+    assert eng.metrics.attention_rows_skipped == sum(s for _, s in ticks) > 0
+    monkeypatch.setattr(pkv, "NO_KEYS", 0)  # the parent's callers: an idle slot walks the sink's first page
+    _, tokens_before, lps_before, _ = serve(paged_block_size=4)
+    for a, b, la, lb in zip(tokens, tokens_before, lps, lps_before):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(la, lb)
+    monkeypatch.setattr(pkv, "FORCE_KERNEL_INTERPRET", False)
+    _, tokens_gather, lps_gather, _ = serve(paged_block_size=4)
+    for a, b, la, lb in zip(tokens, tokens_gather, lps, lps_gather):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_allclose(la, lb, atol=2e-5)
+    if family != "routed_experts":  # which the dense layout does not serve
+        dense, _, _, dense_ticks = serve()
+        assert dense.metrics.attention_rows_skipped == 0 and {s for _, s in dense_ticks} == {0}
 
 
 def test_kernel_in_engine_tp_sharded(tiny_llama):

@@ -138,19 +138,21 @@ def _mosaic_programs(text, name):
 @pytest.mark.parametrize(
     "slots,heads,kv_heads,blocks,table,window,digest",
     [
-        (32, 32, 8, 16 * 2048, 256, 4096, "465c74c7d5cf0c59afd2f67560f370d46a3493f33ca0c4b3da949e537b225db6"),
-        (128, 20, 1, 18433, 144, None, "77ee8c8ef5ce4a187ebbf7d62315cc78917ca272c515fe9817c022d000390b78"),
+        (32, 32, 8, 16 * 2048, 256, 4096, "02ded84ae5a6f0cdb15bb556e2491af1e1ca6984d5f1956a7be39be1bf373450"),
+        (128, 20, 1, 18433, 144, None, "2ac1ae1144392aedae1523ad0b613ce3837f577d52cbc87e25dceb3e69fa765f"),
     ],
     ids=["chat_cell", "longanswer_cell"],
 )
-def test_paged_decode_attention_is_the_program_it_was_before_the_walk_was_shared(
+def test_paged_decode_attention_is_the_program_its_cells_were_measured_with(
     v5e, slots, heads, kv_heads, blocks, table, window, digest
 ):
-    """PR 32 moved the walk over a slot's live pages into ``ops/paged_walk.py`` for the latent kernel to
-    share, on the condition that the K/V kernel's cells pay nothing for it: at the shapes of
-    ``mistral7b-serve-chat`` and ``jamba2-3b-serve-longanswer`` the kernel's Mosaic program, source
-    locations apart, is the one PR 31's tree compiled (the digests are of that tree's). A change to the
-    walk that moves this is a change to the K/V kernel: measure those cells, then take the new digest."""
+    """The walk over a slot's live pages is shared code (``ops/paged_walk.py``: the K/V kernel under two device
+    names and the latent kernel), so a change to it for one cell's sake is a change to every serve cell's kernel.
+    At the shapes of ``mistral7b-serve-chat`` and ``jamba2-3b-serve-longanswer`` the K/V kernel's Mosaic program,
+    source locations apart, is pinned: PR 32 moved the walk out of the kernel's file and left the program PR 31's
+    tree compiled; PR 50 changed it on purpose (a row with a frontier below zero takes no turn, and a row starts
+    the first copies of the next row that has keys) and measured the cells: the digests are of that tree's. A
+    change to the walk that moves this is a change to the K/V kernel: measure those cells, then take the new digest."""
     import hashlib
 
     from accelerate_tpu.ops.pallas_paged_attention import paged_decode_attention
